@@ -190,7 +190,7 @@ struct Predictor {
 // Ensure the interpreter is up; returns a GIL guard state.
 bool ensure_python(PyGILState_STATE* state) {
   if (!Py_IsInitialized()) {
-    // Embedded start: inherit env (MXNET_TPU_PLATFORM etc.)
+    // Embedded start: inherit env (JAX_PLATFORMS etc.)
     Py_InitializeEx(0);
     if (!Py_IsInitialized()) {
       set_last_error("failed to initialize embedded python");

@@ -1,0 +1,550 @@
+#!/usr/bin/env python
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py             # on a machine with a TPU
+    python chip_smoke.py --tiny-cpu  # rehearsal here: tiny shapes,
+                                     # interpret-mode kernels; proves
+                                     # nothing about the device
+
+One process, which touches JAX itself and starts no other. Phases:
+
+``train``    the north-star path through its own entry point —
+             ``examples/train_imagenet.py`` -> ``common/fit.py`` ->
+             ``Module.fit`` -> the fused ``Executor.train_step`` — at full
+             width: ResNet-50, 1000 classes, 3x224x224, batch 32, fp32,
+             SGD-momentum, kvstore ``device``, one chip, one synthetic
+             batch repeated.
+``kernels``  every exported Pallas kernel compiled by Mosaic at the
+             shapes its production caller uses, against its lax twin.
+``dp4``      the same path data-parallel over four chips (global batch
+             128), when four are visible; otherwise reported as skipped.
+
+Each phase prints one status line. Any failure raises: no phase catches
+an exception and carries on. The last line of standard output is one
+JSON object, ``{"ok": true, "device": {...}}``, with the device as JAX
+reports it. Exit code 0 only when every phase that could run passed, on an
+accelerator (or in the explicit rehearsal, whose verdict says so). Walls
+are printed as information; no number here is a measurement claim.
+"""
+import argparse
+import importlib
+import json
+import math
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def _parse():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--tiny-cpu", action="store_true",
+                    help="rehearse on CPU at tiny shapes with interpreted "
+                         "kernels (proves nothing about the device)")
+    ap.add_argument("--phases", default="train,kernels,dp4",
+                    help="comma list of phases to run (debugging aid that "
+                         "saves chip time; a partial run never prints the "
+                         "final ok verdict)")
+    args = ap.parse_args()
+    args.phases = args.phases.split(",")
+    unknown = set(args.phases) - {"train", "kernels", "dp4"}
+    if unknown:
+        ap.error("unknown phase(s): %s" % sorted(unknown))
+    return args
+
+
+ARGS = _parse()
+if ARGS.tiny_cpu:
+    # explicit rehearsal: force the platform BEFORE jax is imported, with
+    # enough virtual host devices for the dp4 phase
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=8").strip()
+
+sys.path.insert(0, HERE)
+sys.path.insert(0, os.path.join(HERE, "examples"))
+try:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import mxnet_tpu as mx
+    import train_imagenet
+except ImportError as e:
+    sys.exit("chip_smoke: cannot import the program next to this script "
+             "(%s); run it from a checkout of the repository" % e)
+
+from mxnet_tpu import programs, telemetry                    # noqa: E402
+
+TINY = ARGS.tiny_cpu
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def status(phase, verdict, **info):
+    print("[chip_smoke] phase %-8s %s %s"
+          % (phase, verdict, json.dumps(info, sort_keys=True)), flush=True)
+
+
+# ---------------------------------------------------------------------------
+# phase 0: the device
+# ---------------------------------------------------------------------------
+
+def device_phase():
+    devs = jax.devices()
+    d0 = devs[0]
+    print("[chip_smoke] device platform=%s device_kind=%r count=%d"
+          % (d0.platform, d0.device_kind, len(devs)), flush=True)
+    print("[chip_smoke] compile cache dir: %s" % programs.cache_dir(),
+          flush=True)
+    if TINY:
+        print("[chip_smoke] REHEARSAL (--tiny-cpu): tiny shapes on the host "
+              "CPU, kernels interpreted. This run proves NOTHING about the "
+              "device.", flush=True)
+    elif d0.platform == "cpu":
+        sys.exit("chip_smoke: JAX found no accelerator (platform 'cpu'). "
+                 "This check only means something on the chip; to rehearse "
+                 "the command here pass --tiny-cpu.")
+    return devs
+
+
+# ---------------------------------------------------------------------------
+# phase train / dp4: the CLI's own main(), watched through a callback
+# ---------------------------------------------------------------------------
+
+def _cross_entropy(mod, batch):
+    """Mean cross-entropy of ``batch`` from the module's softmax output
+    (fetched to the host)."""
+    prob = mod.get_outputs()[0].asnumpy()
+    label = batch.label[0].asnumpy().astype(np.int64)
+    picked = prob[np.arange(label.shape[0]), label]
+    return float(-np.log(np.maximum(picked, 1e-30)).mean())
+
+
+class StepWatch(object):
+    """batch_end_callback: per step, the loss of the batch just trained
+    on (fetched to the host — that fetch is the step's sync point), the
+    wall since the previous step ended, and the compile-request count."""
+
+    def __init__(self):
+        self.loss, self.wall, self.compiles = [], [], []
+        self.module = None
+        self._t = time.perf_counter()
+
+    def __call__(self, param):
+        mod = param.locals["self"]
+        batch = param.locals["data_batch"]
+        self.loss.append(_cross_entropy(mod, batch))
+        now = time.perf_counter()
+        self.wall.append(now - self._t)
+        self._t = now
+        self.compiles.append(telemetry.compile_count())
+        self.module = mod
+
+
+def _model(batch):
+    """The network of both training phases at ``batch`` rows: full-width
+    ResNet-50, or the tiny rehearsal stand-in (a quarter of the rows)."""
+    if TINY:
+        return {"network": "resnet", "num_layers": 8, "num_classes": 10,
+                "image_shape": "3,16,16", "batch_size": batch // 4}
+    return {"network": "resnet", "num_layers": 50, "num_classes": 1000,
+            "image_shape": "3,224,224", "batch_size": batch}
+
+
+# steps 1-2 are warm-up: pjit keeps one executable per input provenance
+# (fresh device_put arrays on step 1, the program's own outputs from
+# step 2 on — programs.warm_twice), so "zero compiles" is asserted from
+# step 3
+WARMUP_STEPS = 2
+STEPS = 8
+
+
+def run_fit(tpus, batch):
+    """``python examples/train_imagenet.py --benchmark 1 ...`` in this
+    process. Returns (watch, real_compiles, disk_hits, fused programs
+    added)."""
+    flags = []
+    for k, v in _model(batch).items():
+        flags += ["--" + k.replace("_", "-"), str(v)]
+    flags += [
+        "--benchmark", "1", "--num-epochs", "1", "--max-batches", str(STEPS),
+        "--kv-store", "device", "--optimizer", "sgd", "--lr", "0.05",
+        "--mom", "0.9", "--wd", "1e-4", "--disp-batches", "4",
+        "--tpus", tpus]
+    mx.random.seed(0)
+    fused0 = _fused_programs()
+    real0 = telemetry.counter("programs/compile_total").value
+    disk0 = telemetry.counter("programs/disk_hits_total").value
+    watch = StepWatch()
+    train_imagenet.main(flags, batch_end_callback=watch)
+    return (watch,
+            telemetry.counter("programs/compile_total").value - real0,
+            telemetry.counter("programs/disk_hits_total").value - disk0,
+            _fused_programs() - fused0)
+
+
+def _fused_programs():
+    return sum(1 for r in programs.entries().values()
+               if r["kind"] == "fused_step")
+
+
+def _check_steps(phase, watch, fused_added):
+    check(len(watch.loss) == STEPS,
+          "%s: %d of %d steps ran" % (phase, len(watch.loss), STEPS))
+    check(all(math.isfinite(v) for v in watch.loss),
+          "%s: non-finite loss %s" % (phase, watch.loss))
+    check(watch.loss[-1] < watch.loss[0],
+          "%s: loss did not fall on a repeated batch: %s"
+          % (phase, watch.loss))
+    check(fused_added == 1,
+          "%s: %d fused_step programs registered, expected exactly 1 (the "
+          "unfused replay registers none, a retrace more than one)"
+          % (phase, fused_added))
+    late = watch.compiles[-1] - watch.compiles[WARMUP_STEPS - 1]
+    check(late == 0, "%s: %d compile request(s) after the warm-up steps "
+          "(per-step totals %s)" % (phase, late, watch.compiles))
+
+
+def _state_arrays(mod):
+    """Every array the step owns: parameters + staged batch, aux states,
+    optimizer state."""
+    exe = mod._exec
+    out = dict(("arg:" + n, a) for n, a in exe.arg_dict.items())
+    out.update(("aux:" + n, a) for n, a in exe.aux_dict.items())
+    for i, st in mod._updater.states.items():
+        for j, a in enumerate(mx.optimizer.fused_state_arrays(st)):
+            out["state:%d:%d" % (i, j)] = a
+    return out
+
+
+def _hbm_in_use(dev):
+    stats = dev.memory_stats()
+    if stats is None:
+        check(TINY, "device %s reports no memory_stats" % dev)
+        return None              # host CPU backend in the rehearsal
+    return int(stats["bytes_in_use"])
+
+
+def _mosaic_in(fn, *args):
+    """Does ``fn`` lower to a Mosaic custom call when traced (tracer
+    inputs: the dispatch every jitted caller sees)?"""
+    return "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
+
+
+def train_phase():
+    # In the rehearsal "the chip" is host device 1, so that anything left
+    # on the default device 0 (where cpu(0)-context arrays live) shows up
+    # exactly like a host-resident array would on a TPU machine.
+    dev_id = 1 if TINY else 0
+    dev = mx.tpu(dev_id).jax_device()
+    t0 = time.perf_counter()
+    watch, real, disk, fused_added = run_fit(str(dev_id), 32)
+    wall = time.perf_counter() - t0
+    _check_steps("train", watch, fused_added)
+    mod = watch.module
+    arrays = _state_arrays(mod)
+    stray = dict((n, sorted(str(d) for d in a._data.devices()))
+                 for n, a in arrays.items()
+                 if a._data.devices() != {dev} or not a._data.committed)
+    check(not stray, "train: %d of %d arrays are not committed to %s: %s"
+          % (len(stray), len(arrays), dev, dict(list(stray.items())[:6])))
+    nbytes = sum(a._data.nbytes for a in arrays.values())
+    hbm = _hbm_in_use(dev)
+    check(hbm is None or hbm >= nbytes,
+          "train: device reports %s bytes in use, the step's arrays alone "
+          "are %d" % (hbm, nbytes))
+    # the update rule the step traced must be the Mosaic kernel on TPU
+    rule = mod._optimizer.fused_rule()
+    idx = len(mod._param_names) - 1
+    w = mod._exec.arg_dict[mod._param_names[idx]]._data
+    st = tuple(a._data for a in mx.optimizer.fused_state_arrays(
+        mod._updater.states[idx]))
+    hyper = mod._optimizer.fused_hyper(idx)
+    mosaic = _mosaic_in(rule, w, w, st, hyper)
+    check(mosaic == (not TINY),
+          "train: update rule %s lowers %s a Mosaic kernel on platform %s"
+          % (rule.__name__, "to" if mosaic else "WITHOUT", dev.platform))
+    steady = sorted(watch.wall[WARMUP_STEPS:])
+    status("train", "passed", arrays=len(arrays), on_device=str(dev),
+           state_mbytes=round(nbytes / 1e6, 1),
+           hbm_in_use_mbytes=None if hbm is None else round(hbm / 1e6, 1),
+           loss_first=round(watch.loss[0], 4),
+           loss_last=round(watch.loss[-1], 4),
+           first_step_wall_s=round(watch.wall[0], 2),
+           steady_ms_per_step=round(steady[len(steady) // 2] * 1e3, 2),
+           phase_wall_s=round(wall, 2), real_compiles=real, disk_hits=disk,
+           step_program="loaded from the compile cache" if real == 0
+           else "compiled", update_rule=rule.__name__, mosaic_update=mosaic)
+
+
+# ---------------------------------------------------------------------------
+# phase kernels
+# ---------------------------------------------------------------------------
+
+def _rel_err(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.max(np.abs(got - ref)) / (np.max(np.abs(ref)) + 1e-12))
+
+
+def kernels_phase():
+    fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+    fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
+    i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
+    rng = np.random.RandomState(0)
+    # on the chip: interpret=None, the production default, which must
+    # resolve to Mosaic (asserted per kernel through _mosaic_in); in the
+    # rehearsal: the interpreter, which runs the same kernel bodies
+    interp = True if TINY else None
+    report = {}
+
+    def f32(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.float32)
+
+    def case(name, kernel, twin, args, tol, exact=False):
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(kernel(*args))
+        wall = time.perf_counter() - t0
+        with jax.default_matmul_precision("highest"):
+            ref = twin(*args)
+        errs = [_rel_err(g, r) for g, r in zip(
+            jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(ref))]
+        if exact:
+            same = all(np.array_equal(np.asarray(g), np.asarray(r))
+                       for g, r in zip(jax.tree_util.tree_leaves(got),
+                                       jax.tree_util.tree_leaves(ref)))
+            check(same, "kernels: %s is not bit-equal to its twin" % name)
+        check(max(errs) <= tol, "kernels: %s differs from its twin by %s "
+              "(tolerance %g)" % (name, errs, tol))
+        if not TINY:
+            check(_mosaic_in(kernel, *args),
+                  "kernels: %s did not lower to a Mosaic kernel" % name)
+        report[name] = {"max_rel_err": max(errs),
+                        "first_call_s": round(wall, 2)}
+
+    # -- fused optimizer updates: the largest conv weight, the FC, a BN
+    #    gamma of ResNet-50 (every parameter of the train phase goes
+    #    through sgd_fused_update)
+    sgd_h = {"lr": 0.1, "wd": 1e-4, "rescale_grad": 1.0 / 32,
+             "momentum": 0.9}
+    adam_h = {"lr": 1e-3, "wd": 1e-4, "rescale_grad": 1.0 / 32,
+              "beta1": 0.9, "one_minus_beta1": 0.1, "beta2": 0.999,
+              "one_minus_beta2": 1e-3, "epsilon": 1e-8}
+    shapes = [(8, 8, 3, 3), (10, 16), (4,)] if TINY else \
+        [(512, 512, 3, 3), (1000, 2048), (64,)]
+    for shape in shapes:
+        tag = "x".join(str(d) for d in shape)
+        w, g, m = f32(*shape), f32(*shape), f32(*shape)
+        v = jnp.abs(f32(*shape))
+        case("sgd_fused_update[%s]" % tag,
+             lambda w, g, m: fu.sgd_fused_update(w, g, (m,), sgd_h,
+                                                 interpret=interp),
+             lambda w, g, m: fu._sgd_fused_xla(w, g, (m,), sgd_h),
+             (w, g, m), 1e-5)
+        case("adam_fused_update[%s]" % tag,
+             lambda w, g, m, v: fu.adam_fused_update(w, g, (m, v), adam_h,
+                                                     interpret=interp),
+             lambda w, g, m, v: fu._adam_fused_xla(w, g, (m, v), adam_h),
+             (w, g, m, v), 1e-5)
+    clip_h = dict(sgd_h, clip_gradient=0.01)
+    w, g, m = f32(*shapes[0]), f32(*shapes[0]), f32(*shapes[0])
+    case("sgd_fused_update[clip_gradient]",
+         lambda w, g, m: fu.sgd_fused_update(w, g, (m,), clip_h,
+                                             interpret=interp),
+         lambda w, g, m: fu._sgd_fused_xla(w, g, (m,), clip_h),
+         (w, g, m), 1e-5)
+
+    # -- flash attention: train_transformer_lm shapes (b8, 16 heads, seq
+    #    1024, head_dim 64), bf16, and head_dim 128
+    flash = [(1, 2, 32, 16, jnp.float32)] if TINY else \
+        [(8, 16, 1024, 64, jnp.float32), (8, 16, 1024, 64, jnp.bfloat16),
+         (2, 8, 1024, 128, jnp.bfloat16)]
+    for b, h, s, d, dt in flash:
+        q, k, v = (jnp.asarray(rng.randn(b, h, s, d), dt) for _ in range(3))
+        case("flash_attention[b%dh%ds%dd%d,%s]" % (b, h, s, d, dt.__name__),
+             lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                                interpret=interp),
+             lambda q, k, v, d=d: fa._flash_fwd_xla(q, k, v, True,
+                                                    1.0 / d ** 0.5)[0],
+             (q, k, v), 2e-2)
+
+    # -- paged decode attention: page_size 16, GQA groups of 4 and 2
+    paged = [(2, 2, 2, 8, 4, 8, 2)] if TINY else \
+        [(8, 2, 4, 64, 16, 512, 16), (8, 4, 2, 128, 16, 512, 16)]
+    for b, kvh, g, hd, ps, npages, ppseq in paged:
+        q = f32(b, kvh, g, hd)
+        kp, vp = f32(npages, ps, kvh, hd), f32(npages, ps, kvh, hd)
+        bt = jnp.asarray(1 + rng.permutation(npages - 1)[:b * ppseq]
+                         .reshape(b, ppseq), jnp.int32)
+        ln = jnp.asarray(rng.randint(1, ps * ppseq + 1, size=(b,)),
+                         jnp.int32)
+        case("paged_decode_attention[b%dkvh%dg%dhd%d]" % (b, kvh, g, hd),
+             lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+                 q, kp, vp, bt, ln, interpret=interp),
+             lambda q, kp, vp, bt, ln, hd=hd: fa._paged_decode_xla(
+                 q, kp, vp, bt, ln, 1.0 / hd ** 0.5),
+             (q, kp, vp, bt, ln), 2e-2)
+
+    # -- flash prefill with the fused page write
+    prefill = [(2, 16, 4, 2, 8, 4)] if TINY else \
+        [(2, 128, 8, 2, 64, 16), (2, 256, 8, 4, 128, 16)]
+    for b, s, nh, kvh, hd, ps in prefill:
+        q = f32(b, s, nh, hd)
+        kg, vg = f32(b, s, kvh, hd), f32(b, s, kvh, hd)
+        npages = b * (s // ps) + 1
+        kp = jnp.zeros((npages, ps, kvh, hd), jnp.float32)
+        vp = jnp.zeros((npages, ps, kvh, hd), jnp.float32)
+        bt = jnp.asarray(np.arange(1, npages).reshape(b, s // ps),
+                         jnp.int32)
+        case("flash_prefill_paged[b%ds%dnh%dkvh%dhd%d]"
+             % (b, s, nh, kvh, hd),
+             lambda *a: fa.flash_prefill_paged(*a, interpret=interp),
+             fa._flash_prefill_xla, (q, kg, vg, kp, vp, bt), 2e-2)
+
+    # -- int8 matmul + im2col conv: ResNet-50's FC and its first 3x3 conv
+    mm = [(8, 40, 12)] if TINY else [(32, 2048, 1000),
+                                    (32 * 56 * 56, 576, 64)]
+    for m_, k_, n_ in mm:
+        x = jnp.asarray(rng.randint(-127, 128, (m_, k_)), jnp.int8)
+        wq = jnp.asarray(rng.randint(-127, 128, (n_, k_)), jnp.int8)
+        sc = jnp.asarray(rng.rand(n_), jnp.float32)
+        case("int8_matmul[%dx%dx%d]" % (m_, k_, n_),
+             lambda x, wq, sc: i8.int8_matmul(x, wq, sc, interpret=interp),
+             i8._int8_matmul_xla, (x, wq, sc), 0.0, exact=True)
+    cb, cc, chw = (2, 4, 8) if TINY else (32, 64, 56)
+    qx = jnp.asarray(rng.randint(-127, 128, (cb, cc, chw, chw)), jnp.int8)
+    wq = jnp.asarray(rng.randint(-127, 128, (cc, cc, 3, 3)), jnp.int8)
+    sc = jnp.asarray(rng.rand(cc), jnp.float32)
+    conv_args = ((1, 1), (1, 1), (1, 1))
+    case("int8_conv_im2col[b%dc%d,%dx%d,3x3]" % (cb, cc, chw, chw),
+         lambda qx, wq, sc: i8.int8_conv_im2col(qx, wq, sc, *conv_args,
+                                                interpret=interp),
+         lambda qx, wq, sc: i8._int8_conv_xla(qx, wq, sc, *conv_args, 1),
+         (qx, wq, sc), 0.0, exact=True)
+
+    exported = set()
+    for mod in (fa, fu, i8):
+        exported.update(mod.PALLAS_KERNELS)
+    covered = set(n.split("[")[0] for n in report)
+    check(covered == exported, "kernels: exported %s, exercised %s"
+          % (sorted(exported), sorted(covered)))
+    status("kernels", "passed", cases=len(report),
+           mode="interpret (rehearsal)" if TINY else "mosaic",
+           worst_rel_err=max(r["max_rel_err"] for r in report.values()),
+           kernels=sorted(exported))
+    for name in sorted(report):
+        print("[chip_smoke]   %-48s %s" % (name, json.dumps(report[name])),
+              flush=True)
+
+
+# ---------------------------------------------------------------------------
+# phase dp4
+# ---------------------------------------------------------------------------
+
+def _reference_first_loss(batch):
+    """First-step loss of the same seed and the same ``batch``-row
+    synthetic batch on ONE chip: bind + init exactly as fit does, one
+    training-mode forward (batch-norm uses batch statistics)."""
+    ns = argparse.Namespace(**_model(batch))
+    net = train_imagenet.get_network(ns)
+    image = tuple(int(x) for x in ns.image_shape.split(","))
+    from common import data as exdata
+    it = exdata.SyntheticDataIter(ns.num_classes,
+                                  (ns.batch_size,) + image, 1, "float32")
+    mx.random.seed(0)
+    mod = mx.module.Module(net, context=mx.tpu(0))
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(mx.init.Xavier(rnd_type="gaussian", factor_type="in",
+                                   magnitude=2))
+    batch0 = it.next()
+    mod.forward(batch0, is_train=True)
+    return _cross_entropy(mod, batch0)
+
+
+def dp4_phase(devs):
+    if len(devs) < 4:
+        why = "%d device(s) visible, the phase needs 4" % len(devs)
+        status("dp4", "SKIPPED", reason=why)
+        return why
+    t0 = time.perf_counter()
+    ref = _reference_first_loss(128)
+    watch, real, disk, fused_added = run_fit("0,1,2,3", 128)
+    wall = time.perf_counter() - t0
+    _check_steps("dp4", watch, fused_added)
+    mod = watch.module
+    exe = mod._exec
+    four = [mx.tpu(i).jax_device() for i in range(4)]
+    data = exe.arg_dict["data"]._data
+    shard_devs = [s.device for s in data.addressable_shards]
+    check(sorted(d.id for d in shard_devs) == sorted(d.id for d in four),
+          "dp4: batch shards sit on %s, expected one on each of %s"
+          % (shard_devs, four))
+    rows = set(s.data.shape[0] for s in data.addressable_shards)
+    check(rows == {data.shape[0] // 4},
+          "dp4: batch shards hold %s rows of %d" % (rows, data.shape[0]))
+    for name in mod._param_names:
+        arr = exe.arg_dict[name]._data
+        check(arr.devices() == set(four) and arr.is_fully_replicated,
+              "dp4: parameter %s is not replicated on the 4 chips: %s"
+              % (name, arr.sharding))
+    hbm = [_hbm_in_use(d) for d in four]
+    check(all(h is None or h > 0 for h in hbm),
+          "dp4: HBM in use per chip %s" % hbm)
+    tol = 1e-3 * max(1.0, abs(ref))
+    check(abs(watch.loss[0] - ref) <= tol,
+          "dp4: first-step loss %.6f on 4 chips vs %.6f on one chip"
+          % (watch.loss[0], ref))
+    steady = sorted(watch.wall[WARMUP_STEPS:])
+    status("dp4", "passed", devices=[str(d) for d in four],
+           loss_first=round(watch.loss[0], 5),
+           loss_first_one_chip=round(ref, 5),
+           loss_last=round(watch.loss[-1], 4),
+           hbm_in_use_mbytes=[None if h is None else round(h / 1e6, 1)
+                              for h in hbm],
+           first_step_wall_s=round(watch.wall[0], 2),
+           steady_ms_per_step=round(steady[len(steady) // 2] * 1e3, 2),
+           phase_wall_s=round(wall, 2), real_compiles=real, disk_hits=disk)
+    return None
+
+
+def main():
+    t0 = time.perf_counter()
+    devs = device_phase()
+    verdicts = {}
+    for phase, run in (("train", train_phase), ("kernels", kernels_phase),
+                       ("dp4", lambda: dp4_phase(devs))):
+        if phase not in ARGS.phases:
+            verdicts[phase] = "NOT RUN (--phases)"
+            continue
+        skipped = run()
+        verdicts[phase] = "SKIPPED (%s)" % skipped if skipped else "passed"
+    d0 = devs[0]
+    print("[chip_smoke] summary: %s; wall %.1fs%s"
+          % (", ".join("%s %s" % kv for kv in verdicts.items()),
+             time.perf_counter() - t0,
+             "; REHEARSAL on the host CPU — nothing about the device was "
+             "proven" if TINY else ""), flush=True)
+    if len(ARGS.phases) < 3:
+        sys.exit("chip_smoke: partial run (--phases %s): no verdict"
+                 % ",".join(ARGS.phases))
+    result = {"ok": True, "device": {"platform": d0.platform,
+                                     "kind": d0.device_kind,
+                                     "count": len(devs)}}
+    if TINY:
+        result["rehearsal"] = True
+    print(json.dumps(result), flush=True)
+
+
+if __name__ == "__main__":
+    main()

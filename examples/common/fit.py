@@ -95,9 +95,10 @@ def add_fit_args(parser):
     return train
 
 
-def fit(args, network, data_loader):
+def fit(args, network, data_loader, batch_end_callback=None):
     """Train ``network`` with the flags in ``args``
-    (reference: common/fit.py fit)."""
+    (reference: common/fit.py fit). ``batch_end_callback``: extra
+    callback(s) run after the Speedometer."""
     kv = None
     if "dist" in args.kv_store:
         kv = mx.kvstore.create(args.kv_store)
@@ -111,7 +112,9 @@ def fit(args, network, data_loader):
     if args.tpus:
         devs = [mx.tpu(int(i)) for i in args.tpus.split(",")]
     else:
-        devs = mx.tpu(0) if mx.num_tpus() > 0 else mx.cpu()
+        # raises when the machine has no accelerator, unless the
+        # platform was forced with JAX_PLATFORMS=cpu (mx.tpu semantics)
+        devs = mx.tpu(0)
 
     lr, lr_scheduler = _get_lr_scheduler(args, kv)
     sym, arg_params, aux_params = _load_model(args, kv.rank if kv else 0)
@@ -131,6 +134,10 @@ def fit(args, network, data_loader):
     checkpoint = _save_model(args, kv.rank if kv else 0)
     batch_end_cbs = [mx.callback.Speedometer(args.batch_size,
                                              args.disp_batches)]
+    if batch_end_callback is not None:
+        batch_end_cbs += (batch_end_callback
+                          if isinstance(batch_end_callback, list)
+                          else [batch_end_callback])
 
     eval_metrics = ["accuracy"]
     if args.num_classes >= 5:

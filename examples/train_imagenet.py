@@ -58,7 +58,10 @@ def get_network(args):
                      "vgg, mobilenet, mlp, inception-bn)" % name)
 
 
-def main():
+def main(argv=None, batch_end_callback=None):
+    """Train with the flags in ``argv`` (default ``sys.argv``); returns
+    the fitted Module. ``batch_end_callback`` runs after the CLI's own
+    Speedometer (chip_smoke.py watches the loss through it)."""
     parser = argparse.ArgumentParser(
         description="train imagenet-1k",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter)
@@ -68,9 +71,10 @@ def main():
     parser.set_defaults(network="resnet", num_layers=50, num_classes=1000,
                         num_examples=1281167, image_shape="3,224,224",
                         batch_size=32, lr=0.1, lr_step_epochs="30,60,80")
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
     net = get_network(args)
-    fit.fit(args, net, data.get_rec_iter)
+    return fit.fit(args, net, data.get_rec_iter,
+                   batch_end_callback=batch_end_callback)
 
 
 if __name__ == "__main__":

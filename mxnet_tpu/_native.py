@@ -4,17 +4,20 @@ The reference keeps its runtime IO/serving hot paths in C++ (src/io/,
 src/c_api/); this build does the same, compiling the sources under
 ``src/native/`` into a shared library consumed via ctypes (pybind11 is
 not in this image — the flat C ABI mirrors the reference's c_api.h
-approach anyway). The library is built on demand with g++ and cached;
-callers must handle ``None`` (pure-Python fallback) when no toolchain
-is present.
+approach anyway). The library is built on demand with g++ from the
+tracked sources and cached under the git-ignored ``build/native``;
+callers must handle ``None`` (pure-Python fallback) when the build
+fails — the failure is logged once, with the compiler's output.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _cache = {}
 
@@ -38,13 +41,21 @@ def _build(name, sources, flags=()):
 
 def load(name, sources, flags=()):
     """Build (if needed) + dlopen lib<name>.so from src/native sources.
-    Returns the ctypes CDLL, or None when the toolchain is unavailable."""
+    Returns the ctypes CDLL, or None when it cannot be built or loaded
+    (no g++, a compile error, a missing system library): logged once —
+    the result is cached — with the compiler's output."""
     with _lock:
         if name in _cache:
             return _cache[name]
         try:
             lib = ctypes.CDLL(_build(name, sources, flags))
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            stderr = getattr(e, "stderr", None) or b""
+            _log.warning(
+                "native lib%s unavailable (%s); callers fall back to "
+                "their pure-Python path. Compiler output:\n%s",
+                name, e, stderr.decode(errors="replace").strip()
+                or "(none)")
             lib = None
         _cache[name] = lib
         return lib

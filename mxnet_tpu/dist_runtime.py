@@ -21,13 +21,15 @@ lifecycle:
 Why the explicit route builds the coordination client by hand
 -------------------------------------------------------------
 ``jax.distributed.initialize`` wires the XLA coordination service with
-defaults that are actively hostile to elastic membership (verified
-empirically against jax 0.4.37 / jaxlib 0.4.36 with gloo collectives):
+defaults that are actively hostile to elastic membership (jax 0.9.0 /
+jaxlib 0.9.0 with gloo collectives; ``tests/test_elastic.py`` is the
+standing check):
 
 * the client's missed-heartbeat/error-poll handler is a hard
-  ``LOG(QFATAL)`` — ~100 s after ANY peer dies, every *survivor* is
-  SIGABRTed by its own runtime ("Terminating process because the JAX
-  distributed service detected fatal errors");
+  ``LOG(QFATAL)`` — ``heartbeat_timeout`` (default 100 s) after ANY
+  peer dies, every *survivor* is SIGABRTed by its own runtime
+  ("Terminating process because the JAX distributed service detected
+  fatal errors");
 * ``jax.distributed.shutdown()`` runs a shutdown *barrier* that blocks
   until every registered task calls in — with a dead peer it parks
   until the same watchdog kills the process;
@@ -35,12 +37,12 @@ empirically against jax 0.4.37 / jaxlib 0.4.36 with gloo collectives):
   no shutdown→reinit cycle at all.
 
 So for the explicit ``MXNET_DIST_COORDINATOR`` route this module
-constructs the service/client itself via ``xla_extension`` and
+constructs the service/client itself via ``jax._src.lib._jax`` and
 installs them into ``jax._src.distributed.global_state`` (the exact
 slots jax's own initialize fills, and the place the gloo CPU backend
 looks for its KV store):
 
-* ``max_missing_heartbeats`` is set effectively infinite — death
+* ``heartbeat_timeout`` is set effectively infinite — death
   detection belongs to the elastic control plane (collective error /
   stale heartbeat / step watchdog), which reacts in
   ``MXNET_DIST_DEAD_S`` instead of aborting the survivor at 100 s;
@@ -49,6 +51,11 @@ looks for its KV store):
   which is what stops its heartbeat/error-poll threads);
 * ``shutdown_on_destruction=False``, so dropping the last Python
   reference can never run a blocking barrier at an awkward time.
+
+jax 0.9.0's client also takes ``recoverable=``; it stays at its default
+(off): with the heartbeat timeout out of the way the kill/rejoin cycles
+of ``tests/test_elastic.py`` pass without it, and a rank here never
+reconnects to the SAME service — a rescale brings up a new one.
 
 Teardown order matters and is load-bearing: drop the backend first
 (the gloo collectives hold a reference to the client's KV store), then
@@ -72,9 +79,8 @@ standard signals ``jax.distributed.initialize()`` autodetects itself
 through the normal tooling needs no extra variables.
 
 On a CPU backend the gloo collectives implementation is selected
-before initialization when this jax exposes the knob (the raw CPU
-backend cannot run multiprocess computations) — the same live-probed
-gate ``tests/test_kvstore_multiprocess.py`` uses.
+before initialization (the raw CPU backend cannot run multiprocess
+computations).
 """
 from __future__ import annotations
 
@@ -97,12 +103,10 @@ _owned = [False]     # did THIS module initialize the runtime?
 _manual = [False]    # did we build the client/service by hand?
 _generation = [0]    # completed initialize cycles (elastic member epochs)
 
-# Coordination-service tuning for the hand-built route.  Heartbeats are
-# kept alive (they double as TCP keepalive) but the miss threshold is
-# effectively infinite: membership death detection is the elastic
-# layer's job, not the coordination service's QFATAL.
-_HB_INTERVAL_S = 10
-_HB_MAX_MISSING = 1 << 20
+# Coordination-service tuning for the hand-built route.  The heartbeat
+# timeout is effectively infinite (30 days): membership death detection
+# is the elastic layer's job, not the coordination service's QFATAL.
+_HB_TIMEOUT_S = 30 * 24 * 3600
 _INIT_TIMEOUT_S = 60
 _SHUTDOWN_TIMEOUT_S = 2
 
@@ -142,39 +146,34 @@ def env_configured():
 
 
 def _select_cpu_collectives():
-    """Route multiprocess CPU computations over gloo when this jax has
-    the knob; a no-op on accelerator backends and older jax (where the
-    raw CPU backend simply cannot run multiprocess programs)."""
+    """Route multiprocess CPU computations over gloo (the raw CPU
+    backend cannot run multiprocess programs); a no-op unless the
+    platform was forced to cpu."""
     import jax
-    if os.environ.get("JAX_PLATFORMS", "").lower() != "cpu" and \
-            _cfg("MXNET_TPU_PLATFORM") != "cpu":
-        return
-    try:
+    from .context import platform_forced_cpu
+    if platform_forced_cpu():
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
 
 
 def _manual_initialize(coord, num_processes, process_id):
     """Build the coordination service (rank 0) + client by hand and
     install them into jax's global state — the elastic-safe equivalent
     of ``jax.distributed.initialize`` (see module docstring)."""
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax as xe
     st = _global_state()
     service = None
     if process_id == 0:
         bind = "[::]:" + coord.rsplit(":", 1)[1]
         service = xe.get_distributed_runtime_service(
             bind, num_processes,
-            heartbeat_interval=_HB_INTERVAL_S,
-            max_missing_heartbeats=_HB_MAX_MISSING)
+            heartbeat_timeout=_HB_TIMEOUT_S,
+            shutdown_timeout=_SHUTDOWN_TIMEOUT_S)
     try:
         client = xe.get_distributed_runtime_client(
             coord, process_id,
             init_timeout=_INIT_TIMEOUT_S,
             shutdown_timeout=_SHUTDOWN_TIMEOUT_S,
-            heartbeat_interval=_HB_INTERVAL_S,
-            max_missing_heartbeats=_HB_MAX_MISSING,
+            heartbeat_timeout=_HB_TIMEOUT_S,
             shutdown_on_destruction=False,
             use_compression=True)
         client.connect()

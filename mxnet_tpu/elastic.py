@@ -108,15 +108,10 @@ def rescale_errors():
     """The exception tuple fit treats as 'the data or control plane
     says the membership changed': the step-boundary detection, the
     step watchdog, and the data plane's own collective failure
-    (XlaRuntimeError — a gloo/ICI all-reduce fails within milliseconds
+    (JaxRuntimeError — a gloo/ICI all-reduce fails within milliseconds
     of a peer death, usually the FIRST signal)."""
-    errs = [MembershipChange, StepStallError]
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        errs.append(XlaRuntimeError)
-    except Exception:          # noqa: BLE001 - optional backend symbol
-        pass
-    return tuple(errs)
+    import jax
+    return (MembershipChange, StepStallError, jax.errors.JaxRuntimeError)
 
 
 def call_bounded(fn, timeout_s, what="train step"):
@@ -124,7 +119,7 @@ def call_bounded(fn, timeout_s, what="train step"):
     after ``timeout_s``.
 
     The body runs in a helper thread so the caller can give up on a
-    wedged collective (the data plane offers no cancellation: a gloo/
+    stalled collective (the data plane offers no cancellation: a gloo/
     ICI all-reduce whose peer vanished without a FIN blocks forever).
     On timeout the helper thread is abandoned — it parks in the dead
     collective until teardown invalidates its runtime; that leak is
@@ -149,7 +144,7 @@ def call_bounded(fn, timeout_s, what="train step"):
     if not done.wait(timeout_s):
         raise StepStallError(
             "%s did not complete within MXNET_STEP_TIMEOUT_S=%.1fs "
-            "(a collective wedged on a dead peer?)" % (what, timeout_s))
+            "(a collective stalled on a dead peer?)" % (what, timeout_s))
     if "error" in box:
         raise box["error"]
     return box.get("value")

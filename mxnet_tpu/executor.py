@@ -102,8 +102,8 @@ class Executor(object):
         # graph fingerprint for the process-wide program registry
         # (programs.py): executors bound to the same symbol at the same
         # shapes SHARE one jitted program — a hot-swap replacement
-        # engine re-warms its ladder as cache hits, and with
-        # MXNET_COMPILE_CACHE_DIR set a fresh process loads it from disk
+        # engine re-warms its ladder as cache hits, and a fresh process
+        # loads it from the persistent compile cache on disk
         self._graph_hash = _pg.graph_hash(symbol)
         self._jitted = {}               # memo over the registry (keys
         self._vjp_jitted = {}           # re-fingerprint per entry; the
@@ -340,6 +340,11 @@ class Executor(object):
             data = value._data
             if self._dp_mesh is not None:
                 data = self._dp_place(name, data)
+            else:
+                # an iterator's batch lives where its context put it —
+                # the host, by default: this is the ONE H2D copy of the
+                # step (a no-op for a batch already on the device)
+                data = jax.device_put(data, self._ctx.jax_device())
         else:
             if isinstance(value, jax.Array):
                 # already on device: cast/move device-side, never via host
@@ -454,6 +459,16 @@ class Executor(object):
         import jax
         import jax.numpy as jnp
         fn = _graph_eval_fn(self._symbol, True)
+        if self._dp_mesh is not None:
+            # Under the dp mesh every operand of the update is replicated
+            # (GSPMD all-reduces the gradient on its way in), so each
+            # device runs the rule on its own replica. The explicit
+            # shard_map is what lets the Pallas update kernels in at
+            # all: GSPMD cannot partition a Mosaic custom call.
+            from jax.sharding import PartitionSpec as P
+            rule = jax.shard_map(rule, mesh=self._dp_mesh,
+                                 in_specs=(P(), P(), P(), P()),
+                                 out_specs=P(), check_vma=False)
 
         def _sentinel(gs, outs):
             # step mode costs ONE reduction pass over each gradient:
@@ -674,8 +689,8 @@ class Executor(object):
                 self._allreduce_bytes = 0
             # process-wide registry entry: a resumed trainer (or a
             # second Module over the same graph/optimizer) shares the
-            # program, and MXNET_COMPILE_CACHE_DIR makes the build a
-            # persistent-cache disk load in a fresh process. A rule
+            # program, and the persistent compile cache makes the build
+            # a disk load in a fresh process. A rule
             # that is a closure gets an instance salt — baked-in cell
             # contents have no stable cross-object identity
             rule_id = "%s.%s" % (getattr(rule, "__module__", "?"),
@@ -894,8 +909,7 @@ class Executor(object):
         for name, array in arg_params.items():
             if name in self.arg_dict:
                 dst = self.arg_dict[name]
-                dst._set_data(array.astype(dst.dtype, copy=False)._data
-                              if array.dtype != dst.dtype else array._data)
+                array.astype(dst.dtype, copy=False).copyto(dst)
             elif not allow_extra_params:
                 raise ValueError("Find name \"%s\" that is not in the arguments"
                                  % name)
@@ -903,8 +917,7 @@ class Executor(object):
             return
         for name, array in aux_params.items():
             if name in self.aux_dict:
-                dst = self.aux_dict[name]
-                dst._set_data(array._data)
+                array.copyto(self.aux_dict[name])
             elif not allow_extra_params:
                 raise ValueError("Find name %s that is not in the auxiliary "
                                  "states" % name)

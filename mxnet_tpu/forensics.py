@@ -21,9 +21,8 @@ per-program sum reconciles with the compiled module's own
 (``reconciliation`` in every report; see docs/observability.md).
 
 Capture runs entirely under ``telemetry.suppress_compile_tracking()``:
-the AOT ``lowered.compile()`` is a persistent-cache disk load when
-``MXNET_COMPILE_CACHE_DIR`` is set (the program was just compiled and
-cached by the jit site) and its events never touch the compile
+the AOT ``lowered.compile()`` is a persistent-cache disk load (the
+program was just compiled and cached by the jit site) and its events never touch the compile
 counters, so every zero-recompile assertion in the serving/training
 tests stays honest. Nothing runs per step — capture is once per
 program fingerprint.
@@ -31,7 +30,7 @@ program fingerprint.
 Reports are content-addressed artifacts: ``<dir>/<fingerprint>.json``
 written via ``checkpoint.atomic_writer`` with an embedded CRC32, where
 ``<dir>`` is ``MXNET_FORENSICS_DIR`` or
-``<MXNET_COMPILE_CACHE_DIR>/forensics``. The fingerprint is the
+``<programs.cache_dir()>/forensics``. The fingerprint is the
 registry ``ProgramKey`` fingerprint — it already folds in the
 jax/jaxlib/backend version salt — so the SAME logical program captured
 under two jax versions or flag sets lands as two files, and
@@ -132,16 +131,14 @@ def configure(on=None, directory=None):
 
 def reports_dir():
     """Where report artifacts land: ``MXNET_FORENSICS_DIR`` (or the
-    :func:`configure` override), else ``<compile cache dir>/forensics``,
-    else None (reports stay in-memory only)."""
+    :func:`configure` override), else ``<compile cache dir>/forensics``."""
     if _dir_override is not None:
         return _dir_override
     d = _config("MXNET_FORENSICS_DIR")
     if d:
         return os.path.abspath(d)
     from . import programs as _pg
-    cd = _pg.cache_dir()
-    return os.path.join(cd, "forensics") if cd else None
+    return os.path.join(_pg.cache_dir(), "forensics")
 
 
 # ---------------------------------------------------------------------------

@@ -56,7 +56,8 @@ __all__ = ["NumericsError", "capture_cost", "register_cost",
            "program_cost", "programs",
            "note_executor_step", "note_serve_batch", "note_decode",
            "note_mfu_divergence",
-           "peak_flops", "peak_hbm_bytes_per_s", "mfu_summary",
+           "DEVICE_PEAKS", "device_peaks", "peak_flops",
+           "peak_hbm_bytes_per_s", "mfu_summary",
            "numerics_mode", "set_numerics", "numerics_policy",
            "set_numerics_policy", "set_spike_factor", "check_numerics",
            "numerics_trips", "watch", "unwatch", "rules",
@@ -113,19 +114,42 @@ _KINDS = ("executor_forward", "fused_step", "serve_bucket",
           "decode_prefill", "decode_step")
 
 
+# Published peaks of ONE chip, keyed by ``jax.Device.device_kind`` —
+# the single table behind every MFU / roofline denominator (live gauges
+# here, bench estimates in benchmark.py). Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s
+# HBM; the chip reports itself as "TPU v5 lite" (read off the device,
+# PR 21). v5e has no separate fp32 systolic path — under JAX's default
+# precision fp32 matmuls run the MXU with bf16 operands — so the bf16
+# peak is the fp32 denominator too. A kind not listed here has NO
+# peak: the live gauges stay unset and bench code raises; a default
+# would price another device's run with a v5e's roof.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks(device=None):
+    """The :data:`DEVICE_PEAKS` row of ``device`` (default: the first
+    JAX device), or None for a ``device_kind`` the table does not
+    hold."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return DEVICE_PEAKS.get(device.device_kind)
+
+
 def peak_flops():
-    """Peak accelerator FLOP/s for MFU denominators. Same knob and
-    default as benchmark.py's estimates (``MXNET_TPU_PEAK_FLOPS``,
-    v5e bf16 MXU peak) so measured and hand-counted MFU are
-    comparable. On a CPU backend the gauge self-describes as a probe
-    (platform is in every diagnostics dump)."""
-    return float(_config("MXNET_TPU_PEAK_FLOPS", 197e12))
+    """Peak FLOP/s of the attached chip, or None (unknown kind)."""
+    row = device_peaks()
+    return row["flops"] if row else None
 
 
 def peak_hbm_bytes_per_s():
-    """Peak HBM bandwidth (``MXNET_TPU_PEAK_HBM_GBPS``, default v5e
-    819 GB/s) for the bytes-accessed roofline axis."""
-    return float(_config("MXNET_TPU_PEAK_HBM_GBPS", 819.0)) * 1e9
+    """Peak HBM bandwidth of the attached chip, or None."""
+    row = device_peaks()
+    return row["hbm_bytes_per_s"] if row else None
 
 
 def capture_cost(kind, key, jitted, args, kwargs=None, pkey=None):
@@ -224,11 +248,13 @@ def programs():
 
 
 def _util(rec, seconds):
-    """(mfu, hbm_bw_util) of one program execution, or None."""
-    if rec is None or seconds is None or seconds <= 0:
+    """(mfu, hbm_bw_util) of one program execution, or None — also
+    for a device whose peaks are unknown: no gauge, never a default."""
+    row = device_peaks()
+    if rec is None or seconds is None or seconds <= 0 or row is None:
         return None
-    return (rec["flops"] / seconds / peak_flops(),
-            rec["bytes"] / seconds / peak_hbm_bytes_per_s())
+    return (rec["flops"] / seconds / row["flops"],
+            rec["bytes"] / seconds / row["hbm_bytes_per_s"])
 
 
 def note_executor_step(rec, seconds):
@@ -242,7 +268,7 @@ def note_executor_step(rec, seconds):
         tm.gauge("executor/mfu",
                  "Model FLOP/s utilization of the fused train step "
                  "(measured cost_analysis FLOPs / step wall / "
-                 "MXNET_TPU_PEAK_FLOPS)").set(util[0])
+                 "the chip's peak, health.DEVICE_PEAKS)").set(util[0])
         tm.gauge("executor/hbm_bw_util",
                  "HBM roofline utilization of the fused train step "
                  "(bytes accessed / step wall / peak bandwidth)"
@@ -319,8 +345,9 @@ def mfu_summary():
     """One-shot roofline summary for diagnostics(): current gauges plus
     the captured-program table."""
     tm = _tm()
+    hbm = peak_hbm_bytes_per_s()
     out = {"peak_flops": peak_flops(),
-           "peak_hbm_gbps": round(peak_hbm_bytes_per_s() / 1e9, 1),
+           "peak_hbm_gbps": round(hbm / 1e9, 1) if hbm else None,
            "programs": {}, "unavailable": 0}
     with _costs_lock:
         for (kind, key), rec in sorted(_costs.items()):

@@ -1,6 +1,6 @@
 """Symbolic model builders (reference: example/image-classification/symbols/).
 
-These mirror the reference's benchmark topologies so `bench.py` measures
+These mirror the reference's benchmark topologies so the benchmarks measure
 the same workloads as docs/faq/perf.md. The Gluon model zoo
 (`mxnet_tpu.gluon.model_zoo`) is the imperative counterpart.
 """

@@ -204,16 +204,6 @@ class BaseModule(object):
         """
         assert num_epoch is not None, "please specify number of epochs"
 
-        if checkpoint_prefix is not None or resume:
-            # a checkpointing (hence restartable) run wires the
-            # persistent compile cache up front: the resumed process's
-            # fused-step build — routed through programs.get_or_build —
-            # loads from disk instead of recompiling, so
-            # restore-to-first-step is dominated by the restore, not
-            # XLA (the train_resume bench banks both walls)
-            from .. import programs as _pg
-            _pg.ensure_persistent_cache()
-
         resume_state = None
         skip_nbatch = 0
         io_seeked = False
@@ -324,7 +314,7 @@ class BaseModule(object):
 
         # SIGTERM = preemption notice: checkpoint within the grace
         # window, then stop. The watchdog hard-exits at grace end —
-        # the platform reclaims the VM then regardless, and a wedged
+        # the platform reclaims the VM then regardless, and a hung
         # save must not make the process outstay the notice.
         preempt = {"flag": False, "watchdog": None}
         prev_handler = None
@@ -532,7 +522,7 @@ class BaseModule(object):
                                                  name, val)
                         train_data.reset()
                 except _rescale_errors as _mchange:
-                    # a membership change (dead peer, wedged
+                    # a membership change (dead peer, stalled
                     # collective, pending joiner): run the rescale
                     # barrier, rebuild on the surviving mesh, and
                     # re-enter the loop at the agreed step

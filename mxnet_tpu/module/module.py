@@ -190,7 +190,7 @@ class Module(BaseModule):
                 if name in cache:
                     cache_arr = cache[name]
                     if cache_arr is not arr:
-                        arr._set_data(cache_arr._data)
+                        cache_arr.copyto(arr)
                 else:
                     if not allow_missing:
                         raise RuntimeError("%s is not presented" % name)
@@ -223,10 +223,10 @@ class Module(BaseModule):
             return
         for name, arr in (arg_params or {}).items():
             if name in self._exec.arg_dict:
-                self._exec.arg_dict[name]._set_data(arr._data)
+                arr.copyto(self._exec.arg_dict[name])
         for name, arr in (aux_params or {}).items():
             if name in self._exec.aux_dict:
-                self._exec.aux_dict[name]._set_data(arr._data)
+                arr.copyto(self._exec.aux_dict[name])
         self.params_initialized = True
         self._params_dirty = True
 
@@ -352,8 +352,12 @@ class Module(BaseModule):
         if self._params_dirty:
             self._sync_params_from_devices()
 
+        # Module binds ONE executor however long the context list: over
+        # a dp mesh its gradients arrive already all-reduced (GSPMD), so
+        # a local/device kvstore has one device to see and nothing to
+        # reduce — the update stays in the fused step
         (kvstore, update_on_kvstore) = _create_kvstore(
-            kvstore, len(self._context), self._arg_params)
+            kvstore, 1, self._arg_params)
         batch_size = self._data_shapes[0].shape[0]
         if kvstore and "dist" in kvstore.type and "_sync" in kvstore.type:
             batch_size *= kvstore.num_workers

@@ -61,8 +61,8 @@ def flash_attention(q, k, v, **kwargs):
     interpret flag is resolved here from the data's actual device —
     inside the op jit only tracers are visible."""
     if "interpret" not in kwargs:
-        from ..ops.pallas.flash_attention import _interpret_default
-        kwargs["interpret"] = _interpret_default(q._data)
+        from ..ops.pallas.flash_attention import on_tpu
+        kwargs["interpret"] = not on_tpu(q._data)
     return invoke_op("_contrib_flash_attention", [q, k, v], kwargs)
 quantize = _wrap("_contrib_quantize", "quantize")
 quantize_v2 = _wrap("_contrib_quantize_v2", "quantize_v2")

@@ -139,11 +139,26 @@ class NDArray:
 
     def copyto(self, other):
         if isinstance(other, NDArray):
-            other._set_data(_device_put(self._data, other._ctx))
+            data, home = self._data, other._home()
+            if home is not None and data.is_fully_addressable:
+                import jax
+                data = jax.device_put(data, home)
+            other._set_data(data)
             return other
         if isinstance(other, Context):
             return NDArray(_device_put(self._data, other), ctx=other)
         raise TypeError("copyto does not support type %s" % type(other))
+
+    def _home(self):
+        """Where this array's buffer belongs: the mesh placement it
+        already holds (a dp mesh replicated or sharded it), else its
+        context's device. None for a global array of a multi-process
+        mesh: its mesh places it (Executor._dp_place), not a context."""
+        data = self._data
+        if not data.is_fully_addressable:
+            return None
+        held = data.sharding
+        return held if len(held.device_set) > 1 else self._ctx.jax_device()
 
     def as_in_context(self, ctx):
         if ctx == self._ctx:
@@ -449,6 +464,12 @@ class NDArray:
                 _jnp().asarray(value, dtype=self.dtype), self.shape)
         else:
             new = self._data.at[key].set(value)
+        home = self._home()
+        if home is not None:
+            # assignment never moves the array: a fill value computed on
+            # the default device lands, committed, where the buffer lives
+            import jax
+            new = jax.device_put(new, home)
         self._set_data(new)
 
     def __iter__(self):
@@ -487,10 +508,6 @@ def invoke_op(name, inputs, attrs, out=None):
         attrs["train_mode"] = autograd.is_training()
 
     arrays = [x._data if isinstance(x, NDArray) else x for x in inputs]
-    key = None
-    if op.needs_rng:
-        key = _random.next_key()
-        arrays = [key] + arrays
 
     ctx = None
     for x in inputs:
@@ -498,7 +515,24 @@ def invoke_op(name, inputs, attrs, out=None):
             ctx = x._ctx
             break
     if ctx is None:
-        ctx = current_context()
+        # creation op: it allocates on the destination's context (the
+        # reference allocates on ctx), computed THERE — a cpu() array
+        # never touches the chip, an initializer filling a tpu() array
+        # never detours through the host
+        first = out[0] if isinstance(out, (list, tuple)) else out
+        ctx = first._ctx if first is not None else current_context()
+        import jax
+        dev = ctx.jax_device()
+        device_scope = jax.default_device(dev)
+    else:
+        dev = None
+        device_scope = _NULL_SCOPE
+
+    key = None
+    if op.needs_rng:
+        with device_scope:
+            key = _random.next_key()
+        arrays = [key] + arrays
 
     from .. import engine as _engine
     if _engine.profiling_imperative():
@@ -515,7 +549,7 @@ def invoke_op(name, inputs, attrs, out=None):
                 if _tr._trace_ops and _tr.active() is not None
                 else _tr.NOOP)
     with tr_scope:
-        with prof_scope:
+        with prof_scope, device_scope:
             raw_out = _reg.invoke_raw(op, arrays, attrs)
             if _engine.is_naive():
                 # NaiveEngine debug mode: serialize every op (reference:
@@ -524,13 +558,10 @@ def invoke_op(name, inputs, attrs, out=None):
                     o.block_until_ready()
     if tm_token is not None:
         _tm.dispatch_end(name, tm_token)
-    if not any(isinstance(x, NDArray) for x in inputs):
-        # creation ops: honor the claimed context's device (the reference
-        # allocates on ctx; JAX would otherwise use the default device)
-        dev = ctx.jax_device()
-        if any(getattr(o, "device", None) != dev for o in raw_out):
-            import jax
-            raw_out = tuple(jax.device_put(o, dev) for o in raw_out)
+    if dev is not None:
+        # commit: an uncommitted result would follow whatever committed
+        # array it meets next, host batch included
+        raw_out = tuple(jax.device_put(o, dev) for o in raw_out)
 
     if op.mutate_inputs:
         for out_i, in_i in enumerate(op.mutate_inputs):

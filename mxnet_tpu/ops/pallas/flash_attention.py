@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "paged_decode_attention",
-           "flash_prefill_paged"]
+           "flash_prefill_paged", "on_tpu"]
 
 # kernel-contract registry: every exported Pallas kernel maps to its
 # module-level pure-lax twin (tools/check_pallas_contracts.py fails the
@@ -42,15 +42,21 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _interpret_default(x):
-    """Interpret (emulate) the kernel unless the data actually lives on
-    TPU: compiled Mosaic kernels only lower for the TPU backend, and jit
-    follows committed input devices (a cpu(0)-context NDArray must not
-    hit the TPU lowering, and vice versa)."""
-    try:
-        return any(d.platform != "tpu" for d in x.devices())
-    except Exception:  # tracer inside an outer jit: no device info
-        return jax.default_backend() != "tpu"
+def on_tpu(x=None):
+    """Whether the program consuming ``x`` is compiled for a TPU — the
+    one test every kernel entry point and caller branch uses to pick
+    the Mosaic kernel over interpret mode or a lax twin.
+
+    A concrete array answers with its own devices: jit follows
+    committed inputs, so a cpu(0)-context NDArray on a TPU machine runs
+    on the host. A tracer (or no array) has no devices; the enclosing
+    program is lowered for the default backend, and should its inputs
+    turn out to be committed to the host, the Mosaic lowering raises
+    ("Only interpret mode is supported on CPU backend") — on a TPU
+    machine a traced call can therefore never land in a twin."""
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return all(d.platform == "tpu" for d in x.devices())
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -135,21 +141,8 @@ def _out_vma(*xs):
     outputs carry the right `vma` under shard_map(check_vma=True)."""
     vma = frozenset()
     for x in xs:
-        try:
-            vma |= frozenset(jax.typeof(x).vma)
-        except Exception:
-            pass
+        vma |= jax.typeof(x).vma
     return vma
-
-
-def _sds(shape, dtype, vma):
-    """ShapeDtypeStruct carrying `vma` where this jax supports it;
-    jax 0.4.x has no varying-axes tracking to propagate (shard_map
-    check_rep covers replication there)."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _flash_fwd_xla(q, k, v, causal, sm_scale):
@@ -158,8 +151,10 @@ def _flash_fwd_xla(q, k, v, causal, sm_scale):
     Used when the kernel would run under the Pallas *interpreter* inside
     a shard_map manual context: the interpreter's internal dynamic_slice
     ops trip check_vma there (JAX-internal limitation). Off the manual
-    path the interpreter still exercises the real kernel logic, and on
-    TPU the compiled Mosaic kernel always runs.
+    path the interpreter still exercises the real kernel logic.
+    ``interpret`` is True only off-TPU (:func:`on_tpu`) or at the
+    caller's explicit request, so on TPU the compiled Mosaic kernel
+    always runs.
     """
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
@@ -197,6 +192,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, seq_len=s_k)
+    vma = _out_vma(q, k, v)
 
     o, lse = pl.pallas_call(
         kernel,
@@ -211,17 +207,16 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
-            _sds((b * h, sp_q, d), q.dtype, _out_vma(q, k, v)),
-            _sds((b * h, sp_q, _LANES), jnp.float32, _out_vma(q, k, v)),
+            jax.ShapeDtypeStruct((b * h, sp_q, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b * h, sp_q, _LANES), jnp.float32,
+                                 vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        # renamed TPUCompilerParams -> CompilerParams across jax releases
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
@@ -316,7 +311,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
-        interpret = _interpret_default(q)
+        interpret = not on_tpu(q)
     block_q = min(block_q, max(8, q.shape[2]))
     block_k = min(block_k, max(8, k.shape[2]))
     return _flash(q, k, v, bool(causal), float(sm_scale),
@@ -349,15 +344,23 @@ def _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size, sm_scale):
-    """Grid (b, kv_heads, pages_per_seq): the trailing page dimension
-    iterates sequentially per (sequence, head), accumulating an online
-    softmax in VMEM scratch exactly like the flash forward kernel —
-    the block table is scalar-prefetched so each step's page DMA is
-    issued from ``block_tables[b, p]`` before the body runs."""
+                  m_scr, l_scr, acc_scr, *, page_size, sm_scale, kv_heads):
+    """Grid (b, pages_per_seq): the trailing page dimension iterates
+    sequentially per sequence, accumulating an online softmax in VMEM
+    scratch exactly like the flash forward kernel — the block table is
+    scalar-prefetched so each step's page DMA is issued from
+    ``block_tables[b, p]`` before the body runs.
+
+    One step holds one whole page — every K/V head of it, block
+    ``(1, page_size, kv_heads, hd)`` — and loops over the heads in the
+    kernel. Mosaic refuses a per-head block ``(1, page_size, 1, hd)``
+    (its second-minor dim is 1 of ``kv_heads``: neither a multiple of 8
+    nor the full dim), and any reshape of the pool outside the kernel
+    is a relayout copy of the WHOLE pool per call; the full trailing
+    dims are legal as they are and move exactly the live pages."""
     b_i = pl.program_id(0)
-    p_i = pl.program_id(2)
-    n_p = pl.num_programs(2)
+    p_i = pl.program_id(1)
+    n_p = pl.num_programs(1)
 
     @pl.when(p_i == 0)
     def _init():
@@ -370,32 +373,34 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(start < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # (g, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (g, ps)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32) * sm_scale       # (g, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)            # (ps, hd)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)              # (g, ps)
+            kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(kpos < length, s, NEG_INF)
 
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, -1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (g, hd)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[h, :, :1] + jnp.sum(p, -1, keepdims=True)
+            v = v_ref[0, :, h, :].astype(jnp.float32)            # (ps, hd)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)              # (g, hd)
+            acc_scr[h] = acc_scr[h] * alpha + pv
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(p_i == n_p - 1)
     def _fin():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        for h in range(kv_heads):
+            l = l_scr[h, :, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -426,7 +431,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     block_tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     if interpret is None:
-        if _interpret_default(q):
+        if not on_tpu(q):
             # production off-TPU path: the XLA twin, not a python-
             # interpreted per-page DMA emulation (interpret=True still
             # forces the interpreter for kernel-logic tests)
@@ -443,39 +448,37 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, sm_scale,
     b, kvh, g, hd = q.shape
     num_pages, page_size = k_pages.shape[:2]
     n_pb = block_tables.shape[1]
-    grid = (b, kvh, n_pb)
 
-    def q_map(b_i, h_i, p_i, bt, ln):
-        return (b_i, h_i, 0, 0)
+    def q_map(b_i, p_i, bt, ln):
+        return (b_i, 0, 0, 0)
 
-    def kv_map(b_i, h_i, p_i, bt, ln):
-        return (bt[b_i, p_i], 0, h_i, 0)
+    def kv_map(b_i, p_i, bt, ln):
+        return (bt[b_i, p_i], 0, 0, 0)
 
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(b, n_pb),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), q_map),
-            pl.BlockSpec((1, page_size, 1, hd), kv_map),
-            pl.BlockSpec((1, page_size, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, g, hd), q_map),
+            pl.BlockSpec((1, page_size, kvh, hd), kv_map),
+            pl.BlockSpec((1, page_size, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), q_map),
+        out_specs=pl.BlockSpec((1, kvh, g, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, _LANES), jnp.float32),
-            pltpu.VMEM((g, _LANES), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((kvh, g, _LANES), jnp.float32),
+            pltpu.VMEM((kvh, g, _LANES), jnp.float32),
+            pltpu.VMEM((kvh, g, hd), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, page_size=page_size,
-                               sm_scale=sm_scale)
+                               sm_scale=sm_scale, kv_heads=kvh)
     return pl.pallas_call(
         kernel,
         grid_spec=spec,
-        out_shape=_sds((b, kvh, g, hd), q.dtype,
-                       _out_vma(q, k_pages, v_pages)),
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, kvh, g, hd), q.dtype, vma=_out_vma(q, k_pages, v_pages)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, q, k_pages, v_pages)
 
@@ -512,26 +515,34 @@ def _flash_prefill_xla(q, kg, vg, k_pages, v_pages, block_tables):
     return o, kp, vp
 
 
-def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, kg_ref, vg_ref,
-                    kp_in, vp_in, o_ref, kp_out, vp_out,
-                    m_scr, l_scr, acc_scr, ksem, vsem, *,
-                    sm_scale, block_q, block_k, page_size, seq_len):
-    """Grid (b, heads, q_blocks, k_blocks): per (batch, head, q tile)
-    the trailing k dimension accumulates an online softmax in VMEM
-    scratch exactly like ``_fwd_kernel``, but K/V stay in the compact
-    GQA layout — grouped query heads index their shared K/V head via
-    the block index map, never materialising the expanded (b, s, nh,
-    hd) tensors the lax twin builds. The page write rides the same
-    pass: the first (head, q-tile) visit of each k block DMAs that
-    block's freshly computed K/V straight from HBM into its rows' pool
-    pages (``block_k`` is a multiple of ``page_size``, so each page is
-    written exactly once per layer and the separate reshape-scatter
-    program — and its HBM round-trip — disappears)."""
+def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
+                    block_k, page_size, seq_len, n_heads, groups,
+                    write_pages):
+    """Grid (b, q_blocks, k_blocks): per (batch, q tile) the trailing k
+    dimension accumulates an online softmax in VMEM scratch exactly
+    like ``_fwd_kernel``, for every head in turn. The tiles keep the
+    caller's ``(b, s, heads, hd)`` layout with the FULL trailing
+    ``(heads, hd)`` dims — Mosaic refuses a per-head ``(1, block, 1,
+    hd)`` block (second-minor dim 1 of ``heads``) — and K/V stay in the
+    compact GQA layout: query head ``h`` reads K/V head ``h // groups``,
+    never materialising the expanded (b, s, nh, hd) tensors the lax
+    twin builds.
+
+    With ``write_pages`` the page write rides the same pass: the first
+    q-tile visit of each k block DMAs that block's freshly computed K/V
+    straight from HBM into its rows' pool pages (``block_k`` is a
+    multiple of ``page_size``, so each page is written exactly once per
+    layer and the separate reshape-scatter program — and its HBM
+    round-trip — disappears)."""
+    if write_pages:
+        (kg_ref, vg_ref, _kp_in, _vp_in, o_ref, kp_out, vp_out,
+         m_scr, l_scr, acc_scr, ksem, vsem) = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     b_i = pl.program_id(0)
-    h_i = pl.program_id(1)
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -542,42 +553,46 @@ def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, kg_ref, vg_ref,
     q_start = qi * block_q
     k_start = ki * block_k
 
-    @pl.when(jnp.logical_and(h_i == 0, qi == 0))
-    def _write_pages():
-        for j in range(block_k // page_size):
-            page = bt_ref[b_i, ki * (block_k // page_size) + j]
-            src = pl.ds(k_start + j * page_size, page_size)
-            kcp = pltpu.make_async_copy(kg_ref.at[b_i, src],
-                                        kp_out.at[page], ksem)
-            vcp = pltpu.make_async_copy(vg_ref.at[b_i, src],
-                                        vp_out.at[page], vsem)
-            kcp.start()
-            vcp.start()
-            kcp.wait()
-            vcp.wait()
+    if write_pages:
+        @pl.when(qi == 0)
+        def _write_pages():
+            for j in range(block_k // page_size):
+                page = bt_ref[b_i, ki * (block_k // page_size) + j]
+                src = pl.ds(k_start + j * page_size, page_size)
+                kcp = pltpu.make_async_copy(kg_ref.at[b_i, src],
+                                            kp_out.at[page], ksem)
+                vcp = pltpu.make_async_copy(vg_ref.at[b_i, src],
+                                            vp_out.at[page], vsem)
+                kcp.start()
+                vcp.start()
+                kcp.wait()
+                vcp.wait()
 
     def _body():
-        q = q_ref[0, :, 0].astype(jnp.float32) * sm_scale     # (bq, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)                # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bq, bk)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(jnp.logical_and(kpos < seq_len, kpos <= qpos),
-                      s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, -1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bq, d)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        kpos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        qpos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        visible = jnp.logical_and(kpos < seq_len, kpos <= qpos)
+        for h in range(n_heads):
+            q = q_ref[0, :, h, :].astype(jnp.float32) * sm_scale   # (bq, d)
+            k = k_ref[0, :, h // groups, :].astype(jnp.float32)    # (bk, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)                # (bq, bk)
+            s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[h, :, :1] + jnp.sum(p, -1, keepdims=True)
+            v = v_ref[0, :, h // groups, :].astype(jnp.float32)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)                # (bq, d)
+            acc_scr[h] = acc_scr[h] * alpha + pv
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     # skip K blocks entirely above the causal diagonal (the page-write
     # epilogue above must NOT be skipped: padded-tail pages are still
@@ -588,9 +603,16 @@ def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, kg_ref, vg_ref,
 
     @pl.when(ki == nk - 1)
     def _fin():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        for h in range(n_heads):
+            l = l_scr[h, :, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, :, h, :] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
+
+
+# VMEM the prefill tiles may claim (q and o tiles double-buffered by the
+# pipeline + the f32 scratch): half of a v5e core's 16 MiB scoped limit,
+# the rest is the K/V tiles and the compiler's own stack
+_PREFILL_VMEM_BUDGET = 8 << 20
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
@@ -599,62 +621,77 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
                    block_q, block_k, interpret):
     b, s, nh, hd = q.shape
     kvh = kg.shape[2]
-    groups = nh // kvh
     ps = k_pages.shape[1]
-    sm_scale = 1.0 / math.sqrt(hd)
-    grid = (b, nh, s // block_q, s // block_k)
+    # Mosaic can only slice an HBM ref whose minor dim fills whole
+    # 128-lane tiles, so the DMA page write needs head_dim % 128 == 0;
+    # narrower heads get the same attention kernel and the twin's
+    # in-place XLA scatter for the pages
+    write_pages = hd % _LANES == 0
+    grid = (b, s // block_q, s // block_k)
 
-    def q_map(b_i, h_i, qi, ki, bt):
-        return (b_i, qi, h_i, 0)
+    def q_map(b_i, qi, ki, bt):
+        return (b_i, qi, 0, 0)
 
-    def kv_map(b_i, h_i, qi, ki, bt):
-        return (b_i, ki, h_i // groups, 0)
+    def kv_map(b_i, qi, ki, bt):
+        return (b_i, ki, 0, 0)
 
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), q_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # kg: page-write src
-            pl.BlockSpec(memory_space=pltpu.ANY),   # vg: page-write src
-            pl.BlockSpec(memory_space=pltpu.ANY),   # k_pages (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # v_pages (aliased)
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), q_map),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
+    q_spec = pl.BlockSpec((1, block_q, nh, hd), q_map)
+    kv_spec = pl.BlockSpec((1, block_k, kvh, hd), kv_map)
+    hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    scratch = [
+        pltpu.VMEM((nh, block_q, _LANES), jnp.float32),
+        pltpu.VMEM((nh, block_q, _LANES), jnp.float32),
+        pltpu.VMEM((nh, block_q, hd), jnp.float32),
+    ]
     kernel = functools.partial(
-        _prefill_kernel, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, page_size=ps, seq_len=s)
+        _prefill_kernel, sm_scale=1.0 / math.sqrt(hd), block_q=block_q,
+        block_k=block_k, page_size=ps, seq_len=s, n_heads=nh,
+        groups=nh // kvh, write_pages=write_pages)
     vma = _out_vma(q, kg, vg, k_pages, v_pages)
+    # the per-head store is strided over the heads dim, which Mosaic
+    # cannot do on a packed (sub-32-bit) tile narrower than 128 lanes:
+    # such outputs leave the kernel as f32 and are cast outside
+    o_dtype = q.dtype if q.dtype.itemsize >= 4 or write_pages \
+        else jnp.float32
+    o_shape = jax.ShapeDtypeStruct((b, s, nh, hd), o_dtype, vma=vma)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    if not write_pages:
+        o = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid,
+                in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+                scratch_shapes=scratch),
+            out_shape=o_shape, compiler_params=params, interpret=interpret,
+        )(block_tables, q, kg, vg)
+        n_pb = s // ps
+        return (o.astype(q.dtype),
+                k_pages.at[block_tables].set(
+                    kg.reshape(b, n_pb, ps, kvh, hd).astype(k_pages.dtype)),
+                v_pages.at[block_tables].set(
+                    vg.reshape(b, n_pb, ps, kvh, hd).astype(v_pages.dtype)))
     return pl.pallas_call(
         kernel,
-        grid_spec=spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            # kg/vg ride twice: blocked tiles for the attention, whole
+            # HBM refs as the page-write source
+            in_specs=[q_spec, kv_spec, kv_spec,
+                      hbm_spec, hbm_spec, hbm_spec, hbm_spec],
+            out_specs=[q_spec, hbm_spec, hbm_spec],
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA,
+                                      pltpu.SemaphoreType.DMA]),
         out_shape=[
-            _sds((b, s, nh, hd), q.dtype, vma),
-            _sds(k_pages.shape, k_pages.dtype, vma),
-            _sds(v_pages.shape, v_pages.dtype, vma),
+            o_shape,
+            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype, vma=vma),
         ],
         # pool arrays alias in->out: pages no row writes keep their
         # contents, and on TPU the pool is updated in place (operand
         # order counts the scalar-prefetch arg: bt=0 ... k_pages=6)
         input_output_aliases={6: 1, 7: 2},
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
     )(block_tables, q, kg, vg, kg, vg, k_pages, v_pages)
 
@@ -696,7 +733,7 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
                          % (s, s // ps, block_tables.shape[1]))
     block_tables = jnp.asarray(block_tables, jnp.int32)[:, :s // ps]
     if interpret is None:
-        if _interpret_default(q):
+        if not on_tpu(q):
             return _flash_prefill_xla(q, kg, vg, k_pages, v_pages,
                                       block_tables)
         interpret = False
@@ -707,6 +744,12 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
         block_k -= ps
     block_q = min(block_q, s)
     while s % block_q:
+        block_q //= 2
+    # every head of a q tile is resident at once: q and o tiles (double-
+    # buffered) plus the f32 m/l/acc scratch, per q row
+    row_bytes = nh * (2 * hd * (q.dtype.itemsize + 4)
+                      + 4 * (2 * _LANES + hd))
+    while block_q > 8 and block_q * row_bytes > _PREFILL_VMEM_BUDGET:
         block_q //= 2
     return _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
                           int(block_q), int(block_k), bool(interpret))

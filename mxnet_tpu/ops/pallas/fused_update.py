@@ -36,7 +36,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
+from .flash_attention import on_tpu
 
 __all__ = ["sgd_fused_update", "adam_fused_update"]
 
@@ -110,9 +110,7 @@ def _fused_update(rule, hv, w, g, state, hyper_keys, block_rows,
         # g=2, state=3..)
         input_output_aliases=dict(
             [(1, 0)] + [(3 + i, 1 + i) for i in range(n_state)]),
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams",
-                                        None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(hv, wf, gf, *sf)
@@ -123,7 +121,7 @@ def _fused_update(rule, hv, w, g, state, hyper_keys, block_rows,
 
 def _fused_update_dispatch(rule, w, g, state, h, block_rows, interpret):
     if interpret is None:
-        if _interpret_default(w):
+        if not on_tpu(w):
             return rule(w, g, tuple(state), h)
         interpret = False
     hyper_keys = tuple(sorted(h))

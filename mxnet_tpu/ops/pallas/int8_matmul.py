@@ -30,7 +30,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default, _out_vma, _pad_to, _sds
+from .flash_attention import _out_vma, _pad_to, on_tpu
 
 __all__ = ["int8_matmul", "int8_conv_im2col"]
 
@@ -93,11 +93,11 @@ def _int8_matmul_pallas(x, w, scale, block_m, block_n, block_k, interpret):
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni, ki: (mi, ni)),
-        out_shape=_sds((xf.shape[0], wf.shape[0]), jnp.float32,
-                       _out_vma(x, w, scale)),
+        out_shape=jax.ShapeDtypeStruct(
+            (xf.shape[0], wf.shape[0]), jnp.float32,
+            vma=_out_vma(x, w, scale)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xf, wf, sf)
@@ -126,7 +126,7 @@ def int8_matmul(x, w, scale, block_m=128, block_n=128, block_k=128,
     x = x.astype(jnp.int8)
     w = w.astype(jnp.int8)
     if interpret is None:
-        if _interpret_default(x):
+        if not on_tpu(x):
             return _int8_matmul_xla(x, w, scale)
         interpret = False
     m, k = x.shape

@@ -260,10 +260,9 @@ def _quantized_conv_int8(data, weight, scale, bias=None, kernel=(),
             3: ("NCDHW", "OIDHW", "NCDHW")}[nd]
     q = _quantize_act(data, act_scale)
     if nd == 2:
-        import jax as _jax
         from .. import config as _config
-        if (_jax.default_backend() == "tpu"
-                or _config.get("MXNET_INT8_CONV_IM2COL")):
+        from .pallas.flash_attention import on_tpu
+        if on_tpu(data) or _config.get("MXNET_INT8_CONV_IM2COL"):
             # im2col route: lower the 2-D conv onto the int8 MXU matmul
             # kernel with the per-channel rescale fused in its epilogue
             # (the PR 11 escape hatch). int32 accumulation is exact, so

@@ -114,13 +114,6 @@ def list_ops():
 @functools.lru_cache(maxsize=None)
 def _jitted(name, attr_key, donate_ok=False):
     import jax
-    # wire the persistent compile cache BEFORE the first eager compile:
-    # bind-time fills (zeros, param loads) run before any registry
-    # get_or_build, and a replica's cold run must write THOSE programs
-    # to disk too or the warm run re-compiles them (cheap no-op once
-    # configured; this builder runs once per (op, attrs))
-    from .. import programs as _programs
-    _programs.ensure_persistent_cache()
     op = _REGISTRY[name]
     attrs = dict(attr_key)
 

@@ -1043,6 +1043,24 @@ class Test(Optimizer):
         state._set_data((state + grad)._data)
 
 
+def _colocate(state, weight):
+    """Place a freshly created optimizer state where its weight actually
+    lives. ``create_state`` allocates on ``weight.context`` — one device —
+    but under a data-parallel mesh the weight is replicated over every
+    device of the mesh, and an update over mixed placements is an error,
+    not a silent copy. (A mesh spanning processes is the fused step's
+    alone; Executor.train_step assembles those states itself.)"""
+    if isinstance(state, NDArray):
+        home = weight._home()
+        if home is not None:
+            import jax
+            state._set_data(jax.device_put(state._data, home))
+    elif isinstance(state, (tuple, list)):
+        for s in state:
+            _colocate(s, weight)
+    return state
+
+
 class Updater(object):
     """Applies an optimizer to (index, grad, weight) triples — the callable
     installed on KVStore (reference: optimizer.py Updater / get_updater)."""
@@ -1057,8 +1075,9 @@ class Updater(object):
         ``index``; returns it. Shared by the per-param path below and the
         fused train step, so their bookkeeping can never drift."""
         if index not in self.states:
-            self.states[index] = self.optimizer.create_state_multi_precision(
-                index, weight)
+            self.states[index] = _colocate(
+                self.optimizer.create_state_multi_precision(index, weight),
+                weight)
             self.states_synced[index] = True
         elif not self.states_synced[index]:
             self.states[index] = self.sync_state_context(self.states[index],

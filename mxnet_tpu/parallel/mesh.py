@@ -130,16 +130,7 @@ def make_batch_global(mesh, host_local_batch, axis="dp"):
     import numpy as np
     data = np.asarray(host_local_batch)
     sh = data_parallel_sharding(mesh, axis=axis, ndim=max(data.ndim, 1))
-    make = getattr(jax, "make_array_from_process_local_data", None)
-    if make is not None:
-        return make(sh, data)
-    # older jax: split the local rows over the local devices by hand
-    local = list(mesh.local_devices)
-    chunks = np.split(data, len(local))
-    nproc = mesh_process_count(mesh)
-    gshape = (data.shape[0] * nproc,) + data.shape[1:]
-    arrs = [jax.device_put(c, d) for c, d in zip(chunks, local)]
-    return jax.make_array_from_single_device_arrays(gshape, sh, arrs)
+    return jax.make_array_from_process_local_data(sh, data)
 
 
 def make_accum_batch_global(mesh, host_local_batch, axis="dp"):
@@ -158,12 +149,4 @@ def make_accum_batch_global(mesh, host_local_batch, axis="dp"):
         raise ValueError("accum batch needs shape [A, L, ...], got %s"
                          % (tuple(data.shape),))
     sh = NamedSharding(mesh, P(None, axis, *([None] * (data.ndim - 2))))
-    make = getattr(jax, "make_array_from_process_local_data", None)
-    if make is not None:
-        return make(sh, data)
-    local = list(mesh.local_devices)
-    chunks = np.split(data, len(local), axis=1)
-    nproc = mesh_process_count(mesh)
-    gshape = (data.shape[0], data.shape[1] * nproc) + data.shape[2:]
-    arrs = [jax.device_put(c, d) for c, d in zip(chunks, local)]
-    return jax.make_array_from_single_device_arrays(gshape, sh, arrs)
+    return jax.make_array_from_process_local_data(sh, data)

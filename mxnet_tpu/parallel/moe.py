@@ -21,7 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["top_k_gating", "moe_apply", "stack_expert_params"]
 

@@ -26,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_apply", "stack_stage_params"]
 

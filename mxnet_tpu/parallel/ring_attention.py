@@ -23,7 +23,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
+
+from ..ops.pallas.flash_attention import on_tpu
 
 NEG_INF = -1e30
 
@@ -67,8 +69,7 @@ def _ring_attention_local(q, k, v, axis_name, causal, sm_scale,
         impl = "flash"
     if impl == "flash":
         if interpret is None:
-            import jax as _jax
-            interpret = _jax.default_backend() != "tpu"
+            interpret = not on_tpu(q)
         return _ring_flash(q, k, v, axis_name, bool(causal),
                            float(sm_scale), bool(interpret))
     return _ring_einsum_local(q, k, v, axis_name, causal, sm_scale)

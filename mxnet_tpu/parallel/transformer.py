@@ -30,8 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
+from ..ops.pallas.flash_attention import on_tpu
 from .ring_attention import _ring_attention_local
 from .moe import top_k_gating
 
@@ -204,12 +205,8 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
 
 def _pvary(x, axes):
     """pcast to varying only over axes x is not already varying on
-    (pcast rejects varying->varying). jax 0.4.x has no varying-manual-
-    axes tracking (no jax.typeof/pcast) — there shard_map's own
-    replication checking covers this and the cast is a no-op."""
-    if not hasattr(jax, "typeof"):
-        return x
-    cur = getattr(jax.typeof(x), "vma", frozenset())
+    (pcast rejects varying->varying)."""
+    cur = jax.typeof(x).vma
     missing = tuple(a for a in axes if a not in cur)
     return jax.lax.pcast(x, missing, to="varying") if missing else x
 
@@ -658,7 +655,7 @@ def _cache_attend(cache, li, q, pos_b, cfg):
     b, nh, hd = q.shape
     kvh = _kv_heads(cfg)
     if isinstance(cache, PagedKVCache):
-        if jax.default_backend() == "tpu":
+        if on_tpu(q):
             from ..ops.pallas.flash_attention import paged_decode_attention
             o = paged_decode_attention(
                 q.reshape(b, kvh, nh // kvh, hd),
@@ -805,8 +802,7 @@ def _prefill_impl(params, tokens, cache, cfg, lengths):
                 pos = jnp.arange(s)
                 q = _rope_bshd(q, pos, cfg.rope_base)
                 kg = _rope_bshd(kg, pos, cfg.rope_base)
-            if (isinstance(cache, PagedKVCache)
-                    and jax.default_backend() == "tpu"):
+            if isinstance(cache, PagedKVCache) and on_tpu(q):
                 # fused Pallas prefill: one program computes the causal
                 # attention AND writes this layer's pages in its DMA
                 # epilogue — the kernel's lax twin is op-for-op the

@@ -33,7 +33,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
+
+from ..ops.pallas.flash_attention import on_tpu
 
 __all__ = ["ulysses_attention", "ulysses_self_attention"]
 
@@ -66,7 +68,7 @@ def _ulysses_local(q, k, v, axis_name, causal, sm_scale, impl,
     attention the inverse all_to_all restores (b, h, S/n, d).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu(q)
     # split h across the axis, gather the full sequence
     qh = jax.lax.all_to_all(q, axis_name, split_axis=1, concat_axis=2,
                             tiled=True)
@@ -107,7 +109,7 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu(q)
     spec = P(None, None, axis, None)
     fn = shard_map(
         functools.partial(_ulysses_local, axis_name=axis,

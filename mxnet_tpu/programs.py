@@ -22,10 +22,15 @@ behind:
    are the cold-start measurement), and (when a site attaches one) the
    program's XLA cost-analysis record from ``health.capture_cost``.
 
-2. **Persistent compile cache** — when ``MXNET_COMPILE_CACHE_DIR`` is
-   set, JAX's persistent compilation cache is wired underneath
-   (``jax_compilation_cache_dir``), so a compile in a FRESH process
-   deserializes the executable from disk instead of running XLA.
+2. **Persistent compile cache** — JAX's persistent compilation cache
+   is always on, so a compile in a FRESH process deserializes the
+   executable from disk instead of running XLA. Its directory is
+   placed from OUTSIDE the program: where ``JAX_COMPILATION_CACHE_DIR``
+   is set JAX picks it up itself and no code sets a directory; where
+   it is not, :func:`configure_compile_cache` (called once at ``import
+   mxnet_tpu``, before anything can compile) points it at the fixed
+   ``<checkout>/.jax_cache``. The path is part of the cache key, so it
+   never moves: no temp, pid or time component.
    Telemetry distinguishes the two honestly: a disk load still counts
    as a compile *request* (``jit/backend_compile_total`` — every
    zero-recompile assertion keeps meaning "zero traces"), while
@@ -49,7 +54,8 @@ behind:
    DecodeEngine's two-pass warmup discovered, so the next subsystem
    doesn't rediscover the bug.
 
-Knobs: ``MXNET_COMPILE_CACHE_DIR``, ``MXNET_PROGRAMS_MAX`` (config.py).
+Knobs: ``JAX_COMPILATION_CACHE_DIR`` (JAX's own),
+``MXNET_PROGRAMS_MAX`` (config.py).
 Docs: docs/compile_cache.md. Bench: ``benchmark.py --job cold_start``.
 """
 from __future__ import annotations
@@ -66,7 +72,7 @@ from .base import MXNetError
 
 __all__ = ["ProgramKey", "fingerprint", "graph_hash", "version_salt",
            "get_or_build", "attach_cost", "prewarm", "warm_twice",
-           "next_instance", "ensure_persistent_cache", "cache_dir",
+           "next_instance", "configure_compile_cache", "cache_dir",
            "warmset_path", "load_warmset", "note_warm", "stats",
            "entries", "reset", "WARMSET_FORMAT"]
 
@@ -79,7 +85,6 @@ _entries = OrderedDict()        # fingerprint -> _Entry (LRU order)
 _build_locks = {}               # fingerprint -> Lock (never removed; tiny)
 _warmset_lock = threading.Lock()
 _warmset_seen = set()           # (path, fp) known recorded: skip the RMW
-_active_cache_dir = [None]      # the dir jax is currently configured with
 _instance_seq = [0]
 _salt_cache = [None]
 
@@ -222,55 +227,30 @@ def next_instance(prefix):
 # persistent compile cache wiring
 # ---------------------------------------------------------------------------
 
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def configure_compile_cache():
+    """Place the persistent compile cache, once, at ``import
+    mxnet_tpu``: nothing when ``JAX_COMPILATION_CACHE_DIR`` already
+    placed it from outside, else the fixed in-checkout directory. The
+    min-compile-time and min-entry-size gates are zeroed either way so
+    every program is cached, not just the slow ones — parameter init
+    and bind-time fills compile dozens of tiny eager programs."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
 def cache_dir():
-    """The configured persistent-cache directory, or None."""
-    d = _config("MXNET_COMPILE_CACHE_DIR")
-    return os.path.abspath(d) if d else None
-
-
-def ensure_persistent_cache():
-    """Point JAX's persistent compilation cache at
-    ``MXNET_COMPILE_CACHE_DIR`` (idempotent; reconfigures on a dir
-    change and detaches when the var is cleared). The min-compile-time
-    and min-entry-size gates are zeroed so every program in a serve
-    ladder is cached, not just the slow ones. Returns the active dir
-    or None."""
-    d = cache_dir()
-    if d == _active_cache_dir[0]:
-        return d
-    try:
-        import jax
-    except Exception:
-        return None
-    try:
-        if d is None:
-            jax.config.update("jax_compilation_cache_dir", None)
-        else:
-            os.makedirs(d, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", d)
-            for knob, val in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1)):
-                try:
-                    jax.config.update(knob, val)
-                except Exception:
-                    pass
-        try:
-            # jax decides cache-or-not ONCE per task; a dir set after
-            # the process's first compile must still take effect
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
-    except Exception as e:
-        _log.warning("persistent compile cache unavailable: %s", e)
-        return None
-    _active_cache_dir[0] = d
-    if d is not None:
-        tm = _tm()
-        if tm._enabled:
-            tm._ensure_compile_listener()
-    return d
+    """The resolved persistent-cache directory (``warmset.json`` and
+    the forensics reports live under it)."""
+    import jax
+    return os.path.abspath(jax.config.jax_compilation_cache_dir)
 
 
 # ---------------------------------------------------------------------------
@@ -310,8 +290,7 @@ def get_or_build(key, build_fn, retain=True):
     so concurrent unrelated builds don't cross-count; note the bracket
     covers ``build_fn`` only, so a site returning a lazily-jitted
     callable attributes its compile to the first invocation — the
-    prewarm report — not the entry), recorded in the warm-set manifest
-    when a cache dir is configured, and bounded by
+    prewarm report — not the entry), recorded in the warm-set manifest, and bounded by
     ``MXNET_PROGRAMS_MAX`` with LRU eviction telemetry.
 
     ``retain=False`` measures and counts the build but does NOT store
@@ -343,7 +322,6 @@ def get_or_build(key, build_fn, retain=True):
                     _entries.move_to_end(fp)
                     e.uses += 1
                     return e.value
-            ensure_persistent_cache()
             if tm._enabled:
                 tm._ensure_compile_listener()
             t0 = tm.monotonic()
@@ -420,7 +398,7 @@ def stats():
             "build_s_total": round(sum(e.build_s for e in rows), 3),
             "compile_requests": sum(e.compile_requests for e in rows),
             "disk_hits": sum(e.disk_hits for e in rows),
-            "cache_dir": _active_cache_dir[0]}
+            "cache_dir": cache_dir()}
 
 
 def reset():
@@ -437,10 +415,7 @@ def reset():
 # ---------------------------------------------------------------------------
 
 def warmset_path(directory=None):
-    d = directory or cache_dir()
-    if d is None:
-        return None
-    return os.path.join(d, "warmset.json")
+    return os.path.join(directory or cache_dir(), "warmset.json")
 
 
 def load_warmset(path=None):
@@ -448,7 +423,7 @@ def load_warmset(path=None):
     missing, torn, or corrupt file by degrading to empty — prewarm then
     falls back to a cold compile, never a crash."""
     path = path or warmset_path()
-    if path is None or not os.path.exists(path):
+    if not os.path.exists(path):
         return {}
     try:
         with open(path) as f:
@@ -486,13 +461,12 @@ def load_warmset(path=None):
 def _append_warmset(key):
     """Record one program's fingerprint + abstract input spec in
     ``<cache_dir>/warmset.json`` (atomic_writer: readers never see a
-    torn file). No-op without a cache dir. Instance-salted keys are
-    NOT recorded: their fingerprints have no cross-process identity,
+    torn file). Instance-salted keys are NOT recorded: their fingerprints have no cross-process identity,
     so prewarm could never replay them — they would only grow the
     manifest without bound in long-lived processes."""
-    path = warmset_path()
-    if path is None or key.instance is not None:
+    if key.instance is not None:
         return
+    path = warmset_path()
     from .checkpoint import atomic_writer
     fp = key.fingerprint
     # a fingerprint this process already recorded (or found recorded)
@@ -501,6 +475,7 @@ def _append_warmset(key):
     # parses per warmup for entries that are all already on disk
     if (path, fp) in _warmset_seen:
         return
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     with _warmset_lock, _warmset_flock(path):
         # (re)load INSIDE both locks: _warmset_lock serializes threads,
         # the flock serializes replicas sharing one cache dir — without
@@ -590,7 +565,6 @@ def prewarm(sites, include=(), graph=None, manifest=None,
     (counted skipped, not replayed). Returns a report dict.
     """
     tm = _tm()
-    ensure_persistent_cache()
     salt = version_salt()
     todo, seen = [], set()
     for kind, spec in include:

@@ -73,9 +73,8 @@ class Kernel(object):
         if out_shapes is None:
             out_shapes = [(arrays[0].shape, arrays[0].dtype)]
         if interpret is None:
-            interpret = not all(
-                d.platform == "tpu"
-                for a in arrays for d in a.devices())
+            from .ops.pallas.flash_attention import on_tpu
+            interpret = not all(on_tpu(a) for a in arrays)
         key = (tuple((tuple(a.shape), str(a.dtype)) for a in arrays),
                tuple((tuple(s), str(d)) for s, d in out_shapes),
                grid, interpret)

@@ -75,9 +75,8 @@ def _prefill_variant():
     boundary compares like with like instead of silently overwriting
     the xla-prefill baseline record with the pallas one (stale manifest
     entries under the old key are skipped by prewarm, not replayed)."""
-    import jax
-    return ("pallas-prefill" if jax.default_backend() == "tpu"
-            else "xla-prefill")
+    from ..ops.pallas.flash_attention import on_tpu
+    return "pallas-prefill" if on_tpu() else "xla-prefill"
 
 
 class DecodeConfig(object):
@@ -350,8 +349,7 @@ class DecodeEngine(object):
         """Compile + execute every bucket program (scheduler thread),
         routed through :func:`programs.prewarm` — the configured
         buckets plus any warm-set manifest entries for this model
-        replay here, loading from the persistent compile cache when
-        ``MXNET_COMPILE_CACHE_DIR`` is set.
+        replay here, loading from the persistent compile cache.
 
         Each program is warmed with :func:`programs.warm_twice`: these
         are DONATED loops (every call donates and returns the page

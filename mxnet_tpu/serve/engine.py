@@ -289,9 +289,8 @@ class InferenceEngine(object):
 
         Routes through :func:`programs.prewarm`: the configured ladder
         plus any warm-set manifest entries for this graph replay here —
-        with ``MXNET_COMPILE_CACHE_DIR`` set, a fresh replica loads
-        every program from the persistent cache on disk instead of
-        running XLA (``programs/disk_hits_total`` vs
+        a fresh replica loads every program from the persistent cache
+        on disk instead of running XLA (``programs/disk_hits_total`` vs
         ``programs/compile_total`` tells them apart; the report lands
         in :attr:`warm_report`)."""
         include = [("serve_bucket", self._bucket_spec(b))

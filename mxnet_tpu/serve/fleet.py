@@ -59,6 +59,7 @@ from ..base import MXNetError
 from ..config import get as _cfg
 from .. import blackbox as _bb
 from .. import fault as _fault
+from .. import programs as _pg
 from .. import telemetry as _tm
 from ..checkpoint import ProcessSupervisor
 from .router import Router
@@ -187,11 +188,10 @@ class Fleet(object):
         return "r%d" % self._counter
 
     def _warm_manifest_present(self, env):
-        cache = env.get("MXNET_COMPILE_CACHE_DIR") \
-            or os.environ.get("MXNET_COMPILE_CACHE_DIR")
-        if not cache:
-            return False
-        return os.path.exists(os.path.join(cache, "warmset.json"))
+        # env is the child's full environment; without a placement
+        # from outside the child resolves the same default as we do
+        cache = env.get("JAX_COMPILATION_CACHE_DIR") or _pg.cache_dir()
+        return os.path.exists(_pg.warmset_path(cache))
 
     def _spawn(self, reason):
         """Launch one worker and wait for it to serve; registers it

@@ -559,7 +559,7 @@ def _on_jax_event(name, secs, **_kw):
             counter("programs/disk_hits_total",
                     "Compile requests served from the persistent "
                     "compilation cache on disk "
-                    "(MXNET_COMPILE_CACHE_DIR)").inc()
+                    "(programs.cache_dir())").inc()
         else:
             counter("programs/compile_total",
                     "Real XLA backend compiles (persistent-cache "

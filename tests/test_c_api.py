@@ -21,7 +21,7 @@ def _child_env():
     env["PYTHONPATH"] = os.pathsep.join([REPO] + site +
                                         [env.get("PYTHONPATH", "")])
     env.pop("PYTHONHOME", None)
-    env["MXNET_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

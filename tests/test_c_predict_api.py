@@ -101,7 +101,7 @@ def test_c_predict_end_to_end(tmp_path, native_lib):
     env["PYTHONPATH"] = os.pathsep.join([REPO] + site +
                                         [env.get("PYTHONPATH", "")])
     env.pop("PYTHONHOME", None)
-    env["MXNET_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([exe, json_path, params_path], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -157,5 +157,5 @@ def _perl_env():
     env["PYTHONPATH"] = os.pathsep.join([REPO] + site +
                                         [env.get("PYTHONPATH", "")])
     env.pop("PYTHONHOME", None)
-    env["MXNET_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     return env

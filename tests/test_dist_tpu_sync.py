@@ -308,8 +308,7 @@ def test_two_process_fit_bitwise_matches_single_process(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", COORD=coord,
                XLA_FLAGS="--xla_force_host_platform_device_count=1",
                MXNET_FUSED_STEP="1")
-    for v in ("MXNET_TPU_PS_URI", "MXNET_COMPILE_CACHE_DIR"):
-        env.pop(v, None)
+    env.pop("MXNET_TPU_PS_URI", None)
     script = str(tmp_path / "worker.py")
     with open(script, "w") as f:
         f.write(_WORKER)

@@ -540,12 +540,12 @@ def _chaos_env(eldir, flight=None):
                MXNET_FUSED_STEP="1", MXNET_ELASTIC_DIR=eldir,
                MXNET_ELASTIC_HB_S="0.2", MXNET_DIST_DEAD_S="2.0",
                MXNET_STEP_TIMEOUT_S="60", ELASTIC_TEST_PACE_S="0.25")
-    # jaxlib's CPU gloo path segfaults deserializing a donated
+    # jaxlib's CPU gloo path has segfaulted deserializing a donated
     # collective program from the persistent compile cache, so it
     # stays off here (dist bench jobs dodge the same bug)
-    for v in ("MXNET_TPU_PS_URI", "MXNET_COMPILE_CACHE_DIR",
-              "MXNET_FAULT_INJECT", "MXNET_ELASTIC_JOIN",
-              "MXNET_FLIGHT_RECORDER"):
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
+    for v in ("MXNET_TPU_PS_URI", "MXNET_FAULT_INJECT",
+              "MXNET_ELASTIC_JOIN", "MXNET_FLIGHT_RECORDER"):
         env.pop(v, None)
     if flight:
         env["MXNET_FLIGHT_RECORDER"] = flight

@@ -571,7 +571,7 @@ def test_fleet_acceptance_ramp_kill_drain(tmp_path):
     bb.reset()
     bb.configure(str(tmp_path / "parent.flight.bin"))
     spec = _write_spec(
-        tmp_path, {"MXNET_COMPILE_CACHE_DIR": str(cache)})
+        tmp_path, {"JAX_COMPILATION_CACHE_DIR": str(cache)})
     sigs = {"rows": []}
     fleet = Fleet(spec, str(tmp_path / "wd"), min_replicas=1,
                   max_replicas=2, interval_s=0.15, scale_up_s=0.4,

@@ -380,5 +380,4 @@ def test_worst_fusions_in_diagnostics(tmp_path):
 def test_bench_job_registered():
     from mxnet_tpu import benchmark
     assert "forensics_overhead" in benchmark.JOBS
-    assert "forensics_overhead" in benchmark.JOB_PRIORITY
     assert callable(benchmark.forensics_overhead)

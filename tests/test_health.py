@@ -167,7 +167,47 @@ def test_flight_recorder_rotation_bounds_disk(tmp_path):
 # pillar 1: MFU / roofline
 # ---------------------------------------------------------------------------
 
-def test_mfu_gauges_after_one_warmed_fused_step():
+@pytest.fixture()
+def known_device(monkeypatch):
+    """Give the test host a row in the peaks table (made-up rates): the
+    gauge mechanics are under test, not a roofline."""
+    import jax
+    monkeypatch.setitem(health.DEVICE_PEAKS, jax.devices()[0].device_kind,
+                        {"flops": 1e12, "int8_ops": 2e12,
+                         "hbm_bytes_per_s": 1e11})
+
+
+def test_unknown_device_kind_no_gauge_and_bench_raises():
+    """A device_kind the peaks table does not hold gets NO live MFU
+    gauge and is an error in bench code — never another chip's roof."""
+    import jax
+    from mxnet_tpu import benchmark
+    assert jax.devices()[0].device_kind not in health.DEVICE_PEAKS
+    assert health.device_peaks() is None and health.peak_flops() is None
+    assert health._util({"flops": 1e9, "bytes": 1e6}, 0.01) is None
+    assert health.note_executor_step({"flops": 1e9, "bytes": 1e6},
+                                     0.01) is None
+    assert tm.REGISTRY._families.get("executor/mfu") is None \
+        or not tm.REGISTRY._families["executor/mfu"].series()
+    assert health.mfu_summary()["peak_flops"] is None
+    with pytest.raises(mx.base.MXNetError, match="no published peak"):
+        benchmark.peak_flops("float32")
+    # the one row there is: read off the chip, rates from the published
+    # v5e figures
+    v5e = health.DEVICE_PEAKS["TPU v5 lite"]
+    assert (v5e["flops"], v5e["int8_ops"], v5e["hbm_bytes_per_s"]) == (
+        197e12, 393e12, 819e9)
+
+
+def test_known_device_kind_prices_with_its_row(known_device):
+    from mxnet_tpu import benchmark
+    assert benchmark.peak_flops("float32") == 1e12
+    assert benchmark.peak_flops("int8") == 2e12
+    mfu, bw = health._util({"flops": 1e9, "bytes": 1e6}, 0.01)
+    assert mfu == pytest.approx(0.1) and bw == pytest.approx(1e-3)
+
+
+def test_mfu_gauges_after_one_warmed_fused_step(known_device):
     """Acceptance: executor/mfu present on /metrics after one warmed
     fused step (plus the captured program's flops are real)."""
     mod, db = _mlp_module()
@@ -192,7 +232,7 @@ def test_capture_cost_unknown_kind_raises():
         health.capture_cost("nope", "k", None, ())
 
 
-def test_serve_bucket_mfu_under_traffic():
+def test_serve_bucket_mfu_under_traffic(known_device):
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
     from mxnet_tpu.serving import Predictor
     from mxnet_tpu.benchmark import _serve_mlp_symbol
@@ -746,7 +786,6 @@ def test_mfu_divergence_warning_unit():
 def test_health_overhead_job_registered():
     from mxnet_tpu import benchmark as B
     assert "health_overhead" in B.JOBS
-    assert "health_overhead" in B.JOB_PRIORITY
 
 
 def test_docs_drift_check_covers_events_and_rules():

@@ -213,7 +213,7 @@ def test_launch_local_two_workers(tmp_path):
     script.write_text(_WORKER_SCRIPT)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MXNET_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

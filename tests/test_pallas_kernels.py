@@ -317,7 +317,6 @@ def test_kernel_contract_lint():
 def test_kernel_burn_down_job_registered():
     from mxnet_tpu import benchmark
     assert "kernel_burn_down" in benchmark.JOBS
-    assert "kernel_burn_down" in benchmark.JOB_PRIORITY
     assert callable(benchmark.kernel_burn_down)
 
 

@@ -184,20 +184,10 @@ def test_transformer_sp_tp_pp():
     _compare_step(_DENSE, (1, 2, 2, 2, 1))
 
 
-# jax 0.4.x shard_map cannot type the MoE aux-loss outputs (no
-# varying-manual-axes tracking; its replication checker raises
-# _SpecError on them); the dense configurations run fine there
-_needs_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="MoE-under-shard_map needs jax>=0.5 vma tracking")
-
-
-@_needs_vma
 def test_transformer_moe_ep():
     _compare_step(_MOE, (2, 1, 1, 1, 4), tol=3e-4, check_loss=False)
 
 
-@_needs_vma
 def test_transformer_moe_pp_ep():
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                             n_layers=4, d_ff=64, max_len=64, num_experts=2,

@@ -2,14 +2,14 @@
 (mxnet_tpu/programs.py; ISSUE 14).
 
 Acceptance: a second ``InferenceEngine.warmup()`` of an 8-bucket ladder
-in a FRESH process with ``MXNET_COMPILE_CACHE_DIR`` set performs ZERO
+in a FRESH process sharing the first one's compile cache performs ZERO
 real backend compiles (telemetry-asserted via the disk-hit/compile
 split) and serves outputs bitwise-identical to the cold-compiled
 replica — ``test_cold_start_fresh_process`` (marked ``slow``: two
 subprocess imports). The cheap in-process analogs — registry program
 sharing across engines, the disk-hit/compile telemetry split, cache-key
-correctness, salt/corruption safety rails — run in tier-1, all against
-ONE tiny shared ladder.
+correctness, salt/corruption safety rails, the cache-directory rule —
+run in tier-1, all against ONE tiny shared ladder.
 """
 import json
 import os
@@ -28,22 +28,17 @@ CLASSES = 3
 
 
 # ---------------------------------------------------------------------------
-# shared fixtures: one cache dir + ONE tiny ladder for the whole module
-# (tier-1 wall budget: every test here reuses these compiles)
+# shared fixtures: the session's cache dir (placed from outside by
+# tests/conftest.py) + ONE tiny ladder for the whole module (tier-1 wall
+# budget: every test here reuses these compiles)
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module", autouse=True)
-def cache_dir(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("compile_cache"))
-    old = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = d
-    pg.ensure_persistent_cache()
-    yield d
-    if old is None:
-        os.environ.pop("MXNET_COMPILE_CACHE_DIR", None)
-    else:
-        os.environ["MXNET_COMPILE_CACHE_DIR"] = old
-    pg.ensure_persistent_cache()         # detach from the tmp dir
+def cache_dir():
+    # the warm-set manifest is session-wide now: start from a registry
+    # (and a seen-set) that says nothing about it
+    pg.reset()
+    return pg.cache_dir()
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +73,62 @@ def warm_engine(model, cache_dir):
     eng = _engine(model)
     eng.warmup()
     return eng
+
+
+# ---------------------------------------------------------------------------
+# the cache-directory rule: placed from outside, else one fixed
+# in-checkout path; never set lazily, never a temp path
+# ---------------------------------------------------------------------------
+
+def _recorded_config_updates(monkeypatch):
+    import jax
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    return calls
+
+
+def test_cache_dir_from_outside_means_code_sets_none(monkeypatch):
+    outside = os.environ["JAX_COMPILATION_CACHE_DIR"]   # tests/conftest.py
+    assert pg.cache_dir() == os.path.abspath(outside)
+    calls = _recorded_config_updates(monkeypatch)
+    pg.configure_compile_cache()
+    assert "jax_compilation_cache_dir" not in [k for k, _ in calls]
+    # the gates are zeroed either way: tiny eager programs are cached
+    assert ("jax_persistent_cache_min_compile_time_secs", 0.0) in calls
+    assert ("jax_persistent_cache_min_entry_size_bytes", -1) in calls
+
+
+def test_cache_dir_default_is_one_fixed_in_checkout_path(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    calls = _recorded_config_updates(monkeypatch)
+    pg.configure_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert ("jax_compilation_cache_dir",
+            os.path.join(repo, ".jax_cache")) in calls
+    assert pg.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_no_other_code_path_sets_a_cache_dir():
+    """configure_compile_cache (called once, at ``import mxnet_tpu``) is
+    the only place the package names jax's cache-dir option."""
+    pkg = os.path.dirname(os.path.abspath(pg.__file__))
+    hits = []
+    for root, _dirs, files in os.walk(pkg):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                with open(path) as f:
+                    src = f.read()
+                if '"jax_compilation_cache_dir"' in src \
+                        or "MXNET_COMPILE_CACHE_DIR" in src \
+                        or "tempfile" in src and "compilation_cache" in src:
+                    hits.append(os.path.relpath(path, pkg))
+    assert hits == ["programs.py"]
+    with open(os.path.join(pkg, "__init__.py")) as f:
+        assert "configure_compile_cache()" in f.read()
 
 
 # ---------------------------------------------------------------------------
@@ -238,7 +289,8 @@ def test_prewarm_skips_stale_salt_and_survives_corruption(cache_dir,
                         include=[("test_site", {"bucket": 3})],
                         use_manifest=False)
     assert report["rejected"] == 1 and report["replayed"] == 0
-    os.unlink(path)                      # leave a clean manifest behind
+    os.unlink(path)                      # leave a clean manifest behind,
+    pg.reset()                           # and no memory of the old one
 
 
 # ---------------------------------------------------------------------------

@@ -64,9 +64,7 @@ def test_sync_bn_axis_name_shard_map():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    # version-portable shard_map (public API on jax>=0.5, experimental
-    # with check_rep quirks on 0.4.x) — the parallel stack's shim
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from mxnet_tpu.ops import registry as reg
 
     op = reg.get_op("SyncBatchNorm")

@@ -197,7 +197,8 @@ def test_dispatch_overhead():
     more than the effect (the standalone dispatch_begin/dispatch_end
     pair measures ~3us against a multi-10s-of-us dispatch). On/off
     chunks are interleaved so machine-speed drift hits both equally;
-    bench.py's banked snapshots carry the production numbers."""
+    benchmark.persist's telemetry snapshots carry the production
+    numbers."""
     x = nd.array(np.random.rand(16, 16).astype("float32"))
     nd.dot(x, x).wait_to_read()          # warm the jit cache
     prev = tm.enabled()

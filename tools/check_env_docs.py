@@ -7,7 +7,7 @@ docs/faq/env_var.md tier). This lint keeps three surfaces from
 drifting:
 
 * **code -> registry**: every ``"MXNET_*"`` string literal in
-  mxnet_tpu/, tools/, or bench.py must be a declared ``VARS`` key —
+  mxnet_tpu/, tools/, or chip_smoke.py must be a declared ``VARS`` key —
   a knob read straight off ``os.environ`` without a registry entry is
   invisible to ``python -m mxnet_tpu.config`` and to this lint's doc
   checks.
@@ -43,7 +43,7 @@ _LITERAL_RE = re.compile(r"""["'](MXNET_[A-Z0-9_]+)["']""")
 
 # directories whose .py files are scanned for code-side literals
 _CODE_SCOPES = ("mxnet_tpu", "tools")
-_CODE_FILES = ("bench.py",)
+_CODE_FILES = ("chip_smoke.py",)
 _DOC_FILES = ("README.md", "ROADMAP.md")
 
 

@@ -1,0 +1,109 @@
+#!/usr/bin/env python
+"""Compile every exported Pallas kernel with Mosaic for a TPU v5e —
+WITHOUT a chip.
+
+libtpu can describe a topology it is not attached to
+(``jax.experimental.topologies``), and ``jit(...).lower(...).compile()``
+against that description runs the real Mosaic/XLA:TPU compiler. So what
+Mosaic refuses (block shapes, DMA slices, VMEM budget, unsupported
+layouts) shows here, for free, before chip time is spent:
+
+    JAX_PLATFORMS=cpu python tools/check_mosaic_aot.py
+
+Nothing is executed: this proves a kernel COMPILES at these shapes, not
+that it is right or fast — ``python chip_smoke.py`` on the chip compares
+every kernel with its lax twin. Exits non-zero when any case fails.
+"""
+import importlib
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import jax                                                    # noqa: E402
+import jax.numpy as jnp                                       # noqa: E402
+from jax.experimental import topologies                       # noqa: E402
+
+fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
+i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
+
+F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+SGD_H = {"lr": 0.1, "wd": 1e-4, "rescale_grad": 1 / 32, "momentum": 0.9}
+ADAM_H = {"lr": 1e-3, "wd": 1e-4, "rescale_grad": 1 / 32, "beta1": 0.9,
+          "one_minus_beta1": 0.1, "beta2": 0.999, "one_minus_beta2": 1e-3,
+          "epsilon": 1e-8}
+
+
+def cases():
+    """(name, fn, [(shape, dtype), ...]) at the production callers'
+    shapes (chip_smoke.py runs the same ones on the chip)."""
+    for shape in ((512, 512, 3, 3), (1000, 2048), (64,)):
+        yield ("sgd_fused_update %s" % (shape,),
+               lambda w, g, m: fu.sgd_fused_update(w, g, (m,), SGD_H,
+                                                   interpret=False),
+               [(shape, F32)] * 3)
+        yield ("adam_fused_update %s" % (shape,),
+               lambda w, g, m, v: fu.adam_fused_update(
+                   w, g, (m, v), ADAM_H, interpret=False),
+               [(shape, F32)] * 4)
+    for b, h, s, d, dt in ((8, 16, 1024, 64, F32), (8, 16, 1024, 64, BF16),
+                           (2, 8, 1024, 128, BF16), (2, 4, 100, 64, F32)):
+        yield ("flash_attention b%dh%ds%dd%d %s" % (b, h, s, d, dt.__name__),
+               lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                                  interpret=False),
+               [((b, h, s, d), dt)] * 3)
+    for dt in (F32, BF16):
+        for b, kvh, g, hd in ((8, 2, 4, 64), (8, 4, 2, 128), (4, 2, 4, 32),
+                              (8, 1, 8, 64), (8, 8, 1, 128)):
+            pool = ((512, 16, kvh, hd), dt)
+            yield ("paged_decode_attention b%dkvh%dg%dhd%d %s"
+                   % (b, kvh, g, hd, dt.__name__),
+                   lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+                       q, kp, vp, bt, ln, interpret=False),
+                   [((b, kvh, g, hd), dt), pool, pool, ((b, 16), I32),
+                    ((b,), I32)])
+        for b, s, nh, kvh, hd in ((2, 128, 8, 2, 64), (2, 256, 8, 4, 128),
+                                  (2, 128, 8, 2, 32), (2, 64, 4, 1, 64),
+                                  (4, 256, 16, 16, 64), (1, 512, 32, 8, 128)):
+            pool = ((b * (s // 16) + 1, 16, kvh, hd), dt)
+            kv = ((b, s, kvh, hd), dt)
+            yield ("flash_prefill_paged b%ds%dnh%dkvh%dhd%d %s"
+                   % (b, s, nh, kvh, hd, dt.__name__),
+                   lambda *a: fa.flash_prefill_paged(*a, interpret=False),
+                   [((b, s, nh, hd), dt), kv, kv, pool, pool,
+                    ((b, s // 16), I32)])
+    for m, k, n in ((32, 2048, 1000), (32 * 56 * 56, 576, 64)):
+        yield ("int8_matmul %dx%dx%d" % (m, k, n),
+               lambda x, w, s: i8.int8_matmul(x, w, s, interpret=False),
+               [((m, k), I8), ((n, k), I8), ((n,), F32)])
+    yield ("int8_conv_im2col b32c64 56x56 3x3",
+           lambda q, w, s: i8.int8_conv_im2col(
+               q, w, s, (1, 1), (1, 1), (1, 1), interpret=False),
+           [((32, 64, 56, 56), I8), ((64, 64, 3, 3), I8), ((64,), F32)])
+
+
+def main():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    print("compiling for %s (no device attached)" % topo.devices[0]
+          .device_kind, flush=True)
+    failed = 0
+    for name, fn, specs in cases():
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in specs]
+        try:
+            jax.jit(fn).lower(*args).compile()
+            print("ok    %s" % name, flush=True)
+        except Exception as e:      # report every case, then fail the run
+            failed += 1
+            print("FAIL  %s\n      %s: %s"
+                  % (name, type(e).__name__, str(e)[:1500]), flush=True)
+    print("%d case(s) failed" % failed if failed
+          else "every kernel compiles for the v5e")
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

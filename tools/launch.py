@@ -132,7 +132,10 @@ def main():
             "MXNET_DIST_NUM_PROCESSES": str(args.num_workers),
         })
 
-    server_env = dict(base_env, MXNET_TPU_ROLE="server")
+    # the parameter server is a host-side service: pinned to the CPU
+    # platform so it can never claim the chips its workers need
+    server_env = dict(base_env, MXNET_TPU_ROLE="server",
+                      JAX_PLATFORMS="cpu")
     server = subprocess.Popen(
         [sys.executable, "-m", "mxnet_tpu.kvstore_server"], env=server_env)
     # wait until the listener actually accepts (a fixed sleep flakes on

@@ -7,9 +7,10 @@ Run this before EVERY snapshot/commit of consequence:
     python tools/preflight.py --fast     # dryrun only (seconds)
 
 Both legs run on a virtual 8-device CPU mesh
-(``--xla_force_host_platform_device_count=8``), the same configuration
-the driver uses for ``MULTICHIP_r*.json`` — so a green preflight means
-the driver gate passes too. Exits non-zero on any failure.
+(``--xla_force_host_platform_device_count=8``); they validate sharding
+semantics and host logic only. Whether the program still starts on the
+chip is ``python chip_smoke.py``, run on a TPU machine. Exits non-zero
+on any failure.
 """
 import argparse
 import os
