@@ -16,6 +16,10 @@ One process, which touches JAX itself and starts no other. Phases:
              batch repeated.
 ``kernels``  every exported Pallas kernel compiled by Mosaic at the
              shapes its production caller uses, against its lax twin.
+``trace_clock``  a profiler trace around three fused steps of the same
+             path: the program's spans are events of the trace's host
+             plane; prints which Python clock that plane is on, and holds
+             each event to its span in ``tracing.span_log()`` to 0.2 ms.
 ``dp4``      the same path data-parallel over four chips (global batch
              128), when four are visible; otherwise reported as skipped.
 
@@ -27,14 +31,18 @@ accelerator (or in the explicit rehearsal, whose verdict says so). Walls
 are printed as information; no number here is a measurement claim.
 """
 import argparse
+import glob
 import importlib
 import json
 import math
 import os
+import shutil
 import sys
+import tempfile
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+PHASES = ("train", "kernels", "trace_clock", "dp4")
 
 
 def _parse():
@@ -42,13 +50,13 @@ def _parse():
     ap.add_argument("--tiny-cpu", action="store_true",
                     help="rehearse on CPU at tiny shapes with interpreted "
                          "kernels (proves nothing about the device)")
-    ap.add_argument("--phases", default="train,kernels,dp4",
+    ap.add_argument("--phases", default=",".join(PHASES),
                     help="comma list of phases to run (debugging aid that "
                          "saves chip time; a partial run never prints the "
                          "final ok verdict)")
     args = ap.parse_args()
     args.phases = args.phases.split(",")
-    unknown = set(args.phases) - {"train", "kernels", "dp4"}
+    unknown = set(args.phases) - set(PHASES)
     if unknown:
         ap.error("unknown phase(s): %s" % sorted(unknown))
     return args
@@ -77,7 +85,7 @@ except ImportError as e:
     sys.exit("chip_smoke: cannot import the program next to this script "
              "(%s); run it from a checkout of the repository" % e)
 
-from mxnet_tpu import programs, telemetry                    # noqa: E402
+from mxnet_tpu import programs, telemetry, tracing           # noqa: E402
 
 TINY = ARGS.tiny_cpu
 
@@ -170,7 +178,7 @@ WARMUP_STEPS = 2
 STEPS = 8
 
 
-def run_fit(tpus, batch):
+def run_fit(tpus, batch, watch=None):
     """``python examples/train_imagenet.py --benchmark 1 ...`` in this
     process. Returns (watch, real_compiles, disk_hits, fused programs
     added)."""
@@ -186,7 +194,7 @@ def run_fit(tpus, batch):
     fused0 = _fused_programs()
     real0 = telemetry.counter("programs/compile_total").value
     disk0 = telemetry.counter("programs/disk_hits_total").value
-    watch = StepWatch()
+    watch = watch or StepWatch()
     train_imagenet.main(flags, batch_end_callback=watch)
     return (watch,
             telemetry.counter("programs/compile_total").value - real0,
@@ -449,6 +457,139 @@ def kernels_phase():
 
 
 # ---------------------------------------------------------------------------
+# phase trace_clock: the program's spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+TRAIN_SPANS = ("train.step", "train.forward_backward", "train.update",
+               "executor.stage_input", "executor.train_step",
+               "train.update_metric", "train.data_wait", "train.callbacks")
+PROBE = "chip_smoke.clock_probe"
+TRACED_STEPS = 3
+CLOCKS = (("time.time_ns", time.time_ns),
+          ("time.monotonic_ns", time.monotonic_ns),
+          ("time.perf_counter_ns", time.perf_counter_ns))
+
+
+class TraceWatch(StepWatch):
+    """Starts a profiler trace in the callback that ends the warm-up and
+    stops it ``TRACED_STEPS`` callbacks later; in between, every callback
+    stamps the three Python clocks and opens one probe annotation."""
+
+    def __init__(self, trace_dir):
+        StepWatch.__init__(self)
+        self.trace_dir = trace_dir
+        self.stamps = []
+
+    def __call__(self, param):
+        StepWatch.__call__(self, param)
+        n = len(self.loss)
+        if n == WARMUP_STEPS + 1:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+        elif WARMUP_STEPS + 1 < n <= WARMUP_STEPS + 1 + TRACED_STEPS:
+            self.stamps.append(tuple(clock() for _name, clock in CLOCKS))
+            with jax.profiler.TraceAnnotation(PROBE):
+                pass
+            if n == WARMUP_STEPS + 1 + TRACED_STEPS:
+                jax.profiler.stop_trace()
+
+
+def _read_xplane(trace_dir):
+    """(profile_start_time ns, host events {name: [(start, duration)]},
+    starts of device 0's program runs), all in the file's nanoseconds."""
+    paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    check(len(paths) == 1, "trace_clock: %d xplane files under %s"
+          % (len(paths), trace_dir))
+    data = jax.profiler.ProfileData.from_file(paths[0])
+    origin, host, modules = None, {}, []
+    for plane in data.planes:
+        origin = dict(plane.stats).get("profile_start_time", origin)
+        for line in plane.lines:
+            if plane.name == "/host:CPU":
+                for ev in line.events:
+                    host.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns))
+            elif plane.name == "/device:TPU:0" and line.name == "XLA Modules":
+                modules += [ev.start_ns for ev in line.events]
+    check(origin is not None, "trace_clock: the xplane states no "
+          "profile_start_time")
+    return int(origin), host, sorted(modules)
+
+
+def trace_clock_phase(start_t0):
+    dev_id = 1 if TINY else 0
+    trace_dir = tempfile.mkdtemp(prefix="chip_smoke_trace_")
+    tracing.reset()
+    try:
+        watch, _real, _disk, _fused = run_fit(str(dev_id), 32,
+                                              TraceWatch(trace_dir))
+        origin, host, modules = _read_xplane(trace_dir)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    since_start = time.perf_counter() - start_t0
+    check(len(watch.stamps) == TRACED_STEPS and
+          len(host.get(PROBE, ())) == TRACED_STEPS,
+          "trace_clock: %d probes stamped, %d in the trace"
+          % (len(watch.stamps), len(host.get(PROBE, ()))))
+    # which Python clock the file's host events are on, counted from its
+    # profile_start_time: the one all probes agree with to 0.2 ms
+    off_ms = {}
+    for k, (name, _clock) in enumerate(CLOCKS):
+        off_ms[name] = max(abs(origin + start - stamp[k]) * 1e-6
+                           for (start, _dur), stamp
+                           in zip(sorted(host[PROBE]), watch.stamps))
+    on = [name for name, ms in off_ms.items() if ms < 0.2]
+    print("[chip_smoke] trace_clock: xplane host events are on %s - "
+          "profile_start_time (largest |offset| over %d probes, ms: %s)"
+          % (on or "NO Python clock", TRACED_STEPS,
+             json.dumps(off_ms, sort_keys=True)), flush=True)
+    check(on == ["time.time_ns"],
+          "trace_clock: tracing.py's docs say the xplane is on "
+          "time.time_ns, it is on %s" % (on or off_ms))
+    # every training span is an event of the trace under its own name,
+    # and the log's perf_counter stamps are that event's start and end:
+    # the first probe's pair of stamps carries one clock to the other
+    # (the file's nanoseconds + to_log = perf_counter nanoseconds)
+    to_log = origin - (watch.stamps[0][0] - watch.stamps[0][2])
+    log = tracing.span_log()
+    worst = {}
+    for name in TRAIN_SPANS:
+        events = sorted(host.get(name, ()))
+        check(len(events) >= TRACED_STEPS - 1,
+              "trace_clock: %d %s events on the trace's host plane, "
+              "expected one a step" % (len(events), name))
+        spans = [(r["t0"] * 1e9, r["t1"] * 1e9) for r in log
+                 if r["name"] == name]
+        for start, dur in events:
+            t0, t1 = min(spans, key=lambda s: abs(s[0] - to_log - start))
+            worst[name] = max(worst.get(name, 0.0),
+                              abs(t0 - to_log - start) * 1e-6,
+                              abs(t1 - to_log - start - dur) * 1e-6)
+    check(max(worst.values()) < 0.2,
+          "trace_clock: a logged span and its own annotation differ by "
+          "more than 0.2 ms: %s" % json.dumps(worst, sort_keys=True))
+    # information: how long after the fused program's call began the
+    # device started on it (the first program run on device 0 after it)
+    lead_ms = None
+    dispatches = sorted(s for s, _d in host.get("executor.train_step", ()))
+    after = [min(m for m in modules if m >= d) - d
+             for d in dispatches if any(m >= d for m in modules)]
+    if after:
+        lead_ms = min(after) * 1e-6
+    status("trace_clock", "passed", xplane_clock="time.time_ns (CLOCK_REALTIME)"
+           " - profile_start_time", probe_offset_ms=round(
+               off_ms["time.time_ns"], 4),
+           worst_span_offset_ms=round(max(worst.values()), 4),
+           worst_span=max(worst, key=worst.get), spans_checked=sorted(worst),
+           seconds_since_start=round(since_start, 1),
+           device_start_after_dispatch_start_ms=None
+           if lead_ms is None else round(lead_ms, 3))
+
+
+# ---------------------------------------------------------------------------
 # phase dp4
 # ---------------------------------------------------------------------------
 
@@ -523,6 +664,7 @@ def main():
     devs = device_phase()
     verdicts = {}
     for phase, run in (("train", train_phase), ("kernels", kernels_phase),
+                       ("trace_clock", lambda: trace_clock_phase(t0)),
                        ("dp4", lambda: dp4_phase(devs))):
         if phase not in ARGS.phases:
             verdicts[phase] = "NOT RUN (--phases)"
@@ -535,7 +677,7 @@ def main():
              time.perf_counter() - t0,
              "; REHEARSAL on the host CPU — nothing about the device was "
              "proven" if TINY else ""), flush=True)
-    if len(ARGS.phases) < 3:
+    if set(ARGS.phases) != set(PHASES):
         sys.exit("chip_smoke: partial run (--phases %s): no verdict"
                  % ",".join(ARGS.phases))
     result = {"ok": True, "device": {"platform": d0.platform,

@@ -359,13 +359,6 @@ VARS = {
                            "or a train step). 0 disables recording but "
                            "keeps X-Request-Id echo; lower in "
                            "production to bound tracer work."),
-    "MXNET_TRACE_OPS": (bool, False,
-                        "Record a per-op op.dispatch span for every "
-                        "eager dispatch under a sampled trace. Off by "
-                        "default: on microsecond-scale ops the span "
-                        "write dominates the dispatch itself (the "
-                        "trace_overhead bench banks it), so structural "
-                        "spans stay cheap and per-op detail is opt-in."),
     "MXNET_TRACE_SLOW_MS": (int, 1000,
                             "Slow-exemplar threshold: sampled traces "
                             "whose root span exceeds this many ms (and "

@@ -360,11 +360,19 @@ class Executor(object):
                 data = jax.device_put(data, self._ctx.jax_device())
         self.arg_dict[name]._set_data(data)
 
+    def _stage_inputs(self, feed):
+        """Bind a call's inputs under one span: the host's side of the
+        step's H2D copy (``device_put`` returns before the copy lands)."""
+        if not feed:
+            return
+        with _tr.child_span("executor.stage_input"):
+            for k, v in feed.items():
+                self._stage_input(k, v)
+
     def forward(self, is_train=False, **kwargs):
         """Run the compiled forward program
         (reference: GraphExecutor::RunOps, graph_executor.cc:64,1318)."""
-        for k, v in kwargs.items():
-            self._stage_input(k, v)
+        self._stage_inputs(kwargs)
         key = _random.next_key() if self._needs_rng else None
         fwd = self._fwd(bool(is_train))
         env = self._env()
@@ -635,8 +643,7 @@ class Executor(object):
                     raise MXNetError("unknown train_step input %r" % n)
             mbenv = {n: self._place_accum(n, v)
                      for n, v in accum_feed.items()}
-        for k, v in (feed or {}).items():
-            self._stage_input(k, v)
+        self._stage_inputs(feed)
 
         # donation honors the same knob as the per-param update kernels
         # (ops/registry.py _donation_allowed): with it off, pre-update

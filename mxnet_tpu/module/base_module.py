@@ -386,10 +386,11 @@ class BaseModule(object):
                                 _elastic.pre_step(epoch, nbatch)
                             _gp_tok = _gp.step_begin()
                             _gp_dw = 0.0
-                            # per-step trace timeline: one root span per step
-                            # (head-sampled), with the phase split a stall
-                            # investigation needs — was the step waiting on
-                            # data, on forward-backward, or on the optimizer?
+                            # per-step trace timeline: one root span per loop
+                            # iteration (head-sampled), callbacks included, so
+                            # that its children tile the step — was it waiting
+                            # on data, on forward-backward, on the optimizer,
+                            # on the metric's fetch or on a callback?
                             with _tr.start_span("train.step",
                                                 attrs={"epoch": epoch,
                                                        "nbatch": nbatch}):
@@ -420,14 +421,15 @@ class BaseModule(object):
                                             epoch, nbatch + 1,
                                             save_optimizer_states, train_data)
                                     raise
-                                if isinstance(data_batch, list):
-                                    self.update_metric(
-                                        eval_metric,
-                                        [db.label for db in data_batch],
-                                        pre_sliced=True)
-                                else:
-                                    self.update_metric(eval_metric,
-                                                       data_batch.label)
+                                with _tr.child_span("train.update_metric"):
+                                    if isinstance(data_batch, list):
+                                        self.update_metric(
+                                            eval_metric,
+                                            [db.label for db in data_batch],
+                                            pre_sliced=True)
+                                    else:
+                                        self.update_metric(eval_metric,
+                                                           data_batch.label)
                                 if _elastic is not None:
                                     # the metric sync above proved the
                                     # step's arrays are materialized:
@@ -451,17 +453,21 @@ class BaseModule(object):
                                             sparse_row_id_fn=sparse_row_id_fn)
                                     except StopIteration:
                                         end_of_batch = True
-                            _gp.step_end(_gp_tok, data_wait_s=_gp_dw)
-                            if monitor is not None:
-                                monitor.toc_print()
-                            if end_of_batch:
-                                eval_name_vals = eval_metric.get_name_value()
-                            if batch_end_callback is not None:
-                                params = _BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                                        eval_metric=eval_metric,
-                                                        locals=locals())
-                                for callback in _as_list(batch_end_callback):
-                                    callback(params)
+                                _gp.step_end(_gp_tok, data_wait_s=_gp_dw)
+                                if monitor is not None:
+                                    monitor.toc_print()
+                                if end_of_batch:
+                                    eval_name_vals = \
+                                        eval_metric.get_name_value()
+                                if batch_end_callback is not None:
+                                    params = _BatchEndParam(
+                                        epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric,
+                                        locals=locals())
+                                    with _tr.child_span("train.callbacks"):
+                                        for callback in _as_list(
+                                                batch_end_callback):
+                                            callback(params)
                             nbatch += 1
                             if preempt["flag"]:
                                 if end_of_batch:

@@ -23,7 +23,6 @@ _NULL_SCOPE = _contextlib.nullcontext()
 from ..context import Context, current_context
 from .. import random as _random
 from .. import telemetry as _tm
-from .. import tracing as _tr
 from ..ops import registry as _reg
 
 __all__ = ["NDArray", "invoke_op", "array", "zeros", "ones", "full", "empty",
@@ -541,21 +540,13 @@ def invoke_op(name, inputs, attrs, out=None):
     else:
         prof_scope = _NULL_SCOPE   # singleton: keep the hot path light
     tm_token = _tm.dispatch_begin() if _tm._enabled else None
-    # per-op trace span only when opted in (MXNET_TRACE_OPS) AND under
-    # a sampled trace: the default dispatch pays one module-attr read;
-    # opted in it pays the contextvar read the trace_overhead bench
-    # bounds at < 5%, and a span write only while a trace is recording
-    tr_scope = (_tr.child_span("op.dispatch", attrs={"op": name})
-                if _tr._trace_ops and _tr.active() is not None
-                else _tr.NOOP)
-    with tr_scope:
-        with prof_scope, device_scope:
-            raw_out = _reg.invoke_raw(op, arrays, attrs)
-            if _engine.is_naive():
-                # NaiveEngine debug mode: serialize every op (reference:
-                # src/engine/naive_engine.cc, MXNET_ENGINE_TYPE)
-                for o in raw_out:
-                    o.block_until_ready()
+    with prof_scope, device_scope:
+        raw_out = _reg.invoke_raw(op, arrays, attrs)
+        if _engine.is_naive():
+            # NaiveEngine debug mode: serialize every op (reference:
+            # src/engine/naive_engine.cc, MXNET_ENGINE_TYPE)
+            for o in raw_out:
+                o.block_until_ready()
     if tm_token is not None:
         _tm.dispatch_end(name, tm_token)
     if dev is not None:

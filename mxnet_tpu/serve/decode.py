@@ -30,7 +30,9 @@ whole batch hostage and a short request pays worst-case latency.
   / ``POST /generate`` chunked responses in serve/http.py), under the
   standard deadline/tracing machinery: per-step ``decode.step`` /
   ``decode.prefill`` / ``decode.schedule`` spans fan into every
-  participating request trace exactly like ``serve.batch`` does.
+  participating request trace exactly like ``serve.batch`` does, and
+  the scheduler's own loop logs one ``decode.iteration`` per pass with
+  its prefills and its step under it, context or none.
 
 Decoding is greedy (argmax) — deliberately: the acceptance contract is
 that batched continuous decode is BITWISE-identical to per-request
@@ -698,26 +700,41 @@ class DecodeEngine(object):
                     self._cond.wait(0.05)
                 if self._warmup_req is not None:
                     continue
-                t_sched0 = _tm.monotonic()
-                evictions = self._expire_locked()
-                admits = []
-                while (self._waiting
-                       and len(self._live) < self._cfg.slots):
-                    sess = self._waiting.popleft()
-                    # joins the slot list BEFORE its prefill runs (so a
-                    # concurrent close/crash-recover can't lose it);
-                    # t_admit is None until the prefill lands, which
-                    # keeps it out of this iteration's step batch
-                    self._live.append(sess)
-                    admits.append(sess)
-                self._m_occupancy.set(len(self._live))
-                t_sched1 = _tm.monotonic()
-            if admits or evictions:
-                self._record_schedule(admits, evictions,
-                                      t_sched0, t_sched1)
-            for sess in admits:
-                self._prefill(sess)
-            self._step()
+                carried = len(self._live)
+            # the engine's own timeline: one root per pass that has
+            # work, whoever's requests it serves (the per-request spans
+            # below go to the traces of callers that brought a context);
+            # into the span log only, so that the ring keeps the
+            # requests' traces. ``live``: sequences carried over from
+            # the pass before — they wait while this pass admits and
+            # prefills
+            with _tr.start_span("decode.iteration", ring=False,
+                                attrs={"live": carried}):
+                for sess in self._schedule():
+                    self._prefill(sess)
+                self._step()
+
+    def _schedule(self):
+        """Expire, sweep and admit for one iteration; returns the
+        sessions admitted (each is owed a prefill)."""
+        with self._cond:
+            t_sched0 = _tm.monotonic()
+            evictions = self._expire_locked()
+            admits = []
+            while (self._waiting
+                   and len(self._live) < self._cfg.slots):
+                sess = self._waiting.popleft()
+                # joins the slot list BEFORE its prefill runs (so a
+                # concurrent close/crash-recover can't lose it);
+                # t_admit is None until the prefill lands, which
+                # keeps it out of this iteration's step batch
+                self._live.append(sess)
+                admits.append(sess)
+            self._m_occupancy.set(len(self._live))
+            t_sched1 = _tm.monotonic()
+        if admits or evictions:
+            self._record_schedule(admits, evictions, t_sched0, t_sched1)
+        return admits
 
     def _expire_locked(self):
         """Fail sessions past their deadline (queued: before a prefill
@@ -785,12 +802,13 @@ class DecodeEngine(object):
             page_ids = _np.asarray(sess.page_ids[:n_pb], _np.int32)
         padded = _np.zeros((1, bucket), _np.int32)
         padded[0, :sess.prompt_len] = sess.prompt
-        t0 = _tm.monotonic()
-        tok0, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
-            self._params, self._k_pages, self._v_pages, page_ids, padded,
-            _np.array([sess.prompt_len], _np.int32))
-        tok0 = int(tok0)
-        t1 = _tm.monotonic()
+        with _tr.child_span("decode.prefill"):
+            t0 = _tm.monotonic()
+            tok0, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
+                self._params, self._k_pages, self._v_pages, page_ids,
+                padded, _np.array([sess.prompt_len], _np.int32))
+            tok0 = int(tok0)
+            t1 = _tm.monotonic()
         self._m_prefill.observe(
             t1 - t0, trace_id=sess.tctx.trace_id if sess.tctx else None)
         _health.note_decode("prefill", bucket, t1 - t0,
@@ -834,11 +852,17 @@ class DecodeEngine(object):
             tokens[i] = sess.last_token
             pos[i] = sess.pos
             bt[i] = sess.block_table
-        t0 = _tm.monotonic()
-        toks, self._k_pages, self._v_pages = self._step_prog(nslots)(
-            self._params, self._k_pages, self._v_pages, bt, tokens, pos)
-        toks = _np.asarray(toks)
-        t1 = _tm.monotonic()
+        # context_tokens: the positions this step attends over, the
+        # new token's own included — the attention kernel's work
+        with _tr.child_span(
+                "decode.step",
+                attrs={"context_tokens": int(pos.sum()) + len(live)}):
+            t0 = _tm.monotonic()
+            toks, self._k_pages, self._v_pages = self._step_prog(nslots)(
+                self._params, self._k_pages, self._v_pages, bt, tokens,
+                pos)
+            toks = _np.asarray(toks)
+            t1 = _tm.monotonic()
         self._m_step.observe(t1 - t0)
         _health.note_decode("step", nslots, t1 - t0,
                             self._prog_costs.get(("step", nslots)))

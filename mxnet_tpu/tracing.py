@@ -33,10 +33,23 @@ Design points (the cost model mirrors fault.py / telemetry.py):
   profiler's chrome-trace dump (one timeline with the bridged gauges),
   and :func:`traces_payload` backs the ``/traces`` HTTP endpoint on
   both the telemetry server and the serving frontend.
+* **in the device trace**: every scoped span also opens a
+  ``jax.profiler.TraceAnnotation`` of its own name — a no-op while no
+  profiler session runs; with one running (``jax.profiler.start_trace``,
+  ``mx.profiler.set_state('run')``) the span is an event of the
+  ``.xplane.pb``'s host plane, beside the device ops. Every span a
+  trace takes in is also kept in ONE flat bounded log (:func:`span_log`)
+  that a benchmark reads when its run ends, long after the ring has
+  rotated.
 
-Span timestamps are absolute ``time.perf_counter()`` readings; the
-chrome exporter rebases them onto the profiler's epoch so spans and
-profiler events line up on one timeline.
+Span timestamps are absolute ``time.perf_counter()`` readings, taken
+right beside the annotation's own stamps; the chrome exporter rebases
+them onto the profiler's epoch. An ``.xplane.pb`` stamps its host events
+with CLOCK_REALTIME (``time.time_ns()``) counted from the session's
+start, which it states as ``profile_start_time`` on its ``Task
+Environment`` plane: one ``(time_ns, perf_counter)`` pair read near the
+trace places a logged span on its own event (``chip_smoke.py``'s
+``trace_clock`` phase holds the two to 0.2 ms).
 """
 from __future__ import annotations
 
@@ -47,13 +60,14 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = ["SpanContext", "Span", "start_span", "child_span",
            "record_span", "use_context", "current", "active",
            "wire_context", "from_wire", "graft", "mark_error",
            "enabled", "enable", "set_sample", "set_slow_ms",
-           "set_trace_ops",
-           "finished_traces", "slow_traces", "get_trace", "traces_payload",
-           "traces_endpoint", "chrome_events", "reset"]
+           "span_log", "finished_traces", "slow_traces", "get_trace",
+           "traces_payload", "traces_endpoint", "chrome_events", "reset"]
 
 _monotonic = time.perf_counter
 _PID = os.getpid()
@@ -74,6 +88,11 @@ _MAX_SPANS = 512
 # cannot evict the interesting traces
 _SLOW_RING = 32
 
+# the flat span log: a whole benchmark run (under 3 k spans: the serving
+# cell's warm-up, ramp and 45 s of decode passes) stays readable at its
+# end; a long-lived process keeps its last 16,384 spans, about 10 MB
+_LOG_SPANS = 16384
+
 
 def _config(name, fallback):
     try:
@@ -87,16 +106,13 @@ def _config(name, fallback):
 _enabled = bool(_config("MXNET_TRACING", True))
 _sample = float(_config("MXNET_TRACE_SAMPLE", 1.0))
 _slow_ms = float(_config("MXNET_TRACE_SLOW_MS", 1000))
-# per-op op.dispatch spans are opt-in: on a microsecond-scale eager op
-# the span write costs more than the dispatch, so the default keeps
-# sampled traces structural (queue/batch/compute/step phases) only
-_trace_ops = bool(_config("MXNET_TRACE_OPS", False))
 
 _current = contextvars.ContextVar("mxnet_trace_ctx", default=None)
 
 _ring_lock = threading.Lock()
 _ring = deque(maxlen=max(1, int(_config("MXNET_TRACE_RING", 64))))
 _slow = deque(maxlen=_SLOW_RING)
+_log = deque(maxlen=_LOG_SPANS)     # deque.append is atomic
 
 
 def new_trace_id():
@@ -205,17 +221,17 @@ class Span(object):
     """A live (open) span; finished into a plain dict on scope exit."""
 
     __slots__ = ("name", "ctx", "parent_id", "t0", "t1", "attrs",
-                 "status", "_root", "_token", "_tid")
+                 "status", "_root", "_ring", "_token", "_tid", "_annotation")
 
-    def __init__(self, name, ctx, parent_id, root):
+    def __init__(self, name, ctx, parent_id, root, ring=True):
         self.name = name
         self.ctx = ctx                   # context of THIS span
         self.parent_id = parent_id
-        self.t0 = _monotonic()
-        self.t1 = None
+        self.t0 = self.t1 = None         # stamped by the scope
         self.attrs = {}
         self.status = "ok"
         self._root = root
+        self._ring = ring
         self._token = None
         self._tid = threading.get_ident() % 100000
 
@@ -232,7 +248,6 @@ class Span(object):
         return self.ctx.span_id
 
     def _finish(self, exc=None):
-        self.t1 = _monotonic()
         if exc is not None:
             self.status = "error"
             self.attrs.setdefault("error", "%s: %s"
@@ -245,31 +260,43 @@ class Span(object):
                 # kvstore transport noise — must not claim a slot in
                 # the bounded error-exemplar ring.
                 self.ctx.buf.error = self.attrs["error"]
-        self.ctx.buf.add(_span_dict(self.name, self.ctx.trace_id,
-                                    self.ctx.span_id, self.parent_id,
-                                    self.t0, self.t1, self.attrs,
-                                    self.status, self._tid),
-                         force=self._root)
+        span = _span_dict(self.name, self.ctx.trace_id, self.ctx.span_id,
+                          self.parent_id, self.t0, self.t1, self.attrs,
+                          self.status, self._tid)
+        if self.ctx.buf.add(span, force=self._root):
+            _log.append(span)
         if self._root:
             _finalize(self)
 
 
 class _SpanScope(object):
     """Context manager around one Span: sets/restores the implicit
-    context on its own thread, records the span on exit."""
+    context on its own thread, holds the span's annotation open in the
+    profiler's trace, records the span on exit (an exception closes the
+    annotation too)."""
 
     __slots__ = ("span",)
 
     def __init__(self, span):
         self.span = span
 
+    # The clock is read right beside the annotation's own stamp, with no
+    # allocation between (a collection there once put 0.4 ms between a
+    # span and its event on the chip).
     def __enter__(self):
-        self.span._token = _current.set(self.span.ctx)
-        return self.span
+        span = self.span
+        span._token = _current.set(span.ctx)
+        span._annotation = _TraceAnnotation(span.name)
+        span._annotation.__enter__()
+        span.t0 = _monotonic()
+        return span
 
     def __exit__(self, exc_type, exc, tb):
-        _current.reset(self.span._token)
-        self.span._finish(exc)
+        span = self.span
+        span.t1 = _monotonic()
+        span._annotation.__exit__(exc_type, exc, tb)
+        _current.reset(span._token)
+        span._finish(exc)
         return False
 
 
@@ -305,7 +332,7 @@ def _span_dict(name, trace_id, span_id, parent_id, t0, t1, attrs, status,
             "attrs": attrs or {}, "status": status, "tid": tid}
 
 
-def start_span(name, ctx=None, attrs=None, trace_id=None):
+def start_span(name, ctx=None, attrs=None, trace_id=None, ring=True):
     """Open a span as a context manager.
 
     * With an explicit ``ctx`` (or an implicit current context), the
@@ -314,6 +341,10 @@ def start_span(name, ctx=None, attrs=None, trace_id=None):
     * With no context at all, this is a ROOT: the head-sampling
       decision is made here (``MXNET_TRACE_SAMPLE``). ``trace_id``
       pins the new trace's id (an accepted ``X-Request-Id``).
+      ``ring=False`` is for a loop's own passes (the decode engine's,
+      sixteen a second): logged and annotated like any span, but kept
+      as a finished trace only when slow or errored, so that a busy
+      loop cannot turn the ring of request traces over.
 
     Always safe to call; returns a shared no-op scope when tracing is
     disabled or the trace is unsampled.
@@ -328,7 +359,7 @@ def start_span(name, ctx=None, attrs=None, trace_id=None):
         buf = _TraceBuf()
         span_ctx = SpanContext(trace_id or new_trace_id(), new_span_id(),
                                True, buf)
-        span = Span(name, span_ctx, None, root=True)
+        span = Span(name, span_ctx, None, root=True, ring=ring)
     else:
         if not parent.sampled:
             return _NOOP
@@ -362,11 +393,13 @@ def record_span(name, ctx, t0, t1, attrs=None, span_id=None,
     if not _enabled or ctx is None or not ctx.sampled:
         return None
     sid = span_id or new_span_id()
-    ctx.buf.add(_span_dict(name, ctx.trace_id, sid,
-                           parent_id if parent_id is not None
-                           else ctx.span_id,
-                           t0, t1, attrs, status,
-                           threading.get_ident() % 100000))
+    # seen after the fact: logged, but no annotation can be opened for it
+    span = _span_dict(name, ctx.trace_id, sid,
+                      parent_id if parent_id is not None else ctx.span_id,
+                      t0, t1, attrs, status,
+                      threading.get_ident() % 100000)
+    if ctx.buf.add(span):
+        _log.append(span)
     return sid
 
 
@@ -486,6 +519,9 @@ def graft(spans, ctx=None, clock=None):
 def _finalize(root_span):
     buf = root_span.ctx.buf
     dur_ms = (root_span.t1 - root_span.t0) * 1e3
+    slow = dur_ms >= _slow_ms or buf.error is not None
+    if not (slow or root_span._ring):
+        return                  # a loop's pass: the span log has it
     with buf._lock:
         spans = sorted(buf.spans, key=lambda s: s["t0"])
         phases = {}
@@ -501,16 +537,25 @@ def _finalize(root_span):
                  "spans": spans,
                  "dropped_spans": buf.dropped,
                  "phases": {k: round(v, 3) for k, v in phases.items()},
+                 "slow": bool(slow),
                  "wall_ts": time.time()}
         # spans recorded from now on (a worker finishing a batch whose
         # requester already timed out) land in the retained record too
         buf._trace = trace
-    slow = dur_ms >= _slow_ms or buf.error is not None
-    trace["slow"] = bool(slow)
     with _ring_lock:
-        _ring.append(trace)
+        if root_span._ring:
+            _ring.append(trace)
         if slow:
             _slow.append(trace)
+
+
+def span_log():
+    """Every span a trace of this process took in, oldest first, as the
+    dicts the rings hold (do not mutate them): the last ``16,384`` of
+    them, whatever trace each belongs to. ``t0``/``t1`` are ``perf_counter``
+    seconds (the module docstring says how an ``.xplane.pb`` counts the
+    same instants); the tree is in ``span_id``/``parent_id``."""
+    return list(_log)
 
 
 def finished_traces(limit=None):
@@ -554,11 +599,7 @@ def _chrome_events_for(trace, prof_t0):
             args["parent_id"] = s["parent_id"]
         args.update(s["attrs"])
         events.append({
-            # op.dispatch spans only: surfacing the op name keeps the
-            # timeline readable; kv.* spans also carry an "op" attr but
-            # must keep their span identity in the merged trace
-            "name": (s["attrs"].get("op", s["name"])
-                     if s["name"] == "op.dispatch" else s["name"]),
+            "name": s["name"],
             "cat": "trace",
             "ph": "X",
             "ts": max(0.0, (s["t0"] - prof_t0) * 1e6),
@@ -678,17 +719,10 @@ def set_slow_ms(ms):
     return prev
 
 
-def set_trace_ops(on):
-    """Toggle per-op op.dispatch span recording (also: MXNET_TRACE_OPS).
-    Returns the previous setting."""
-    global _trace_ops
-    prev = _trace_ops
-    _trace_ops = bool(on)
-    return prev
-
-
 def reset():
-    """Clear both rings (test isolation). Live spans are unaffected."""
+    """Clear both rings and the span log (test isolation). Live spans
+    are unaffected."""
     with _ring_lock:
         _ring.clear()
         _slow.clear()
+    _log.clear()

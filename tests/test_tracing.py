@@ -320,28 +320,6 @@ def test_tracer_does_not_consume_global_rng():
     assert [random.random() for _ in range(5)] == expect
 
 
-def test_op_dispatch_spans_opt_in():
-    """Per-op op.dispatch spans only record under MXNET_TRACE_OPS (the
-    span write dominates a microsecond-scale dispatch, so the default
-    keeps sampled traces structural)."""
-    x = mx.nd.array(np.eye(4, dtype=np.float32))
-    with tr.start_span("test.root") as span:
-        tid = span.trace_id
-        mx.nd.dot(x, x).wait_to_read()
-    assert "op.dispatch" not in {s["name"]
-                                 for s in tr.get_trace(tid)["spans"]}
-    prev = tr.set_trace_ops(True)
-    try:
-        with tr.start_span("test.root") as span:
-            tid = span.trace_id
-            mx.nd.dot(x, x).wait_to_read()
-    finally:
-        tr.set_trace_ops(prev)
-    ops = [s for s in tr.get_trace(tid)["spans"]
-           if s["name"] == "op.dispatch"]
-    assert ops and ops[0]["attrs"]["op"] == "dot"
-
-
 def test_ring_bounded():
     cap = tr._ring.maxlen
     for _ in range(cap + 25):
@@ -597,22 +575,327 @@ def test_histogram_exemplar_expires_when_traffic_stops():
     assert h.exemplar()[1] == "eeee"
 
 
-def test_chrome_rename_limited_to_op_dispatch():
-    """Only op.dispatch events take their op attr as the event name;
-    kv.* spans carry an "op" attr too but keep their span identity."""
-    prev = tr.set_trace_ops(True)
-    try:
-        with tr.start_span("test.root"):
-            with tr.child_span("kv.attempt",
-                               attrs={"op": "push", "attempt": 1}):
+# ---------------------------------------------------------------------------
+# the profiler's trace and the flat span log (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+TRAIN_SPANS = {"train.step", "train.forward_backward", "train.update",
+               "executor.stage_input", "executor.train_step",
+               "train.update_metric", "train.data_wait", "train.callbacks"}
+ITERATION_SPANS = {"decode.iteration", "decode.prefill", "decode.step"}
+
+
+def _host_events(trace_dir):
+    """(profile_start_time ns, {name: [(start_ns, duration_ns)]}) of the
+    ``/host:CPU`` plane of the one xplane under ``trace_dir``."""
+    import glob
+    import jax
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    origin, events = None, {}
+    for plane in data.planes:
+        origin = dict(plane.stats).get("profile_start_time", origin)
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.duration_ns))
+    return origin, events
+
+
+class _Boom(Exception):
+    pass
+
+
+@pytest.fixture
+def profiled(tmp_path):
+    """Runs the test's spans inside one ``jax.profiler`` session and
+    hands back what the xplane holds of them, with ``offset``: what the
+    profiler's clock (``time.time_ns``) read beyond ``perf_counter``
+    while the session ran."""
+    import jax
+    out = {}
+
+    def run(body):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        out["offset"] = time.time_ns() - time.perf_counter_ns()
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        out["origin"], out["events"] = _host_events(tmp_path)
+        return out
+
+    return run
+
+
+@pytest.mark.parametrize("name", ["test.root", "test.child", "test.raises"])
+def test_span_is_an_event_of_the_profilers_trace_on_its_clock(profiled, name):
+    """A scoped span is on the xplane's host plane under its own name,
+    and the log's ``t0``/``t1`` are that event's start and end to 0.2 ms
+    (the file counts CLOCK_REALTIME from its ``profile_start_time``). A
+    span left by an exception is closed in the trace as it is in the
+    log."""
+    def body():
+        with pytest.raises(_Boom):
+            with tr.start_span("test.root"):
+                with tr.child_span("test.child"):
+                    time.sleep(0.003)
+                with tr.child_span("test.raises"):
+                    time.sleep(0.002)
+                    raise _Boom()
+
+    got = profiled(body)
+    (start_ns, dur_ns), = got["events"][name]
+    rec, = [r for r in tr.span_log() if r["name"] == name]
+    at = got["origin"] + start_ns - got["offset"]    # on perf_counter, ns
+    assert abs(at - rec["t0"] * 1e9) < 0.2e6
+    assert abs(at + dur_ns - rec["t1"] * 1e9) < 0.2e6
+    assert rec["status"] == ("ok" if name == "test.child" else "error")
+
+
+def test_no_annotation_is_left_open_by_an_exception(profiled):
+    """After a span died of an exception the thread's next annotation is
+    a sibling, not a child of a span that never closed: every event of
+    the trace ends, and in the order the spans did."""
+    def body():
+        for _ in range(3):
+            with pytest.raises(_Boom):
+                with tr.start_span("test.dies"):
+                    raise _Boom()
+        with tr.start_span("test.after"):
+            pass
+
+    events = profiled(body)["events"]
+    assert len(events["test.dies"]) == 3 and len(events["test.after"]) == 1
+    ends = [s + d for s, d in sorted(events["test.dies"])]
+    starts = [s for s, _d in sorted(events["test.dies"])]
+    assert all(e <= s for e, s in zip(ends, starts[1:]))
+    assert ends[-1] <= events["test.after"][0][0]
+
+
+def test_span_log_is_flat_outlives_the_ring_and_is_cleared_by_reset():
+    """One log for every trace: it outlives the ring's rotation, holds
+    the last ``_LOG_SPANS`` spans and ``reset()`` empties it."""
+    assert tr._log.maxlen == tr._LOG_SPANS == 16384
+    n = tr._ring.maxlen + 6
+    for i in range(n):
+        with tr.start_span("test.root", attrs={"i": i}):
+            with tr.child_span("test.child"):
                 pass
-            x = mx.nd.array(np.eye(2, dtype=np.float32))
-            mx.nd.dot(x, x).wait_to_read()
+    log = tr.span_log()
+    assert len(tr.finished_traces()) == tr._ring.maxlen < n
+    assert [r["attrs"]["i"] for r in log if r["name"] == "test.root"] \
+        == list(range(n))
+    assert len(log) == 2 * n
+    roots = dict((r["span_id"], r) for r in log if r["name"] == "test.root")
+    assert all(r["parent_id"] in roots
+               for r in log if r["name"] == "test.child")
+    # the record IS the ring's dict: no second allocation
+    newest = tr.finished_traces()[0]
+    assert any(r is s for r in log for s in newest["spans"])
+    tr.reset()
+    assert tr.span_log() == []
+
+
+def test_span_log_is_bounded(monkeypatch):
+    from collections import deque
+    monkeypatch.setattr(tr, "_log", deque(maxlen=8))
+    for _ in range(20):
+        with tr.start_span("test.root"):
+            pass
+    assert len(tr.span_log()) == 8
+
+
+def test_recorded_interval_reaches_the_log(profiled):
+    """``record_span`` (an interval seen after the fact) can have no
+    annotation, but the log holds it with the ends it was given."""
+    def body():
+        with tr.start_span("test.root") as span:
+            t0 = time.perf_counter()
+            tr.record_span("test.late", span.ctx, t0, t0 + 0.002)
+
+    events = profiled(body)["events"]
+    rec, = [r for r in tr.span_log() if r["name"] == "test.late"]
+    assert rec["t1"] - rec["t0"] == pytest.approx(0.002)
+    assert "test.root" in events and "test.late" not in events
+
+
+def test_disabled_logs_nothing():
+    """``MXNET_TRACING=0`` (``enable(False)``) turns the log and the
+    annotations off with everything else."""
+    tr.enable(False)
+    with tr.start_span("test.root"):
+        with tr.child_span("test.child"):
+            pass
+    tr.enable(True)
+    assert tr.span_log() == []
+    tr.set_sample(0.0)
+    with tr.start_span("test.root"):
+        pass
+    assert tr.span_log() == []
+
+
+def test_fit_step_logs_the_eight_training_spans_under_train_step():
+    rng = np.random.RandomState(0)
+    it = io.NDArrayIter(rng.rand(80, 16).astype(np.float32),
+                        rng.randint(0, 8, size=(80,)).astype(np.float32),
+                        batch_size=20)
+    seen = []
+    mod = Module(_mlp_sym(), context=mx.cpu())
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            optimizer_params=(("learning_rate", 0.1),),
+            batch_end_callback=lambda p: seen.append(p.nbatch))
+    log = tr.span_log()
+    roots = [r for r in log if r["name"] == "train.step"]
+    assert [r["attrs"]["nbatch"] for r in roots] == seen == [0, 1, 2, 3]
+    last = roots[-1]                    # a warm step: nothing compiles
+    step = [r for r in log if r["trace_id"] == last["trace_id"]]
+    assert sorted(r["name"] for r in step) == sorted(TRAIN_SPANS)
+    by_id = dict((r["span_id"], r) for r in step)
+    for r in step:
+        up = r
+        while up["parent_id"] is not None:
+            up = by_id[up["parent_id"]]
+        assert up is last
+        # children lie inside the root: they tile the step
+        assert last["t0"] <= r["t0"] and r["t1"] <= last["t1"]
+    # the deferred fused step runs under the update: stage, then call
+    staged, = [r for r in step if r["name"] == "executor.stage_input"]
+    call, = [r for r in step if r["name"] == "executor.train_step"]
+    update, = [r for r in step if r["name"] == "train.update"]
+    assert staged["parent_id"] == call["parent_id"] == update["span_id"]
+    assert staged["t1"] <= call["t0"]
+    # the root covers the callbacks
+    cb, = [r for r in step if r["name"] == "train.callbacks"]
+    assert last["t0"] < cb["t0"] and cb["t1"] <= last["t1"]
+
+
+def test_callback_exception_closes_step_and_callbacks_spans():
+    """The benchmark ends ``fit`` by raising out of a callback."""
+    rng = np.random.RandomState(0)
+    it = io.NDArrayIter(rng.rand(40, 16).astype(np.float32),
+                        np.zeros(40, np.float32), batch_size=20)
+
+    def stop(_param):
+        raise _Boom()
+
+    mod = Module(_mlp_sym(), context=mx.cpu())
+    with pytest.raises(_Boom):
+        mod.fit(it, num_epoch=1, optimizer="sgd", batch_end_callback=stop)
+    assert tr.current() is None
+    ends = dict((r["name"], r) for r in tr.span_log())
+    assert ends["train.callbacks"]["status"] == "error"
+    assert ends["train.step"]["status"] == "error"
+    assert ends["train.update_metric"]["status"] == "ok"
+
+
+def _tiny_decode_engine():
+    import jax
+    from jax.sharding import Mesh
+    from mxnet_tpu.parallel.transformer import (TransformerConfig,
+                                                init_transformer_params)
+    from mxnet_tpu.serve.decode import DecodeConfig, DecodeEngine
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                            n_layers=2, d_ff=64, max_len=64)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1, 1, 1),
+                ("dp", "sp", "tp", "pp", "ep"))
+    params, _ = init_transformer_params(cfg, mesh, seed=0)
+    dcfg = DecodeConfig(slots=4, page_size=4, num_pages=64, max_context=48,
+                        max_new_tokens=8, queue_depth=16,
+                        default_timeout_ms=60000)
+    return DecodeEngine(params, cfg, dcfg).start().warmup()
+
+
+def test_decode_loop_logs_its_iterations_without_a_caller_context():
+    """``submit()`` with no context: the per-request spans record
+    nothing, the engine's own loop still logs one ``decode.iteration``
+    per pass with its prefills and its step, and ``context_tokens`` is
+    the positions the step attends over."""
+    eng = _tiny_decode_engine()
+    tr.reset()                          # the warm-up's compile spans
+    try:
+        first = eng.submit([1, 2, 3, 4, 5], max_new_tokens=4)
+        assert first.tctx is None
+        first.result()
+        alone = len(tr.span_log())
+        pair = [eng.submit([7, 8, 9], max_new_tokens=3),
+                eng.submit([5, 6], max_new_tokens=3)]
+        for sess in pair:
+            sess.result()
     finally:
-        tr.set_trace_ops(prev)
-    names = {e["name"] for e in tr.chrome_events()}
-    assert "kv.attempt" in names and "push" not in names
-    assert "dot" in names and "op.dispatch" not in names
+        eng.close(drain=False)
+    log = tr.span_log()
+    assert set(r["name"] for r in log) == ITERATION_SPANS
+    its = [r for r in log if r["name"] == "decode.iteration"]
+    ids = set(r["span_id"] for r in its)
+    assert all(r["parent_id"] is None for r in its)
+    assert all(r["parent_id"] in ids for r in log if r not in its)
+    # the engine's passes are in the log, not in the ring of traces
+    assert tr.finished_traces() == []
+
+    def kids(it, name):
+        return [r for r in log
+                if r["parent_id"] == it["span_id"] and r["name"] == name]
+
+    # the first request alone: prompt of 5, so its three decode steps
+    # attend over 6, 7 and 8 positions (the new token's own included)
+    steps = [r for r in log[:alone] if r["name"] == "decode.step"]
+    assert [s["attrs"] for s in steps] == [{"context_tokens": n}
+                                           for n in (6, 7, 8)]
+    assert len(kids(its[0], "decode.prefill")) == 1
+    assert its[0]["attrs"] == {"live": 0}
+    # a pass's prefills and its step lie inside it, in that order
+    for it in its:
+        order = kids(it, "decode.prefill") + kids(it, "decode.step")
+        assert order and len(kids(it, "decode.step")) <= 1
+        assert all(a["t1"] <= b["t0"] for a, b in zip(order, order[1:]))
+        assert it["t0"] <= order[0]["t0"] and order[-1]["t1"] <= it["t1"]
+    # two sequences in one step: both contexts are counted (prompts of 3
+    # and 2: the first step over both attends over 4 + 3 positions)
+    later = [r["attrs"]["context_tokens"] for r in log[alone:]
+             if r["name"] == "decode.step"]
+    assert 7 in later
+    # sequences carried into a pass are its ``live``
+    assert any(it["attrs"]["live"] > 0 for it in its)
+
+
+def test_engine_passes_do_not_turn_the_request_ring_over():
+    """A request that came with a context keeps its trace in the ring
+    (``get_trace`` by its id, ``/traces``' recent list) however many
+    passes the engine makes after it, at the default ring size: a pass
+    goes to the span log alone. A pass that dies of an error is kept
+    among the slow exemplars like any trace."""
+    assert tr._ring.maxlen == 64
+    eng = _tiny_decode_engine()
+    tr.reset()
+    try:
+        with tr.start_span("http.request", trace_id="req-1") as span:
+            eng.submit([1, 2, 3], max_new_tokens=3,
+                       ctx=span.ctx).result()
+        for _ in range(20):
+            eng.submit([4, 5, 6, 7], max_new_tokens=8).result()
+    finally:
+        eng.close(drain=False)
+    passes = [r for r in tr.span_log() if r["name"] == "decode.iteration"]
+    assert len(passes) > 100
+    kept = tr.get_trace("req-1")
+    assert kept is not None and kept["root"] == "http.request"
+    assert {"decode.schedule", "decode.prefill", "decode.step"} \
+        <= set(kept["phases"])
+    recent = tr.traces_payload()["recent"]
+    assert [t["trace_id"] for t in recent] == ["req-1"]
+    assert tr.slow_traces() == []
+    with pytest.raises(_Boom):
+        with tr.start_span("decode.iteration", ring=False):
+            raise _Boom()
+    assert [t["root"] for t in tr.slow_traces()] == ["decode.iteration"]
+    assert [t["trace_id"] for t in tr.finished_traces()] == ["req-1"]
 
 
 # ---------------------------------------------------------------------------
@@ -694,8 +977,7 @@ def test_metrics_docs_in_sync():
 def test_dispatch_overhead_sampling0():
     """The sampling-0 path (tracing enabled, nothing recording) stays
     close to the disabled path on the dispatch microbench. Asserted
-    loosely (CI wall-clock drifts more than the effect); the banked
-    trace_overhead bench job carries the production < 5% evidence."""
+    loosely (CI wall-clock drifts more than the effect)."""
     x = mx.nd.array(np.random.rand(16, 16).astype("float32"))
     mx.nd.dot(x, x).wait_to_read()
 

@@ -163,5 +163,7 @@ def test_chip_smoke_rehearsal_runs_green():
     assert verdict["device"]["platform"] == "cpu"
     out = r.stdout
     assert "proves NOTHING about the device" in out
-    for phase in ("train", "kernels", "dp4"):
+    for phase in ("train", "kernels", "trace_clock", "dp4"):
         assert "phase %-8s passed" % phase in out, out[-2000:]
+    # the clock tracing.py's docs name for the xplane's host events
+    assert "xplane host events are on ['time.time_ns']" in out
