@@ -446,26 +446,31 @@ class Executor(object):
 
     # -- fused train step --------------------------------------------------
     def _build_fused_step(self, rule, update_names, default_ct, donate,
-                          numerics="off", accum=1, accum_names=()):
+                          hyper_keys, numerics="off", accum=1,
+                          accum_names=()):
         """Trace + jit ONE program computing forward outputs, all
         gradients (jax.vjp over the same pure graph function), the
         optimizer update for every parameter in ``update_names`` via
         ``rule``, and the aux-state updates. Parameter and optimizer-state
         buffers are donated so XLA aliases them input→output: an in-place
-        HBM update with no per-parameter copies.
+        HBM update with no per-parameter copies. The hyper-parameters
+        arrive as ONE float32 array (a row per name in ``update_names``,
+        a column per key in ``hyper_keys``); each rule call reads its
+        row as a dict of scalars in its weight's dtype.
 
         ``numerics`` != 'off' folds the health sentinels into the SAME
         program: a loss proxy (mean of the first output), the global
         gradient L2 norm, and the nonfinite-element count — all over
         the gradients the program already holds, so the sentinel costs
         a handful of reductions and ZERO extra host dispatches or
-        recompiles (hyper scalars stay traced arguments). ``full``
+        recompiles (the hyper array stays a traced argument). ``full``
         additionally returns per-parameter norm/nonfinite vectors for
         blast-radius attribution. Everything is packed into ONE flat
         float32 vector so the host pays a single small D2H fetch per
         step."""
         import jax
         import jax.numpy as jnp
+        from .optimizer import unpack_fused_hyper
         fn = _graph_eval_fn(self._symbol, True)
         if self._dp_mesh is not None:
             # Under the dp mesh every operand of the update is replicated
@@ -510,7 +515,14 @@ class Executor(object):
                 return head
             return jnp.concatenate([head, jnp.sqrt(sq), jnp.stack(nf)])
 
-        def _core(genv, senv, henv, fenv, key, cts):
+        def _update(genv, gs, senv, harr):
+            new_p, new_s = {}, {}
+            for i, n in enumerate(update_names):
+                h = unpack_fused_hyper(harr[i], hyper_keys, genv[n].dtype)
+                new_p[n], new_s[n] = rule(genv[n], gs[n], senv[n], h)
+            return new_p, new_s
+
+        def _core(genv, senv, harr, fenv, key, cts):
             def fwd(ge):
                 env = dict(fenv)
                 env.update(ge)
@@ -521,12 +533,10 @@ class Executor(object):
                 cts = tuple(jnp.ones(o.shape, dtype=o.dtype) for o in outs)
             (gs,) = vjp_fn(tuple(cts))
             sentinel = _sentinel(gs, outs) if numerics != "off" else None
-            new_p, new_s = {}, {}
-            for n in update_names:
-                new_p[n], new_s[n] = rule(genv[n], gs[n], senv[n], henv[n])
+            new_p, new_s = _update(genv, gs, senv, harr)
             return new_p, new_s, new_aux, outs, sentinel
 
-        def _accum_core(genv, senv, henv, fenv, key, mbenv):
+        def _accum_core(genv, senv, harr, fenv, key, mbenv):
             # Gradient accumulation INSIDE the donated program: a
             # lax.scan over the leading microbatch axis of ``mbenv``,
             # with the gradient accumulator as the carry, then ONE
@@ -564,21 +574,18 @@ class Executor(object):
             else:
                 outs = tuple(o[None] for o in outs0)
             sentinel = _sentinel(g_tot, outs) if numerics != "off" else None
-            new_p, new_s = {}, {}
-            for n in update_names:
-                new_p[n], new_s[n] = rule(genv[n], g_tot[n], senv[n],
-                                          henv[n])
+            new_p, new_s = _update(genv, g_tot, senv, harr)
             return new_p, new_s, {}, outs, sentinel
 
         if accum_names:
-            def run(genv, senv, henv, fenv, key, mbenv):
-                return _accum_core(genv, senv, henv, fenv, key, mbenv)
+            def run(genv, senv, harr, fenv, key, mbenv):
+                return _accum_core(genv, senv, harr, fenv, key, mbenv)
         elif default_ct:
-            def run(genv, senv, henv, fenv, key):
-                return _core(genv, senv, henv, fenv, key, None)
+            def run(genv, senv, harr, fenv, key):
+                return _core(genv, senv, harr, fenv, key, None)
         else:
-            def run(genv, senv, henv, fenv, key, cts):
-                return _core(genv, senv, henv, fenv, key, cts)
+            def run(genv, senv, harr, fenv, key, cts):
+                return _core(genv, senv, harr, fenv, key, cts)
 
         return jax.jit(run, donate_argnums=(0, 1) if donate else ())
 
@@ -596,8 +603,12 @@ class Executor(object):
             grad_req='write'.
         states : dict name -> tuple of NDArray optimizer-state buffers
             (``optimizer.fused_state_arrays``); updated in place.
-        hyper : dict name -> dict of python scalars for ``rule`` — traced
-            arguments, so lr-schedule/rescale changes never recompile.
+        hyper : dict name -> dict of python scalars for ``rule``
+            (``Optimizer.fused_hyper``), all with the same keys. Packed
+            here into ONE float32 array (``optimizer.pack_fused_hyper``)
+            and handed to the program as one traced argument: one
+            host→device hand-over a step, and lr-schedule/rescale
+            changes never recompile.
         feed : optional dict of input name -> NDArray/host array, staged
             like ``forward(**kwargs)``.
         out_grads : optional output cotangents (default: ones, matching
@@ -652,8 +663,11 @@ class Executor(object):
         donate = bool(_cfg("MXNET_UPDATE_BUFFER_DONATION"))
         numerics = _health.numerics_mode()
         accum_names = tuple(sorted(accum_feed)) if accum_feed else ()
+        from .optimizer import pack_fused_hyper
+        hyper_keys, harr = pack_fused_hyper(
+            [hyper[n] for n in update_names])
         cache_key = (rule, update_names, out_grads is None, donate,
-                     numerics, accum, accum_names)
+                     numerics, accum, accum_names, hyper_keys)
 
         env = self._env()
         genv = {n: env.pop(n) for n in update_names}
@@ -675,7 +689,7 @@ class Executor(object):
                 tup.append(d)
             senv[n] = tuple(tup)
         key = _random.next_key() if self._needs_rng else None
-        args = [genv, senv, hyper, env, key]
+        args = [genv, senv, harr, env, key]
         if mbenv is not None:
             args.append(mbenv)
         elif out_grads is not None:
@@ -722,7 +736,8 @@ class Executor(object):
                  "default_ct": out_grads is None, "donate": donate,
                  "numerics": numerics, "args": self._buffer_sig(),
                  "mesh": self._mesh_sig(), "rng": self._needs_rng,
-                 "accum": [accum, accum_sig] if accum_sig else None},
+                 "accum": [accum, accum_sig] if accum_sig else None,
+                 "hyper": list(hyper_keys)},
                 instance=instance)
             built = []
 
@@ -751,7 +766,8 @@ class Executor(object):
                                 ).inc()
                 return self._build_fused_step(
                     rule, update_names, out_grads is None, donate,
-                    numerics, accum=accum, accum_names=accum_names)
+                    hyper_keys, numerics, accum=accum,
+                    accum_names=accum_names)
 
             run = _pg.get_or_build(pkey, build)
             self._fused_jitted[cache_key] = run
@@ -818,6 +834,11 @@ class Executor(object):
         if _tm._enabled:
             _tm.counter("executor/fused_step_total",
                         "Completed fused train steps").inc()
+            _tm.counter("executor/fused_step_hyper_put_total",
+                        "Host-to-device hand-overs of the fused step's "
+                        "packed hyper-parameter array (one a step: a "
+                        "step that hands over more has fallen back to "
+                        "scalar leaves)").inc()
             if self._dp_nproc > 1:
                 # in-program collective accounting: one allreduce rode
                 # this step, over this many gradient bytes — and ZERO

@@ -23,7 +23,8 @@ from . import ndarray as nd
 __all__ = ["Optimizer", "SGD", "Signum", "NAG", "Adam", "AdaGrad", "RMSProp",
            "AdaDelta", "Ftrl", "FTML", "Adamax", "Nadam", "SGLD", "DCASGD",
            "Test", "Updater", "get_updater", "create", "register",
-           "fused_apply", "fused_state_arrays"]
+           "fused_apply", "fused_state_arrays", "pack_fused_hyper",
+           "unpack_fused_hyper"]
 
 
 # ---------------------------------------------------------------------------
@@ -31,10 +32,14 @@ __all__ = ["Optimizer", "SGD", "Signum", "NAG", "Adam", "AdaGrad", "RMSProp",
 #
 # Each rule is a PURE function ``rule(weight, grad, state, hyper) ->
 # (new_weight, new_state)`` over raw jax arrays: ``state`` is a tuple of
-# state arrays (possibly empty), ``hyper`` a dict of python scalars that
-# jit traces as weak-typed 0-d arguments — so a changing learning-rate
-# schedule (or rescale_grad per batch size) NEVER retriggers XLA
-# compilation. The rules mirror the fused kernels in ops/optimizer_ops.py
+# state arrays (possibly empty), ``hyper`` a dict of 0-d arrays in the
+# weight's dtype. The scalars of ALL parameters cross to the device as
+# ONE float32 array a step (``pack_fused_hyper``; a python scalar handed
+# to jit is a transfer of its own) and the traced program takes each
+# parameter's dict back out of its row (``unpack_fused_hyper``). They
+# are data, so a changing learning-rate schedule (or rescale_grad per
+# batch size) NEVER retriggers XLA compilation; only the key set is
+# static. The rules mirror the fused kernels in ops/optimizer_ops.py
 # op for op, and every scalar-scalar expression the kernels fold in python
 # (e.g. Adam's ``1 - beta1``) is folded HOST-side into ``hyper`` here, so
 # a fused train step is bitwise-identical to the unfused
@@ -188,6 +193,36 @@ def _test_fused(w, g, state, h):
     return (w - h["lr"] * g * h["rescale_grad"], (state[0] + g,))
 
 
+def pack_fused_hyper(hypers):
+    """Per-parameter hyper dicts (``Optimizer.fused_hyper``) -> ``(keys,
+    float32 [n, k])``: one row a parameter, one column a sorted key. The
+    array is the ONE leaf the scalars add to a jitted program's
+    arguments; ``keys`` is static (it belongs in the program's cache
+    key). Every dict must hold the same keys — nothing is padded."""
+    keys = tuple(sorted(hypers[0])) if hypers else ()
+    for h in hypers:
+        if h.keys() != hypers[0].keys():
+            raise MXNetError(
+                "fused hyper-parameter keys differ between parameters: "
+                "%s vs %s" % (sorted(h), list(keys)))
+    rows = [[h[k] for k in keys] for h in hypers]
+    return keys, numpy.asarray(rows, numpy.float32).reshape(
+        len(hypers), len(keys))
+
+
+def unpack_fused_hyper(row, keys, dtype):
+    """Inside the trace: one row of the packed array -> the ``{key: 0-d
+    array}`` dict a fused rule reads. A python float entered jit WEAK
+    and took the weight's dtype in every product; an element of a
+    float32 array is strong and would promote a bfloat16/float16 update
+    to float32 (and break the donation of its buffers), so the row is
+    cast to a floating ``dtype`` first — a no-op for float32."""
+    import jax.numpy as jnp
+    if jnp.issubdtype(dtype, jnp.floating):
+        row = row.astype(dtype)
+    return {k: row[j] for j, k in enumerate(keys)}
+
+
 def fused_state_arrays(state):
     """Normalize an optimizer state (None | NDArray | tuple) to the flat
     tuple of NDArray buffers a fused rule consumes/produces."""
@@ -277,9 +312,12 @@ class Optimizer(object):
         return None
 
     def fused_hyper(self, index):
-        """Per-step scalar hyperparameters for ``fused_rule`` — advances
-        the same update-count/lr-schedule bookkeeping as update(), so a
-        fused and an unfused run see identical schedules."""
+        """Per-step scalar hyperparameters for ``fused_rule``, as a dict
+        of python floats (packed with every other parameter's into one
+        array before they reach the device: ``pack_fused_hyper``) —
+        advances the same update-count/lr-schedule bookkeeping as
+        update(), so a fused and an unfused run see identical
+        schedules."""
         self._update_count(index)
         h = {"lr": float(self._get_lr(index)),
              "wd": float(self._get_wd(index)),
@@ -1145,9 +1183,11 @@ def fused_apply(optimizer, items):
 
     Returns True when the fused path ran (weights/states updated in
     place); False when this optimizer/configuration has no pure rule —
-    the caller must then run the per-param update() path. Scalar
-    hyperparameters (lr schedule, rescale_grad) are traced, so their
-    value changes never recompile.
+    the caller must then run the per-param update() path. The scalar
+    hyperparameters (lr schedule, rescale_grad) of all items enter the
+    program as ONE float32 array (``pack_fused_hyper``), so their value
+    changes never recompile and a step hands over one array, not a
+    scalar per parameter and key.
     """
     from .config import get as _cfg
     if not items or not _cfg("MXNET_FUSED_STEP"):
@@ -1161,13 +1201,14 @@ def fused_apply(optimizer, items):
             return False
 
     state_tuples = [fused_state_arrays(s) for (_i, _w, _g, s) in items]
-    hyper = [optimizer.fused_hyper(i) for (i, _w, _g, _s) in items]
+    hyper_keys, hyper = pack_fused_hyper(
+        [optimizer.fused_hyper(i) for (i, _w, _g, _s) in items])
 
     cache = optimizer.__dict__.setdefault("_fused_apply_cache", {})
     # donation honors the same knob as the per-param update kernels
     # (ops/registry.py _donation_allowed)
     donate = bool(_cfg("MXNET_UPDATE_BUFFER_DONATION"))
-    cache_key = (rule, len(items), donate)
+    cache_key = (rule, len(items), donate, hyper_keys)
     jfn = cache.get(cache_key)
     if jfn is None:
         import jax
@@ -1175,7 +1216,9 @@ def fused_apply(optimizer, items):
         install_donation_warning_filter()
 
         def apply_all(ws, gs, ss, hs):
-            new = [rule(w, g, s, h) for w, g, s, h in zip(ws, gs, ss, hs)]
+            new = [rule(w, g, s,
+                        unpack_fused_hyper(hs[i], hyper_keys, w.dtype))
+                   for i, (w, g, s) in enumerate(zip(ws, gs, ss))]
             return [n[0] for n in new], [n[1] for n in new]
 
         jfn = jax.jit(apply_all, donate_argnums=(0, 2) if donate else ())

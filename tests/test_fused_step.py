@@ -8,6 +8,7 @@ kernels of src/operator/optimizer_op.cc, collapsed across the step
 boundary.
 """
 import os
+import warnings
 
 import numpy as np
 import pytest
@@ -60,6 +61,39 @@ def _train(mod, batches):
         mod.forward_backward(db)
         mod.update()
     return {n: mod._exec.arg_dict[n].asnumpy() for n in mod._param_names}
+
+
+def _step_leaves(record, fn, shardings=None):
+    """Wrap jitted ``fn`` so each call appends the leaves of its arguments
+    to ``record`` (what the program is actually handed); the first call
+    also fills ``shardings`` with where the compiled program holds each
+    leaf."""
+    import jax
+
+    def wrapped(*args):
+        record.append(jax.tree_util.tree_leaves(args))
+        if shardings is not None and not shardings:
+            shardings.extend(jax.tree_util.tree_leaves(
+                fn.lower(*args).compile().input_shardings[0]))
+        return fn(*args)
+    return wrapped
+
+
+def _record_fused_programs(exe, record, shardings=None):
+    """Put :func:`_step_leaves` round every fused-step program ``exe`` holds."""
+    for key, fn in list(exe._fused_jitted.items()):
+        exe._fused_jitted[key] = _step_leaves(record, fn, shardings)
+
+
+def _assert_one_hyper_array(leaves, n_params):
+    import jax
+    scalars = [x for x in leaves if isinstance(x, (bool, int, float))]
+    assert not scalars, "python scalars among the program's leaves"
+    host = [x for x in leaves if not isinstance(x, jax.Array)]
+    assert len(host) == 1, [type(x) for x in host]
+    assert isinstance(host[0], np.ndarray)
+    assert host[0].dtype == np.float32 and host[0].shape[0] == n_params
+    return host[0]
 
 
 OPT_CONFIGS = [
@@ -176,6 +210,9 @@ def test_lr_schedule_does_not_recompile(monkeypatch):
         batches = _batches(12, 16)
         _train(mod, batches[:2])            # compile + commit buffers
 
+        record = []
+        exe = mod._exec
+        _record_fused_programs(exe, record)
         lr_before = mod._optimizer._get_lr(0)
         compiles_before = tm.compile_count()
         builds_before = tm.snapshot()["fused_step_compiles"]
@@ -183,6 +220,16 @@ def test_lr_schedule_does_not_recompile(monkeypatch):
         assert tm.compile_count() == compiles_before, \
             "lr schedule step retriggered XLA compilation"
         assert tm.snapshot()["fused_step_compiles"] == builds_before
+        # the schedule travels in the ONE packed array: its lr column
+        # (sorted keys: lr, momentum, rescale_grad, wd) falls every
+        # step, its other columns stand, and no program was added
+        assert len(exe._fused_jitted) == 1 and len(record) == 10
+        hypers = [_assert_one_hyper_array(leaves, len(mod._param_names))
+                  for leaves in record]
+        lrs = [float(h[0, 0]) for h in hypers]
+        assert all(b < a for a, b in zip(lrs, lrs[1:])), lrs
+        for h in hypers:
+            np.testing.assert_array_equal(h[:, 1:], hypers[0][:, 1:])
         # the schedule really advanced (so the zero-recompile claim is
         # about changing lr values, not a frozen schedule)
         assert mod._optimizer._get_lr(0) < lr_before * 0.5
@@ -439,6 +486,8 @@ def test_fused_step_dp_mesh_matches_single_device(monkeypatch):
     training."""
     monkeypatch.setenv("MXNET_FUSED_STEP", "1")
 
+    handed = {}
+
     def losses(contexts, steps=6, batch=32):
         rng = np.random.RandomState(4)
         centers = rng.randn(10, 64).astype(np.float32) * 1.5
@@ -458,6 +507,7 @@ def test_fused_step_dp_mesh_matches_single_device(monkeypatch):
                            optimizer_params={"learning_rate": 0.1,
                                              "momentum": 0.9})
         out = []
+        record = handed[len(mod._context)] = []
         for i in range(steps):
             lo = (i * batch) % (len(data) - batch)
             db = io.DataBatch(
@@ -465,6 +515,9 @@ def test_fused_step_dp_mesh_matches_single_device(monkeypatch):
                 label=[mx.nd.array(labels[lo:lo + batch])])
             mod.forward_backward(db)
             mod.update()
+            if i == 0:
+                shardings = handed["shardings", len(mod._context)] = []
+                _record_fused_programs(mod._exec, record, shardings)
             probs = mod.get_outputs()[0].asnumpy()
             li = labels[lo:lo + batch].astype(int)
             out.append(float(-np.mean(np.log(np.maximum(
@@ -476,3 +529,235 @@ def test_fused_step_dp_mesh_matches_single_device(monkeypatch):
     multi = losses([mx.cpu(i) for i in range(4)])
     np.testing.assert_allclose(multi, single, rtol=2e-4, atol=2e-5)
     assert single[-1] < single[0], "training did not reduce loss"
+    # under the mesh the step is handed the same ONE host array as on one
+    # device, and the program holds it replicated on every device
+    assert len(handed[1]) == len(handed[4]) == 5
+    for one, four in zip(handed[1], handed[4]):
+        np.testing.assert_array_equal(_assert_one_hyper_array(one, 6),
+                                      _assert_one_hyper_array(four, 6))
+    import jax
+    (hyper_sharding,) = [
+        sh for sh, x in zip(handed["shardings", 4], handed[4][0])
+        if not isinstance(x, jax.Array)]
+    assert hyper_sharding.is_fully_replicated
+    assert len(hyper_sharding.device_set) == 4
+
+
+# ---------------------------------------------------------------------------
+# the packed hyper-parameter array (optimizer.pack_fused_hyper): the scalars
+# of every updated parameter cross to the device as ONE float32 array a step
+# ---------------------------------------------------------------------------
+
+FUSED_RULE_OPTIMIZERS = [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4}),
+    ("sgd", {"learning_rate": 0.1}),                       # stateless
+    ("nag", {"learning_rate": 0.05, "momentum": 0.9}),
+    ("signum", {"learning_rate": 0.01, "momentum": 0.9, "wd_lh": 1e-4}),
+    ("adam", {"learning_rate": 1e-3, "wd": 1e-4, "clip_gradient": 1.0}),
+    ("adagrad", {"learning_rate": 0.05}),
+    ("rmsprop", {"learning_rate": 1e-3}),
+    ("rmsprop", {"learning_rate": 1e-3, "centered": True,
+                 "clip_weights": 0.5}),
+    ("adadelta", {}),
+    ("ftrl", {}),
+    ("ftml", {}),
+    ("adamax", {}),
+    ("test", {"learning_rate": 0.1}),
+]
+
+
+def test_every_fused_rule_optimizer_is_covered():
+    """The list above names every optimizer that has a ``fused_rule``."""
+    have = {name for name, klass in mx.optimizer.Optimizer.opt_registry.items()
+            if klass.fused_rule is not mx.optimizer.Optimizer.fused_rule}
+    assert have == {name for name, _ in FUSED_RULE_OPTIMIZERS}
+
+
+@pytest.mark.parametrize("optimizer,opt_params", FUSED_RULE_OPTIMIZERS)
+def test_packed_step_bitwise_matches_scalar_rule(optimizer, opt_params):
+    """Three steps of ``fused_apply`` (the packed array, unpacked in the
+    trace) leave weights and states bitwise where the same rule, jitted
+    over the dicts of python scalars as it was before the array, leaves
+    them — fp32, every optimizer with a ``fused_rule``."""
+    import jax
+    from mxnet_tpu import optimizer as opt
+    rng = np.random.RandomState(5)
+    shapes = [(8, 6), (6,), (3, 4, 2)]
+    w0 = [rng.randn(*s).astype(np.float32) * 0.3 for s in shapes]
+    grads = [[rng.randn(*s).astype(np.float32) for s in shapes]
+             for _ in range(3)]
+
+    def make():
+        o = opt.create(optimizer, **opt_params)
+        ws = [mx.nd.array(w) for w in w0]
+        states = [o.create_state(i, w) for i, w in enumerate(ws)]
+        return o, ws, states
+
+    o, ws, states = make()
+    for step in grads:
+        items = [(i, ws[i], mx.nd.array(step[i]), states[i])
+                 for i in range(len(ws))]
+        assert opt.fused_apply(o, items)
+    got_w = [w.asnumpy() for w in ws]
+    got_s = [[a.asnumpy() for a in opt.fused_state_arrays(s)]
+             for s in states]
+
+    o, ws, states = make()
+    rule = o.fused_rule()
+    scalar_step = jax.jit(lambda w, g, s, hs: [
+        rule(w[i], g[i], s[i], hs[i]) for i in range(len(w))])
+    w = [a._data for a in ws]
+    s = [tuple(a._data for a in opt.fused_state_arrays(st))
+         for st in states]
+    for step in grads:
+        hs = [o.fused_hyper(i) for i in range(len(w))]
+        assert all(isinstance(v, float) for h in hs for v in h.values())
+        new = scalar_step(w, [mx.nd.array(g)._data for g in step], s, hs)
+        w, s = [n[0] for n in new], [n[1] for n in new]
+
+    for i in range(len(shapes)):
+        assert np.array_equal(got_w[i], np.asarray(w[i])), (optimizer, i)
+        assert len(got_s[i]) == len(s[i])
+        for a, b in zip(got_s[i], s[i]):
+            assert np.array_equal(a, np.asarray(b)), (optimizer, i)
+
+
+def test_train_step_hands_over_one_hyper_array(monkeypatch):
+    """The leaves ``Executor.train_step`` hands its jitted program hold no
+    python scalar and exactly one host array — the packed hypers, a row
+    per updated parameter — and the hand-over counter rises by 1 a step."""
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    prev = tm.enable(True)
+    try:
+        mod = _make_module("sgd", {"learning_rate": 0.1, "momentum": 0.9,
+                                   "wd": 1e-4})
+        batches = _batches(4, 16)
+        _train(mod, batches[:1])                # build the program
+        exe = mod._exec
+        record = []
+        _record_fused_programs(exe, record)
+
+        def puts():
+            fam = tm.REGISTRY._families.get(
+                "executor/fused_step_hyper_put_total")
+            return sum(c.value for _lv, c in fam.series())
+
+        before = puts()
+        _train(mod, batches[1:])
+        assert puts() - before == 3
+        assert len(record) == 3
+        n_params = len(mod._param_names)
+        for leaves in record:
+            harr = _assert_one_hyper_array(leaves, n_params)
+            # columns = sorted keys of SGD-momentum's fused_hyper:
+            # lr, momentum, rescale_grad (1 / batch), wd
+            assert harr.shape == (n_params, 4)
+            np.testing.assert_array_equal(
+                harr[0], np.float32([0.1, 0.9, 1.0 / 16, 1e-4]))
+    finally:
+        tm.enable(prev)
+
+
+def test_fused_apply_hands_over_one_hyper_array():
+    """Same for ``optimizer.fused_apply`` (the Gluon Trainer's update)."""
+    from mxnet_tpu import optimizer as opt
+    o = opt.create("adam", learning_rate=1e-3)
+    ws = [mx.nd.ones((4, 3)), mx.nd.ones((3,))]
+    states = [o.create_state(i, w) for i, w in enumerate(ws)]
+
+    def items():
+        return [(i, ws[i], mx.nd.ones(ws[i].shape), states[i])
+                for i in range(2)]
+
+    assert opt.fused_apply(o, items())
+    record = []
+    cache = o._fused_apply_cache
+    for key, fn in list(cache.items()):
+        cache[key] = _step_leaves(record, fn)
+    assert opt.fused_apply(o, items())
+    assert len(cache) == 1 and len(record) == 1
+    harr = _assert_one_hyper_array(record[0], 2)
+    assert harr.shape == (2, len(o.fused_hyper(0)))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16"])
+def test_packed_hyper_keeps_narrow_dtype_and_donation(dtype):
+    """A strong float32 scalar would promote a bfloat16/float16 update to
+    float32; the unpacked scalars take the weight's dtype (where the weak
+    python scalar was demoted), so outputs keep it, the donated buffers
+    are reused, and the result is the python-scalar rule's bitwise."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import optimizer as opt
+    rng = np.random.RandomState(9)
+    w0 = rng.randn(16, 8).astype(np.float32)
+    g0 = rng.randn(16, 8).astype(np.float32)
+    o = opt.create("sgd", learning_rate=0.1, momentum=0.9, wd=1e-3)
+    hyper = o.fused_hyper(0)
+    keys, harr = opt.pack_fused_hyper([hyper])
+    rule = o.fused_rule()
+
+    def packed(w, g, s, hs):
+        return rule(w, g, s, opt.unpack_fused_hyper(hs[0], keys, w.dtype))
+
+    w = jnp.asarray(w0, dtype)
+    g = jnp.asarray(g0, dtype)
+    mom = jnp.zeros_like(w)
+    want_w, (want_m,) = jax.jit(rule)(w, g, (mom,), hyper)
+    assert want_w.dtype == jnp.dtype(dtype)
+
+    lowered = jax.jit(packed, donate_argnums=(0, 2)).lower(
+        w, g, (mom,), harr)
+    assert lowered.as_text().count("tf.aliasing_output") == 2
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        got_w, (got_m,) = jax.jit(packed, donate_argnums=(0, 2))(
+            w, g, (mom,), harr)
+    assert not [c for c in caught if "donated" in str(c.message)]
+    assert got_w.dtype == jnp.dtype(dtype) and got_m.dtype == got_w.dtype
+    assert w.is_deleted() and mom.is_deleted()
+    assert np.array_equal(np.asarray(got_w, np.float32),
+                          np.asarray(want_w, np.float32))
+    assert np.array_equal(np.asarray(got_m, np.float32),
+                          np.asarray(want_m, np.float32))
+
+
+def test_fused_apply_narrow_dtype_end_to_end():
+    """``fused_apply`` on a bfloat16 weight: dtype kept, in place."""
+    from mxnet_tpu import optimizer as opt
+    o = opt.create("sgd", learning_rate=0.1, momentum=0.9)
+    w = mx.nd.ones((8, 4), dtype="bfloat16")
+    s = o.create_state(0, w)
+    assert opt.fused_apply(o, [(0, w, mx.nd.ones((8, 4), dtype="bfloat16"),
+                                s)])
+    assert str(w._data.dtype) == "bfloat16"
+    assert str(s._data.dtype) == "bfloat16"
+    np.testing.assert_allclose(w.asnumpy().astype(np.float32), 0.9,
+                               rtol=1e-2)
+
+
+def test_differing_hyper_key_sets_raise():
+    """Nothing is padded: parameters whose hyper dicts differ in their
+    keys are refused, by the helper and by ``train_step``."""
+    from mxnet_tpu import optimizer as opt
+    from mxnet_tpu.base import MXNetError
+    with pytest.raises(MXNetError, match="keys differ"):
+        opt.pack_fused_hyper([{"lr": 0.1, "wd": 0.0},
+                              {"lr": 0.1, "momentum": 0.9}])
+    with pytest.raises(MXNetError, match="keys differ"):
+        opt.pack_fused_hyper([{"lr": 0.1, "wd": 0.0}, {"lr": 0.1}])
+    keys, arr = opt.pack_fused_hyper([{"b": 2.0, "a": 1.0}] * 3)
+    assert keys == ("a", "b") and arr.shape == (3, 2)
+    assert arr.dtype == np.float32 and arr[1].tolist() == [1.0, 2.0]
+    assert opt.pack_fused_hyper([])[1].shape == (0, 0)
+
+    mod = _make_module("sgd", {"learning_rate": 0.1, "momentum": 0.9})
+    exe = mod._exec
+    names = tuple(mod._param_names)
+    states = {n: opt.fused_state_arrays(
+        mod._updater.ensure_state(i, exe.arg_dict[n]))
+        for i, n in enumerate(names)}
+    hyper = {n: mod._optimizer.fused_hyper(i) for i, n in enumerate(names)}
+    hyper[names[-1]]["clip_gradient"] = 1.0
+    with pytest.raises(MXNetError, match="keys differ"):
+        exe.train_step(mod._optimizer.fused_rule(), names, states, hyper)
