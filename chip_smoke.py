@@ -310,6 +310,7 @@ def kernels_phase():
     fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
     fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
     i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
+    mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
     rng = np.random.RandomState(0)
     # on the chip: interpret=None, the production default, which must
     # resolve to Mosaic (asserted per kernel through _mosaic_in); in the
@@ -404,6 +405,21 @@ def kernels_phase():
                  q, kp, vp, bt, ln, 1.0 / hd ** 0.5),
              (q, kp, vp, bt, ln), 2e-2)
 
+    # -- the same against a RING of block-table entries under a window
+    #    (rows not wrapped, wrapped once and several times)
+    b, kvh, g, hd, ps, ring, window = (3, 2, 2, 8, 4, 3, 8) if TINY else \
+        (8, 4, 7, 128, 16, 17, 256)
+    q = f32(b, kvh, g, hd)
+    kp, vp = f32(b * ring + 1, ps, kvh, hd), f32(b * ring + 1, ps, kvh, hd)
+    bt = jnp.asarray(1 + np.arange(b * ring).reshape(b, ring), jnp.int32)
+    ln = jnp.asarray(rng.randint(1, 6 * window, size=(b,)), jnp.int32)
+    case("paged_decode_attention[window%d,ring%d]" % (window, ring),
+         lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+             q, kp, vp, bt, ln, interpret=interp, window=window),
+         lambda q, kp, vp, bt, ln: fa._paged_decode_xla(
+             q, kp, vp, bt, ln, 1.0 / hd ** 0.5, window),
+         (q, kp, vp, bt, ln), 2e-2)
+
     # -- flash prefill with the fused page write
     prefill = [(2, 16, 4, 2, 8, 4)] if TINY else \
         [(2, 128, 8, 2, 64, 16), (2, 256, 8, 4, 128, 16)]
@@ -419,6 +435,54 @@ def kernels_phase():
              % (b, s, nh, kvh, hd),
              lambda *a: fa.flash_prefill_paged(*a, interpret=interp),
              fa._flash_prefill_xla, (q, kg, vg, kp, vp, bt), 2e-2)
+
+    # -- ... by the rows' real lengths onto a ring, under a window
+    b, s, nh, kvh, hd, ps, ring, window = (2, 32, 4, 2, 8, 4, 3, 8) \
+        if TINY else (2, 1024, 28, 4, 128, 16, 17, 256)
+    q = f32(b, s, nh, hd)
+    kg, vg = f32(b, s, kvh, hd), f32(b, s, kvh, hd)
+    kp, vp = (jnp.zeros((b * ring + 1, ps, kvh, hd), jnp.float32)
+              for _ in range(2))
+    bt = jnp.asarray(1 + np.arange(b * ring).reshape(b, ring), jnp.int32)
+    ln = jnp.asarray([s - 2 * ps - 3, ps + 2], jnp.int32)
+
+    def real_pages(out):
+        # page 0 takes whatever is not kept, in any order
+        return (out[0],) + tuple(p[1:] for p in out[1:])
+
+    case("flash_prefill_paged[window%d,ring%d]" % (window, ring),
+         lambda *a: real_pages(fa.flash_prefill_paged(
+             *a[:6], interpret=interp, lengths=a[6], window=window)),
+         lambda *a: real_pages(fa._flash_prefill_xla(*a, window)),
+         (q, kg, vg, kp, vp, bt, ln), 2e-2)
+
+    # -- grouped expert FFN: a decode step's tile of 16 and a prefill's of
+    #    128, rows sorted by expert, one expert left without a row
+    from mxnet_tpu.parallel.moe import sorted_dispatch, top_k_routing
+    moe = [(24, 32, 16, 8, 3, 16)] if TINY else \
+        [(32, 2560, 768, 64, 6, 16), (2048, 2560, 768, 64, 6, 128)]
+    for n, d, f, e, k, tile in moe:
+        dt = jnp.float32 if TINY else jnp.bfloat16
+        wg, wu = (jnp.asarray(rng.randn(1, 2, e, d, f) * 0.02, dt)
+                  for _ in range(2))
+        wd = jnp.asarray(rng.randn(1, 2, e, f, d) * 0.02, dt)
+        logits = f32(n, e).at[:, 1].add(-50.0)
+        src, _dest, sizes, _counts = sorted_dispatch(
+            top_k_routing(logits, k)[0], e, tile)
+        rows = jnp.asarray(rng.randn(n, d), dt)[src]
+        used = int(sizes.sum())          # rows past it are never written
+
+        def twin(r, gs, a, b_, c, u=used):
+            # bf16 products are exact in one pass; XLA:TPU's ragged_dot
+            # refuses "highest" over bf16 operands ("Bad lhs type")
+            with jax.default_matmul_precision("bfloat16"):
+                return mf._moe_grouped_ffn_xla(r, gs, a, b_, c,
+                                               (0, 1))[:u]
+
+        case("moe_grouped_ffn[n%dtile%d]" % (n, tile),
+             lambda r, gs, a, b_, c, t=tile, u=used: mf.moe_grouped_ffn(
+                 r, gs, a, b_, c, t, interpret=interp, lead=(0, 1))[:u],
+             twin, (rows, sizes, wg, wu, wd), 2e-2)
 
     # -- int8 matmul + im2col conv: ResNet-50's FC and its first 3x3 conv
     mm = [(8, 40, 12)] if TINY else [(32, 2048, 1000),
@@ -442,7 +506,7 @@ def kernels_phase():
          (qx, wq, sc), 0.0, exact=True)
 
     exported = set()
-    for mod in (fa, fu, i8):
+    for mod in (fa, fu, i8, mf):
         exported.update(mod.PALLAS_KERNELS)
     covered = set(n.split("[")[0] for n in report)
     check(covered == exported, "kernels: exported %s, exercised %s"

@@ -323,18 +323,42 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
 # a block-table-addressed page pool — serve/decode.py's hot kernel)
 # ---------------------------------------------------------------------------
 
+def ring_positions(n_entries, page_size, lengths):
+    """Absolute position held by every slot of a RING block table:
+    entry ``e`` of a row whose newest position is ``lengths - 1`` holds
+    the newest page ``a`` with ``a % n_entries == e`` and ``a <=
+    (lengths - 1) // page_size`` (negative: never written). Returns
+    (b, n_entries * page_size) int32. While a row has not wrapped this
+    is ``arange``, so a table as long as the context is a ring too."""
+    a_last = (lengths - 1) // page_size                        # (b,)
+    e = jnp.arange(n_entries, dtype=jnp.int32)[None, :]
+    a = a_last[:, None] - (a_last[:, None] - e) % n_entries    # (b, R)
+    pos = a[:, :, None] * page_size \
+        + jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
+    return pos.reshape(lengths.shape[0], n_entries * page_size)
+
+
 def _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
-                      sm_scale):
+                      sm_scale, window=None):
     """Pure-lax twin of the paged kernel (the CPU tier-1 path and the
     numeric reference): block-table gather materializes each row's
-    (L, kv_heads, hd) view, then standard masked GQA softmax."""
+    (L, kv_heads, hd) view, then standard masked GQA softmax. With a
+    ``window`` the table is a ring (:func:`ring_positions`) and only
+    the last ``window`` positions are visible."""
     b, kvh, g, hd = q.shape
     kc = k_pages[block_tables]           # (b, pages, page_size, kvh, hd)
     vc = v_pages[block_tables]
     L = kc.shape[1] * kc.shape[2]
     kc = kc.reshape(b, L, kvh, hd).transpose(0, 2, 1, 3)
     vc = vc.reshape(b, L, kvh, hd).transpose(0, 2, 1, 3)
-    visible = jnp.arange(L)[None, :] < lengths[:, None]       # (b, L)
+    if window is None:
+        visible = jnp.arange(L)[None, :] < lengths[:, None]   # (b, L)
+    else:
+        kpos = ring_positions(block_tables.shape[1], k_pages.shape[1],
+                              lengths)
+        visible = jnp.logical_and(
+            jnp.logical_and(kpos >= 0, kpos < lengths[:, None]),
+            kpos >= lengths[:, None] - window)
     sc = jnp.einsum("bkgd,bkld->bkgl", q.astype(jnp.float32),
                     kc.astype(jnp.float32)) * sm_scale
     sc = jnp.where(visible[:, None, None, :], sc, NEG_INF)
@@ -344,7 +368,8 @@ def _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size, sm_scale, kv_heads):
+                  m_scr, l_scr, acc_scr, *, page_size, sm_scale, kv_heads,
+                  window=None):
     """Grid (b, pages_per_seq): the trailing page dimension iterates
     sequentially per sequence, accumulating an online softmax in VMEM
     scratch exactly like the flash forward kernel — the block table is
@@ -369,9 +394,22 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     length = len_ref[b_i]
-    start = p_i * page_size
+    if window is None:
+        start = p_i * page_size
+        live = start < length
+    else:
+        # a ring: this entry holds the newest page congruent to it (see
+        # ring_positions); it counts while it was ever written and
+        # still reaches into the last ``window`` positions
+        a_last = (length - 1) // page_size
+        page = a_last - jax.lax.rem(a_last - p_i + n_p, n_p)
+        start = page * page_size
+        # (a live page always holds a visible key, so the running
+        # maximum is finite from the first page on, as without a window)
+        live = jnp.logical_and(page >= 0,
+                               start + page_size > length - window)
 
-    @pl.when(start < length)
+    @pl.when(live)
     def _body():
         for h in range(kv_heads):
             q = q_ref[0, h].astype(jnp.float32) * sm_scale       # (g, hd)
@@ -380,7 +418,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)              # (g, ps)
             kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(kpos < length, s, NEG_INF)
+            seen = kpos < length
+            if window is not None:
+                seen = jnp.logical_and(seen, kpos >= length - window)
+            s = jnp.where(seen, s, NEG_INF)
 
             m_prev = m_scr[h, :, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -404,7 +445,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
-                           sm_scale=None, interpret=None):
+                           sm_scale=None, interpret=None, window=None):
     """Decode-phase attention against a PAGED KV cache: one query token
     per sequence, keys/values gathered page-by-page via a block table.
 
@@ -417,6 +458,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     block_tables : (b, pages_per_seq) int32 — page ids per row, in
         position order.
     lengths : (b,) int32 — row ``r`` attends positions ``< lengths[r]``.
+    window : optional int — attend only the last ``window`` of them,
+        and read the table as a RING: position ``p`` lives at entry
+        ``(p // page_size) % pages_per_seq`` (a table as long as the
+        context is the special case that never wraps). The grid walks
+        the ring's entries, so a window layer costs its window, not
+        its context.
 
     Returns (b, kv_heads, group, head_dim). Forward-only (serving);
     no VJP is defined. On TPU this is a Mosaic kernel whose page DMAs
@@ -436,15 +483,19 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
             # interpreted per-page DMA emulation (interpret=True still
             # forces the interpreter for kernel-logic tests)
             return _paged_decode_xla(q, k_pages, v_pages, block_tables,
-                                     lengths, float(sm_scale))
+                                     lengths, float(sm_scale), window)
         interpret = False
+    if window is None:
+        return _paged_decode(q, k_pages, v_pages, block_tables, lengths,
+                             float(sm_scale), bool(interpret))
     return _paged_decode(q, k_pages, v_pages, block_tables, lengths,
-                         float(sm_scale), bool(interpret))
+                         float(sm_scale), bool(interpret), int(window))
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret",
+                                             "window"))
 def _paged_decode(q, k_pages, v_pages, block_tables, lengths, sm_scale,
-                  interpret):
+                  interpret, window=None):
     b, kvh, g, hd = q.shape
     num_pages, page_size = k_pages.shape[:2]
     n_pb = block_tables.shape[1]
@@ -472,6 +523,8 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, sm_scale,
     )
     kernel = functools.partial(_paged_kernel, page_size=page_size,
                                sm_scale=sm_scale, kv_heads=kvh)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=spec,
@@ -490,7 +543,50 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, sm_scale,
 # worst-fusions report ranks worst is exactly this scatter round-trip)
 # ---------------------------------------------------------------------------
 
-def _flash_prefill_xla(q, kg, vg, k_pages, v_pages, block_tables):
+def prefill_page_dest(block_tables, n_pb, page_size, lengths=None,
+                      window=None):
+    """(b, n_pb) pool page that each page of a prefill bucket is written
+    to. Plainly, the table's first ``n_pb`` entries. With ``lengths``
+    only the pages that hold a real position keep their place (the
+    padded tail goes to the null page 0); with a ``window`` the table
+    is a ring — page ``a`` at entry ``a % entries`` — and of the real
+    pages only the last ``entries`` are kept, so that the padding can
+    never wrap onto an entry the first decode steps still read."""
+    if lengths is None and window is None:
+        return block_tables[:, :n_pb]
+    n_entries = block_tables.shape[1]
+    a = jnp.arange(n_pb, dtype=jnp.int32)[None, :]
+    if window is None:
+        dest = block_tables[:, :n_pb]
+    else:
+        if lengths is None and n_pb > n_entries:
+            raise ValueError(
+                "a ring of %d entries cannot take a %d-page prompt "
+                "without its length" % (n_entries, n_pb))
+        dest = jnp.take_along_axis(
+            block_tables, jnp.broadcast_to(a % n_entries, (
+                block_tables.shape[0], n_pb)), axis=1)
+    if lengths is not None:
+        a_last = ((lengths - 1) // page_size)[:, None]
+        keep = a <= a_last
+        if window is not None:
+            keep = jnp.logical_and(keep, a > a_last - n_entries)
+        dest = jnp.where(keep, dest, 0)
+    return dest
+
+
+def causal_mask(s, window=None):
+    """(s, s) bool: query i sees key j for ``0 <= i - j`` and, under a
+    window, ``i - j < window``."""
+    mask = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        mask = jnp.logical_and(mask, ~jnp.tril(jnp.ones((s, s), bool),
+                                               -window))
+    return mask
+
+
+def _flash_prefill_xla(q, kg, vg, k_pages, v_pages, block_tables,
+                       lengths=None, window=None):
     """Pure-lax twin of :func:`flash_prefill_paged` — op-for-op the
     attention + page write of ``transformer._prefill_impl``'s paged
     branch (expand-KV einsum / sqrt(hd), tril mask, softmax, and the
@@ -503,11 +599,11 @@ def _flash_prefill_xla(q, kg, vg, k_pages, v_pages, block_tables):
     n_pb = s // ps
     k = kg if groups == 1 else jnp.repeat(kg, groups, axis=2)
     v = vg if groups == 1 else jnp.repeat(vg, groups, axis=2)
-    mask = jnp.tril(jnp.ones((s, s), bool))
+    mask = causal_mask(s, window)
     sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
     sc = jnp.where(mask[None, None], sc, NEG_INF)
     o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-    bt = block_tables[:, :n_pb]
+    bt = prefill_page_dest(block_tables, n_pb, ps, lengths, window)
     kp = k_pages.at[bt].set(
         kg.reshape(b, n_pb, ps, kvh, hd).astype(k_pages.dtype))
     vp = v_pages.at[bt].set(
@@ -515,9 +611,9 @@ def _flash_prefill_xla(q, kg, vg, k_pages, v_pages, block_tables):
     return o, kp, vp
 
 
-def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
-                    block_k, page_size, seq_len, n_heads, groups,
-                    write_pages):
+def _prefill_kernel(bt_ref, *refs, sm_scale, block_q, block_k, page_size,
+                    seq_len, n_heads, groups, write_pages, by_length=False,
+                    window=None):
     """Grid (b, q_blocks, k_blocks): per (batch, q tile) the trailing k
     dimension accumulates an online softmax in VMEM scratch exactly
     like ``_fwd_kernel``, for every head in turn. The tiles keep the
@@ -533,7 +629,13 @@ def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
     straight from HBM into its rows' pool pages (``block_k`` is a
     multiple of ``page_size``, so each page is written exactly once per
     layer and the separate reshape-scatter program — and its HBM
-    round-trip — disappears)."""
+    round-trip — disappears). ``by_length`` (a second scalar-prefetched
+    array, the rows' real lengths) and ``window`` choose which pages are
+    written and where, as :func:`prefill_page_dest` says; a ``window``
+    also masks ``i - j >= window`` and skips the k blocks behind it."""
+    if by_length:
+        len_ref, refs = refs[0], refs[1:]
+    q_ref, k_ref, v_ref, *rest = refs
     if write_pages:
         (kg_ref, vg_ref, _kp_in, _vp_in, o_ref, kp_out, vp_out,
          m_scr, l_scr, acc_scr, ksem, vsem) = rest
@@ -556,8 +658,7 @@ def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
     if write_pages:
         @pl.when(qi == 0)
         def _write_pages():
-            for j in range(block_k // page_size):
-                page = bt_ref[b_i, ki * (block_k // page_size) + j]
+            def copy_page(j, page):
                 src = pl.ds(k_start + j * page_size, page_size)
                 kcp = pltpu.make_async_copy(kg_ref.at[b_i, src],
                                             kp_out.at[page], ksem)
@@ -568,12 +669,28 @@ def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
                 kcp.wait()
                 vcp.wait()
 
+            n_entries = bt_ref.shape[1]
+            for j in range(block_k // page_size):
+                a = ki * (block_k // page_size) + j
+                entry = a if window is None else jax.lax.rem(a, n_entries)
+                if not by_length:
+                    copy_page(j, bt_ref[b_i, entry])
+                    continue
+                a_last = (len_ref[b_i] - 1) // page_size
+                keep = a <= a_last
+                if window is not None:
+                    keep = jnp.logical_and(keep, a > a_last - n_entries)
+                pl.when(keep)(functools.partial(
+                    copy_page, j, bt_ref[b_i, entry]))
+
     def _body():
         kpos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         qpos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         visible = jnp.logical_and(kpos < seq_len, kpos <= qpos)
+        if window is not None:
+            visible = jnp.logical_and(visible, qpos - kpos < window)
         for h in range(n_heads):
             q = q_ref[0, :, h, :].astype(jnp.float32) * sm_scale   # (bq, d)
             k = k_ref[0, :, h // groups, :].astype(jnp.float32)    # (bk, d)
@@ -597,7 +714,14 @@ def _prefill_kernel(bt_ref, q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
     # skip K blocks entirely above the causal diagonal (the page-write
     # epilogue above must NOT be skipped: padded-tail pages are still
     # written, exactly like the twin's scatter)
-    @pl.when(k_start <= q_start + block_q - 1)
+    in_reach = k_start <= q_start + block_q - 1
+    if window is not None:
+        # ... and those wholly behind every query's window (a row whose
+        # own keys come later wipes what a masked block left: alpha = 0)
+        in_reach = jnp.logical_and(
+            in_reach, q_start - (k_start + block_k - 1) < window)
+
+    @pl.when(in_reach)
     def _():
         _body()
 
@@ -616,9 +740,9 @@ _PREFILL_VMEM_BUDGET = 8 << 20
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
-                                             "interpret"))
+                                             "interpret", "window"))
 def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, lengths=None, window=None):
     b, s, nh, hd = q.shape
     kvh = kg.shape[2]
     ps = k_pages.shape[1]
@@ -629,10 +753,10 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
     write_pages = hd % _LANES == 0
     grid = (b, s // block_q, s // block_k)
 
-    def q_map(b_i, qi, ki, bt):
+    def q_map(b_i, qi, ki, *_prefetched):
         return (b_i, qi, 0, 0)
 
-    def kv_map(b_i, qi, ki, bt):
+    def kv_map(b_i, qi, ki, *_prefetched):
         return (b_i, ki, 0, 0)
 
     q_spec = pl.BlockSpec((1, block_q, nh, hd), q_map)
@@ -647,6 +771,13 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
         _prefill_kernel, sm_scale=1.0 / math.sqrt(hd), block_q=block_q,
         block_k=block_k, page_size=ps, seq_len=s, n_heads=nh,
         groups=nh // kvh, write_pages=write_pages)
+    prefetched = (block_tables,)
+    if lengths is not None or window is not None:
+        kernel = functools.partial(kernel, by_length=lengths is not None,
+                                   window=window)
+        if lengths is not None:
+            prefetched += (lengths,)
+    n_pre = len(prefetched)
     vma = _out_vma(q, kg, vg, k_pages, v_pages)
     # the per-head store is strided over the heads dim, which Mosaic
     # cannot do on a packed (sub-32-bit) tile narrower than 128 lanes:
@@ -660,21 +791,22 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
         o = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=grid,
+                num_scalar_prefetch=n_pre, grid=grid,
                 in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
                 scratch_shapes=scratch),
             out_shape=o_shape, compiler_params=params, interpret=interpret,
-        )(block_tables, q, kg, vg)
+        )(*prefetched, q, kg, vg)
         n_pb = s // ps
+        dest = prefill_page_dest(block_tables, n_pb, ps, lengths, window)
         return (o.astype(q.dtype),
-                k_pages.at[block_tables].set(
+                k_pages.at[dest].set(
                     kg.reshape(b, n_pb, ps, kvh, hd).astype(k_pages.dtype)),
-                v_pages.at[block_tables].set(
+                v_pages.at[dest].set(
                     vg.reshape(b, n_pb, ps, kvh, hd).astype(v_pages.dtype)))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
+            num_scalar_prefetch=n_pre, grid=grid,
             # kg/vg ride twice: blocked tiles for the attention, whole
             # HBM refs as the page-write source
             in_specs=[q_spec, kv_spec, kv_spec,
@@ -690,14 +822,15 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
         # pool arrays alias in->out: pages no row writes keep their
         # contents, and on TPU the pool is updated in place (operand
         # order counts the scalar-prefetch arg: bt=0 ... k_pages=6)
-        input_output_aliases={6: 1, 7: 2},
+        input_output_aliases={n_pre + 5: 1, n_pre + 6: 2},
         compiler_params=params,
         interpret=interpret,
-    )(block_tables, q, kg, vg, kg, vg, k_pages, v_pages)
+    )(*prefetched, q, kg, vg, kg, vg, k_pages, v_pages)
 
 
 def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
-                        block_q=128, block_k=128, interpret=None):
+                        block_q=128, block_k=128, interpret=None,
+                        lengths=None, window=None):
     """Prefill-phase flash attention over a paged KV pool: one batched
     causal forward per layer whose epilogue writes the prompt's K/V
     pages, replacing ``(s, s)``-score XLA attention + a separate
@@ -714,6 +847,13 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
     block_tables : (b, pages_per_row) int32 — destination page ids in
         position order (``pages_per_row = s // page_size``); rows of a
         warmup batch may all point at the reserved null page 0.
+    lengths : optional (b,) int32 real prompt lengths — only pages that
+        hold a real position are written (the padded tail is not).
+    window : optional int — position ``i`` attends ``0 <= i - j <
+        window`` only, and the table is a ring of ``block_tables.shape[1]``
+        entries (page ``a`` at entry ``a % entries``): with ``lengths``
+        just the last ``entries`` real pages are written. See
+        :func:`prefill_page_dest`.
 
     Returns ``(o, k_pages, v_pages)`` with ``o`` (b, s, n_heads,
     head_dim). Score scale is fixed at ``1/sqrt(head_dim)``. Causal
@@ -727,15 +867,23 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
     if s % ps:
         raise ValueError("prefill bucket %d is not a multiple of "
                          "page_size %d" % (s, ps))
-    if s // ps > block_tables.shape[1]:
-        raise ValueError("prefill bucket %d needs %d pages/row; "
-                         "block table holds %d"
-                         % (s, s // ps, block_tables.shape[1]))
-    block_tables = jnp.asarray(block_tables, jnp.int32)[:, :s // ps]
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    if window is None:
+        if s // ps > block_tables.shape[1]:
+            raise ValueError("prefill bucket %d needs %d pages/row; "
+                             "block table holds %d"
+                             % (s, s // ps, block_tables.shape[1]))
+        block_tables = block_tables[:, :s // ps]
+    elif lengths is None and s // ps > block_tables.shape[1]:
+        raise ValueError("a ring of %d entries cannot take a %d-page "
+                         "prompt without its length"
+                         % (block_tables.shape[1], s // ps))
+    if lengths is not None:
+        lengths = jnp.asarray(lengths, jnp.int32)
     if interpret is None:
         if not on_tpu(q):
             return _flash_prefill_xla(q, kg, vg, k_pages, v_pages,
-                                      block_tables)
+                                      block_tables, lengths, window)
         interpret = False
     # block_k must be a multiple of page_size (each page written by
     # exactly one k block) and divide s; block_q must divide s
@@ -751,5 +899,9 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
                       + 4 * (2 * _LANES + hd))
     while block_q > 8 and block_q * row_bytes > _PREFILL_VMEM_BUDGET:
         block_q //= 2
+    if lengths is None and window is None:
+        return _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
+                              int(block_q), int(block_k), bool(interpret))
     return _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
-                          int(block_q), int(block_k), bool(interpret))
+                          int(block_q), int(block_k), bool(interpret),
+                          lengths, None if window is None else int(window))

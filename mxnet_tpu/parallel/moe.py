@@ -13,6 +13,13 @@ Pieces:
   position-in-expert via cumsum, and the GShard load-balancing aux loss;
 * :func:`moe_apply` — dispatch → per-device expert FFN (vmapped over
   local experts) → combine, inside ``shard_map``.
+
+The serving path of a model with many small experts has a second,
+drop-free recipe on one device (:func:`moe_ffn_sorted`): top-k of E with
+a softmax over the chosen, the assignments sorted by expert into padded
+groups (:func:`sorted_dispatch`), one grouped product over the experts
+(``ops/pallas/moe_ffn.py``) and a weighted gather back. It has no
+capacity: every assignment is computed at any batch or prompt size.
 """
 from __future__ import annotations
 
@@ -23,7 +30,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
-__all__ = ["top_k_gating", "moe_apply", "stack_expert_params"]
+__all__ = ["top_k_gating", "moe_apply", "stack_expert_params",
+           "top_k_routing", "sorted_dispatch", "moe_ffn_sorted"]
 
 
 def stack_expert_params(params_list):
@@ -130,3 +138,100 @@ def moe_apply(x, gate_w, expert_params, expert_fn, mesh=None, axis="ep",
     out = fn(expert_params, dispatch.astype(x.dtype),
              combine.astype(x.dtype), x)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# drop-free routing: sorted rows, grouped products (single device)
+# ---------------------------------------------------------------------------
+
+def top_k_routing(router_logits, k):
+    """The ``k`` largest of each token's E router logits and a softmax
+    over those ``k`` alone (weights sum to 1): ``(experts (n, k) int32,
+    weights (n, k) float32)``, largest first."""
+    top, idx = jax.lax.top_k(router_logits.astype(jnp.float32), k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def dispatch_block_rows(assignments, num_experts):
+    """Rows of one tile of the grouped product: the power of two at or
+    above an expert's mean share, between 16 (a packed bf16 sublane
+    tile; a decode step's experts see one to a few rows) and 128 (the
+    MXU's edge; a long prefill's see hundreds)."""
+    mean = -(-int(assignments) // int(num_experts))
+    return min(128, max(16, 1 << (mean - 1).bit_length()))
+
+
+def sorted_dispatch(experts, num_experts, block_rows):
+    """Lay ``n * k`` assignments out as rows sorted by expert, every
+    expert's group padded to a multiple of ``block_rows``.
+
+    experts : (n, k) int32. Returns ``(src (rows,), dest (n, k),
+    group_sizes (E,), counts (E,))``: row ``r`` of the layout holds
+    token ``src[r]`` (padding rows hold token 0 and are read by
+    nobody), assignment ``(t, j)`` sits at row ``dest[t, j]``,
+    ``group_sizes`` are the padded and ``counts`` the true sizes.
+    ``rows`` is static: ``n * k`` plus at most ``block_rows - 1`` a
+    group, whatever the routing — nothing is ever dropped."""
+    n, k = experts.shape
+    flat = experts.reshape(-1)
+    counts = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    padded = (counts + block_rows - 1) // block_rows * block_rows
+    order = jnp.argsort(flat, stable=True)      # assignments by expert
+    by_expert = flat[order]
+    rank = jnp.arange(n * k, dtype=jnp.int32) \
+        - (jnp.cumsum(counts) - counts)[by_expert]
+    dest = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        (jnp.cumsum(padded) - padded)[by_expert] + rank)
+    rows = (n * k + num_experts * (block_rows - 1)) \
+        // block_rows * block_rows
+    src = jnp.zeros((rows,), jnp.int32).at[dest].set(
+        jnp.arange(n * k, dtype=jnp.int32) // k)
+    return src, dest.reshape(n, k), padded, counts
+
+
+# tokens routed in one grouped product; a longer call (a prefill of 8 k
+# tokens and more) goes through in chunks of this many, one after the
+# other, so that its sorted rows — six a token, in and out — never
+# exceed a third of a gigabyte at the served width
+MAX_ROUTED_TOKENS = 4096
+
+
+def moe_ffn_sorted(x, router_logits, wg, wu, wd, k, lead=()):
+    """Drop-free top-k mixture of gated ReLU experts on one device.
+
+    x : (n, d) tokens (the FFN's normalised input); router_logits :
+    (n, E); wg, wu : (E, d, f); wd : (E, f, d), or a model's stacks of
+    them with ``lead`` the static (stage, layer) of this call (the
+    grouped product then reads the stack in place). Returns
+    ``(out (n, d), experts (k, n), active)``: ``out[t] = sum_j w[t, j] *
+    expert_{experts[j, t]}(x[t])`` with ``w`` the softmax over the
+    chosen ``k`` (the choice is the MAJOR axis of ``experts`` too: it is
+    kept, and ``(n, k)`` pads k to a lane tile of 128); ``active``
+    counts the experts that received a row, a chunk at a time (what the
+    grouped products had to read of the weights)."""
+    from ..ops.pallas.moe_ffn import moe_grouped_ffn
+    num_experts = wg.shape[len(lead)]
+
+    def routed(x, router_logits):
+        n = x.shape[0]
+        experts, w = top_k_routing(router_logits, k)
+        block_rows = dispatch_block_rows(n * k, num_experts)
+        src, dest, group_sizes, counts = sorted_dispatch(
+            experts, num_experts, block_rows)
+        y = moe_grouped_ffn(x[src], group_sizes, wg, wu, wd, block_rows,
+                            lead=lead)
+        # weighted sum in float32, the choice as the MAJOR axis ((n, k,
+        # d) would pad k to a tile of 8)
+        out = jnp.sum(w.T[:, :, None] * y[dest.T].astype(jnp.float32),
+                      axis=0)
+        return out.astype(x.dtype), experts.T, jnp.sum(counts > 0)
+
+    n, d = x.shape
+    if n <= MAX_ROUTED_TOKENS or n % MAX_ROUTED_TOKENS:
+        return routed(x, router_logits)
+    out, experts, active = jax.lax.map(
+        lambda part: routed(*part),
+        (x.reshape(-1, MAX_ROUTED_TOKENS, d),
+         router_logits.reshape(-1, MAX_ROUTED_TOKENS, num_experts)))
+    return (out.reshape(n, d), experts.transpose(1, 0, 2).reshape(k, n),
+            jnp.sum(active))

@@ -32,13 +32,14 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
-from ..ops.pallas.flash_attention import on_tpu
+from ..ops.pallas.flash_attention import causal_mask as _causal_mask, on_tpu
 from .ring_attention import _ring_attention_local
-from .moe import top_k_gating
+from .moe import moe_ffn_sorted, top_k_gating
 
 __all__ = ["TransformerConfig", "init_transformer_params",
            "make_transformer_train_step", "transformer_forward_single",
            "init_kv_cache", "init_kv_pages", "PagedKVCache",
+           "HybridKVCache", "kv_layer_kinds", "paged_cache", "cache_pools",
            "transformer_decode_step", "transformer_decode_step_paged",
            "transformer_prefill", "transformer_prefill_paged",
            "transformer_generate"]
@@ -50,13 +51,37 @@ def _kv_heads(cfg):
     return cfg.n_kv_heads or cfg.n_heads
 
 
+def _head_dim(cfg):
+    return cfg.head_dim or cfg.d_model // cfg.n_heads
+
+
+def _layer_rule(cfg, li):
+    """``(rotary, window)`` of layer ``li``: whether q/k rotate there
+    (``rope_layout``; every layer of a "rope" model without one) and
+    how far back it attends (``sliding_window`` where ``window_layout``
+    marks the layer, or everywhere without a layout; None = to the
+    start). The one place a layer's kind is decided."""
+    rotary = cfg.pos_type == "rope" and (
+        cfg.rope_layout is None or bool(cfg.rope_layout[li]))
+    windowed = cfg.sliding_window is not None and (
+        cfg.window_layout is None or bool(cfg.window_layout[li]))
+    return rotary, (int(cfg.sliding_window) if windowed else None)
+
+
+def kv_layer_kinds(cfg):
+    """Per layer, ``"window"`` where the layer attends a sliding window
+    (its cache may forget older positions) else ``"full"``."""
+    return tuple("window" if _layer_rule(cfg, li)[1] else "full"
+                 for li in range(cfg.n_layers))
+
+
 def _expand_kv(t, groups, head_axis):
     """Repeat each K/V head ``groups`` times along ``head_axis`` so
     grouped K/V line up with the query heads (GQA -> MHA view)."""
     return t if groups == 1 else jnp.repeat(t, groups, axis=head_axis)
 
 
-def _validate_heads(cfg):
+def _validate_config(cfg):
     kvh = cfg.n_kv_heads
     if kvh is not None:
         if not isinstance(kvh, int) or kvh < 1:
@@ -65,6 +90,40 @@ def _validate_heads(cfg):
         if cfg.n_heads % kvh:
             raise ValueError("n_heads=%d must divide by n_kv_heads=%d"
                              % (cfg.n_heads, kvh))
+    for name, allowed in (("norm", ("layernorm", "rmsnorm")),
+                          ("moe_router", ("capacity", "topk")),
+                          ("moe_router_input", ("ffn", "layer"))):
+        if getattr(cfg, name) not in allowed:
+            raise ValueError("%s=%r is not one of %s"
+                             % (name, getattr(cfg, name), allowed))
+    if cfg.moe_router == "topk" and not cfg.num_experts:
+        raise ValueError("moe_router='topk' needs num_experts > 0")
+    if cfg.moe_router_input == "layer" and cfg.moe_router != "topk":
+        raise ValueError("moe_router_input='layer' needs "
+                         "moe_router='topk'")
+    for name in ("window_layout", "rope_layout"):
+        layout = getattr(cfg, name)
+        if layout is not None and len(layout) < cfg.n_layers:
+            raise ValueError("%s has %d entries for n_layers=%d"
+                             % (name, len(layout), cfg.n_layers))
+
+
+# what the shard_map training block (``_block_local``) computes, by
+# field: a config that asks for anything else is refused by name
+_TRAINABLE = {"head_dim": None, "norm": "layernorm",
+              "tie_embeddings": True, "moe_router": "capacity",
+              "moe_router_input": "ffn", "sliding_window": None,
+              "window_layout": None, "rope_layout": None}
+
+
+def _validate_trainable(cfg):
+    for name, only in _TRAINABLE.items():
+        if getattr(cfg, name) != only:
+            raise ValueError(
+                "make_transformer_train_step cannot run %s=%r: the "
+                "sharded training block computes %s=%r only (the "
+                "single-device forward, prefill and decode paths run "
+                "it)" % (name, getattr(cfg, name), name, only))
 
 
 def _rope_bshd(t, positions, base):
@@ -113,6 +172,25 @@ class TransformerConfig:
     # default; extrapolates past training length)
     pos_type: str = "learned"
     rope_base: float = 10000.0
+    # -- the block's other shapes; each default is the GPT-2 block ------
+    head_dim: int = None          # None = d_model // n_heads
+    norm: str = "layernorm"       # | "rmsnorm" (a gain, no shift)
+    norm_eps: float = 1e-5
+    tie_embeddings: bool = True   # False: an output map of its own
+    # "capacity": top-2 one-hot dispatch that drops what overflows, over
+    # two-matrix GELU experts; "topk": moe_top_k of E, softmax over the
+    # chosen, rows sorted by expert, grouped products over gated ReLU
+    # experts relu(x Wg) * (x Wu) Wd, nothing dropped (parallel/moe.py)
+    moe_router: str = "capacity"
+    # what the router reads: the FFN's normalised input, or ("layer")
+    # the residual stream as it enters the layer, before attention
+    moe_router_input: str = "ffn"
+    # per-layer kinds: a layer with window_layout[l] attends the last
+    # sliding_window positions only; one with rope_layout[l] rotates
+    # q/k (None = every layer does what pos_type / sliding_window say)
+    sliding_window: int = None
+    window_layout: tuple = None
+    rope_layout: tuple = None
 
 
 # ---------------------------------------------------------------------------
@@ -128,11 +206,9 @@ def _param_specs(cfg, pp):
         "wv": P("pp", None, None, "tp"), "wo": P("pp", None, "tp", None),
     }
     if cfg.num_experts:
-        lyr.update({
-            "gate": P("pp", None, None, None),
-            "we1": P("pp", None, "ep", None, None),
-            "we2": P("pp", None, "ep", None, None),
-        })
+        lyr["gate"] = P("pp", None, None, None)
+        for name in _expert_names(cfg):
+            lyr[name] = P("pp", None, "ep", None, None)
     else:
         lyr.update({"w1": P("pp", None, None, "tp"),
                     "w2": P("pp", None, "tp", None)})
@@ -141,9 +217,22 @@ def _param_specs(cfg, pp):
         "lnf_g": P(None,), "lnf_b": P(None,),
         "layers": lyr,
     }
+    if cfg.norm == "rmsnorm":
+        for name in ("ln1_b", "ln2_b"):
+            del lyr[name]
+        del specs["lnf_b"]
+    if not cfg.tie_embeddings:
+        specs["head"] = P(None, None)
     if cfg.pos_type == "learned":
         specs["pos"] = P(None, None)
     return specs
+
+
+def _expert_names(cfg):
+    """The stacked expert maps of a layer: gate, up and down of the
+    drop-free router's gated experts, or the two of a GELU one."""
+    return (("we_gate", "we_up", "we_down") if cfg.moe_router == "topk"
+            else ("we1", "we2"))
 
 
 def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
@@ -152,12 +241,14 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     Layer stacks have shape (pp, layers_per_stage, ...) so the leading
     axis shards over pipeline stages.
     """
-    _validate_heads(cfg)
+    _validate_config(cfg)
     pp = mesh.shape.get("pp", 1)
     assert cfg.n_layers % pp == 0, "n_layers must divide pp"
     lps = cfg.n_layers // pp
     rng = np.random.RandomState(seed)
     d, f, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
+    dq = cfg.n_heads * _head_dim(cfg)
+    dkv = _kv_heads(cfg) * _head_dim(cfg)
     s = 0.02
 
     def rand(*shape):
@@ -168,15 +259,17 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
         "ln1_b": jnp.zeros((pp, lps, d), cfg.dtype),
         "ln2_g": jnp.ones((pp, lps, d), cfg.dtype),
         "ln2_b": jnp.zeros((pp, lps, d), cfg.dtype),
-        "wq": rand(pp, lps, d, d),
-        "wk": rand(pp, lps, d, _kv_heads(cfg) * (d // cfg.n_heads)),
-        "wv": rand(pp, lps, d, _kv_heads(cfg) * (d // cfg.n_heads)),
-        "wo": rand(pp, lps, d, d),
+        "wq": rand(pp, lps, d, dq),
+        "wk": rand(pp, lps, d, dkv),
+        "wv": rand(pp, lps, d, dkv),
+        "wo": rand(pp, lps, dq, d),
     }
     if cfg.num_experts:
         layers["gate"] = rand(pp, lps, d, cfg.num_experts)
-        layers["we1"] = rand(pp, lps, cfg.num_experts, d, f)
-        layers["we2"] = rand(pp, lps, cfg.num_experts, f, d)
+        for name in _expert_names(cfg):
+            layers[name] = (rand(pp, lps, cfg.num_experts, f, d)
+                            if name in ("we2", "we_down")
+                            else rand(pp, lps, cfg.num_experts, d, f))
     else:
         layers["w1"] = rand(pp, lps, d, f)
         layers["w2"] = rand(pp, lps, f, d)
@@ -186,10 +279,16 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
         "lnf_b": jnp.zeros((d,), cfg.dtype),
         "layers": layers,
     }
+    if not cfg.tie_embeddings:
+        params["head"] = rand(d, V)
     if cfg.pos_type == "learned":
         # rope has no length-bound table; don't allocate/shard/update one
         params["pos"] = rand(cfg.max_len, d)
     specs = _param_specs(cfg, pp)
+    # an RMSNorm has no shift: drop what the specs do not name
+    params = {k: v for k, v in params.items() if k in specs}
+    params["layers"] = {k: v for k, v in layers.items()
+                        if k in specs["layers"]}
     shard = {k: (jax.tree_util.tree_map(lambda sp: NamedSharding(mesh, sp),
                                         specs[k])
                  if isinstance(specs[k], dict) else
@@ -215,6 +314,73 @@ def _ln(x, g, b, eps=1e-5):
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.var(x, -1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
+
+
+def _norm(cfg, p, name, x):
+    """The block's normalisation over the last axis with parameters
+    ``p[name + "_g"]`` (and ``"_b"``): LayerNorm in the array's own
+    type, or RMSNorm — x / sqrt(mean(x^2) + eps) * g, the mean taken in
+    float32 whatever the array's type."""
+    if cfg.norm == "rmsnorm":
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
+        return (xf * jax.lax.rsqrt(ms + cfg.norm_eps)).astype(x.dtype) \
+            * p[name + "_g"]
+    return _ln(x, p[name + "_g"], p[name + "_b"], cfg.norm_eps)
+
+
+def _logits(cfg, params, x):
+    """Output map: the embedding's transpose, or the model's own."""
+    x = _norm(cfg, params, "lnf", x)
+    if cfg.tie_embeddings:
+        return x @ params["embed"].T
+    return x @ params["head"]
+
+
+def _ffn(cfg, lp, h, x_in, layers, at):
+    """The block's second half on ONE device: ``h`` the normalised
+    residual stream (..., d), ``x_in`` the stream as it entered the
+    layer (what an early router reads); ``layers[name][at]`` is
+    ``lp[name]`` (the grouped expert product reads the stacks in place:
+    a sliced operand of a custom call is a copy). Returns ``(f,
+    stats)``; stats is None for a dense FFN, else ``(experts, active)``
+    of the drop-free router (None, None for the capacity router, which
+    reports none)."""
+    if not cfg.num_experts:
+        return jax.nn.gelu(h @ lp["w1"]) @ lp["w2"], None
+    d = h.shape[-1]
+    tok = h.reshape(-1, d)
+    if cfg.moe_router == "topk":
+        src = x_in if cfg.moe_router_input == "layer" else h
+        # the 64 logits decide by their order: accumulate in float32
+        logits = jnp.dot(src.reshape(-1, d), lp["gate"],
+                         preferred_element_type=jnp.float32)
+        out, experts, active = moe_ffn_sorted(
+            tok, logits, layers["we_gate"], layers["we_up"],
+            layers["we_down"], cfg.moe_top_k, lead=at)
+        return out.reshape(h.shape), (experts, active)
+    logits = tok @ lp["gate"]
+    cap = max(1, int(cfg.capacity_factor * tok.shape[0]
+                     * min(cfg.moe_top_k, 2) / cfg.num_experts))
+    disp, comb, _ = top_k_gating(logits, cfg.num_experts, cap,
+                                 k=cfg.moe_top_k)
+    exp_in = jnp.einsum("nec,nd->ecd", disp.astype(h.dtype), tok)
+    hh = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", exp_in, lp["we1"]))
+    eo = jnp.einsum("ecf,efd->ecd", hh, lp["we2"])
+    f = jnp.einsum("nec,ecd->nd", comb.astype(h.dtype), eo)
+    return f.reshape(h.shape), (None, None)
+
+
+def _stats(cfg, per_layer):
+    """What a forward reports beside its logits: for the drop-free
+    router, per layer the chosen experts (L, k, n) — choice-major, as
+    ``moe_ffn_sorted`` returns them — and the number of experts that
+    received a row (L,); else nothing."""
+    if not (cfg.num_experts and cfg.moe_router == "topk"):
+        return {}
+    return {"moe_experts": jnp.stack([e for e, _a in per_layer]),
+            "moe_active_experts": jnp.stack(
+                [a for _e, a in per_layer]).astype(jnp.int32)}
 
 
 def _attention_local(lp, x, cfg, heads_local):
@@ -435,7 +601,8 @@ def make_transformer_train_step(cfg: TransformerConfig, mesh: Mesh,
         if ax not in mesh.axis_names:
             raise ValueError("mesh is missing axis %r" % ax)
     mesh_shape = {a: mesh.shape[a] for a in AXES}
-    _validate_heads(cfg)
+    _validate_config(cfg)
+    _validate_trainable(cfg)
     if _kv_heads(cfg) % mesh_shape["tp"]:
         raise ValueError(
             "GQA: n_kv_heads=%d must divide by tp=%d (K/V projections "
@@ -483,54 +650,46 @@ def make_transformer_train_step(cfg: TransformerConfig, mesh: Mesh,
     return jax.jit(loop, donate_argnums=(0,))
 
 
-def transformer_forward_single(params, tokens, cfg: TransformerConfig):
+def transformer_forward_single(params, tokens, cfg: TransformerConfig,
+                               with_stats=False):
     """Single-device reference forward (used by tests to validate the
-    sharded step; also the flagship single-chip inference path)."""
+    sharded step; also the flagship single-chip inference path).
+    ``with_stats`` also returns :func:`_stats`' dict."""
+    _validate_config(cfg)
     x = params["embed"][tokens]
     if cfg.pos_type == "learned":
         x = x + params["pos"][: tokens.shape[1]]
     layers = params["layers"]
     pp, lps = jax.tree_util.tree_leaves(layers)[0].shape[:2]
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
     groups = cfg.n_heads // _kv_heads(cfg)
+    per_layer = []
     for st in range(pp):
         for li in range(lps):
             lp = jax.tree_util.tree_map(lambda p: p[st, li], layers)
-            h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+            rotary, window = _layer_rule(cfg, st * lps + li)
+            h = _norm(cfg, lp, "ln1", x)
             b, s, d = h.shape
             q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
             k = _expand_kv((h @ lp["wk"]).reshape(b, s, _kv_heads(cfg),
                                                   hd), groups, 2)
             v = _expand_kv((h @ lp["wv"]).reshape(b, s, _kv_heads(cfg),
                                                   hd), groups, 2)
-            if cfg.pos_type == "rope":
+            if rotary:
                 pos = jnp.arange(s)
                 q = _rope_bshd(q, pos, cfg.rope_base)
                 k = _rope_bshd(k, pos, cfg.rope_base)
             sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
-            mask = jnp.tril(jnp.ones((s, s), bool))
-            sc = jnp.where(mask, sc, -1e30)
+            sc = jnp.where(_causal_mask(s, window), sc, -1e30)
             o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-            x = x + o.reshape(b, s, d) @ lp["wo"]
-            h2 = _ln(x, lp["ln2_g"], lp["ln2_b"])
-            if cfg.num_experts:
-                tok = h2.reshape(b * s, d)
-                logits = tok @ lp["gate"]
-                cap = max(1, int(cfg.capacity_factor * tok.shape[0]
-                                 * min(cfg.moe_top_k, 2) / cfg.num_experts))
-                disp, comb, _ = top_k_gating(logits, cfg.num_experts, cap,
-                                             k=cfg.moe_top_k)
-                exp_in = jnp.einsum("nec,nd->ecd", disp.astype(x.dtype), tok)
-                hh = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", exp_in,
-                                            lp["we1"]))
-                eo = jnp.einsum("ecf,efd->ecd", hh, lp["we2"])
-                f = jnp.einsum("nec,ecd->nd", comb.astype(x.dtype),
-                               eo).reshape(b, s, d)
-            else:
-                f = jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
+            x_in = x
+            x = x + o.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
+            f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in,
+                           layers, (st, li))
+            per_layer.append(st_l)
             x = x + f
-    x = _ln(x, params["lnf_g"], params["lnf_b"])
-    return x @ params["embed"].T
+    logits = _logits(cfg, params, x)
+    return (logits, _stats(cfg, per_layer)) if with_stats else logits
 
 
 # ---------------------------------------------------------------------------
@@ -558,7 +717,7 @@ def init_kv_cache(cfg: TransformerConfig, batch, max_len=None):
     GQA stores only the shared heads, an n_heads/n_kv_heads memory
     saving at long context."""
     max_len = max_len or cfg.max_len
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
     # layer stacking mirrors the params layout (pp, lps, ...)
     n_l = cfg.n_layers
     shape = (n_l, batch, _kv_heads(cfg), max_len, hd)
@@ -598,15 +757,82 @@ jax.tree_util.register_pytree_node(
     lambda ps, ch: PagedKVCache(ch[0], ch[1], ch[2], ps))
 
 
+class HybridKVCache(object):
+    """Two kinds of layer in one cache: ``full`` is a
+    :class:`PagedKVCache` over the model's global layers (every position
+    kept, as ever), ``window`` one over its sliding-window layers, whose
+    block tables are RINGS of ``window / page_size + 1`` entries —
+    position ``p`` lives at entry ``(p // page_size) % entries``, so a
+    sequence holds at most a window's worth of pages there however long
+    it grows. Each kind has a pool of its own, stacked over that kind's
+    layers in layer order (:func:`kv_layer_kinds`). A prefill into a
+    hybrid cache writes only the pages that hold a real position (by
+    ``lengths``), so a row needs pages for its tokens, not its bucket."""
+
+    __slots__ = ("full", "window")
+
+    def __init__(self, full, window):
+        self.full = full
+        self.window = window
+
+
+jax.tree_util.register_pytree_node(
+    HybridKVCache,
+    lambda c: ((c.full, c.window), None),
+    lambda _aux, ch: HybridKVCache(ch[0], ch[1]))
+
+
+def paged_cache(k_pages, v_pages, block_tables, page_size):
+    """The cache view a program builds from its arguments: one pool and
+    one table, or for a model with window layers a ``(full, window)``
+    pair of each (what :func:`init_kv_pages` returns for a pair)."""
+    if isinstance(k_pages, (tuple, list)):
+        return HybridKVCache(*(PagedKVCache(k, v, bt, page_size)
+                               for k, v, bt in zip(k_pages, v_pages,
+                                                   block_tables)))
+    return PagedKVCache(k_pages, v_pages, block_tables, page_size)
+
+
+def cache_pools(cache):
+    """``(k_pages, v_pages)`` back out of :func:`paged_cache`'s view."""
+    if isinstance(cache, HybridKVCache):
+        return ((cache.full.k_pages, cache.window.k_pages),
+                (cache.full.v_pages, cache.window.v_pages))
+    return cache.k_pages, cache.v_pages
+
+
+def _layer_cache(cache, cfg, li):
+    """``(cache that holds layer li, the layer's index in it, put)``;
+    ``put(c)`` is the whole cache with that part replaced."""
+    if not isinstance(cache, HybridKVCache):
+        return cache, li, lambda c: c
+    kinds = kv_layer_kinds(cfg)
+    idx = kinds[:li].count(kinds[li])
+    if kinds[li] == "window":
+        return cache.window, idx, lambda c: HybridKVCache(cache.full, c)
+    return cache.full, idx, lambda c: HybridKVCache(c, cache.window)
+
+
 def init_kv_pages(cfg: TransformerConfig, num_pages, page_size):
     """Zeroed page pool ``(k_pages, v_pages)``, each (layers,
     num_pages, page_size, kv_heads, hd). Sized once at engine start:
     HBM cost is 2 * layers * num_pages * page_size * kv_heads * hd *
-    itemsize, independent of live traffic."""
-    hd = cfg.d_model // cfg.n_heads
-    shape = (cfg.n_layers, int(num_pages), int(page_size),
-             _kv_heads(cfg), hd)
-    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+    itemsize, independent of live traffic. ``num_pages`` as a ``(full,
+    window)`` pair gives a pair of each, one pool a kind of layer
+    (:class:`HybridKVCache`)."""
+    hd = _head_dim(cfg)
+
+    def pool(n_layers, n_pages):
+        shape = (n_layers, int(n_pages), int(page_size), _kv_heads(cfg),
+                 hd)
+        return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+
+    if isinstance(num_pages, (tuple, list)):
+        kinds = kv_layer_kinds(cfg)
+        (kf, vf), (kw, vw) = (pool(kinds.count(kind), n) for kind, n in
+                              zip(("full", "window"), num_pages))
+        return (kf, kw), (vf, vw)
+    return pool(cfg.n_layers, num_pages)
 
 
 def _positions_vec(pos, b):
@@ -624,13 +850,17 @@ def _rope_token(t, pos_b, base):
                  base)[..., 0, :]
 
 
-def _cache_write_token(cache, li, k_t, v_t, pos_b):
+def _cache_write_token(cache, li, k_t, v_t, pos_b, window=None):
     """Write one token's K/V (b, kv_heads, hd) at per-row positions —
-    the single place the two cache layouts diverge on the write path."""
+    the single place the two cache layouts diverge on the write path.
+    A window layer's block table is a ring (the dense strip keeps
+    everything and masks)."""
     if isinstance(cache, PagedKVCache):
+        entry = pos_b // cache.page_size
+        if window is not None:
+            entry = entry % cache.block_tables.shape[1]
         page = jnp.take_along_axis(
-            cache.block_tables,
-            (pos_b // cache.page_size)[:, None], axis=1)[:, 0]
+            cache.block_tables, entry[:, None], axis=1)[:, 0]
         off = pos_b % cache.page_size
         return PagedKVCache(
             cache.k_pages.at[li, page, off].set(
@@ -645,7 +875,7 @@ def _cache_write_token(cache, li, k_t, v_t, pos_b):
                 v_t.astype(cache["v"].dtype))}
 
 
-def _cache_attend(cache, li, q, pos_b, cfg):
+def _cache_attend(cache, li, q, pos_b, cfg, window=None):
     """One-token GQA attention against layer ``li`` of either cache
     layout: q (b, n_heads, hd) -> context (b, d_model). Grouped heads
     attend the compact cache directly (expanding it per step would
@@ -661,8 +891,8 @@ def _cache_attend(cache, li, q, pos_b, cfg):
                 q.reshape(b, kvh, nh // kvh, hd),
                 cache.k_pages[li], cache.v_pages[li],
                 cache.block_tables, pos_b + 1,
-                sm_scale=1.0 / np.sqrt(hd))
-            return o.reshape(b, cfg.d_model)
+                sm_scale=1.0 / np.sqrt(hd), window=window)
+            return o.reshape(b, nh * hd)
         # pure-lax gather fallback (CPU tier-1): block-table gather
         # materializes the same (b, kvh, L, hd) view the dense layout
         # slices, then the shared math below runs unchanged
@@ -675,16 +905,27 @@ def _cache_attend(cache, li, q, pos_b, cfg):
         kc = cache["k"][li]                   # (b, kvh, max_len, hd)
         vc = cache["v"][li]
         L = kc.shape[2]
-    visible = jnp.arange(L)[None, :] <= pos_b[:, None]      # (b, L)
+    if window is None:
+        visible = jnp.arange(L)[None, :] <= pos_b[:, None]  # (b, L)
+    else:
+        if isinstance(cache, PagedKVCache):
+            from ..ops.pallas.flash_attention import ring_positions
+            kpos = ring_positions(cache.block_tables.shape[1],
+                                  cache.page_size, pos_b + 1)
+        else:
+            kpos = jnp.arange(L)[None, :]
+        visible = jnp.logical_and(
+            jnp.logical_and(kpos >= 0, kpos <= pos_b[:, None]),
+            kpos > pos_b[:, None] - window)
     qg = q.reshape(b, kvh, nh // kvh, hd)
     sc = jnp.einsum("bkgd,bkld->bkgl", qg, kc) / np.sqrt(hd)
     sc = jnp.where(visible[:, None, None, :], sc, -1e30)
     o = jnp.einsum("bkgl,bkld->bkgd", jax.nn.softmax(sc, -1), vc)
-    return o.reshape(b, cfg.d_model)
+    return o.reshape(b, nh * hd)
 
 
 def transformer_decode_step(params, cache, tokens_t, pos,
-                            cfg: TransformerConfig):
+                            cfg: TransformerConfig, with_stats=False):
     """One decode step: tokens_t (b,) int32 at position(s) ``pos`` ->
     (logits (b, V), updated cache).
 
@@ -694,10 +935,12 @@ def transformer_decode_step(params, cache, tokens_t, pos,
     ``cache`` is the dense dict from :func:`init_kv_cache` or a
     :class:`PagedKVCache`; either way attention reads a fixed-shape
     view under a <= pos mask, so the step compiles once per (batch,
-    layout) and never again."""
+    layout) and never again. A model with window layers takes a
+    :class:`HybridKVCache` (or the dense dict, which keeps everything
+    and masks). ``with_stats`` also returns :func:`_stats`' dict."""
     layers = params["layers"]
     pp, lps = jax.tree_util.tree_leaves(layers)[0].shape[:2]
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
     b = tokens_t.shape[0]
     pos_b = _positions_vec(pos, b)
 
@@ -705,45 +948,41 @@ def transformer_decode_step(params, cache, tokens_t, pos,
     if cfg.pos_type == "learned":
         x = x + params["pos"][pos_b]                  # (b, d) gather
     li_flat = 0
+    per_layer = []
     for st in range(pp):
         for li in range(lps):
             lp = jax.tree_util.tree_map(lambda p: p[st, li], layers)
-            h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+            rotary, window = _layer_rule(cfg, li_flat)
+            h = _norm(cfg, lp, "ln1", x)
             q = (h @ lp["wq"]).reshape(b, cfg.n_heads, hd)
             k_t = (h @ lp["wk"]).reshape(b, _kv_heads(cfg), hd)
             v_t = (h @ lp["wv"]).reshape(b, _kv_heads(cfg), hd)
-            if cfg.pos_type == "rope":
+            if rotary:
                 q = _rope_token(q, pos_b, cfg.rope_base)
                 k_t = _rope_token(k_t, pos_b, cfg.rope_base)
-            cache = _cache_write_token(cache, li_flat, k_t, v_t, pos_b)
-            o = _cache_attend(cache, li_flat, q, pos_b, cfg)
+            part, part_li, put = _layer_cache(cache, cfg, li_flat)
+            part = _cache_write_token(part, part_li, k_t, v_t, pos_b,
+                                      window)
+            o = _cache_attend(part, part_li, q, pos_b, cfg, window)
+            cache = put(part)
+            x_in = x
             x = x + o @ lp["wo"]
-            h2 = _ln(x, lp["ln2_g"], lp["ln2_b"])
-            if cfg.num_experts:
-                logits = h2 @ lp["gate"]
-                cap = max(1, int(cfg.capacity_factor * b
-                                 * min(cfg.moe_top_k, 2)
-                                 / cfg.num_experts))
-                disp, comb, _ = top_k_gating(logits, cfg.num_experts,
-                                             cap, k=cfg.moe_top_k)
-                exp_in = jnp.einsum("nec,nd->ecd", disp.astype(x.dtype),
-                                    h2)
-                hh = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", exp_in,
-                                            lp["we1"]))
-                eo = jnp.einsum("ecf,efd->ecd", hh, lp["we2"])
-                f = jnp.einsum("nec,ecd->nd", comb.astype(x.dtype), eo)
-            else:
-                f = jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
+            f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in,
+                           layers, (st, li))
+            per_layer.append(st_l)
             x = x + f
             li_flat += 1
-    x = _ln(x, params["lnf_g"], params["lnf_b"])
-    return x @ params["embed"].T, cache
+    logits = _logits(cfg, params, x)
+    if with_stats:
+        return logits, cache, _stats(cfg, per_layer)
+    return logits, cache
 
 
-def _cache_write_prompt(cache, li, kg, vg):
+def _cache_write_prompt(cache, li, kg, vg, lengths=None, window=None):
     """Write a prompt's K/V (b, s, kv_heads, hd) for layer ``li`` into
     either cache layout — the prefill counterpart of
-    :func:`_cache_write_token`."""
+    :func:`_cache_write_token`. ``lengths`` / ``window`` choose the
+    pages as ``flash_attention.prefill_page_dest`` says."""
     b, s, hk, hd = kg.shape
     if isinstance(cache, PagedKVCache):
         ps = cache.page_size
@@ -751,14 +990,16 @@ def _cache_write_prompt(cache, li, kg, vg):
             raise ValueError("prefill bucket %d is not a multiple of "
                              "page_size %d" % (s, ps))
         n_pb = s // ps
-        if n_pb > cache.block_tables.shape[1]:
+        if window is None and n_pb > cache.block_tables.shape[1]:
             raise ValueError("prefill bucket %d needs %d pages/row; "
                              "block table holds %d"
                              % (s, n_pb, cache.block_tables.shape[1]))
         # (b, s, hk, hd) -> (b, pages, page_size, hk, hd): position j
         # of row r scatters to page block_tables[r, j // ps] offset
         # j % ps — one reshape, one scatter per layer
-        bt = cache.block_tables[:, :n_pb]
+        from ..ops.pallas.flash_attention import prefill_page_dest
+        bt = prefill_page_dest(cache.block_tables, n_pb, ps, lengths,
+                               window)
         return PagedKVCache(
             cache.k_pages.at[li, bt].set(
                 kg.reshape(b, n_pb, ps, hk, hd)
@@ -774,7 +1015,7 @@ def _cache_write_prompt(cache, li, kg, vg):
                 vg.transpose(0, 2, 1, 3).astype(cache["v"].dtype))}
 
 
-def _prefill_impl(params, tokens, cache, cfg, lengths):
+def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
     """Shared prefill body for both cache layouts: one batched causal
     forward computes and caches every prompt position's K/V. With
     ``lengths`` (b,) the returned logits are each row's last REAL
@@ -782,27 +1023,35 @@ def _prefill_impl(params, tokens, cache, cfg, lengths):
     b, s = tokens.shape
     layers = params["layers"]
     pp, lps = jax.tree_util.tree_leaves(layers)[0].shape[:2]
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
+    if lengths is not None:
+        lengths = jnp.asarray(lengths, jnp.int32)
+    # a hybrid cache's rows hold pages for their tokens, not for their
+    # bucket: its page writes go by the real lengths
+    write_lengths = lengths if isinstance(cache, HybridKVCache) else None
 
     x = params["embed"][tokens]
     if cfg.pos_type == "learned":
         x = x + params["pos"][:s]
     mask = jnp.tril(jnp.ones((s, s), bool))
     li_flat = 0
+    per_layer = []
     for st in range(pp):
         for li in range(lps):
             lp = jax.tree_util.tree_map(lambda p: p[st, li], layers)
-            h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+            rotary, window = _layer_rule(cfg, li_flat)
+            h = _norm(cfg, lp, "ln1", x)
             q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
             kg = (h @ lp["wk"]).reshape(b, s, _kv_heads(cfg), hd)
             vg = (h @ lp["wv"]).reshape(b, s, _kv_heads(cfg), hd)
-            if cfg.pos_type == "rope":
+            if rotary:
                 # rotate BEFORE caching: decode stores rotated keys, so
                 # prefill must too (q rotates here as well)
                 pos = jnp.arange(s)
                 q = _rope_bshd(q, pos, cfg.rope_base)
                 kg = _rope_bshd(kg, pos, cfg.rope_base)
-            if isinstance(cache, PagedKVCache) and on_tpu(q):
+            part, part_li, put = _layer_cache(cache, cfg, li_flat)
+            if isinstance(part, PagedKVCache) and on_tpu(q):
                 # fused Pallas prefill: one program computes the causal
                 # attention AND writes this layer's pages in its DMA
                 # epilogue — the kernel's lax twin is op-for-op the
@@ -811,51 +1060,43 @@ def _prefill_impl(params, tokens, cache, cfg, lengths):
                 from ..ops.pallas.flash_attention import (
                     flash_prefill_paged)
                 o, kp, vp = flash_prefill_paged(
-                    q, kg, vg, cache.k_pages[li_flat],
-                    cache.v_pages[li_flat], cache.block_tables)
-                cache = PagedKVCache(
-                    cache.k_pages.at[li_flat].set(kp),
-                    cache.v_pages.at[li_flat].set(vp),
-                    cache.block_tables, cache.page_size)
+                    q, kg, vg, part.k_pages[part_li],
+                    part.v_pages[part_li], part.block_tables,
+                    lengths=write_lengths, window=window)
+                part = PagedKVCache(
+                    part.k_pages.at[part_li].set(kp),
+                    part.v_pages.at[part_li].set(vp),
+                    part.block_tables, part.page_size)
             else:
-                cache = _cache_write_prompt(cache, li_flat, kg, vg)
+                part = _cache_write_prompt(part, part_li, kg, vg,
+                                           write_lengths, window)
                 groups = cfg.n_heads // _kv_heads(cfg)
                 k = _expand_kv(kg, groups, 2)
                 v = _expand_kv(vg, groups, 2)
                 sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
-                sc = jnp.where(mask[None, None], sc, -1e30)
+                sc = jnp.where((mask if window is None
+                                else _causal_mask(s, window))[None, None],
+                               sc, -1e30)
                 o = jnp.einsum("bhqk,bkhd->bqhd",
                                jax.nn.softmax(sc, -1), v)
-            x = x + o.reshape(b, s, cfg.d_model) @ lp["wo"]
-            h2 = _ln(x, lp["ln2_g"], lp["ln2_b"])
-            if cfg.num_experts:
-                tok = h2.reshape(b * s, cfg.d_model)
-                logits_g = tok @ lp["gate"]
-                cap = max(1, int(cfg.capacity_factor * tok.shape[0]
-                                 * min(cfg.moe_top_k, 2)
-                                 / cfg.num_experts))
-                disp, comb, _ = top_k_gating(logits_g, cfg.num_experts,
-                                             cap, k=cfg.moe_top_k)
-                exp_in = jnp.einsum("nec,nd->ecd", disp.astype(x.dtype),
-                                    tok)
-                hh = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", exp_in,
-                                            lp["we1"]))
-                eo = jnp.einsum("ecf,efd->ecd", hh, lp["we2"])
-                f = jnp.einsum("nec,ecd->nd", comb.astype(x.dtype),
-                               eo).reshape(b, s, cfg.d_model)
-            else:
-                f = jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
+            cache = put(part)
+            x_in = x
+            x = x + o.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
+            f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in,
+                           layers, (st, li))
+            per_layer.append(st_l)
             x = x + f
             li_flat += 1
     if lengths is None:
         xl = x[:, -1]
     else:
         # each row's last REAL position, not the padded tail
-        lengths = jnp.asarray(lengths, jnp.int32)
         xl = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
                                  axis=1)[:, 0]
-    xl = _ln(xl, params["lnf_g"], params["lnf_b"])
-    return xl @ params["embed"].T, cache
+    logits = _logits(cfg, params, xl)
+    if with_stats:
+        return logits, cache, _stats(cfg, per_layer)
+    return logits, cache
 
 
 def transformer_prefill(params, tokens, cache, cfg: TransformerConfig):
@@ -868,7 +1109,8 @@ def transformer_prefill(params, tokens, cache, cfg: TransformerConfig):
 
 
 def transformer_prefill_paged(params, cache: PagedKVCache, tokens,
-                              lengths, cfg: TransformerConfig):
+                              lengths, cfg: TransformerConfig,
+                              with_stats=False):
     """Bucketed paged prefill: ONE batched causal forward fills each
     row's pages from its prompt and returns the logits each row needs
     to pick its first generated token.
@@ -881,8 +1123,12 @@ def transformer_prefill_paged(params, cache: PagedKVCache, tokens,
     in the row's own reserved pages but are never visible — decode
     masks ``kpos <= pos`` — and causality keeps them out of every real
     position's forward, so the result is bitwise what an unpadded
-    prefill computes."""
-    return _prefill_impl(params, tokens, cache, cfg, lengths=lengths)
+    prefill computes. A :class:`HybridKVCache` does not even write the
+    padded tail (it could wrap onto a window layer's ring), and its
+    window layers keep the last ``ring`` pages of the real prompt.
+    ``with_stats`` also returns :func:`_stats`' dict."""
+    return _prefill_impl(params, tokens, cache, cfg, lengths=lengths,
+                         with_stats=with_stats)
 
 
 def transformer_decode_step_paged(params, k_pages, v_pages, block_tables,
@@ -918,11 +1164,8 @@ def _pick_token(logits, rng_t, temperature, top_k):
 
 def _generate_program(cfg: TransformerConfig, b, s, steps, max_len,
                       temperature, top_k):
-    key = (id(type(cfg)), cfg.vocab_size, cfg.d_model, cfg.n_heads,
-           _kv_heads(cfg), cfg.pos_type, cfg.rope_base,
-           cfg.n_layers, cfg.d_ff, cfg.num_experts, cfg.moe_top_k,
-           cfg.capacity_factor, str(cfg.dtype), b, s, steps, max_len,
-           temperature, top_k)
+    key = (id(type(cfg)), repr(cfg), b, s, steps, max_len, temperature,
+           top_k)
     fn = _GENERATE_CACHE.get(key)
     if fn is not None:
         return fn

@@ -47,6 +47,7 @@ Knobs: ``MXNET_DECODE_*`` (config.py). Docs: docs/decode_serving.md.
 """
 from __future__ import annotations
 
+import concurrent.futures as _futures
 import functools
 import queue as _queue
 import threading
@@ -85,13 +86,15 @@ class DecodeConfig(object):
     """Decode-serving knobs. Defaults come from the ``MXNET_DECODE_*``
     config tier; constructor arguments override per engine."""
 
-    __slots__ = ("slots", "page_size", "num_pages", "max_context",
-                 "queue_depth", "max_new_tokens", "default_timeout",
-                 "worker_restarts", "prefill_buckets", "slot_buckets")
+    __slots__ = ("slots", "page_size", "num_pages", "window_pages",
+                 "max_context", "queue_depth", "max_new_tokens",
+                 "default_timeout", "worker_restarts", "prefill_buckets",
+                 "slot_buckets")
 
     def __init__(self, slots=None, page_size=None, num_pages=None,
                  max_context=None, queue_depth=None, max_new_tokens=None,
-                 default_timeout_ms=None, worker_restarts=None):
+                 default_timeout_ms=None, worker_restarts=None,
+                 window_pages=None):
         from ..config import get as _cfg
 
         def pick(val, name):
@@ -100,6 +103,11 @@ class DecodeConfig(object):
         self.slots = int(pick(slots, "MXNET_DECODE_SLOTS"))
         self.page_size = int(pick(page_size, "MXNET_DECODE_PAGE_SIZE"))
         self.num_pages = int(pick(num_pages, "MXNET_DECODE_NUM_PAGES"))
+        # pages of the window layers' pool, for a model that has such
+        # layers (then ``num_pages`` is the global layers'); None =
+        # a whole ring for every slot (queued requests hold theirs too)
+        self.window_pages = (None if window_pages is None
+                             else int(window_pages))
         self.max_context = int(pick(max_context,
                                     "MXNET_DECODE_MAX_CONTEXT"))
         self.queue_depth = int(pick(queue_depth,
@@ -147,8 +155,10 @@ class DecodeSession(object):
 
     __slots__ = ("prompt", "prompt_len", "max_new_tokens", "stop_token",
                  "deadline", "t_enq", "t_admit", "t_first", "t_done",
-                 "tctx", "page_ids", "block_table", "pos", "last_token",
-                 "generated", "out_tokens", "error", "_q", "_finished")
+                 "tctx", "page_ids", "block_table", "window_page_ids",
+                 "window_block_table", "pos", "last_token",
+                 "generated", "out_tokens", "expert_choices", "error",
+                 "_q", "_finished")
 
     def __init__(self, prompt, max_new_tokens, stop_token, deadline,
                  tctx):
@@ -164,10 +174,17 @@ class DecodeSession(object):
         self.tctx = tctx
         self.page_ids = None
         self.block_table = None
+        self.window_page_ids = None      # a model with window layers:
+        self.window_block_table = None   # its ring in their pool
         self.pos = 0                     # next position to WRITE
         self.last_token = None           # feeds the next decode step
         self.generated = 0
         self.out_tokens = []
+        # a model with a drop-free router: the experts the programs that
+        # served this request chose, (layers, positions, top_k) arrays in
+        # order — the prompt's, then one position a decode step (the
+        # last token emitted is never fed, so never routed)
+        self.expert_choices = []
         self.error = None
         self._q = _queue.Queue()
         self._finished = False
@@ -251,10 +268,30 @@ class DecodeEngine(object):
         self._model_cfg = model_cfg
         self._params = params
         self._vocab = int(model_cfg.vocab_size)
-        self._pool = PagePool(self._cfg.num_pages)
-        from ..parallel.transformer import init_kv_pages
-        self._k_pages, self._v_pages = init_kv_pages(
-            model_cfg, self._cfg.num_pages, self._cfg.page_size)
+        from ..parallel.transformer import kv_layer_kinds
+        # a model with sliding-window layers keeps two kinds of cache:
+        # its global layers' pages as ever, its window layers' in a
+        # pool of their own where a sequence holds a RING of ring_pages
+        # entries (serve/kv_pages.py, transformer.HybridKVCache)
+        self._window = ("window" in kv_layer_kinds(model_cfg)
+                        and int(model_cfg.sliding_window))
+        self._ring_pages = self._wpool = None
+        if self._window:
+            self._ring_pages = min(
+                pages_needed(self._window, self._cfg.page_size) + 1,
+                self._cfg.pages_per_seq)
+            if self._cfg.window_pages is None:
+                self._cfg.window_pages = (self._cfg.slots
+                                          * self._ring_pages + 1)
+            self._wpool = PagePool(self._cfg.window_pages, kind="window")
+        self._pool = PagePool(self._cfg.num_pages,
+                              kind="global" if self._window else None)
+        # the drop-free expert router reports, in the tokens' own fetch,
+        # how many experts each layer's call touched and which ones each
+        # row chose
+        self._moe = (bool(model_cfg.num_experts)
+                     and model_cfg.moe_router == "topk")
+        self._k_pages, self._v_pages = self._fresh_pools()
         self._prefill_progs = {}
         self._step_progs = {}
         self._prog_costs = {}            # (phase, bucket) -> rec | None
@@ -291,7 +328,21 @@ class DecodeEngine(object):
             "decode/slot_occupancy",
             "Live decode slots (out of MXNET_DECODE_SLOTS)")
         self._m_free = _tm.gauge(
-            "decode/page_pool_free", "Free KV-cache pages in the pool")
+            "decode/page_pool_free", "Free KV-cache pages in the pool "
+            "(the global layers' pool of a model with window layers)")
+        self._m_pages_free = _tm.gauge(
+            "decode/pages_free", "Free KV-cache pages by the kind of "
+            "layer whose pool they are in (global | window)", ("kind",))
+        self._m_moe_rows = _tm.counter(
+            "decode/moe_assignments_total",
+            "Token-to-expert assignments of the prompts prefilled and "
+            "the tokens decoded (top-k a token a layer; the padding of "
+            "a bucket and dummy slots are not counted)")
+        self._m_moe_active = _tm.counter(
+            "decode/moe_expert_activations_total",
+            "Experts that received at least one row (padding included), "
+            "summed over layers and calls: what the grouped expert "
+            "product had to read")
         self._m_prefill = _tm.histogram(
             "decode/prefill_seconds",
             "Prefill wall time per admission (bucketed prompt forward)")
@@ -305,7 +356,21 @@ class DecodeEngine(object):
         self._m_timeouts = _tm.counter(
             "decode/timeouts_total",
             "Sessions failed on deadline expiry (queued or decoding)")
+        self._note_free()
+
+    def _fresh_pools(self):
+        from ..parallel.transformer import init_kv_pages
+        return init_kv_pages(
+            self._model_cfg,
+            (self._cfg.num_pages, self._cfg.window_pages)
+            if self._window else self._cfg.num_pages, self._cfg.page_size)
+
+    def _note_free(self):
         self._m_free.set(self._pool.free_pages)
+        self._m_pages_free.labels("global").set(self._pool.free_pages)
+        if self._wpool is not None:
+            self._m_pages_free.labels("window").set(
+                self._wpool.free_pages)
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
@@ -328,12 +393,13 @@ class DecodeEngine(object):
         XLA compile — the jit cache is exactly ``len(prefill_buckets)
         + len(slot_buckets)`` programs.
 
-        The compiles run ON the scheduler thread (warmup posts a
-        request to the loop and waits): jax's jit cache is keyed per
-        thread-local context, so a program compiled on the caller's
-        thread can MISS when the scheduler later runs it — a stray
-        recompile per bucket on first traffic. Compile where you
-        execute."""
+        The programs are traced and lowered ON the scheduler thread
+        (warmup posts a request to the loop and waits): jax's jit cache
+        is keyed per thread-local context, so a program lowered on the
+        caller's thread can MISS when the scheduler later runs it — a
+        stray recompile per bucket on first traffic. Lower where you
+        execute; the backend compile of a lowering (or its load from
+        the persistent cache) may run anywhere (:meth:`_precompile`)."""
         self.start()
         req = {"event": threading.Event(), "error": None}
         with self._cond:
@@ -365,20 +431,51 @@ class DecodeEngine(object):
                     for b in self._cfg.prefill_buckets]
                    + [("decode_step", {"slots": int(n)})
                       for n in self._cfg.slot_buckets])
+        self._precompile()
         self._warm_report = _pg.prewarm(
             sites={"decode_prefill": self._warm_prefill_spec,
                    "decode_step": self._warm_step_spec},
             include=include, graph=self._graph_hash)
 
+    def _prefill_args(self, bucket):
+        n_pb = bucket // self._cfg.page_size
+        return (self._params, self._k_pages, self._v_pages,
+                self._tables(_np.zeros(n_pb, _np.int32),
+                             _np.zeros(self._ring_pages or 0, _np.int32)),
+                _np.zeros((1, bucket), _np.int32),
+                _np.array([bucket], _np.int32))
+
+    def _step_args(self, nslots):
+        return (self._params, self._k_pages, self._v_pages,
+                self._tables(
+                    _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32),
+                    _np.zeros((nslots, self._ring_pages or 0), _np.int32)),
+                _np.zeros(nslots, _np.int32),
+                _np.zeros(nslots, _np.int32))
+
+    def _precompile(self):
+        """Compile the whole ladder while it is being lowered: this
+        (the scheduler's) thread traces and lowers one program after
+        the other, which holds the interpreter lock, and hands each
+        lowering to a few threads that compile it or fetch its
+        executable from the persistent compile cache and load it, which
+        does not. jit keeps the lowering of a function at given argument
+        types, and the lowering keeps its executable, so the warm calls
+        that follow find both and only execute."""
+        jobs = ([(self._prefill_prog(b), self._prefill_args(b))
+                 for b in self._cfg.prefill_buckets]
+                + [(self._step_prog(n), self._step_args(n))
+                   for n in self._cfg.slot_buckets])
+        with _futures.ThreadPoolExecutor(4) as pool:
+            for done in [pool.submit(prog.lower(*args).compile)
+                         for prog, args in jobs]:
+                done.result()
+
     def _warm_prefill_spec(self, spec):
         bucket = int(spec.get("bucket", 0))
         if bucket not in self._cfg.prefill_buckets:
             return False
-        n_pb = bucket // self._cfg.page_size
-        pargs = (self._params, self._k_pages, self._v_pages,
-                 _np.zeros(n_pb, _np.int32),
-                 _np.zeros((1, bucket), _np.int32),
-                 _np.array([bucket], _np.int32))
+        pargs = self._prefill_args(bucket)
         prog = self._prefill_prog(bucket)
         if ("prefill", bucket) not in self._prog_costs:
             # roofline capture BEFORE executing: the pools are donated
@@ -393,16 +490,13 @@ class DecodeEngine(object):
         tok0, self._k_pages, self._v_pages = _pg.warm_twice(
             prog, pargs,
             rebuild=lambda out, a: (a[0], out[1], out[2]) + a[3:])
-        int(tok0)                        # block: compile + execute done
+        _np.asarray(tok0)                # block: compile + execute done
 
     def _warm_step_spec(self, spec):
         nslots = int(spec.get("slots", 0))
         if nslots not in self._cfg.slot_buckets:
             return False
-        sargs = (self._params, self._k_pages, self._v_pages,
-                 _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32),
-                 _np.zeros(nslots, _np.int32),
-                 _np.zeros(nslots, _np.int32))
+        sargs = self._step_args(nslots)
         prog = self._step_prog(nslots)
         if ("step", nslots) not in self._prog_costs:
             self._prog_costs[("step", nslots)] = _health.capture_cost(
@@ -537,11 +631,15 @@ class DecodeEngine(object):
         sess = DecodeSession(prompt, max_new, stop_token, deadline,
                              ctx if ctx is not None else _tr.active())
         # pages for the whole lifetime: the prefill BUCKET (its page
-        # write covers the padded prompt) and prompt+max_new positions
+        # write covers the padded prompt) and prompt+max_new positions.
+        # With window layers the prefill writes by the prompt's real
+        # length: pages for the positions alone, and of the window
+        # pool at most a ring
         ps = self._cfg.page_size
-        n_pages = max(pages_needed(plen + max_new, ps),
-                      pages_needed(pick_bucket(
-                          plen, self._cfg.prefill_buckets), ps))
+        n_pages = pages_needed(plen + max_new, ps)
+        if not self._window:
+            n_pages = max(n_pages, pages_needed(pick_bucket(
+                plen, self._cfg.prefill_buckets), ps))
         with self._cond:
             if not self._accepting or self._closing:
                 self._m_rejected.labels("closed").inc()
@@ -554,15 +652,23 @@ class DecodeEngine(object):
                     "later" % self._cfg.queue_depth)
             try:
                 sess.page_ids = self._pool.alloc(n_pages)
+                if self._window:
+                    sess.window_page_ids = self._wpool.alloc(
+                        min(n_pages, self._ring_pages))
             except PagePoolExhausted:
+                self._release_pages(sess)
                 self._m_rejected.labels("pages").inc()
                 raise
             bt = _np.zeros(self._cfg.pages_per_seq, _np.int32)
             bt[:n_pages] = sess.page_ids
             sess.block_table = bt
+            if self._window:
+                ring = _np.zeros(self._ring_pages, _np.int32)
+                ring[:len(sess.window_page_ids)] = sess.window_page_ids
+                sess.window_block_table = ring
             self._waiting.append(sess)
             self._m_requests.inc()
-            self._m_free.set(self._pool.free_pages)
+            self._note_free()
             self._cond.notify_all()
         return sess
 
@@ -591,7 +697,7 @@ class DecodeEngine(object):
                 self._waiting.remove(sess)
                 self._release_pages(sess)
                 sess._finish(err)
-                self._m_free.set(self._pool.free_pages)
+                self._note_free()
                 return True
             sess._finish(err)            # scheduler sweep retires it
             self._cond.notify_all()
@@ -639,18 +745,24 @@ class DecodeEngine(object):
                 self._m_preempted.inc()
                 sess._finish(err)
             self._m_occupancy.set(0)
-            self._m_free.set(self._pool.free_pages)
+            self._note_free()
         # donated pool buffers are unusable after a mid-program crash;
         # same-shape zeros re-hit the warmed fill program (no new
         # compile)
-        from ..parallel.transformer import init_kv_pages
-        self._k_pages, self._v_pages = init_kv_pages(
-            self._model_cfg, self._cfg.num_pages, self._cfg.page_size)
+        self._k_pages, self._v_pages = self._fresh_pools()
 
     def _release_pages(self, sess):
         if sess.page_ids:
             self._pool.free(sess.page_ids)
             sess.page_ids = None
+        if sess.window_page_ids:
+            self._wpool.free(sess.window_page_ids)
+            sess.window_page_ids = None
+
+    def _tables(self, full, ring):
+        """Block tables as the programs take them: the one table, or
+        with window layers the (global, window-ring) pair."""
+        return (full, ring) if self._window else full
 
     def _retire_locked(self, sess, error=None):
         """Retire a session (caller holds the lock): slot freed for
@@ -660,7 +772,7 @@ class DecodeEngine(object):
         self._release_pages(sess)
         sess._finish(error)
         self._m_occupancy.set(len(self._live))
-        self._m_free.set(self._pool.free_pages)
+        self._note_free()
 
     def set_iteration_hook(self, fn):
         """Install (or clear, with None) a callable run on the
@@ -751,7 +863,7 @@ class DecodeEngine(object):
             self._m_preempted.inc()
             evicted += 1
         self._m_occupancy.set(len(self._live))
-        self._m_free.set(self._pool.free_pages)
+        self._note_free()
         for sess in [s for s in self._waiting
                      if s.deadline is not None and now > s.deadline]:
             self._waiting.remove(sess)
@@ -799,16 +911,25 @@ class DecodeEngine(object):
                 return                   # cancel/deadline) pre-prefill
             # snapshot under the lock: a concurrent close may null
             # page_ids the instant the session is failed
-            page_ids = _np.asarray(sess.page_ids[:n_pb], _np.int32)
+            if self._window:
+                page_ids = (sess.block_table[:n_pb],
+                            sess.window_block_table)
+            else:
+                page_ids = _np.asarray(sess.page_ids[:n_pb], _np.int32)
         padded = _np.zeros((1, bucket), _np.int32)
         padded[0, :sess.prompt_len] = sess.prompt
-        with _tr.child_span("decode.prefill"):
+        with _tr.child_span("decode.prefill") as span:
             t0 = _tm.monotonic()
-            tok0, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
+            out, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
                 self._params, self._k_pages, self._v_pages, page_ids,
                 padded, _np.array([sess.prompt_len], _np.int32))
-            tok0 = int(tok0)
+            out = _np.asarray(out).reshape(-1)
+            tok0 = int(out[0])
             t1 = _tm.monotonic()
+            if self._moe:
+                experts = self._note_moe(span, out[1:], bucket,
+                                         sess.prompt_len)
+                sess.expert_choices.append(experts[:, :sess.prompt_len])
         self._m_prefill.observe(
             t1 - t0, trace_id=sess.tctx.trace_id if sess.tctx else None)
         _health.note_decode("prefill", bucket, t1 - t0,
@@ -824,6 +945,24 @@ class DecodeEngine(object):
             sess.t_admit = t0
             sess.pos = sess.prompt_len
             self._emit_locked(sess, tok0)
+
+    def _note_moe(self, span, stats, width, real):
+        """What a call's expert layers did, from the numbers its program
+        returned after the tokens (``stats``: per layer the count of
+        experts that received a row, then the experts chosen for each of
+        the ``width`` rows the program ran): counts the assignments of
+        the ``real`` rows (padding and dummy slots are no work) and the
+        experts touched, puts both on the call's span, and returns the
+        choices as ``(layers, width, top_k)``."""
+        layers, k = self._model_cfg.n_layers, self._model_cfg.moe_top_k
+        n_rows = real * k * layers
+        n_active = int(stats[:layers].sum())
+        self._m_moe_rows.inc(n_rows)
+        self._m_moe_active.inc(n_active)
+        span.set_attr("moe_rows", n_rows)
+        span.set_attr("moe_active_experts", n_active)
+        return stats[layers:].reshape(layers, k, width).transpose(
+            0, 2, 1).astype(_np.int16)
 
     def _emit_locked(self, sess, tok):
         """Deliver one token; retire the session once it hits its
@@ -848,21 +987,34 @@ class DecodeEngine(object):
         tokens = _np.zeros(nslots, _np.int32)
         pos = _np.zeros(nslots, _np.int32)
         bt = _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32)
+        ring = _np.zeros((nslots, self._ring_pages or 0), _np.int32)
         for i, sess in enumerate(live):
             tokens[i] = sess.last_token
             pos[i] = sess.pos
             bt[i] = sess.block_table
+            if self._window:
+                ring[i] = sess.window_block_table
         # context_tokens: the positions this step attends over, the
-        # new token's own included — the attention kernel's work
-        with _tr.child_span(
-                "decode.step",
-                attrs={"context_tokens": int(pos.sum()) + len(live)}):
+        # new token's own included — the attention kernel's work in a
+        # global layer; window_context_tokens: the same in a window
+        # layer, which sees at most its window of each row
+        context = pos[:len(live)] + 1
+        attrs = {"context_tokens": int(context.sum()),
+                 "window_context_tokens": int(
+                     _np.minimum(context, self._window).sum()
+                     if self._window else context.sum())}
+        with _tr.child_span("decode.step", attrs=attrs) as span:
             t0 = _tm.monotonic()
             toks, self._k_pages, self._v_pages = self._step_prog(nslots)(
-                self._params, self._k_pages, self._v_pages, bt, tokens,
-                pos)
+                self._params, self._k_pages, self._v_pages,
+                self._tables(bt, ring), tokens, pos)
             toks = _np.asarray(toks)
             t1 = _tm.monotonic()
+            if self._moe:
+                experts = self._note_moe(span, toks[nslots:], nslots,
+                                         len(live))
+                for i, sess in enumerate(live):
+                    sess.expert_choices.append(experts[:, i:i + 1])
         self._m_step.observe(t1 - t0)
         _health.note_decode("step", nslots, t1 - t0,
                             self._prog_costs.get(("step", nslots)))
@@ -899,18 +1051,27 @@ class DecodeEngine(object):
                 import jax
                 import jax.numpy as jnp
                 from ..parallel.transformer import (
-                    PagedKVCache, transformer_prefill_paged)
-                cfg, ps = self._model_cfg, self._cfg.page_size
+                    cache_pools, paged_cache, transformer_prefill_paged)
+                cfg, ps, moe = (self._model_cfg, self._cfg.page_size,
+                                self._moe)
 
                 @functools.partial(jax.jit, donate_argnums=(1, 2))
                 def prog(params, k_pages, v_pages, page_ids, tokens,
                          length):
-                    paged = PagedKVCache(k_pages, v_pages,
-                                         page_ids[None], ps)
-                    logits, paged = transformer_prefill_paged(
-                        params, paged, tokens, length, cfg)
-                    return (jnp.argmax(logits, -1).astype(jnp.int32)[0],
-                            paged.k_pages, paged.v_pages)
+                    paged = paged_cache(
+                        k_pages, v_pages, jax.tree_util.tree_map(
+                            lambda t: t[None], page_ids), ps)
+                    logits, paged, stats = transformer_prefill_paged(
+                        params, paged, tokens, length, cfg,
+                        with_stats=True)
+                    tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
+                    # what the expert layers did rides the token's own
+                    # fetch: (1 + layers + layers * k * bucket,) int32
+                    out = (jnp.concatenate(
+                        [tok0, stats["moe_active_experts"],
+                         stats["moe_experts"].reshape(-1)]) if moe
+                        else tok0[0])
+                    return (out,) + cache_pools(paged)
 
                 return prog
 
@@ -928,18 +1089,23 @@ class DecodeEngine(object):
                 import jax
                 import jax.numpy as jnp
                 from ..parallel.transformer import (
-                    PagedKVCache, transformer_decode_step)
-                cfg, ps = self._model_cfg, self._cfg.page_size
+                    cache_pools, paged_cache, transformer_decode_step)
+                cfg, ps, moe = (self._model_cfg, self._cfg.page_size,
+                                self._moe)
 
                 @functools.partial(jax.jit, donate_argnums=(1, 2))
                 def prog(params, k_pages, v_pages, block_tables, tokens,
                          pos):
-                    paged = PagedKVCache(k_pages, v_pages, block_tables,
-                                         ps)
-                    logits, paged = transformer_decode_step(
-                        params, paged, tokens, pos, cfg)
-                    return (jnp.argmax(logits, -1).astype(jnp.int32),
-                            paged.k_pages, paged.v_pages)
+                    paged = paged_cache(k_pages, v_pages, block_tables,
+                                        ps)
+                    logits, paged, stats = transformer_decode_step(
+                        params, paged, tokens, pos, cfg, with_stats=True)
+                    out = jnp.argmax(logits, -1).astype(jnp.int32)
+                    if moe:         # ... + layers * (1 + k * slots)
+                        out = jnp.concatenate(
+                            [out, stats["moe_active_experts"],
+                             stats["moe_experts"].reshape(-1)])
+                    return (out,) + cache_pools(paged)
 
                 return prog
 

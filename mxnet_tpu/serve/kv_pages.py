@@ -21,6 +21,11 @@ Invariants (tested in tests/test_decode_serve.py):
   the write target for padding slots in a partially-filled decode
   batch (their K/V writes land there harmlessly instead of corrupting
   a live request's page). ``capacity`` therefore = ``num_pages - 1``.
+
+A model with sliding-window layers beside global ones has a pool a KIND
+of layer (``parallel.transformer.HybridKVCache``): one allocator each,
+named, each with its own null page; a request holds pages of both and
+returns both.
 """
 from __future__ import annotations
 
@@ -52,7 +57,10 @@ class PagePool(object):
     reserved as the null page). Thread-safe: the submit path reserves
     pages from HTTP threads while the scheduler thread frees them."""
 
-    def __init__(self, num_pages):
+    def __init__(self, num_pages, kind=None):
+        # ``kind``: which layers' pages these are ("global", "window"),
+        # where an engine holds a pool a kind; exhaustion names it
+        self.kind = kind
         num_pages = int(num_pages)
         if num_pages < 2:
             raise MXNetError("page pool needs >= 2 pages (page 0 is "
@@ -96,9 +104,11 @@ class PagePool(object):
         with self._lock:
             if n > len(self._free):
                 raise PagePoolExhausted(
-                    "kv page pool exhausted: need %d pages, %d free "
+                    "%skv page pool exhausted: need %d pages, %d free "
                     "of %d (raise MXNET_DECODE_NUM_PAGES or shed "
-                    "load)" % (n, len(self._free), self.capacity))
+                    "load)" % ("%s-layer " % self.kind if self.kind
+                               else "", n, len(self._free),
+                               self.capacity))
             ids = [self._free.pop() for _ in range(n)]
             for p in ids:
                 # self-check: the free list and allocated set must

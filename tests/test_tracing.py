@@ -846,8 +846,11 @@ def test_decode_loop_logs_its_iterations_without_a_caller_context():
     # the first request alone: prompt of 5, so its three decode steps
     # attend over 6, 7 and 8 positions (the new token's own included)
     steps = [r for r in log[:alone] if r["name"] == "decode.step"]
-    assert [s["attrs"] for s in steps] == [{"context_tokens": n}
-                                           for n in (6, 7, 8)]
+    # (a model without window layers sees the same under a window, and
+    # a dense one carries none of the expert layer's moe_* stamps)
+    assert [s["attrs"] for s in steps] == [
+        {"context_tokens": n, "window_context_tokens": n}
+        for n in (6, 7, 8)]
     assert len(kids(its[0], "decode.prefill")) == 1
     assert its[0]["attrs"] == {"live": 0}
     # a pass's prefills and its step lie inside it, in that order

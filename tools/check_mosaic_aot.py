@@ -8,7 +8,7 @@ against that description runs the real Mosaic/XLA:TPU compiler. So what
 Mosaic refuses (block shapes, DMA slices, VMEM budget, unsupported
 layouts) shows here, for free, before chip time is spent:
 
-    JAX_PLATFORMS=cpu python tools/check_mosaic_aot.py
+    JAX_PLATFORMS=cpu python tools/check_mosaic_aot.py [name part ...]
 
 Nothing is executed: this proves a kernel COMPILES at these shapes, not
 that it is right or fast — ``python chip_smoke.py`` on the chip compares
@@ -28,6 +28,7 @@ from jax.experimental import topologies                       # noqa: E402
 fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
 fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
 i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
+mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
 
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 SGD_H = {"lr": 0.1, "wd": 1e-4, "rescale_grad": 1 / 32, "momentum": 0.9}
@@ -78,6 +79,31 @@ def cases():
         yield ("int8_matmul %dx%dx%d" % (m, k, n),
                lambda x, w, s: i8.int8_matmul(x, w, s, interpret=False),
                [((m, k), I8), ((n, k), I8), ((n,), F32)])
+    # a window layer of the two-kind cache at its served size: 32 rows on
+    # rings of 257 entries, window 4096; an 8192-token prefill onto a ring
+    ring_pool = ((32 * 257 + 1, 16, 4, 128), BF16)
+    yield ("paged_decode_attention window4096 ring257 b32kvh4g7hd128",
+           lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+               q, kp, vp, bt, ln, interpret=False, window=4096),
+           [((32, 4, 7, 128), BF16), ring_pool, ring_pool, ((32, 257), I32),
+            ((32,), I32)])
+    kv = ((1, 8192, 4, 128), BF16)
+    yield ("flash_prefill_paged window4096 ring257 s8192nh28kvh4hd128",
+           lambda q, k, v, kp, vp, bt, ln: fa.flash_prefill_paged(
+               q, k, v, kp, vp, bt, interpret=False, lengths=ln,
+               window=4096),
+           [((1, 8192, 28, 128), BF16), kv, kv, ring_pool, ring_pool,
+            ((1, 257), I32), ((1,), I32)])
+    # 64 experts of 2560 x 768: a 32-slot decode step's 192 assignments
+    # in tiles of 16, a 16384-token prefill's 98304 in tiles of 128
+    # (layer 1 of a stack of two, read in place)
+    for rows, tile in ((1152, 16), (106368, 128)):
+        w_in, w_out = ((1, 2, 64, 2560, 768), BF16), ((1, 2, 64, 768, 2560),
+                                                      BF16)
+        yield ("moe_grouped_ffn rows%d tile%d" % (rows, tile),
+               lambda x, gs, wg, wu, wd, t=tile: mf.moe_grouped_ffn(
+                   x, gs, wg, wu, wd, t, interpret=False, lead=(0, 1)),
+               [((rows, 2560), BF16), ((64,), I32), w_in, w_in, w_out])
     yield ("int8_conv_im2col b32c64 56x56 3x3",
            lambda q, w, s: i8.int8_conv_im2col(
                q, w, s, (1, 1), (1, 1), (1, 1), interpret=False),
@@ -91,7 +117,10 @@ def main():
     print("compiling for %s (no device attached)" % topo.devices[0]
           .device_kind, flush=True)
     failed = 0
+    only = sys.argv[1:]
     for name, fn, specs in cases():
+        if only and not any(o in name for o in only):
+            continue
         args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in specs]
         try:
             jax.jit(fn).lower(*args).compile()
