@@ -456,6 +456,66 @@ def kernels_phase():
          lambda *a: real_pages(fa._flash_prefill_xla(*a, window)),
          (q, kg, vg, kp, vp, bt, ln), 2e-2)
 
+    # -- both paged kernels over the WHOLE pool of a kind of layer, at a
+    #    layer that is not the first — how the serving programs call them
+    #    (no pool[layer] slice: a copy in front of a custom call). Shapes
+    #    of both served cells: 24 layers of 16 KV heads under 32 slots of
+    #    2048 tokens; 9 window layers of 4 KV heads, window 4096 on rings
+    #    of 257 entries. Pools drawn on the device, fewer pages than served
+    whole = [(3, 2, 2, 2, 8, 4, 2, 9, None, 1),
+             (3, 3, 2, 2, 8, 4, 3, 12, 8, 2)] if TINY else \
+        [(24, 32, 16, 1, 128, 16, 128, 513, None, 23),
+         (9, 32, 4, 7, 128, 16, 257, 4096, 4096, 8)]
+    dt = jnp.float32 if TINY else jnp.bfloat16
+    for layers, b, kvh, g, hd, ps, ppseq, npages, window, layer in whole:
+        keys = jax.random.split(jax.random.PRNGKey(layer), 3)
+        q = jax.random.normal(keys[0], (b, kvh, g, hd), dt)
+        kp, vp = (jax.random.normal(k, (layers, npages, ps, kvh, hd), dt)
+                  for k in keys[1:])
+        bt = jnp.asarray(rng.randint(1, npages, size=(b, ppseq)), jnp.int32)
+        ln = jnp.asarray(rng.randint(1, (6 if window else 1) * ps * ppseq
+                                     + 1, size=(b,)), jnp.int32)
+        case("paged_decode_attention[layer%dof%d,b%dkvh%dg%dhd%d,window%s]"
+             % (layer, layers, b, kvh, g, hd, window),
+             lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+                 q, kp, vp, bt, ln, interpret=interp, window=window,
+                 layer=layer),
+             lambda q, kp, vp, bt, ln: fa._paged_decode_xla(
+                 q, kp, vp, bt, ln, 1.0 / hd ** 0.5, window, layer),
+             (q, kp, vp, bt, ln), 2e-2)
+
+    whole = [(3, 16, 4, 2, 8, 4, 4, None, 1),
+             (3, 32, 4, 2, 128, 4, 3, 8, 2)] if TINY else \
+        [(24, 2048, 16, 16, 128, 16, 128, None, 23),
+         (9, 4608, 28, 4, 128, 16, 257, 4096, 8)]
+    for layers, s, nh, kvh, hd, ps, entries, window, layer in whole:
+        keys = jax.random.split(jax.random.PRNGKey(100 + layer), 5)
+        q = jax.random.normal(keys[0], (1, s, nh, hd), dt)
+        kg, vg = (jax.random.normal(k, (1, s, kvh, hd), dt)
+                  for k in keys[1:3])
+        kp, vp = (jax.random.normal(k, (layers, entries + 1, ps, kvh, hd),
+                                    dt) for k in keys[3:])
+        bt = jnp.asarray(1 + np.arange(entries).reshape(1, entries),
+                         jnp.int32)
+        ln = None if window is None else jnp.asarray([s - ps - 3], jnp.int32)
+
+        def layer_and_rest(out, kp=kp, vp=vp, layer=layer):
+            # the layer's real pages (page 0 takes what is not kept), and
+            # whether every OTHER layer came back as it went in
+            rest = [jnp.asarray(jnp.array_equal(
+                jnp.delete(new, layer, 0), jnp.delete(old, layer, 0)),
+                jnp.float32) for new, old in zip(out[1:], (kp, vp))]
+            return [out[0]] + [p[layer, 1:] for p in out[1:]] + rest
+
+        case("flash_prefill_paged[layer%dof%d,s%dnh%dkvh%dhd%d,window%s]"
+             % (layer, layers, s, nh, kvh, hd, window),
+             lambda *a: layer_and_rest(fa.flash_prefill_paged(
+                 *a, interpret=interp, lengths=ln, window=window,
+                 layer=layer)),
+             lambda *a: layer_and_rest(fa._flash_prefill_xla(
+                 *a, ln, window, layer)),
+             (q, kg, vg, kp, vp, bt), 2e-2)
+
     # -- grouped expert FFN: a decode step's tile of 16 and a prefill's of
     #    128, rows sorted by expert, one expert left without a row
     from mxnet_tpu.parallel.moe import sorted_dispatch, top_k_routing

@@ -339,13 +339,16 @@ def ring_positions(n_entries, page_size, lengths):
 
 
 def _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
-                      sm_scale, window=None):
+                      sm_scale, window=None, layer=None):
     """Pure-lax twin of the paged kernel (the CPU tier-1 path and the
     numeric reference): block-table gather materializes each row's
     (L, kv_heads, hd) view, then standard masked GQA softmax. With a
     ``window`` the table is a ring (:func:`ring_positions`) and only
-    the last ``window`` positions are visible."""
+    the last ``window`` positions are visible. With a ``layer`` the
+    pools are whole, (layers, ...), and that layer's pages are read."""
     b, kvh, g, hd = q.shape
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     kc = k_pages[block_tables]           # (b, pages, page_size, kvh, hd)
     vc = v_pages[block_tables]
     L = kc.shape[1] * kc.shape[2]
@@ -367,7 +370,7 @@ def _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
     return o.astype(q.dtype)
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(bt_ref, len_ref, _layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, page_size, sm_scale, kv_heads,
                   window=None):
     """Grid (b, pages_per_seq): the trailing page dimension iterates
@@ -377,12 +380,19 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     ``block_tables[b, p]`` before the body runs.
 
     One step holds one whole page — every K/V head of it, block
-    ``(1, page_size, kv_heads, hd)`` — and loops over the heads in the
-    kernel. Mosaic refuses a per-head block ``(1, page_size, 1, hd)``
-    (its second-minor dim is 1 of ``kv_heads``: neither a multiple of 8
-    nor the full dim), and any reshape of the pool outside the kernel
-    is a relayout copy of the WHOLE pool per call; the full trailing
-    dims are legal as they are and move exactly the live pages."""
+    ``(1, page_size, kv_heads, hd)`` of ONE layer of the whole pool (the
+    layer is a squeezed leading block dim that the index map fills in
+    from a third scalar-prefetched array, so every layer of a model runs
+    ONE kernel: a static index would be lowered once a layer, 24 times
+    the set-up) — and loops over the heads in the kernel. Mosaic refuses a per-head
+    block ``(1, page_size, 1, hd)`` (its second-minor dim is 1 of
+    ``kv_heads``: neither a multiple of 8 nor the full dim). Nothing is
+    done to the pool outside the kernel: XLA has no view of an array
+    for a custom call's operand, so a reshape is a relayout copy of the
+    WHOLE pool per call and a ``pool[layer]`` slice a copy of that
+    layer's share of it (201 MB a call at the served size: the whole
+    pool once a token step). The block index picks the layer and the
+    page instead, and moves exactly the live pages."""
     b_i = pl.program_id(0)
     p_i = pl.program_id(1)
     n_p = pl.num_programs(1)
@@ -444,8 +454,29 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
 
 
+def _check_layer(k_pages, layer):
+    """A whole 5-D pool comes with the index of a layer it has; one
+    layer's 4-D pool comes without."""
+    if k_pages.ndim == 4:
+        if layer is not None:
+            raise ValueError("layer=%r given with one layer's 4-D pool"
+                             % (layer,))
+    elif layer is None or not 0 <= layer < k_pages.shape[0]:
+        raise ValueError("a whole pool %s needs its layer's index, got %r"
+                         % (k_pages.shape, layer))
+
+
+def _whole_pools(k_pages, v_pages, layer):
+    """``(k_pages, v_pages, layer)`` as the kernels take them, 5-D and a
+    (1,) int32 index: one layer's 4-D pool is a whole pool of one layer."""
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    return k_pages, v_pages, jnp.asarray([layer], jnp.int32)
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
-                           sm_scale=None, interpret=None, window=None):
+                           sm_scale=None, interpret=None, window=None,
+                           layer=None):
     """Decode-phase attention against a PAGED KV cache: one query token
     per sequence, keys/values gathered page-by-page via a block table.
 
@@ -453,8 +484,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     ----------
     q : (b, kv_heads, group, head_dim) — query heads grouped per shared
         K/V head (GQA layout; ``group = n_heads // kv_heads``).
-    k_pages, v_pages : (num_pages, page_size, kv_heads, head_dim) —
-        one layer's slice of the shared page pool.
+    k_pages, v_pages : (layers, num_pages, page_size, kv_heads,
+        head_dim) — the WHOLE pool of a kind of layer, as the cache
+        holds it — with ``layer``; or one layer's (num_pages, page_size,
+        kv_heads, head_dim), which is the same thing with one layer (a
+        free reshape). Never slice a pool by layer for this call: a
+        slice in front of a custom call is a copy (``_paged_kernel``).
     block_tables : (b, pages_per_seq) int32 — page ids per row, in
         position order.
     lengths : (b,) int32 — row ``r`` attends positions ``< lengths[r]``.
@@ -464,6 +499,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         context is the special case that never wraps). The grid walks
         the ring's entries, so a window layer costs its window, not
         its context.
+    layer : int — which layer of a whole pool is read. It reaches the
+        kernel as a scalar-prefetched operand, so a model's layers share
+        one lowered kernel.
 
     Returns (b, kv_heads, group, head_dim). Forward-only (serving);
     no VJP is defined. On TPU this is a Mosaic kernel whose page DMAs
@@ -473,6 +511,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     same contract, the tier-1 path.
     """
     b, kvh, g, hd = q.shape
+    _check_layer(k_pages, layer)
     if sm_scale is None:
         sm_scale = 1.0 / (hd ** 0.5)
     block_tables = jnp.asarray(block_tables, jnp.int32)
@@ -483,37 +522,34 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
             # interpreted per-page DMA emulation (interpret=True still
             # forces the interpreter for kernel-logic tests)
             return _paged_decode_xla(q, k_pages, v_pages, block_tables,
-                                     lengths, float(sm_scale), window)
+                                     lengths, float(sm_scale), window,
+                                     layer)
         interpret = False
-    if window is None:
-        return _paged_decode(q, k_pages, v_pages, block_tables, lengths,
-                             float(sm_scale), bool(interpret))
-    return _paged_decode(q, k_pages, v_pages, block_tables, lengths,
-                         float(sm_scale), bool(interpret), int(window))
+    k_pages, v_pages, layer = _whole_pools(k_pages, v_pages, layer)
+    return _paged_decode(q, k_pages, v_pages, block_tables, lengths, layer,
+                         float(sm_scale), bool(interpret),
+                         None if window is None else int(window))
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret",
                                              "window"))
-def _paged_decode(q, k_pages, v_pages, block_tables, lengths, sm_scale,
-                  interpret, window=None):
+def _paged_decode(q, k_pages, v_pages, block_tables, lengths, layer,
+                  sm_scale, interpret, window):
     b, kvh, g, hd = q.shape
-    num_pages, page_size = k_pages.shape[:2]
+    page_size = k_pages.shape[2]
     n_pb = block_tables.shape[1]
 
-    def q_map(b_i, p_i, bt, ln):
+    def q_map(b_i, p_i, bt, ln, li):
         return (b_i, 0, 0, 0)
 
-    def kv_map(b_i, p_i, bt, ln):
-        return (bt[b_i, p_i], 0, 0, 0)
+    def kv_map(b_i, p_i, bt, ln, li):
+        return (li[0], bt[b_i, p_i], 0, 0, 0)
 
+    kv_spec = pl.BlockSpec((None, 1, page_size, kvh, hd), kv_map)
     spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_pb),
-        in_specs=[
-            pl.BlockSpec((1, kvh, g, hd), q_map),
-            pl.BlockSpec((1, page_size, kvh, hd), kv_map),
-            pl.BlockSpec((1, page_size, kvh, hd), kv_map),
-        ],
+        in_specs=[pl.BlockSpec((1, kvh, g, hd), q_map), kv_spec, kv_spec],
         out_specs=pl.BlockSpec((1, kvh, g, hd), q_map),
         scratch_shapes=[
             pltpu.VMEM((kvh, g, _LANES), jnp.float32),
@@ -521,19 +557,16 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, sm_scale,
             pltpu.VMEM((kvh, g, hd), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_kernel, page_size=page_size,
-                               sm_scale=sm_scale, kv_heads=kvh)
-    if window is not None:
-        kernel = functools.partial(kernel, window=window)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_paged_kernel, page_size=page_size,
+                          sm_scale=sm_scale, kv_heads=kvh, window=window),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct(
             (b, kvh, g, hd), q.dtype, vma=_out_vma(q, k_pages, v_pages)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, lengths, q, k_pages, v_pages)
+    )(block_tables, lengths, layer, q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -586,34 +619,44 @@ def causal_mask(s, window=None):
 
 
 def _flash_prefill_xla(q, kg, vg, k_pages, v_pages, block_tables,
-                       lengths=None, window=None):
+                       lengths=None, window=None, layer=None):
     """Pure-lax twin of :func:`flash_prefill_paged` — op-for-op the
     attention + page write of ``transformer._prefill_impl``'s paged
     branch (expand-KV einsum / sqrt(hd), tril mask, softmax, and the
-    ``at[bt].set`` reshape-scatter), so the CPU tier-1 prefill path and
-    the dense==paged bitwise contract are this exact computation."""
-    b, s, nh, hd = q.shape
-    kvh = kg.shape[2]
-    groups = nh // kvh
-    ps = k_pages.shape[1]
-    n_pb = s // ps
+    ``at[bt].set`` reshape-scatter — ``at[layer, bt]`` into a whole
+    pool), so the CPU tier-1 prefill path and the dense==paged bitwise
+    contract are this exact computation."""
+    s, nh, hd = q.shape[1:]
+    groups = nh // kg.shape[2]
     k = kg if groups == 1 else jnp.repeat(kg, groups, axis=2)
     v = vg if groups == 1 else jnp.repeat(vg, groups, axis=2)
     mask = causal_mask(s, window)
     sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
     sc = jnp.where(mask[None, None], sc, NEG_INF)
     o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-    bt = prefill_page_dest(block_tables, n_pb, ps, lengths, window)
-    kp = k_pages.at[bt].set(
-        kg.reshape(b, n_pb, ps, kvh, hd).astype(k_pages.dtype))
-    vp = v_pages.at[bt].set(
-        vg.reshape(b, n_pb, ps, kvh, hd).astype(v_pages.dtype))
-    return o, kp, vp
+    return (o,) + _scatter_pages(kg, vg, k_pages, v_pages, block_tables,
+                                 lengths, window, layer)
 
 
-def _prefill_kernel(bt_ref, *refs, sm_scale, block_q, block_k, page_size,
-                    seq_len, n_heads, groups, write_pages, by_length=False,
-                    window=None):
+def _scatter_pages(kg, vg, k_pages, v_pages, block_tables, lengths, window,
+                   layer):
+    """The prompt's K/V (b, s, kv_heads, hd) scattered to their pages
+    (:func:`prefill_page_dest`), in place under jit: of one layer's
+    pool, or of layer ``layer`` of a whole one."""
+    b, s, kvh, hd = kg.shape
+    ps = k_pages.shape[-3]
+    n_pb = s // ps
+    dest = prefill_page_dest(block_tables, n_pb, ps, lengths, window)
+    at = dest if layer is None else (layer, dest)
+    return (k_pages.at[at].set(
+                kg.reshape(b, n_pb, ps, kvh, hd).astype(k_pages.dtype)),
+            v_pages.at[at].set(
+                vg.reshape(b, n_pb, ps, kvh, hd).astype(v_pages.dtype)))
+
+
+def _prefill_kernel(bt_ref, layer_ref, *refs, sm_scale, block_q, block_k,
+                    page_size, seq_len, n_heads, groups, write_pages,
+                    by_length=False, window=None):
     """Grid (b, q_blocks, k_blocks): per (batch, q tile) the trailing k
     dimension accumulates an online softmax in VMEM scratch exactly
     like ``_fwd_kernel``, for every head in turn. The tiles keep the
@@ -626,13 +669,15 @@ def _prefill_kernel(bt_ref, *refs, sm_scale, block_q, block_k, page_size,
 
     With ``write_pages`` the page write rides the same pass: the first
     q-tile visit of each k block DMAs that block's freshly computed K/V
-    straight from HBM into its rows' pool pages (``block_k`` is a
-    multiple of ``page_size``, so each page is written exactly once per
-    layer and the separate reshape-scatter program — and its HBM
-    round-trip — disappears). ``by_length`` (a second scalar-prefetched
-    array, the rows' real lengths) and ``window`` choose which pages are
-    written and where, as :func:`prefill_page_dest` says; a ``window``
-    also masks ``i - j >= window`` and skips the k blocks behind it."""
+    straight from HBM into its rows' pages of layer ``layer_ref[0]`` of
+    the WHOLE pool, which is aliased in->out and never sliced outside
+    (``block_k`` is a multiple of ``page_size``, so each page is written
+    exactly once per layer and the separate reshape-scatter program —
+    and its HBM round-trip — disappears). ``by_length`` (a further
+    scalar-prefetched array, the rows' real lengths) and ``window``
+    choose which pages are written and where, as
+    :func:`prefill_page_dest` says; a ``window`` also masks ``i - j >=
+    window`` and skips the k blocks behind it."""
     if by_length:
         len_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, v_ref, *rest = refs
@@ -658,12 +703,14 @@ def _prefill_kernel(bt_ref, *refs, sm_scale, block_q, block_k, page_size,
     if write_pages:
         @pl.when(qi == 0)
         def _write_pages():
+            layer = layer_ref[0]
+
             def copy_page(j, page):
                 src = pl.ds(k_start + j * page_size, page_size)
                 kcp = pltpu.make_async_copy(kg_ref.at[b_i, src],
-                                            kp_out.at[page], ksem)
+                                            kp_out.at[layer, page], ksem)
                 vcp = pltpu.make_async_copy(vg_ref.at[b_i, src],
-                                            vp_out.at[page], vsem)
+                                            vp_out.at[layer, page], vsem)
                 kcp.start()
                 vcp.start()
                 kcp.wait()
@@ -741,11 +788,11 @@ _PREFILL_VMEM_BUDGET = 8 << 20
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
                                              "interpret", "window"))
-def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
-                   block_q, block_k, interpret, lengths=None, window=None):
+def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables, layer,
+                   block_q, block_k, interpret, lengths, window):
     b, s, nh, hd = q.shape
     kvh = kg.shape[2]
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     # Mosaic can only slice an HBM ref whose minor dim fills whole
     # 128-lane tiles, so the DMA page write needs head_dim % 128 == 0;
     # narrower heads get the same attention kernel and the twin's
@@ -770,13 +817,10 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
     kernel = functools.partial(
         _prefill_kernel, sm_scale=1.0 / math.sqrt(hd), block_q=block_q,
         block_k=block_k, page_size=ps, seq_len=s, n_heads=nh,
-        groups=nh // kvh, write_pages=write_pages)
-    prefetched = (block_tables,)
-    if lengths is not None or window is not None:
-        kernel = functools.partial(kernel, by_length=lengths is not None,
-                                   window=window)
-        if lengths is not None:
-            prefetched += (lengths,)
+        groups=nh // kvh, write_pages=write_pages,
+        by_length=lengths is not None, window=window)
+    prefetched = (block_tables, layer) if lengths is None \
+        else (block_tables, layer, lengths)
     n_pre = len(prefetched)
     vma = _out_vma(q, kg, vg, k_pages, v_pages)
     # the per-head store is strided over the heads dim, which Mosaic
@@ -796,13 +840,9 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
                 scratch_shapes=scratch),
             out_shape=o_shape, compiler_params=params, interpret=interpret,
         )(*prefetched, q, kg, vg)
-        n_pb = s // ps
-        dest = prefill_page_dest(block_tables, n_pb, ps, lengths, window)
-        return (o.astype(q.dtype),
-                k_pages.at[dest].set(
-                    kg.reshape(b, n_pb, ps, kvh, hd).astype(k_pages.dtype)),
-                v_pages.at[dest].set(
-                    vg.reshape(b, n_pb, ps, kvh, hd).astype(v_pages.dtype)))
+        return (o.astype(q.dtype),) + _scatter_pages(
+            kg, vg, k_pages, v_pages, block_tables, lengths, window,
+            layer[0])
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -819,9 +859,10 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype, vma=vma),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype, vma=vma),
         ],
-        # pool arrays alias in->out: pages no row writes keep their
-        # contents, and on TPU the pool is updated in place (operand
-        # order counts the scalar-prefetch arg: bt=0 ... k_pages=6)
+        # the whole pools alias in->out: every other layer and the pages
+        # no row writes keep their contents, and on TPU the pool is
+        # updated in place (operand order counts the scalar-prefetch
+        # args: bt=0, layer=1 ... k_pages=n_pre + 5)
         input_output_aliases={n_pre + 5: 1, n_pre + 6: 2},
         compiler_params=params,
         interpret=interpret,
@@ -830,7 +871,7 @@ def _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
 
 def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
                         block_q=128, block_k=128, interpret=None,
-                        lengths=None, window=None):
+                        lengths=None, window=None, layer=None):
     """Prefill-phase flash attention over a paged KV pool: one batched
     causal forward per layer whose epilogue writes the prompt's K/V
     pages, replacing ``(s, s)``-score XLA attention + a separate
@@ -841,9 +882,13 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
     q : (b, s, n_heads, head_dim) — prompt queries (RoPE-rotated).
     kg, vg : (b, s, kv_heads, head_dim) — compact GQA K/V; the kernel
         never materialises the ``n_heads``-expanded copies.
-    k_pages, v_pages : (num_pages, page_size, kv_heads, head_dim) —
-        one layer's slice of the shared pool; returned updated (the
-        arrays alias in->out).
+    k_pages, v_pages : (layers, num_pages, page_size, kv_heads,
+        head_dim) — the WHOLE pool of a kind of layer — with ``layer``;
+        or one layer's 4-D pool, the same thing with one layer. Returned
+        updated, in the shape given: the arrays alias in->out, so the
+        caller takes them as its new pools. Never slice a pool by layer
+        for this call and set the slice back: both are copies of the
+        layer (``_paged_kernel`` says why).
     block_tables : (b, pages_per_row) int32 — destination page ids in
         position order (``pages_per_row = s // page_size``); rows of a
         warmup batch may all point at the reserved null page 0.
@@ -854,6 +899,8 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
         entries (page ``a`` at entry ``a % entries``): with ``lengths``
         just the last ``entries`` real pages are written. See
         :func:`prefill_page_dest`.
+    layer : int — which layer of a whole pool is written (a scalar-
+        prefetched operand of the kernel, as in the decode kernel).
 
     Returns ``(o, k_pages, v_pages)`` with ``o`` (b, s, n_heads,
     head_dim). Score scale is fixed at ``1/sqrt(head_dim)``. Causal
@@ -863,7 +910,8 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
     pure-lax twin (the tier-1 path) runs; ``interpret=True`` forces
     the Pallas interpreter for parity tests."""
     b, s, nh, hd = q.shape
-    ps = k_pages.shape[1]
+    _check_layer(k_pages, layer)
+    ps = k_pages.shape[-3]
     if s % ps:
         raise ValueError("prefill bucket %d is not a multiple of "
                          "page_size %d" % (s, ps))
@@ -883,7 +931,7 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
     if interpret is None:
         if not on_tpu(q):
             return _flash_prefill_xla(q, kg, vg, k_pages, v_pages,
-                                      block_tables, lengths, window)
+                                      block_tables, lengths, window, layer)
         interpret = False
     # block_k must be a multiple of page_size (each page written by
     # exactly one k block) and divide s; block_q must divide s
@@ -899,9 +947,8 @@ def flash_prefill_paged(q, kg, vg, k_pages, v_pages, block_tables,
                       + 4 * (2 * _LANES + hd))
     while block_q > 8 and block_q * row_bytes > _PREFILL_VMEM_BUDGET:
         block_q //= 2
-    if lengths is None and window is None:
-        return _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
-                              int(block_q), int(block_k), bool(interpret))
-    return _flash_prefill(q, kg, vg, k_pages, v_pages, block_tables,
-                          int(block_q), int(block_k), bool(interpret),
-                          lengths, None if window is None else int(window))
+    kp, vp, li = _whole_pools(k_pages, v_pages, layer)
+    o, kp, vp = _flash_prefill(
+        q, kg, vg, kp, vp, block_tables, li, int(block_q), int(block_k),
+        bool(interpret), lengths, None if window is None else int(window))
+    return (o, kp[0], vp[0]) if layer is None else (o, kp, vp)

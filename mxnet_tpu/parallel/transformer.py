@@ -735,6 +735,16 @@ class PagedKVCache(object):
     offset ``p % page_size``. A registered pytree (page_size is static
     aux data), so it traces straight through jit with the pool arrays
     donated.
+
+    On the TPU the pool stays whole from the program's arguments to its
+    results: the paged kernels take it with the layer's index and read
+    and write that layer's pages in place. A ``k_pages[layer]`` in front
+    of a Mosaic call is never free — XLA has no view of an array for a
+    custom call's operand, so the slice is a copy of one layer of the
+    pool per call, and ``.at[layer].set`` of a kernel's result another
+    (the whole pool once a token step: 63 % of a decode step's device
+    time before the kernels took the index). Only the CPU twins index
+    the pool by layer, inside fusions XLA owns.
     """
 
     __slots__ = ("k_pages", "v_pages", "block_tables", "page_size")
@@ -889,9 +899,9 @@ def _cache_attend(cache, li, q, pos_b, cfg, window=None):
             from ..ops.pallas.flash_attention import paged_decode_attention
             o = paged_decode_attention(
                 q.reshape(b, kvh, nh // kvh, hd),
-                cache.k_pages[li], cache.v_pages[li],
+                cache.k_pages, cache.v_pages,
                 cache.block_tables, pos_b + 1,
-                sm_scale=1.0 / np.sqrt(hd), window=window)
+                sm_scale=1.0 / np.sqrt(hd), window=window, layer=li)
             return o.reshape(b, nh * hd)
         # pure-lax gather fallback (CPU tier-1): block-table gather
         # materializes the same (b, kvh, L, hd) view the dense layout
@@ -1053,20 +1063,19 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
             part, part_li, put = _layer_cache(cache, cfg, li_flat)
             if isinstance(part, PagedKVCache) and on_tpu(q):
                 # fused Pallas prefill: one program computes the causal
-                # attention AND writes this layer's pages in its DMA
-                # epilogue — the kernel's lax twin is op-for-op the
+                # attention AND writes this layer's pages of the whole
+                # pool in its DMA epilogue (what it returns IS the new
+                # pool) — the kernel's lax twin is op-for-op the
                 # _cache_write_prompt + expand/einsum branch below, so
                 # CPU tier-1 (and dense==paged) semantics are that path
                 from ..ops.pallas.flash_attention import (
                     flash_prefill_paged)
                 o, kp, vp = flash_prefill_paged(
-                    q, kg, vg, part.k_pages[part_li],
-                    part.v_pages[part_li], part.block_tables,
-                    lengths=write_lengths, window=window)
-                part = PagedKVCache(
-                    part.k_pages.at[part_li].set(kp),
-                    part.v_pages.at[part_li].set(vp),
-                    part.block_tables, part.page_size)
+                    q, kg, vg, part.k_pages, part.v_pages,
+                    part.block_tables, lengths=write_lengths,
+                    window=window, layer=part_li)
+                part = PagedKVCache(kp, vp, part.block_tables,
+                                    part.page_size)
             else:
                 part = _cache_write_prompt(part, part_li, kg, vg,
                                            write_lengths, window)

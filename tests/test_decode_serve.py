@@ -265,6 +265,36 @@ def test_paged_attention_kernel_matches_xla_twin():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_attention_kernel_reads_its_layer_of_a_whole_pool(layer):
+    """Given the pool of every layer and a layer's index, the kernel
+    reads that layer's pages: it agrees with its twin and is bitwise the
+    call over the layer's own 4-D pool."""
+    from mxnet_tpu.ops.pallas.flash_attention import (
+        _paged_decode_xla, paged_decode_attention)
+    rng = np.random.RandomState(6)
+    q = jnp.asarray(rng.randn(2, 2, 2, 8).astype(np.float32))
+    kp = jnp.asarray(rng.randn(3, 8, 4, 2, 8).astype(np.float32))
+    vp = jnp.asarray(rng.randn(3, 8, 4, 2, 8).astype(np.float32))
+    bt = jnp.asarray(np.array([[1, 2], [3, 4]], np.int32))
+    ln = jnp.asarray(np.array([5, 7], np.int32))
+    ref = _paged_decode_xla(q, kp, vp, bt, ln, 1 / np.sqrt(8), layer=layer)
+    got = paged_decode_attention(q, kp, vp, bt, ln, interpret=True,
+                                 layer=layer)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    one = paged_decode_attention(q, kp[layer], vp[layer], bt, ln,
+                                 interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    other = paged_decode_attention(q, kp, vp, bt, ln, interpret=True,
+                                   layer=1)
+    assert not np.allclose(np.asarray(got), np.asarray(other))
+    # off the TPU the default dispatch is the twin, layer and all
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(q, kp, vp, bt, ln, layer=layer)),
+        np.asarray(ref))
+
+
 # ---------------------------------------------------------------------------
 # engine acceptance
 # ---------------------------------------------------------------------------

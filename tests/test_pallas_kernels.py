@@ -97,6 +97,55 @@ def test_flash_prefill_interpret_matches_twin(b, s, nh, kvh, hd, ps):
                                           np.asarray(kp[p]))
 
 
+@pytest.mark.parametrize("hd", [16, 128])     # XLA scatter / DMA page write
+@pytest.mark.parametrize("layer", [1, 2])
+def test_flash_prefill_whole_pool_writes_its_layer_only(layer, hd):
+    """The kernel takes the pool of every layer and the layer's index: it
+    agrees with its twin, is bitwise the call over that layer's own 4-D
+    pool, and every other layer comes back as it went in."""
+    b, s, nh, kvh, ps = 2, 32, 4, 2, 8
+    num_pages = 2 * b * (s // ps) + 3
+    q, kg, vg, _kp, _vp, bt = _prefill_case(4, b, s, nh, kvh, hd, ps,
+                                            num_pages)
+    rng = np.random.RandomState(40 + layer)
+    kp, vp = (jnp.asarray(rng.randn(3, num_pages, ps, kvh, hd)
+                          .astype(np.float32)) for _ in range(2))
+    o, kp_n, vp_n = flash_prefill_paged(q, kg, vg, kp, vp, bt,
+                                        interpret=True, layer=layer)
+    ox, kpx, vpx = _flash_prefill_xla(q, kg, vg, kp, vp, bt, layer=layer)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ox),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(kp_n), np.asarray(kpx))
+    np.testing.assert_array_equal(np.asarray(vp_n), np.asarray(vpx))
+    o1, kp1, vp1 = flash_prefill_paged(q, kg, vg, kp[layer], vp[layer], bt,
+                                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o1))
+    np.testing.assert_array_equal(np.asarray(kp_n[layer]), np.asarray(kp1))
+    np.testing.assert_array_equal(np.asarray(vp_n[layer]), np.asarray(vp1))
+    assert not np.array_equal(np.asarray(kp_n[layer]), np.asarray(kp[layer]))
+    for other in set(range(3)) - {layer}:
+        np.testing.assert_array_equal(np.asarray(kp_n[other]),
+                                      np.asarray(kp[other]))
+        np.testing.assert_array_equal(np.asarray(vp_n[other]),
+                                      np.asarray(vp[other]))
+
+
+@pytest.mark.parametrize("ndim,layer", [(5, None), (5, 3), (5, -1), (4, 0)])
+def test_paged_kernels_refuse_a_pool_without_its_layer(ndim, layer):
+    """A whole pool needs a layer it has; one layer's pool takes none."""
+    from mxnet_tpu.ops.pallas.flash_attention import paged_decode_attention
+    q, kg, vg, kp, vp, bt = _prefill_case(5, 1, 16, 2, 2, 8, 8, 4)
+    if ndim == 5:
+        kp, vp = jnp.stack([kp] * 3), jnp.stack([vp] * 3)
+    with pytest.raises(ValueError, match="layer"):
+        flash_prefill_paged(q, kg, vg, kp, vp, bt, interpret=True,
+                            layer=layer)
+    with pytest.raises(ValueError, match="layer"):
+        paged_decode_attention(q[:, 0].reshape(1, 2, 1, 8), kp, vp, bt,
+                               jnp.asarray([3], jnp.int32), interpret=True,
+                               layer=layer)
+
+
 def test_flash_prefill_default_dispatch_is_twin_off_tpu():
     if jax.default_backend() == "tpu":
         pytest.skip("off-TPU dispatch contract")

@@ -350,6 +350,56 @@ def test_windowed_paged_decode_kernel_matches_its_twin():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_windowed_paged_decode_kernel_reads_a_layer_of_the_window_pool():
+    """The same rings, read at layer 1 of a pool of three layers."""
+    rng = np.random.RandomState(7)
+    b, kvh, g, hd, ps, ring, window = 3, 2, 2, 8, 4, 3, 8
+    q = jnp.asarray(rng.randn(b, kvh, g, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(3, 12, ps, kvh, hd).astype(np.float32))
+    vp = jnp.asarray(rng.randn(3, 12, ps, kvh, hd).astype(np.float32))
+    bt = jnp.asarray(1 + np.arange(b * ring, dtype=np.int32)
+                     .reshape(b, ring))
+    ln = jnp.asarray(np.array([5, 14, 39], np.int32))
+    want = _paged_decode_xla(q, kp, vp, bt, ln, 1 / np.sqrt(hd), window,
+                             layer=1)
+    got = paged_decode_attention(q, kp, vp, bt, ln, interpret=True,
+                                 window=window, layer=1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    one = paged_decode_attention(q, kp[1], vp[1], bt, ln, interpret=True,
+                                 window=window)
+    assert np.array_equal(np.asarray(got), np.asarray(one))
+
+
+@pytest.mark.parametrize("hd", [8, 128])      # scatter / DMA page write
+def test_windowed_prefill_kernel_writes_a_layer_of_the_window_pool(hd):
+    """Rings, real lengths and a window, written at layer 2 of a pool of
+    three layers; layers 0 and 1 come back untouched."""
+    rng = np.random.RandomState(8)
+    b, s, nh, kvh, ps, ring, window = 2, 32, 4, 2, 4, 3, 8
+    q = jnp.asarray(rng.randn(b, s, nh, hd).astype(np.float32))
+    kg = jnp.asarray(rng.randn(b, s, kvh, hd).astype(np.float32))
+    vg = jnp.asarray(rng.randn(b, s, kvh, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(3, 8, ps, kvh, hd).astype(np.float32))
+    vp = jnp.asarray(rng.randn(3, 8, ps, kvh, hd).astype(np.float32))
+    bt = jnp.asarray(1 + np.arange(b * ring, dtype=np.int32)
+                     .reshape(b, ring))
+    ln = jnp.asarray(np.array([21, 6], np.int32))
+    want = _flash_prefill_xla(q, kg, vg, kp, vp, bt, ln, window, layer=2)
+    got = flash_prefill_paged(q, kg, vg, kp, vp, bt, block_q=8, block_k=8,
+                              interpret=True, lengths=ln, window=window,
+                              layer=2)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    for a, w, before in zip(got[1:], want[1:], (kp, vp)):
+        # page 0 takes whatever is not kept; every real page agrees
+        assert np.array_equal(np.asarray(a)[2, 1:], np.asarray(w)[2, 1:])
+        assert np.array_equal(np.asarray(a)[:2], np.asarray(before)[:2])
+    assert np.array_equal(np.asarray(got[1])[2, 1],
+                          np.asarray(kg)[0, 12:16])
+    assert np.array_equal(np.asarray(got[1])[2, 6], np.asarray(kp)[2, 6])
+
+
 @pytest.mark.parametrize("hd", [8, 128])      # scatter / DMA page write
 def test_windowed_prefill_kernel_matches_its_twin(hd):
     rng = np.random.RandomState(6)

@@ -130,6 +130,35 @@ def test_every_exported_kernel_compiles_for_the_v5e():
     assert "compiling for TPU v5 lite" in r.stdout
 
 
+@pytest.fixture(scope="module")
+def pool_in_place():
+    """``tools/check_pool_in_place.py``, once: the serving programs of a
+    small paged model compiled for a described v5e, TPU branches taken."""
+    r = _run(["tools/check_pool_in_place.py"])
+    lines = [json.loads(ln) for ln in r.stdout.splitlines()
+             if ln.startswith("{")]
+    return r, {(ln["config"], ln["program"]): ln for ln in lines}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("config", ["one_pool", "pool_a_kind"])
+def test_serving_program_leaves_the_pool_in_place(pool_in_place, config,
+                                                  program):
+    """No slice, copy or fusion outside the Mosaic calls makes an array
+    of one layer's pool shape, both pools are aliased argument -> result
+    and the temporaries are smaller than a layer: the kernels address
+    the layer themselves. (A ``pool[layer]`` in front of a custom call
+    is a copy: two a layer in each program before the kernels took the
+    layer's index.)"""
+    r, lines = pool_in_place
+    assert (config, program) in lines, r.stdout[-2000:] + r.stderr[-2000:]
+    line = lines[config, program]
+    assert line["mosaic_calls"] >= 3
+    assert line["layer_copies"] == 0, r.stdout[-3000:]
+    assert line["aliased_bytes"] >= line["pool_bytes"]
+    assert line["temp_bytes"] < line["layer_bytes"]
+
+
 # ---------------------------------------------------------------------------
 # chip_smoke.py
 # ---------------------------------------------------------------------------

@@ -94,6 +94,43 @@ def cases():
                window=4096),
            [((1, 8192, 28, 128), BF16), kv, kv, ring_pool, ring_pool,
             ((1, 257), I32), ((1,), I32)])
+    # the WHOLE pools of both served cells, read and written in place at
+    # a layer that is not the first: 24 layers of 3072 pages x 16 KV
+    # heads under 32 slots and a 2048-token prompt; the 9 window layers'
+    # pool of 4096 pages x 4 KV heads on rings of 257 entries
+    whole = ((24, 3072, 16, 16, 128), BF16)
+    yield ("paged_decode_attention layer23of24 b32kvh16g1hd128",
+           lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+               q, kp, vp, bt, ln, interpret=False, layer=23),
+           [((32, 16, 1, 128), BF16), whole, whole, ((32, 128), I32),
+            ((32,), I32)])
+    kv = ((1, 2048, 16, 128), BF16)
+    yield ("flash_prefill_paged layer23of24 s2048nh16kvh16hd128",
+           lambda q, k, v, kp, vp, bt: fa.flash_prefill_paged(
+               q, k, v, kp, vp, bt, interpret=False, layer=23),
+           [((1, 2048, 16, 128), BF16), kv, kv, whole, whole,
+            ((1, 128), I32)])
+    whole = ((9, 4096, 16, 4, 128), BF16)
+    yield ("paged_decode_attention layer8of9 window4096 ring257 b32kvh4g7",
+           lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+               q, kp, vp, bt, ln, interpret=False, window=4096, layer=8),
+           [((32, 4, 7, 128), BF16), whole, whole, ((32, 257), I32),
+            ((32,), I32)])
+    kv = ((1, 16384, 4, 128), BF16)
+    yield ("flash_prefill_paged layer8of9 window4096 ring257 s16384nh28kvh4",
+           lambda q, k, v, kp, vp, bt, ln: fa.flash_prefill_paged(
+               q, k, v, kp, vp, bt, interpret=False, lengths=ln,
+               window=4096, layer=8),
+           [((1, 16384, 28, 128), BF16), kv, kv, whole, whole,
+            ((1, 257), I32), ((1,), I32)])
+    # a head narrower than a lane tile: the kernel attends, XLA scatters
+    # into the layer in place
+    narrow = ((3, 33, 16, 2, 64), F32)
+    kv = ((2, 128, 2, 64), F32)
+    yield ("flash_prefill_paged layer1of3 s128nh8kvh2hd64 scatter",
+           lambda q, k, v, kp, vp, bt: fa.flash_prefill_paged(
+               q, k, v, kp, vp, bt, interpret=False, layer=1),
+           [((2, 128, 8, 64), F32), kv, kv, narrow, narrow, ((2, 8), I32)])
     # 64 experts of 2560 x 768: a 32-slot decode step's 192 assignments
     # in tiles of 16, a 16384-token prefill's 98304 in tiles of 128
     # (layer 1 of a stack of two, read in place)
