@@ -37,9 +37,6 @@ VARS = {
     "MXNET_TPU_ROLE": (str, "worker", "PS-mode process role (worker/"
                        "server/scheduler) for the launch.py tooling "
                        "path."),
-    "MXNET_TPU_BENCH_DIR": (str, "", "Override for the benchmark "
-                            "results directory (default .bench/ under "
-                            "the repo root)."),
     "MXNET_DIST_COORDINATOR": (str, "", "host:port of process 0's "
                                "jax.distributed coordinator for "
                                "dist_tpu_sync multi-host training "
@@ -430,12 +427,6 @@ VARS = {
                                     "threshold over decode/"
                                     "step_seconds p99 (inter-token "
                                     "latency)."),
-    "MXNET_SLO_MFU_DIVERGENCE": (float, 0.20,
-                                 "Default mfu_divergence SLO rule "
-                                 "threshold: the health/mfu_divergence "
-                                 "gauge (|measured/hand-counted - 1| "
-                                 "from bench runs) above this fires "
-                                 "/alerts in events mode."),
     "MXNET_SLO_BADPUT_FRACTION": (float, 0.5,
                                   "Default badput_fraction SLO rule "
                                   "threshold on the goodput/"

@@ -67,8 +67,8 @@ Configuration (config.py):
 
 * ``MXNET_DIST_COORDINATOR`` — ``host:port`` of process 0's
   coordinator service.  Setting it (plus the two below) is the
-  explicit, works-anywhere route — the CPU/gloo acceptance tests and
-  the ``dist_train_sync`` bench use it, and it is the only route that
+  explicit, works-anywhere route — the CPU/gloo acceptance tests
+  (tests/test_dist_tpu_sync.py) use it, and it is the only route that
   supports :func:`reinit` (elastic rescale).
 * ``MXNET_DIST_NUM_PROCESSES`` / ``MXNET_DIST_PROCESS_ID`` — world
   size and this process's rank.

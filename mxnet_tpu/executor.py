@@ -877,8 +877,8 @@ class Executor(object):
 
     def _check_sentinel(self, sentinel, numerics, update_names):
         """Read one step's packed sentinel vector (a single small D2H
-        fetch — not an op dispatch, not a recompile; the
-        health_overhead bench bounds it under 2% of the step) and
+        fetch — not an op dispatch, not a recompile:
+        tests/test_health.py holds both counts) and
         apply the numerics policy."""
         from .parallel.mesh import host_local_value
         vals = _np.asarray(host_local_value(sentinel))
@@ -904,8 +904,8 @@ class Executor(object):
     def fused_cost(self):
         """Cost-analysis record of the most recently used fused-step
         program ({'flops','bytes',...}), or None where the backend
-        offers no analysis (benchmark.py banks ``mfu_measured`` from
-        this)."""
+        offers no analysis (``health.note_executor_step`` prices the
+        ``executor/mfu`` gauge from it)."""
         return self._fused_cost_rec
 
     def forward_cost(self, is_train=False):

@@ -46,8 +46,8 @@ Surfaces:
 * ``python -m mxnet_tpu.forensics <report|dir> [--diff A B] [--json]``
   (the blackbox CLI pattern; ``--diff`` exits 1 on a regression).
 * ``mxnet_tpu.diagnostics()`` carries :func:`worst_fusions` — the
-  top-N fusions by ``bytes_share x (1 - measured MFU)``.
-* ``benchmark.persist`` banks :func:`digest` beside each bench record.
+  top-N fusions by ``bytes_share x (1 - measured MFU)``, from the
+  reports captured in this process.
 
 On backends without compiled-HLO text or cost analysis the capture
 degrades to an ``unavailable`` report stanza plus
@@ -75,7 +75,7 @@ _log = logging.getLogger("mxnet_tpu.forensics")
 __all__ = ["enabled", "configure", "reports_dir", "maybe_capture",
            "analyze_hlo", "reports", "report_for", "load_report",
            "write_report", "reports_on_disk", "diff", "summary",
-           "digest", "worst_fusions", "measured_mfu",
+           "worst_fusions", "measured_mfu",
            "programs_endpoint", "main", "reset"]
 
 FORMAT = 1
@@ -780,24 +780,6 @@ def worst_fusions(limit=5):
                                (1.0 if gap is None else gap), 4)})
     rows.sort(key=lambda r: -r["score"])
     return rows[:limit]
-
-
-def digest():
-    """Compact forensics digest banked beside every bench record
-    (``benchmark.persist``): report/fusion counts, the single worst
-    fusion's bytes share, and the residual bytes — compiler provenance
-    for BENCH_* rounds. None when nothing was captured."""
-    reps = [r for r in reports().values() if not r.get("unavailable")]
-    if not reps:
-        n_unavail = len(reports())
-        return ({"reports": 0, "unavailable": n_unavail}
-                if n_unavail else None)
-    shares = [f["bytes_share"] for r in reps for f in r["fusions"][:1]]
-    return {"reports": len(reps),
-            "fusion_count": sum(len(r["fusions"]) for r in reps),
-            "top_fusion_bytes_share": max(shares) if shares else 0.0,
-            "residual_bytes": int(sum(r["residual"]["bytes"]
-                                      for r in reps))}
 
 
 # ---------------------------------------------------------------------------

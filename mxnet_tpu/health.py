@@ -12,10 +12,10 @@ active pillars, plus the crash-safe flight recorder in blackbox.py:
    — an HLO cost pass, NOT a second backend compile) at compile time;
    measured step wall times then turn into ``executor/mfu`` /
    ``executor/hbm_bw_util`` and per-serve-bucket equivalents on
-   ``/metrics``. The FLOP number is *measured from the program*, which
-   resolves the hand-count convention ambiguity documented in
-   benchmark.py (the bench satellite records both and warns on
-   divergence). Where the backend returns no analysis the capture
+   ``/metrics``. The FLOP number is *measured from the program* by
+   XLA's own cost pass, never hand-counted, so a gauge cannot drift
+   from the code it prices when a layer or a dtype changes. Where
+   the backend returns no analysis the capture
    degrades to an ``unavailable`` counter and the gauges simply never
    appear (the documented n/a fallback).
 2. **Numerics sentinels** — ``MXNET_NUMERICS=off|step|full`` folds a
@@ -38,9 +38,9 @@ active pillars, plus the crash-safe flight recorder in blackbox.py:
 Cost model: nothing here sits on a per-dispatch hot path. Cost capture
 runs once per compiled program at compile/warmup time; MFU gauge
 updates are a few float ops per *step*; the sentinel's per-step cost
-is one small-array D2H fetch (bounded < 2% by the ``health_overhead``
-bench); the SLO evaluator wakes every ``MXNET_SLO_INTERVAL_S`` seconds
-and only ever *reads* telemetry.
+is one small-array D2H fetch (not an op dispatch, not a recompile);
+the SLO evaluator wakes every ``MXNET_SLO_INTERVAL_S`` seconds and
+only ever *reads* telemetry.
 """
 from __future__ import annotations
 
@@ -55,9 +55,9 @@ from .base import MXNetError
 __all__ = ["NumericsError", "capture_cost", "register_cost",
            "program_cost", "programs",
            "note_executor_step", "note_serve_batch", "note_decode",
-           "note_mfu_divergence",
-           "DEVICE_PEAKS", "device_peaks", "peak_flops",
-           "peak_hbm_bytes_per_s", "mfu_summary",
+           "DEVICE_PEAKS", "device_peaks",
+           "peak_flops", "peak_hbm_bytes_per_s",
+           "mfu_summary",
            "numerics_mode", "set_numerics", "numerics_policy",
            "set_numerics_policy", "set_spike_factor", "check_numerics",
            "numerics_trips", "watch", "unwatch", "rules",
@@ -115,15 +115,15 @@ _KINDS = ("executor_forward", "fused_step", "serve_bucket",
 
 
 # Published peaks of ONE chip, keyed by ``jax.Device.device_kind`` —
-# the single table behind every MFU / roofline denominator (live gauges
-# here, bench estimates in benchmark.py). Source: Google Cloud
+# the single table behind every live MFU / roofline gauge (the benchmark
+# prices its readings from bench/peaks.json). Source: Google Cloud
 # documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s
 # HBM; the chip reports itself as "TPU v5 lite" (read off the device,
 # PR 21). v5e has no separate fp32 systolic path — under JAX's default
 # precision fp32 matmuls run the MXU with bf16 operands — so the bf16
 # peak is the fp32 denominator too. A kind not listed here has NO
-# peak: the live gauges stay unset and bench code raises; a default
-# would price another device's run with a v5e's roof.
+# peak: the live gauges stay unset; a default would price another
+# device's run with a v5e's roof.
 DEVICE_PEAKS = {
     "TPU v5 lite": {"flops": 197e12, "int8_ops": 393e12,
                     "hbm_bytes_per_s": 819e9},
@@ -316,31 +316,6 @@ def note_decode(phase, bucket, seconds, rec):
     return util
 
 
-def note_mfu_divergence(est, measured):
-    """Bank the measured-vs-hand-counted MFU divergence as a proper
-    gauge (``health/mfu_divergence`` = |measured/est - 1|) so it shows
-    on ``/metrics`` and the default ``mfu_divergence`` SLO rule can
-    fire ``/alerts`` — instead of the warning living only inside bench
-    records (benchmark._note_mfu_divergence calls this). Returns the
-    ratio, or None when either side is missing."""
-    try:
-        est, measured = float(est or 0.0), float(measured or 0.0)
-    except (TypeError, ValueError):
-        return None
-    if est <= 0.0 or measured <= 0.0:
-        return None
-    ratio = measured / est
-    tm = _tm()
-    if tm._enabled:
-        tm.gauge("health/mfu_divergence",
-                 "Absolute divergence |measured/est - 1| between the "
-                 "measured MFU (XLA cost_analysis FLOPs) and the "
-                 "hand-counted estimate of the same bench run; the "
-                 "mfu_divergence SLO rule fires past "
-                 "MXNET_SLO_MFU_DIVERGENCE").set(abs(ratio - 1.0))
-    return ratio
-
-
 def mfu_summary():
     """One-shot roofline summary for diagnostics(): current gauges plus
     the captured-program table."""
@@ -368,11 +343,6 @@ def mfu_summary():
     if fam is not None:
         out["serve_bucket_mfu"] = {
             lv[0]: round(c.value, 6) for lv, c in fam.series()}
-    fam = tm.REGISTRY._families.get("health/mfu_divergence")
-    if fam is not None:
-        series = fam.series()
-        if series:
-            out["mfu_divergence"] = round(series[0][1].value, 4)
     return out
 
 
@@ -829,13 +799,6 @@ def _ensure_defaults():
           threshold=0.0,
           description="numerics-sentinel trips (nonfinite grads/loss "
                       "or grad-norm spike)")
-    watch("mfu_divergence", gauge="health/mfu_divergence",
-          threshold=float(_config("MXNET_SLO_MFU_DIVERGENCE", 0.20)),
-          mode="events",
-          description="measured MFU (cost_analysis FLOPs) diverges "
-                      "from the hand-counted estimate past "
-                      "MXNET_SLO_MFU_DIVERGENCE (a single divergent "
-                      "bench sample fires)")
     watch("badput_fraction", gauge="goodput/badput_fraction",
           threshold=float(_config("MXNET_SLO_BADPUT_FRACTION", 0.5)),
           description="goodput ledger: fraction of run wall NOT spent "

@@ -56,7 +56,7 @@ behind:
 
 Knobs: ``JAX_COMPILATION_CACHE_DIR`` (JAX's own),
 ``MXNET_PROGRAMS_MAX`` (config.py).
-Docs: docs/compile_cache.md. Bench: ``benchmark.py --job cold_start``.
+Docs: docs/compile_cache.md. Fresh-process gate: tests/test_programs.py.
 """
 from __future__ import annotations
 
@@ -391,7 +391,7 @@ def entries():
 
 
 def stats():
-    """Registry totals for bench records / diagnostics."""
+    """Registry totals for diagnostics and bug reports."""
     with _lock:
         rows = list(_entries.values())
     return {"entries": len(rows),

@@ -69,7 +69,7 @@ __all__ = ["Fleet", "main"]
 _monotonic = time.perf_counter
 
 # the /alerts rules whose firing means "this replica is drowning in
-# serve load" — training-side rules (mfu_divergence, numerics) and
+# serve load" — training-side rules (badput_fraction, numerics) and
 # meta-rules must not scale the fleet
 BURN_RULES = frozenset(("serve_p99", "decode_itl_p99", "queue_depth"))
 

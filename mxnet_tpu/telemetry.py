@@ -852,7 +852,7 @@ def serve(port=0, addr="127.0.0.1", registry=None):
 # ---------------------------------------------------------------------------
 
 def snapshot():
-    """Compact summary for benchmark records / bug reports: dispatch and
+    """Compact summary for tests, drivers and bug reports: dispatch and
     compile totals plus a live allocator poll (the allocator tracks its
     own peak, so this is meaningful even if no gauge was ever set)."""
     fam = REGISTRY._families.get("op/dispatch_total")
@@ -873,7 +873,7 @@ def snapshot():
            # backend compiles vs persistent-cache disk loads (their sum
            # is backend_compile_total once the cache is on), registry
            # volume/evictions, and warm-set replay — the cold-start
-           # evidence banked with cold_start bench records
+           # evidence (tests/test_programs.py reads the split)
            "programs_compile_total": _val("programs/compile_total"),
            "programs_disk_hits": _val("programs/disk_hits_total"),
            "programs_registered": _val("programs/registered_total"),
@@ -885,7 +885,7 @@ def snapshot():
                _val("programs/prewarm_skipped_total"),
            # fused train-step accounting (executor.train_step): steps
            # run, program builds, and python-cache hit/miss — the
-           # O(1)-dispatch-per-step evidence banked with bench records
+           # O(1)-dispatch-per-step evidence
            "fused_step_total": _val("executor/fused_step_total"),
            "fused_step_compiles": _val("executor/fused_step_compile_total"),
            "fused_step_cache_hits":
@@ -893,8 +893,8 @@ def snapshot():
            "fused_step_cache_misses":
                _val("executor/fused_step_cache_miss_total"),
            # serving-path accounting (serve.InferenceEngine): volume,
-           # backpressure, and the realized batching efficiency banked
-           # with predictor_serve bench records
+           # backpressure, and the realized batching efficiency
+           # (mean rows a batch, padding waste: below)
            "serve_requests": _val("serving/requests_total"),
            "serve_rejected": _val("serving/rejected_total"),
            "serve_timeouts": _val("serving/timeouts_total"),
@@ -902,7 +902,7 @@ def snapshot():
            "serve_swaps": _val("serving/swaps_total"),
            # continuous-batching decode accounting (serve.DecodeEngine):
            # token volume, admission refusals, and abnormal slot
-           # retirements banked with decode_serve bench records
+           # retirements (preempted, timed out)
            "decode_requests": _val("decode/requests_total"),
            "decode_rejected": _val("decode/rejected_total"),
            "decode_tokens": _val("decode/tokens_total"),
@@ -911,7 +911,7 @@ def snapshot():
            # fault-tolerance accounting: crash-consistent checkpoint
            # traffic, kvstore transport retries, serve worker crashes,
            # and armed faults fired (test runs) — the robustness
-           # evidence banked with train_resume bench records
+           # evidence (tests/test_fault_tolerance.py reads it)
            "ckpt_saves": _val("checkpoint/saves_total"),
            "ckpt_restores": _val("checkpoint/restores_total"),
            "ckpt_fallbacks": _val("checkpoint/fallbacks_total"),
@@ -926,8 +926,8 @@ def snapshot():
            "kv_worker_rejoins": _val("kvstore/worker_rejoins_total"),
            "serve_worker_restarts": _val("serving/worker_restarts_total"),
            # quantized-serving accounting: artifacts produced, int8
-           # hot-swaps, and the shadow A/B canary volume banked with
-           # quantized_serve bench records
+           # hot-swaps, and the shadow A/B canary volume
+           # (the quantize/* counters)
            "quantize_checkpoints": _val("quantize/checkpoints_total"),
            "quantize_swaps": _val("quantize/swaps_total"),
            "quantize_shadow_requests":
@@ -935,8 +935,8 @@ def snapshot():
            "quantize_shadow_errors": _val("quantize/shadow_errors_total"),
            "faults_injected": _val("fault/injected_total")}
     # health-layer accounting: firing SLO rules, numerics-sentinel
-    # trips, and flight-recorder volume ride every bench record for
-    # free (benchmark.persist embeds snapshot())
+    # trips, and flight-recorder volume ride every snapshot for
+    # free (mx.diagnostics() embeds snapshot())
     try:
         from . import health as _hl
         from . import blackbox as _bb
@@ -948,13 +948,13 @@ def snapshot():
         out["numerics_trips"] = 0
         out["flight_records"] = 0
     # compiler-forensics accounting (forensics.py): per-program HLO
-    # reports captured vs degraded — bench records carry whether the
-    # run has fusion-level provenance
+    # reports captured vs degraded — whether the run has
+    # fusion-level provenance
     out["forensics_captured"] = _val("forensics/captured_total")
     out["forensics_unavailable"] = _val("forensics/unavailable_total")
     # goodput-ledger accounting (goodput.py): what fraction of the
     # run's wall was useful step compute, and where the rest went —
-    # banked with every bench record when a fit session is live
+    # present whenever a fit session is live
     try:
         from . import goodput as _gp
         rep = _gp.report()

@@ -542,7 +542,7 @@ def _chaos_env(eldir, flight=None):
                MXNET_STEP_TIMEOUT_S="60", ELASTIC_TEST_PACE_S="0.25")
     # jaxlib's CPU gloo path has segfaulted deserializing a donated
     # collective program from the persistent compile cache, so it
-    # stays off here (dist bench jobs dodge the same bug)
+    # stays off here
     env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
     for v in ("MXNET_TPU_PS_URI", "MXNET_FAULT_INJECT",
               "MXNET_ELASTIC_JOIN", "MXNET_FLIGHT_RECORDER"):

@@ -467,7 +467,7 @@ def test_autoscaler_hysteresis(tmp_path, monkeypatch):
     assert fleet.target == 1
 
     # training-side rules must not scale the serving fleet
-    sigs["rows"] = [{"name": "r1", "firing": ["mfu_divergence"],
+    sigs["rows"] = [{"name": "r1", "firing": ["numerics"],
                      "queue_depth": 0.0}]
     fleet.target = 1
     fleet._last_scale = None
@@ -520,6 +520,106 @@ def _scrape_counter(port, prom_name):
         if line.startswith(prom_name + " "):
             return float(line.split()[-1])
     return 0.0
+
+
+def _predict_until(stop, front, results):
+    """One client: /predict after /predict through the router's front
+    until ``stop``; appends (status, seconds), -1 for a refused
+    connection."""
+    while not stop.is_set():
+        t0 = time.perf_counter()
+        try:
+            status, _, _ = _post(
+                front.url, "/predict",
+                {"inputs": {"data": [[1.0, 2.0, 3.0, 4.0]]},
+                 "timeout_ms": 20000}, timeout=30)
+        except (OSError, urllib.error.URLError):
+            status = -1
+        results.append((status, time.perf_counter() - t0))
+        time.sleep(0.02)
+
+
+def _bank_compile_baselines(fleet, baselines):
+    """Each serving replica's compile counter, read once, the first
+    time it is seen up (a replica is up only after its warmup)."""
+    for rep in fleet.status()["replicas"]:
+        if rep["port"] and rep["name"] not in baselines:
+            baselines[rep["name"]] = (
+                rep["port"],
+                _scrape_counter(rep["port"],
+                                "mxnet_jit_backend_compile_total"))
+
+
+def _assert_no_replica_compiled(fleet, baselines):
+    """Zero XLA compiles after warmup on EVERY replica that is still
+    up; one spawned off the warm-set manifest rode the disk cache."""
+    up = {r["name"] for r in fleet.status()["replicas"]}
+    for name, (port, base) in baselines.items():
+        if name not in up:
+            continue                               # killed/retired
+        now_count = _scrape_counter(
+            port, "mxnet_jit_backend_compile_total")
+        assert now_count == base, (name, base, now_count)
+        if name != "r1":
+            assert _scrape_counter(
+                port, "mxnet_programs_disk_hits_total") > 0
+
+
+def test_fleet_scales_up_and_no_replica_compiles(tmp_path):
+    """The acceptance's compile gate, in tier-1: a REAL fleet of worker
+    subprocesses scales 1 -> 2 under live traffic, the second replica
+    spawning warm off the first's warm-set manifest, and no replica
+    compiles anything after its warmup."""
+    cache = tmp_path / "cache"
+    cache.mkdir()
+    spec = _write_spec(
+        tmp_path, {"JAX_COMPILATION_CACHE_DIR": str(cache)})
+    sigs = {"rows": []}
+    fleet = Fleet(spec, str(tmp_path / "wd"), min_replicas=1,
+                  max_replicas=2, interval_s=0.15, scale_up_s=0.4,
+                  scale_down_s=30.0, cooldown_s=0.6,
+                  spawn_timeout_s=120, drain_timeout_s=30,
+                  signals_fn=lambda: sigs["rows"])
+    results, baselines = [], {}
+    stop = threading.Event()
+    try:
+        fleet.start()
+        front = serve_router(fleet.router, port=0)
+        _bank_compile_baselines(fleet, baselines)
+        clients = [threading.Thread(target=_predict_until,
+                                    args=(stop, front, results),
+                                    daemon=True) for _ in range(2)]
+        for t in clients:
+            t.start()
+        sigs["rows"] = [{"name": "r1", "firing": ["serve_p99"],
+                         "queue_depth": 0.0}]
+        deadline = time.time() + 60
+        while time.time() < deadline:
+            st = fleet.status()
+            if st["live"] == 2 and all(r["spawn_s"]
+                                       for r in st["replicas"]):
+                break                            # the second one SERVES
+            time.sleep(0.1)
+        assert st["live"] == 2 and fleet.target == 2, st
+        assert [r for r in st["replicas"] if r["name"] != "r1"][0]["warm"]
+        _bank_compile_baselines(fleet, baselines)
+        sigs["rows"] = []
+        served = len(results)
+        deadline = time.time() + 30              # traffic over BOTH
+        while time.time() < deadline and len(results) < served + 20:
+            time.sleep(0.05)
+        stop.set()
+        for t in clients:
+            t.join(timeout=30)
+        assert len(baselines) == 2
+        assert results and all(s == 200 for s, _ in results), results
+        for port, _base in baselines.values():     # both replicas served
+            assert _scrape_counter(port, "mxnet_serving_requests_total") > 0
+        _assert_no_replica_compiled(fleet, baselines)
+        front.close()
+    finally:
+        stop.set()
+        fleet.close()
 
 
 @pytest.mark.slow
@@ -581,35 +681,14 @@ def test_fleet_acceptance_ramp_kill_drain(tmp_path):
     results = []
     stop = threading.Event()
 
-    def _traffic():
-        while not stop.is_set():
-            t0 = time.perf_counter()
-            try:
-                status, _, _ = _post(
-                    front.url, "/predict",
-                    {"inputs": {"data": [[1.0, 2.0, 3.0, 4.0]]},
-                     "timeout_ms": 20000}, timeout=30)
-            except (OSError, urllib.error.URLError):
-                status = -1
-            results.append((status, time.perf_counter() - t0))
-            time.sleep(0.02)
-
     try:
         fleet.start()
         front = serve_router(fleet.router, port=0)
         baselines = {}
-
-        def _bank_baselines():
-            for rep in fleet.status()["replicas"]:
-                if rep["port"] and rep["name"] not in baselines:
-                    baselines[rep["name"]] = (
-                        rep["port"],
-                        _scrape_counter(
-                            rep["port"],
-                            "mxnet_jit_backend_compile_total"))
-
-        _bank_baselines()
-        threads = [threading.Thread(target=_traffic, daemon=True)
+        _bank_compile_baselines(fleet, baselines)
+        threads = [threading.Thread(target=_predict_until,
+                                    args=(stop, front, results),
+                                    daemon=True)
                    for _ in range(2)]
         for t in threads:
             t.start()
@@ -625,7 +704,7 @@ def test_fleet_acceptance_ramp_kill_drain(tmp_path):
         assert st["live"] == 2 and fleet.target == 2, st
         mid_ramp = [r for r in st["replicas"] if r["name"] != "r1"][0]
         assert mid_ramp["warm"], st                # manifest was present
-        _bank_baselines()
+        _bank_compile_baselines(fleet, baselines)
         sigs["rows"] = []                          # hold (hysteresis)
 
         # ---- SIGKILL the oldest replica under live traffic
@@ -641,7 +720,7 @@ def test_fleet_acceptance_ramp_kill_drain(tmp_path):
             time.sleep(0.1)
         st = fleet.status()
         assert st["live"] == 2 and st["degraded"] is None, st
-        _bank_baselines()
+        _bank_compile_baselines(fleet, baselines)
         time.sleep(0.5)                            # traffic on new fleet
 
         # ---- slack: sustained cold drains back to min (hysteresis
@@ -674,17 +753,7 @@ def test_fleet_acceptance_ramp_kill_drain(tmp_path):
 
         # ---- zero XLA compiles after warmup on EVERY replica that is
         # still up, including the warmset-spawned mid-ramp one
-        for name, (port, base) in baselines.items():
-            if name not in {r["name"] for r in
-                            fleet.status()["replicas"]}:
-                continue                           # killed/retired
-            now_count = _scrape_counter(
-                port, "mxnet_jit_backend_compile_total")
-            assert now_count == base, (name, base, now_count)
-            # and the warm replica really did ride the disk cache
-            if name != "r1":
-                assert _scrape_counter(
-                    port, "mxnet_programs_disk_hits_total") > 0
+        _assert_no_replica_compiled(fleet, baselines)
 
         # ---- the flight ring tells the story post-mortem
         events, _torn = bb.read_events()

@@ -19,6 +19,7 @@ import os
 import struct
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 import urllib.request
@@ -65,6 +66,35 @@ def _mlp_module(batch=16, seed=0):
         label=[mx.nd.array(rng.randint(0, 10, (batch,))
                            .astype(np.float32))])
     return mod, db
+
+
+def _serve_mlp_predictor(feature, hidden, classes):
+    """A bound Predictor over softmax(FC(relu(FC(data)))) — small, so a
+    serving test probes the batching engine and not the matmuls."""
+    from mxnet_tpu.serving import Predictor
+    data = mx.sym.Variable("data")
+    h = mx.sym.Activation(
+        mx.sym.FullyConnected(data, num_hidden=hidden, name="fc1"),
+        act_type="relu")
+    sym = mx.sym.softmax(
+        mx.sym.FullyConnected(h, num_hidden=classes, name="fc2"),
+        name="prob")
+    rng = np.random.RandomState(0)
+    params = {
+        "arg:fc1_weight": mx.nd.array(
+            rng.randn(hidden, feature).astype(np.float32) * 0.05),
+        "arg:fc1_bias": mx.nd.array(np.zeros(hidden, np.float32)),
+        "arg:fc2_weight": mx.nd.array(
+            rng.randn(classes, hidden).astype(np.float32) * 0.05),
+        "arg:fc2_bias": mx.nd.array(np.zeros(classes, np.float32)),
+    }
+    with tempfile.TemporaryDirectory() as d:
+        path = os.path.join(d, "p.params")
+        mx.nd.save(path, params)
+        with open(path, "rb") as f:
+            blob = f.read()
+    return Predictor(sym.tojson(), blob, dev_type=1,
+                     input_shapes={"data": (1, feature)})
 
 
 # ---------------------------------------------------------------------------
@@ -177,11 +207,10 @@ def known_device(monkeypatch):
                          "hbm_bytes_per_s": 1e11})
 
 
-def test_unknown_device_kind_no_gauge_and_bench_raises():
-    """A device_kind the peaks table does not hold gets NO live MFU
-    gauge and is an error in bench code — never another chip's roof."""
+def test_unknown_device_kind_has_no_peak_and_no_gauge():
+    """A device_kind the peaks table does not hold gets NO peak and NO
+    live MFU gauge — never another chip's roof."""
     import jax
-    from mxnet_tpu import benchmark
     assert jax.devices()[0].device_kind not in health.DEVICE_PEAKS
     assert health.device_peaks() is None and health.peak_flops() is None
     assert health._util({"flops": 1e9, "bytes": 1e6}, 0.01) is None
@@ -190,8 +219,6 @@ def test_unknown_device_kind_no_gauge_and_bench_raises():
     assert tm.REGISTRY._families.get("executor/mfu") is None \
         or not tm.REGISTRY._families["executor/mfu"].series()
     assert health.mfu_summary()["peak_flops"] is None
-    with pytest.raises(mx.base.MXNetError, match="no published peak"):
-        benchmark.peak_flops("float32")
     # the one row there is: read off the chip, rates from the published
     # v5e figures
     v5e = health.DEVICE_PEAKS["TPU v5 lite"]
@@ -200,9 +227,8 @@ def test_unknown_device_kind_no_gauge_and_bench_raises():
 
 
 def test_known_device_kind_prices_with_its_row(known_device):
-    from mxnet_tpu import benchmark
-    assert benchmark.peak_flops("float32") == 1e12
-    assert benchmark.peak_flops("int8") == 2e12
+    assert health.peak_flops() == 1e12
+    assert health.peak_hbm_bytes_per_s() == 1e11
     mfu, bw = health._util({"flops": 1e9, "bytes": 1e6}, 0.01)
     assert mfu == pytest.approx(0.1) and bw == pytest.approx(1e-3)
 
@@ -234,17 +260,7 @@ def test_capture_cost_unknown_kind_raises():
 
 def test_serve_bucket_mfu_under_traffic(known_device):
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
-    from mxnet_tpu.serving import Predictor
-    from mxnet_tpu.benchmark import _serve_mlp_symbol
-    import tempfile
-    sym, params = _serve_mlp_symbol(32, 32, 8)
-    with tempfile.TemporaryDirectory() as d:
-        path = os.path.join(d, "p.params")
-        mx.nd.save(path, params)
-        with open(path, "rb") as f:
-            blob = f.read()
-    pred = Predictor(sym.tojson(), blob, dev_type=1,
-                     input_shapes={"data": (1, 32)})
+    pred = _serve_mlp_predictor(32, 32, 8)
     eng = InferenceEngine(pred, ServeConfig(max_batch=4, workers=1,
                                             batch_wait_ms=0))
     eng.start().warmup()
@@ -263,20 +279,10 @@ def test_concurrent_engines_price_batches_with_own_costs():
     share one global bucket cost record: each prices its batches with
     ITS program's FLOPs."""
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
-    from mxnet_tpu.serving import Predictor
-    from mxnet_tpu.benchmark import _serve_mlp_symbol
-    import tempfile
     engines = []
     try:
         for hidden in (16, 64):          # different-size models
-            sym, params = _serve_mlp_symbol(16, hidden, 4)
-            with tempfile.TemporaryDirectory() as d:
-                path = os.path.join(d, "p.params")
-                mx.nd.save(path, params)
-                with open(path, "rb") as f:
-                    blob = f.read()
-            pred = Predictor(sym.tojson(), blob, dev_type=1,
-                             input_shapes={"data": (1, 16)})
+            pred = _serve_mlp_predictor(16, hidden, 4)
             eng = InferenceEngine(pred, ServeConfig(max_batch=2,
                                                     workers=1,
                                                     batch_wait_ms=0))
@@ -594,17 +600,7 @@ def test_acceptance_alerts_fire_and_clear_under_slow_compute():
     injected slow-compute fault and clears after recovery — through a
     real InferenceEngine and the HTTP endpoint."""
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
-    from mxnet_tpu.serving import Predictor
-    from mxnet_tpu.benchmark import _serve_mlp_symbol
-    import tempfile
-    sym, params = _serve_mlp_symbol(32, 32, 8)
-    with tempfile.TemporaryDirectory() as d:
-        path = os.path.join(d, "p.params")
-        mx.nd.save(path, params)
-        with open(path, "rb") as f:
-            blob = f.read()
-    pred = Predictor(sym.tojson(), blob, dev_type=1,
-                     input_shapes={"data": (1, 32)})
+    pred = _serve_mlp_predictor(32, 32, 8)
     eng = InferenceEngine(pred, ServeConfig(max_batch=4, workers=1,
                                             batch_wait_ms=0,
                                             default_timeout_ms=20000))
@@ -664,17 +660,7 @@ def test_acceptance_alerts_fire_and_clear_under_slow_compute():
 def test_alerts_endpoint_on_serve_http():
     """The serving frontend mounts the SAME /alerts implementation."""
     from mxnet_tpu.serve import InferenceEngine, ServeConfig, serve_http
-    from mxnet_tpu.serving import Predictor
-    from mxnet_tpu.benchmark import _serve_mlp_symbol
-    import tempfile
-    sym, params = _serve_mlp_symbol(16, 16, 4)
-    with tempfile.TemporaryDirectory() as d:
-        path = os.path.join(d, "p.params")
-        mx.nd.save(path, params)
-        with open(path, "rb") as f:
-            blob = f.read()
-    pred = Predictor(sym.tojson(), blob, dev_type=1,
-                     input_shapes={"data": (1, 16)})
+    pred = _serve_mlp_predictor(16, 16, 4)
     eng = InferenceEngine(pred, ServeConfig(max_batch=2, workers=1))
     eng.start().warmup()
     srv = serve_http(eng)
@@ -769,24 +755,8 @@ def test_exemplar_expiry_still_enforced(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# satellite: bench wiring
+# satellite: docs drift
 # ---------------------------------------------------------------------------
-
-def test_mfu_divergence_warning_unit():
-    from mxnet_tpu import benchmark as B
-    extra = {"mfu_est": 0.10, "mfu_measured": 0.25}
-    B._note_mfu_divergence(extra)
-    assert "mfu_divergence_warning" in extra
-    assert extra["mfu_measured_vs_est"] == 2.5
-    ok = {"mfu_est": 0.10, "mfu_measured": 0.11}
-    B._note_mfu_divergence(ok)
-    assert "mfu_divergence_warning" not in ok
-
-
-def test_health_overhead_job_registered():
-    from mxnet_tpu import benchmark as B
-    assert "health_overhead" in B.JOBS
-
 
 def test_docs_drift_check_covers_events_and_rules():
     sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
@@ -797,7 +767,7 @@ def test_docs_drift_check_covers_events_and_rules():
     _m, _s, events, rules, endpoints = chk.collect_code_names()
     assert set(blackbox.EVENTS) <= events
     assert {"serve_p99", "numerics", "kv_giveups",
-            "mfu_divergence"} <= rules
+            "badput_fraction"} <= rules
     assert {"/metrics", "/alerts", "/programs"} <= endpoints
     drift = chk.check()
     assert not any(drift.values()), drift

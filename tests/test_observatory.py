@@ -183,9 +183,46 @@ def test_badput_slo_rule_registered():
     assert "badput_fraction" in health.rules()
 
 
-def test_goodput_overhead_job_registered():
-    from mxnet_tpu import benchmark as B
-    assert "goodput_overhead" in B.JOBS
+def test_ledger_adds_no_device_dispatch():
+    """The ledger is host arithmetic: a fused-step loop under the fit
+    loop's hooks makes the same number of dispatches (and compiles
+    nothing) whether the ledger is on or gated off."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu.context import current_context
+    from mxnet_tpu.io import DataBatch
+    from mxnet_tpu.models import mlp
+    from mxnet_tpu.module import Module
+    mod = Module(mlp(), context=current_context())
+    mod.bind(data_shapes=[("data", (8, 784))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params()
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.05,
+                                         "momentum": 0.9})
+    rng = np.random.RandomState(0)
+    db = DataBatch(
+        data=[mx.nd.array(rng.randn(8, 784).astype(np.float32))],
+        label=[mx.nd.array(rng.randint(0, 10, (8,)).astype(np.float32))])
+
+    def loop(on, steps=5):
+        gp.enable(on)
+        if on and not gp.active():
+            gp.session_begin()
+        snap0 = tm.snapshot()
+        for _ in range(steps):
+            tok = gp.step_begin()
+            mod.forward_backward(db)
+            mod.update()
+            gp.step_end(tok)
+        snap1 = tm.snapshot()
+        return (snap1["op_dispatch_total"] - snap0["op_dispatch_total"],
+                snap1["backend_compile_total"]
+                - snap0["backend_compile_total"])
+
+    loop(False), loop(True)              # warm both gate states
+    off, on = loop(False), loop(True)
+    assert off[0] > 0 and on == off and on[1] == 0
 
 
 def test_goodput_gauges_exported():

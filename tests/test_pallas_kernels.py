@@ -1,7 +1,7 @@
 """Pallas hot-path burn-down: interpret-mode parity for the PR-17
 kernels (flash prefill attention with fused page write, fused
 SGD/Adam optimizer update, int8 im2col conv) plus the kernel-contract
-lint and the kernel_burn_down bench job.
+lint and the warmed-dispatch compile gate.
 
 Every kernel under ops/pallas/ is pinned to its pure-lax twin
 (PALLAS_KERNELS registry): the Pallas interpreter result must match
@@ -350,7 +350,7 @@ def test_quantized_conv_int8_op_im2col_route(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# kernel contract lint + bench job + prefill variant tag
+# kernel contract lint + warmed-dispatch gate + prefill variant tag
 # ---------------------------------------------------------------------------
 
 def test_kernel_contract_lint():
@@ -363,10 +363,41 @@ def test_kernel_contract_lint():
     assert all(not v for v in drift.values()), drift
 
 
-def test_kernel_burn_down_job_registered():
-    from mxnet_tpu import benchmark
-    assert "kernel_burn_down" in benchmark.JOBS
-    assert callable(benchmark.kernel_burn_down)
+def _warmed_dispatch_case(kernel):
+    """(production dispatch, its arguments) of one burned-down kernel."""
+    from mxnet_tpu.optimizer import _adam_fused_pallas, _sgd_fused_pallas
+    if kernel == "flash_prefill_paged":
+        return flash_prefill_paged, _prefill_case(3, 2, 32, 4, 2, 16, 8, 11)
+    if kernel == "sgd_fused_update":
+        w, g, m = _wg(13, (64, 33))
+        return (lambda w, g, m: _sgd_fused_pallas(w, g, (m,), _SGD_H),
+                (w, g, m))
+    if kernel == "adam_fused_update":
+        w, g, mean = _wg(14, (64, 33))
+        return (lambda w, g, m, v: _adam_fused_pallas(w, g, (m, v), _ADAM_H),
+                (w, g, mean, jnp.abs(mean)))
+    q, wq, scale = _conv_case(15, 2, 3, 8, 4, (3, 3, 3))
+    return (lambda q, wq, scale: int8_conv_im2col(
+        q, wq, scale, (1, 1), (1, 1), (1, 1), 1), (q, wq, scale))
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_prefill_paged", "sgd_fused_update", "adam_fused_update",
+    "int8_conv_im2col"])
+def test_warmed_pallas_dispatch_compiles_nothing(kernel):
+    """A kernel's production dispatch, jitted and called once, leaks no
+    counted backend compile into the calls that follow."""
+    from mxnet_tpu import telemetry as tm
+    fn, args = _warmed_dispatch_case(kernel)
+    run = jax.jit(fn)
+    tm.enable(True)                      # installs the compile listener
+    cold = tm.snapshot()["backend_compile_total"]
+    jax.block_until_ready(run(*args))    # compile + execute = warm
+    compiles0 = tm.snapshot()["backend_compile_total"]
+    assert compiles0 > cold              # the counter sees this program
+    for _ in range(3):
+        jax.block_until_ready(run(*args))
+    assert tm.snapshot()["backend_compile_total"] == compiles0
 
 
 def test_prefill_variant_tag_in_program_key():

@@ -13,6 +13,8 @@ run in tier-1, all against ONE tiny shared ladder.
 """
 import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -25,6 +27,7 @@ from mxnet_tpu.serving import Predictor
 
 FEATURE = 4
 CLASSES = 3
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -388,16 +391,79 @@ def test_stats_and_entries_surface():
 # imports + an 8-bucket ladder compile)
 # ---------------------------------------------------------------------------
 
+_COLD_START_DRIVER = r"""
+import hashlib, json, sys
+import numpy as np
+import mxnet_tpu as mx
+from mxnet_tpu import telemetry as tm
+from mxnet_tpu.serve import InferenceEngine, ServeConfig
+from mxnet_tpu.serving import Predictor
+
+params_path, max_batch = sys.argv[1], int(sys.argv[2])
+data = mx.sym.Variable("data")
+h = mx.sym.FullyConnected(data, num_hidden=64, name="fc1")
+h = mx.sym.Activation(h, act_type="relu", name="relu1")
+h = mx.sym.FullyConnected(h, num_hidden=10, name="fc2")
+sym = mx.sym.softmax(h, name="prob")
+rng = np.random.RandomState(7)
+mx.nd.save(params_path, {
+    "arg:fc1_weight": mx.nd.array(
+        (rng.randn(64, 784) * 0.1).astype(np.float32)),
+    "arg:fc1_bias": mx.nd.array(np.zeros(64, np.float32)),
+    "arg:fc2_weight": mx.nd.array(
+        (rng.randn(10, 64) * 0.1).astype(np.float32)),
+    "arg:fc2_bias": mx.nd.array(np.zeros(10, np.float32))})
+with open(params_path, "rb") as f:
+    blob = f.read()
+pred = Predictor(sym.tojson(), blob, input_shapes={"data": (1, 784)})
+eng = InferenceEngine(pred, ServeConfig(max_batch=max_batch, workers=1))
+eng.warmup()
+# bitwise probe: one fixed input through every bucket program
+probe_rng = np.random.RandomState(11)
+h = hashlib.md5()
+for b in eng.config.buckets:
+    x = probe_rng.randn(b, 784).astype(np.float32)
+    outs = eng._bucket_pred(b)._exe.forward(is_train=False, data=x)
+    h.update(outs[0].asnumpy().tobytes())
+snap = tm.snapshot()
+print("COLD_START " + json.dumps({
+    "buckets": len(eng.config.buckets),
+    "compiles": snap["programs_compile_total"],
+    "disk_hits": snap["programs_disk_hits"],
+    "probe_md5": h.hexdigest()}), flush=True)
+"""
+
+
+def _run_driver(source, args, env_extra, marker, timeout=600):
+    """Run ``source`` in a FRESH python process from the repo root (so
+    ``-c`` puts the checkout on sys.path and nothing is written outside
+    it) and parse its ``marker``-prefixed JSON line."""
+    env = dict(os.environ)
+    env.update(env_extra)
+    r = subprocess.run([sys.executable, "-c", source] + list(args),
+                       capture_output=True, text=True, timeout=timeout,
+                       cwd=REPO_ROOT, env=env)
+    for line in reversed((r.stdout or "").splitlines()):
+        if line.startswith(marker + " "):
+            return json.loads(line[len(marker) + 1:])
+    raise RuntimeError(
+        "driver produced no %s line (rc %d): %s" % (
+            marker, r.returncode, (r.stderr or "")[-800:]))
+
+
 @pytest.mark.slow
-def test_cold_start_fresh_process():
-    """Second warmup of an 8-bucket ladder in a FRESH process: zero
-    real backend compiles (all disk hits), outputs bitwise-identical.
-    Reuses the cold_start bench driver, which raises on either
-    violation."""
-    from mxnet_tpu.benchmark import cold_start
-    ratio, extra = cold_start()
-    assert extra["warm_compiles"] == 0
-    assert extra["warm_disk_hits"] > 0
-    assert extra["probe_bitwise_identical"]
-    assert extra["buckets"] == 8
-    assert ratio > 0
+def test_cold_start_fresh_process(tmp_path):
+    """Two FRESH processes each build + warm an 8-bucket MLP serve
+    ladder against one shared ``JAX_COMPILATION_CACHE_DIR``: the first
+    compiles and fills the cache and the warm-set manifest; the second's
+    warmup does ZERO real backend compiles (all disk hits) and its
+    outputs are bitwise the cold-compiled replica's."""
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
+           "MXNET_TELEMETRY": "1"}
+    args = [str(tmp_path / "m.params"), "128"]
+    cold = _run_driver(_COLD_START_DRIVER, args, env, "COLD_START")
+    warm = _run_driver(_COLD_START_DRIVER, args, env, "COLD_START")
+    assert cold["buckets"] == 8 and cold["compiles"] > 0
+    assert warm["compiles"] == 0
+    assert warm["disk_hits"] > 0
+    assert warm["probe_md5"] == cold["probe_md5"]

@@ -1,7 +1,7 @@
 """Telemetry tests: registry semantics, histogram buckets, Prometheus
 rendering, the /metrics + /healthz endpoint over a real socket, jit-cache
 hit/miss movement across cached vs fresh-shape dispatches, and the
-dispatch-overhead bound."""
+registry updates one dispatch makes."""
 import json
 import threading
 import time
@@ -191,36 +191,48 @@ def test_training_loop_populates_families_and_serves():
         srv.close()
 
 
+def _dispatch_series():
+    """Every series of the dispatch path's families: a counter's value,
+    a histogram's observation count."""
+    out = {}
+    for fam in tm.REGISTRY.families():
+        if fam.name.startswith(("op/", "jit/")):
+            for lv, child in fam.series():
+                out[fam.name + fam._label_suffix(lv)] = (
+                    child.count if fam.kind == "histogram" else child.value)
+    return out
+
+
 def test_dispatch_overhead():
-    """Telemetry-enabled dispatch stays close to disabled dispatch. The
-    target is <5%; asserted loosely here because CI wall-clock drifts
-    more than the effect (the standalone dispatch_begin/dispatch_end
-    pair measures ~3us against a multi-10s-of-us dispatch). On/off
-    chunks are interleaved so machine-speed drift hits both equally;
-    benchmark.persist's telemetry snapshots carry the production
-    numbers."""
+    """What telemetry adds to an op dispatch is fixed and bounded: on, a
+    warmed dispatch makes exactly three registry updates (its op's
+    counter, its op's latency histogram, the jit-cache hit counter) and
+    creates no series; off, it makes none. How long three updates take
+    is not asserted: a wall-clock ratio of two loops on a shared sandbox
+    measured the machine's load (it failed under six xdist workers and
+    passed alone)."""
     x = nd.array(np.random.rand(16, 16).astype("float32"))
-    nd.dot(x, x).wait_to_read()          # warm the jit cache
-    prev = tm.enabled()
-
-    def chunk(flag, iters=200):
-        tm.enable(flag)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            nd.dot(x, x)
-        return time.perf_counter() - t0
-
+    prev = tm.enable(True)
     try:
-        chunk(True)                      # warm both paths once
-        chunk(False)
-        on, off = float("inf"), float("inf")
-        for _ in range(6):               # alternate: drift hits both
-            on = min(on, chunk(True))
-            off = min(off, chunk(False))
+        nd.dot(x, x).wait_to_read()      # warm: compile, create the series
+        iters = 200
+
+        def moved(flag):
+            tm.enable(flag)
+            before = _dispatch_series()
+            for _ in range(iters):
+                nd.dot(x, x)
+            after = _dispatch_series()
+            assert set(after) == set(before), "a dispatch created a series"
+            return {k: after[k] - before[k] for k in after
+                    if after[k] != before[k]}
+
+        assert moved(True) == {"op/dispatch_total{op=dot}": iters,
+                               "op/dispatch_seconds{op=dot}": iters,
+                               "jit/cache_hits_total": iters}
+        assert moved(False) == {}
     finally:
         tm.enable(prev)
-    assert on <= off * 1.5 + 1e-3, \
-        "telemetry overhead too high: on=%.4fs off=%.4fs" % (on, off)
 
 
 def test_enable_disable_switch():

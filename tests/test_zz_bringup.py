@@ -90,7 +90,7 @@ def test_import_initialises_no_backend_and_places_the_default_cache():
     env = dict(os.environ)
     env.pop("JAX_COMPILATION_CACHE_DIR")           # tests/conftest.py set it
     r = _run("import mxnet_tpu, mxnet_tpu.serve.fleet, mxnet_tpu.serve.router,"
-             " mxnet_tpu.benchmark, mxnet_tpu.module, mxnet_tpu.gluon,"
+             " mxnet_tpu.module, mxnet_tpu.gluon,"
              " mxnet_tpu.kvstore, mxnet_tpu.parallel, mxnet_tpu.programs\n"
              "import jax\n"
              "from jax._src import xla_bridge\n"
