@@ -125,6 +125,9 @@ class Executor(object):
         self._dp_batch_names = ()
         self._dp_nproc = 1
         self._allreduce_bytes = 0
+        # the look-aside of ``prestage``: name -> (source buffer, placed
+        # array) for the NEXT step's inputs; consumed once, by identity
+        self._prestaged = {}
         if _tm._enabled:
             _tm.counter("executor/bind_total",
                         "Executor binds (graph → buffers)").inc()
@@ -155,6 +158,7 @@ class Executor(object):
         self._dp_mesh = mesh
         self._dp_batch_names = tuple(batch_arg_names)
         self._dp_nproc = mesh_process_count(mesh)
+        self._prestaged = {}            # placed for the layout before
         # the mesh signature is part of every program fingerprint:
         # drop the memos so programs built before the mesh was set
         # can't be confused with their sharded successors (rebuilds
@@ -174,6 +178,24 @@ class Executor(object):
             if arr is not None:
                 arr._set_data(self._dp_place(n, arr._data))
 
+    def _dp_sharding(self, name, data):
+        """The mesh sharding ``data`` is declared to have as argument
+        ``name``: batch args split on dim 0 over 'dp', all else
+        replicated."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        mesh = self._dp_mesh
+        if name not in self._dp_batch_names:
+            return NamedSharding(mesh, P())
+        ndev = mesh.shape["dp"]
+        local_div = (len(mesh.local_devices) if self._dp_nproc > 1
+                     else ndev)
+        if data.ndim == 0 or data.shape[0] % local_div != 0:
+            raise MXNetError(
+                "data-parallel Module: batch dim of %r (shape %s) must "
+                "be divisible by the %d devices"
+                % (name, tuple(data.shape), local_div))
+        return NamedSharding(mesh, P("dp", *([None] * (data.ndim - 1))))
+
     def _dp_place(self, name, data):
         """device_put ``data`` to its declared mesh sharding if it is not
         already there (no-op on the steady-state path).
@@ -185,22 +207,7 @@ class Executor(object):
         (every host already holds the value — replication moves no
         bytes)."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = self._dp_mesh
-        is_batch = name in self._dp_batch_names
-        if is_batch:
-            ndev = mesh.shape["dp"]
-            local_div = (len(mesh.local_devices) if self._dp_nproc > 1
-                         else ndev)
-            if data.ndim == 0 or data.shape[0] % local_div != 0:
-                raise MXNetError(
-                    "data-parallel Module: batch dim of %r (shape %s) must "
-                    "be divisible by the %d devices"
-                    % (name, tuple(data.shape), local_div))
-            spec = P("dp", *([None] * (data.ndim - 1)))
-        else:
-            spec = P()
-        sh = NamedSharding(mesh, spec)
+        sh = self._dp_sharding(name, data)
         if getattr(data, "sharding", None) == sh:
             return data
         if self._dp_nproc == 1:
@@ -208,9 +215,9 @@ class Executor(object):
         from .parallel.mesh import (host_local_value, make_batch_global,
                                     make_replicated_global)
         local = host_local_value(data)      # host/local view to restage
-        if is_batch:
-            return make_batch_global(mesh, local)
-        return make_replicated_global(mesh, local)
+        if name in self._dp_batch_names:
+            return make_batch_global(self._dp_mesh, local)
+        return make_replicated_global(self._dp_mesh, local)
 
     def _place_accum(self, name, value):
         """Place one microbatched train-step input (host-local
@@ -328,46 +335,122 @@ class Executor(object):
                         tgt._set_data(placed)
         return env
 
-    def _stage_input(self, name, value):
-        """Bind one forward/train_step input, committed to this executor's
-        device (and dp-mesh sharding). Host arrays go through
-        jax.device_put to self._ctx — jnp.asarray would land them on
-        JAX's default device and ignore the bound context."""
+    def _place_input(self, name, value):
+        """One forward/train_step input as the array the programs take:
+        committed to this executor's device (and dp-mesh sharding). Host
+        arrays go through jax.device_put to self._ctx — jnp.asarray
+        would land them on JAX's default device and ignore the bound
+        context."""
         import jax
+        if isinstance(value, NDArray):
+            # an iterator's batch lives where its context put it — the
+            # host, by default: this is the ONE H2D copy of the step (a
+            # no-op for a batch already on the device)
+            data = value._data
+        elif isinstance(value, jax.Array):
+            # already on device: cast/move device-side, never via host
+            data = value
+            want = self.arg_dict[name].dtype
+            if data.dtype != want:
+                data = data.astype(want)
+        else:
+            data = _np.asarray(value, dtype=self.arg_dict[name].dtype)
+        if self._dp_mesh is not None:
+            return self._dp_place(name, data)
+        return jax.device_put(data, self._ctx.jax_device())
+
+    @staticmethod
+    def _source(value):
+        """The buffer an input is made from: an NDArray's immutable
+        ``jax.Array`` (every write to the NDArray replaces it), else the
+        value itself."""
+        return value._data if isinstance(value, NDArray) else value
+
+    def _take_prestaged(self, name, source):
+        """The array ``prestage`` placed for ``name``, if it was made
+        from the very buffer now offered and lies where this executor
+        would place it now; else None. The entry goes either way."""
+        held = self._prestaged.pop(name, None)
+        if held is None or held[0] is not source:
+            return None
+        placed = held[1]
+        if self._dp_mesh is not None:
+            if placed.sharding != self._dp_sharding(name, placed):
+                return None
+        elif placed.devices() != {self._ctx.jax_device()}:
+            return None
+        return placed
+
+    def _stage_input(self, name, value):
+        """Bind one forward/train_step input (``_place_input``). The
+        look-aside of ``prestage`` is asked first: an input whose buffer
+        IS the one a ``prestage`` call placed ahead binds that array (a
+        hit: its H2D copy was issued a step ago) and every other one is
+        placed now (a miss: the path of a call nobody prepared). The
+        look-aside's entry for ``name`` is dropped in both cases, so a
+        placed array is bound to one step at most. Returns whether it
+        was a hit."""
         if name not in self.arg_dict:
             raise MXNetError("unknown forward argument %r" % name)
-        if isinstance(value, NDArray):
-            data = value._data
-            if self._dp_mesh is not None:
-                data = self._dp_place(name, data)
+        data = self._take_prestaged(name, self._source(value))
+        hit = data is not None
+        if not hit:
+            data = self._place_input(name, value)
+        if _tm._enabled:
+            if hit:
+                _tm.counter("executor/prestage_hits_total",
+                            "Step inputs bound from the array "
+                            "Executor.prestage placed ahead").inc()
             else:
-                # an iterator's batch lives where its context put it —
-                # the host, by default: this is the ONE H2D copy of the
-                # step (a no-op for a batch already on the device)
-                data = jax.device_put(data, self._ctx.jax_device())
-        else:
-            if isinstance(value, jax.Array):
-                # already on device: cast/move device-side, never via host
-                data = value
-                want = self.arg_dict[name].dtype
-                if data.dtype != want:
-                    data = data.astype(want)
-            else:
-                data = _np.asarray(value, dtype=self.arg_dict[name].dtype)
-            if self._dp_mesh is not None:
-                data = self._dp_place(name, data)
-            else:
-                data = jax.device_put(data, self._ctx.jax_device())
+                _tm.counter("executor/prestage_misses_total",
+                            "Step inputs placed at the call itself "
+                            "(never prepared, or not the prepared "
+                            "buffer)").inc()
         self.arg_dict[name]._set_data(data)
+        return hit
 
     def _stage_inputs(self, feed):
         """Bind a call's inputs under one span: the host's side of the
-        step's H2D copy (``device_put`` returns before the copy lands)."""
+        step's H2D copy (``device_put`` returns before the copy lands).
+        ``prestaged`` on the span: every input came from the
+        look-aside, which is empty after ANY call: what ``prestage``
+        placed is for the call right after it, or for none."""
+        if feed:
+            with _tr.child_span("executor.stage_input") as span:
+                hits = [self._stage_input(k, v) for k, v in feed.items()]
+                span.set_attr("prestaged", all(hits))
+        self._prestaged = {}
+
+    def prestage(self, feed):
+        """Place the NEXT call's inputs now, while the device still runs
+        this one: ``feed`` as ``forward(**kwargs)`` / ``train_step(feed=)``
+        take it, each input placed exactly as ``_stage_input`` would
+        (same device or mesh sharding, same dtype handling, the same
+        ``executor.stage_input`` span) and kept in a one-call look-aside
+        ``{name: (source, placed)}``. Nothing bound changes: not
+        ``arg_dict``, not ``outputs``, no program runs.
+
+        ``source`` is the immutable ``jax.Array`` the input was made
+        from (an NDArray's ``_data``: writing to the NDArray replaces
+        it, so a batch written to after this call no longer matches).
+        An input that is neither (a numpy array can be written in
+        place, so its identity proves nothing) is left to the call.
+        A second ``prestage`` replaces the first."""
+        import jax
+        self._prestaged = {}
         if not feed:
             return
-        with _tr.child_span("executor.stage_input"):
-            for k, v in feed.items():
-                self._stage_input(k, v)
+        held = {}
+        with _tr.child_span("executor.stage_input", attrs={"ahead": True}):
+            for name, value in feed.items():
+                source = self._source(value)
+                if name in self.arg_dict and isinstance(source, jax.Array):
+                    held[name] = (source, self._place_input(name, value))
+        self._prestaged = held
+
+    def drop_prestaged(self):
+        """Empty the look-aside (the module's shapes changed)."""
+        self._prestaged = {}
 
     def forward(self, is_train=False, **kwargs):
         """Run the compiled forward program

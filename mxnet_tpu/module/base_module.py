@@ -375,6 +375,10 @@ class BaseModule(object):
                         eval_name_vals = eval_metric.get_name_value()
                         try:
                             next_data_batch = next(data_iter)
+                            # the epoch's first batch is prepared where
+                            # it is fetched, as every later one is
+                            self.prepare(next_data_batch,
+                                         sparse_row_id_fn=sparse_row_id_fn)
                         except StopIteration:
                             end_of_batch = True
                         while not end_of_batch:
@@ -421,22 +425,12 @@ class BaseModule(object):
                                             epoch, nbatch + 1,
                                             save_optimizer_states, train_data)
                                     raise
-                                with _tr.child_span("train.update_metric"):
-                                    if isinstance(data_batch, list):
-                                        self.update_metric(
-                                            eval_metric,
-                                            [db.label for db in data_batch],
-                                            pre_sliced=True)
-                                    else:
-                                        self.update_metric(eval_metric,
-                                                           data_batch.label)
-                                if _elastic is not None:
-                                    # the metric sync above proved the
-                                    # step's arrays are materialized:
-                                    # vote it completed and refresh the
-                                    # host param mirror survivors would
-                                    # restore from
-                                    _elastic.note_step(epoch, nbatch + 1)
+                                # batch N+1 is fetched and handed to
+                                # prepare() HERE, behind step N's dispatch
+                                # and before the metric below waits on the
+                                # device: its H2D copy then runs beside the
+                                # step (Module.prepare). data_batch stays
+                                # step N's until the loop's top
                                 fetched = None
                                 with _tr.child_span("train.data_wait"):
                                     _gp_dw = time.perf_counter()
@@ -453,6 +447,22 @@ class BaseModule(object):
                                             sparse_row_id_fn=sparse_row_id_fn)
                                     except StopIteration:
                                         end_of_batch = True
+                                with _tr.child_span("train.update_metric"):
+                                    if isinstance(data_batch, list):
+                                        self.update_metric(
+                                            eval_metric,
+                                            [db.label for db in data_batch],
+                                            pre_sliced=True)
+                                    else:
+                                        self.update_metric(eval_metric,
+                                                           data_batch.label)
+                                if _elastic is not None:
+                                    # the metric sync above proved the
+                                    # step's arrays are materialized:
+                                    # vote it completed and refresh the
+                                    # host param mirror survivors would
+                                    # restore from
+                                    _elastic.note_step(epoch, nbatch + 1)
                                 _gp.step_end(_gp_tok, data_wait_s=_gp_dw)
                                 if monitor is not None:
                                     monitor.toc_print()
@@ -674,8 +684,15 @@ class BaseModule(object):
 
     # -- computation -------------------------------------------------------
     def prepare(self, data_batch, sparse_row_id_fn=None):
-        """Prepare for processing a batch (row-sparse pull hook in the
-        reference; no-op here)."""
+        """Prepare for processing ``data_batch``, the batch of the NEXT
+        step (row-sparse pull hook in the reference). ``fit`` calls it
+        as soon as that batch is fetched: for an epoch's first batch
+        before the first step, after that right behind step N's
+        ``update()`` and BEFORE ``update_metric`` syncs on step N, so
+        what it starts runs beside the device's work. ``Module`` places
+        the batch on the device(s) there; a no-op here. It must not
+        change what ``get_outputs()`` returns: step N's outputs are
+        read after it."""
 
     def _flush_numerics(self):
         """Drain the bound executor's deferred numerics sentinel (the

@@ -203,6 +203,9 @@ class BucketingModule(BaseModule):
         data_shapes = data_batch.provide_data
         label_shapes = data_batch.provide_label
         self.switch_bucket(bucket_key, data_shapes, label_shapes)
+        # the batch's own bucket pre-stages it (Module.prepare)
+        self._curr_module.prepare(data_batch,
+                                  sparse_row_id_fn=sparse_row_id_fn)
         self.switch_bucket(original_bucket_key, None, None)
 
     def forward(self, data_batch, is_train=None):

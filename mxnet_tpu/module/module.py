@@ -480,11 +480,27 @@ class Module(BaseModule):
                 return False
         return True
 
+    def prepare(self, data_batch, sparse_row_id_fn=None):
+        """Put the NEXT step's batch on the device(s) ahead of the step
+        (``fit`` calls this right after step N was dispatched, with
+        batch N+1). Where that step will run fused, its inputs go to
+        ``Executor.prestage``: placed now, bound by the step itself if
+        it is handed the very same buffers (docs/input_pipeline.md).
+        Otherwise (a list of batches, the unfused path, elastic
+        gradient accumulation) nothing happens, as before. No bound
+        array, no output and no deferred batch is touched."""
+        if (isinstance(data_batch, list) or self._elastic_accum != 1
+                or not self._fused_step_ok()):
+            return
+        self._exec.prestage(self._build_feed(data_batch))
+
     def forward_backward(self, data_batch):
         """Forward + backward; when the fused step is engaged the batch
         is deferred and the whole step (forward, gradients, optimizer
         update) runs as one XLA program inside the following
-        ``update()`` call — outputs become available after it, and the
+        ``update()`` call, which binds the inputs ``prepare`` placed
+        ahead when this is the batch it was given, and stages them
+        itself when not — outputs become available after it, and the
         per-parameter gradient buffers (``_exec.grad_dict``) are NOT
         materialized: gradients exist only inside the program. Reading
         ``get_outputs()`` before ``update()`` replays the batch unfused
@@ -642,6 +658,7 @@ class Module(BaseModule):
         assert self.binded
         self._data_shapes, self._label_shapes = _parse_data_desc(
             self._data_names, self._label_names, data_shapes, label_shapes)
+        self._exec.drop_prestaged()
 
     def borrow_optimizer(self, shared_module):
         assert shared_module.optimizer_initialized

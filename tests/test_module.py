@@ -309,3 +309,313 @@ def test_bf16_end_to_end_convergence():
         total += n_real
     acc = correct / total
     assert acc > 0.9, acc
+
+
+# ---------------------------------------------------------------------------
+# Module.prepare pre-stages the next batch (Executor.prestage): fit against
+# a manual loop over DISTINCT batches, bitwise. test_module_dp.py runs the
+# same helpers over a 4-device dp mesh.
+
+
+class _ListIter(io.DataIter):
+    """The given batches, in order, once an epoch."""
+
+    def __init__(self, batches):
+        super().__init__(batch_size=batches[0].data[0].shape[0])
+        self._batches, self._cur = batches, 0
+        self.provide_data = batches[0].provide_data
+        self.provide_label = batches[0].provide_label
+
+    def reset(self):
+        self._cur = 0
+
+    def next(self):
+        if self._cur >= len(self._batches):
+            raise StopIteration
+        self._cur += 1
+        return self._batches[self._cur - 1]
+
+
+_PS_DIM, _PS_BATCH = 20, 16
+_PS_OPT = {"learning_rate": 0.1, "momentum": 0.9}
+
+
+def _ps_sym(hidden=32):
+    data = mx.sym.Variable("data")
+    fc1 = mx.sym.FullyConnected(data, name="fc1", num_hidden=hidden)
+    act = mx.sym.Activation(fc1, name="relu1", act_type="relu")
+    fc2 = mx.sym.FullyConnected(act, name="fc2", num_hidden=10)
+    return mx.sym.SoftmaxOutput(fc2, name="softmax")
+
+
+def _ps_batches(k, seed=0, dim=_PS_DIM, bucket_keys=None):
+    """k batches, no two alike."""
+    rng = np.random.RandomState(seed)
+    out = []
+    for i in range(k):
+        shape = (_PS_BATCH, dim) if bucket_keys is None \
+            else (_PS_BATCH, bucket_keys[i])
+        out.append(io.DataBatch(
+            data=[mx.nd.array(rng.randn(*shape).astype(np.float32))],
+            label=[mx.nd.array(rng.randint(0, 10, _PS_BATCH)
+                               .astype(np.float32))],
+            bucket_key=None if bucket_keys is None else bucket_keys[i],
+            provide_data=[io.DataDesc("data", shape)],
+            provide_label=[io.DataDesc("softmax_label", (_PS_BATCH,))]))
+    return out
+
+
+def _ps_module(ctx, hidden=32):
+    """Bound, seeded, optimizer ready: two of these start bitwise alike."""
+    mod = Module(_ps_sym(hidden), context=ctx)
+    mod.bind(data_shapes=[("data", (_PS_BATCH, _PS_DIM))],
+             label_shapes=[("softmax_label", (_PS_BATCH,))])
+    mod.init_params(mx.init.Xavier())
+    rng = np.random.RandomState(11)
+    args = {n: mx.nd.array(rng.randn(*a.shape).astype(np.float32) * 0.05)
+            for n, a in sorted(mod._exec.arg_dict.items())
+            if n not in ("data", "softmax_label")}
+    mod.set_params(args, {}, allow_missing=True, force_init=True)
+    mod.init_optimizer(optimizer="sgd", optimizer_params=_PS_OPT)
+    return mod
+
+
+def _ps_fit(mod, batches, callback=None):
+    mod.fit(_ListIter(batches), num_epoch=1, optimizer="sgd",
+            optimizer_params=_PS_OPT, batch_end_callback=callback)
+
+
+def _ps_counts():
+    from mxnet_tpu import telemetry as tm
+    return (tm.counter("executor/prestage_hits_total").value,
+            tm.counter("executor/prestage_misses_total").value)
+
+
+def _ps_state(mod):
+    """Parameters and optimizer state, as host arrays."""
+    arg_p, aux_p = mod.get_params()
+    out = dict(("arg:" + k, v.asnumpy()) for k, v in arg_p.items())
+    out.update(("aux:" + k, v.asnumpy()) for k, v in aux_p.items())
+    for i, st in mod._updater.states.items():
+        for j, a in enumerate(mx.optimizer.fused_state_arrays(st)):
+            out["state:%d:%d" % (i, j)] = a.asnumpy()
+    return out
+
+
+def _ps_assert_same_state(a, b):
+    sa, sb = _ps_state(a), _ps_state(b)
+    assert sorted(sa) == sorted(sb) and any(k.startswith("state:")
+                                            for k in sa)
+    for k in sa:
+        assert np.array_equal(sa[k], sb[k]), k
+
+
+def _ps_manual(mod, batches):
+    """forward_backward / update over the batches: never calls prepare."""
+    outs = []
+    for db in batches:
+        mod.forward_backward(db)
+        mod.update()
+        outs.append(mod.get_outputs()[0].asnumpy())
+    return outs
+
+
+def _check_fit_is_the_manual_loop(ctx, k=6):
+    batches = _ps_batches(k)
+    fitted, manual = _ps_module(ctx), _ps_module(ctx)
+    seen = []
+    h0, m0 = _ps_counts()
+    _ps_fit(fitted, batches, lambda p: seen.append(
+        p.locals["self"].get_outputs()[0].asnumpy()))
+    h1, m1 = _ps_counts()
+    want = _ps_manual(manual, batches)
+    h2, m2 = _ps_counts()
+    # every batch of the epoch, the first included, was prepared before
+    # its step: K hits an input, no miss; the manual loop never hits; both
+    # count one put an input a step (docs/observability.md)
+    assert (h1 - h0, m1 - m0) == (2 * k, 0)
+    assert (h2 - h1, m2 - m1) == (0, 2 * k)
+    assert len(seen) == k
+    for got, ref in zip(seen, want):
+        assert np.array_equal(got, ref)
+    assert not np.array_equal(want[0], want[1])
+    _ps_assert_same_state(fitted, manual)
+    assert fitted._exec._prestaged == {}
+    return fitted
+
+
+def test_prestage_fit_is_bitwise_the_manual_loop():
+    _check_fit_is_the_manual_loop(mx.cpu())
+
+
+class _StopFit(Exception):
+    pass
+
+
+def _check_forward_after_fit_was_cut(ctx, hidden):
+    """The benchmark's sequence: a callback ends fit (the next batch is
+    pre-staged by then), then the driver forwards a batch of its own."""
+    from mxnet_tpu import programs
+    batches = _ps_batches(6)
+    other = _ps_batches(1, seed=99)[0]
+    mod = _ps_module(ctx, hidden)
+
+    def stop(param):
+        if param.nbatch == 2:
+            raise _StopFit()
+
+    with pytest.raises(_StopFit):
+        _ps_fit(mod, batches, stop)
+    exe = mod._exec
+    # batch 3 was placed for a step that never ran, and nothing is deferred
+    assert sorted(exe._prestaged) == ["data", "softmax_label"]
+    assert exe._prestaged["data"][0] is batches[3].data[0]._data
+    assert mod._fused_batch is None
+    fresh = _ps_module(ctx, hidden)
+    fresh.set_params(*mod.get_params(), force_init=True)
+
+    def kinds():
+        out = {}
+        for rec in programs.entries().values():
+            out[rec["kind"]] = out.get(rec["kind"], 0) + 1
+        return out
+
+    before = kinds()
+    h0, m0 = _ps_counts()
+    for is_train in (False, True):
+        mod.forward(other, is_train=is_train)
+        got = mod.get_outputs()[0].asnumpy()
+        assert exe._prestaged == {}
+        fresh.forward(other, is_train=is_train)
+        assert np.array_equal(got, fresh.get_outputs()[0].asnumpy())
+    assert _ps_counts() == (h0, m0 + 8)          # two inputs, four calls
+    after = kinds()
+    # the two forward programs and nothing else: no backward (the unfused
+    # replay of a deferred batch), no second fused step
+    assert after.get("executor_forward", 0) \
+        - before.get("executor_forward", 0) <= 2
+    for kind in ("executor_vjp", "fused_step"):
+        assert after.get(kind, 0) == before.get(kind, 0), kind
+    assert exe.arg_dict["data"]._data is not batches[3].data[0]._data
+    assert np.array_equal(exe.arg_dict["data"].asnumpy(),
+                          other.data[0].asnumpy())
+
+
+def test_prestage_forward_after_fit_was_cut_sees_its_own_batch():
+    _check_forward_after_fit_was_cut(mx.cpu(), hidden=37)
+
+
+def _check_batch_written_after_prepare(ctx):
+    first, new = _ps_batches(2, seed=5)
+    mod, ref = _ps_module(ctx), _ps_module(ctx)
+    mod.prepare(first)
+    first.data[0][:] = new.data[0]           # in place: a new buffer
+    h0, m0 = _ps_counts()
+    mod.forward_backward(first)
+    mod.update()
+    # the data is placed anew (its prepared copy holds the old values),
+    # the untouched label is bound from the look-aside
+    assert _ps_counts() == (h0 + 1, m0 + 1)
+    ref.forward_backward(io.DataBatch(data=new.data, label=first.label))
+    ref.update()
+    assert np.array_equal(mod.get_outputs()[0].asnumpy(),
+                          ref.get_outputs()[0].asnumpy())
+    _ps_assert_same_state(mod, ref)
+
+
+def test_prestage_batch_written_after_prepare_misses():
+    _check_batch_written_after_prepare(mx.cpu())
+
+
+def test_prestage_callbacks_see_the_steps_own_batch_and_outputs():
+    """Inside a callback ``data_batch`` is still step N's (batch N+1 is
+    fetched and prepared by then) and ``get_outputs()`` is step N's,
+    without a compile: the unfused replay did not run."""
+    from mxnet_tpu import telemetry as tm
+    batches = _ps_batches(6)
+    want = _ps_manual(_ps_module(mx.cpu()), batches)
+    mod = _ps_module(mx.cpu())
+    compiles = []
+
+    def watch(param):
+        assert param.locals["data_batch"] is batches[param.nbatch]
+        if param.nbatch + 1 < len(batches):
+            assert param.locals["next_data_batch"] \
+                is batches[param.nbatch + 1]
+        before = tm.compile_count()
+        got = param.locals["self"].get_outputs()[0].asnumpy()
+        assert tm.compile_count() == before
+        assert np.array_equal(got, want[param.nbatch])
+        compiles.append(tm.compile_count())
+
+    _ps_fit(mod, batches, watch)
+    assert len(compiles) == len(batches)
+    assert len(set(compiles[1:])) == 1       # the warm steps compile nothing
+
+
+def test_prestage_reshape_between_prepare_and_step_misses():
+    batch, = _ps_batches(1, seed=7)
+    mod, ref = _ps_module(mx.cpu()), _ps_module(mx.cpu())
+    mod.prepare(batch)
+    assert sorted(mod._exec._prestaged) == ["data", "softmax_label"]
+    mod.reshape(batch.provide_data, batch.provide_label)
+    assert mod._exec._prestaged == {}
+    h0, m0 = _ps_counts()
+    mod.forward_backward(batch)
+    mod.update()
+    assert _ps_counts() == (h0, m0 + 2)
+    _ps_manual(ref, [batch])
+    _ps_assert_same_state(mod, ref)
+    data = mod._exec.arg_dict["data"]._data
+    assert data.committed and data.devices() == {mx.cpu().jax_device()}
+
+
+def test_prestage_across_bucket_switches():
+    """BucketingModule.prepare binds the next batch's bucket, lets that
+    bucket's module pre-stage it, and switches back: the metric and the
+    callbacks still read the step that ran."""
+    keys = [16, 8, 8, 16, 8, 16]
+
+    def sym_gen(seq_len):
+        data = mx.sym.Variable("data")
+        pooled = mx.sym.mean(data, axis=1, keepdims=True)
+        fc = mx.sym.FullyConnected(pooled, num_hidden=10, name="fc")
+        return (mx.sym.SoftmaxOutput(fc, name="softmax"), ("data",),
+                ("softmax_label",))
+
+    def module():
+        mod = mx.module.BucketingModule(sym_gen, default_bucket_key=16,
+                                        context=mx.cpu())
+        mod.bind(data_shapes=[("data", (_PS_BATCH, 16))],
+                 label_shapes=[("softmax_label", (_PS_BATCH,))])
+        mod.init_params(mx.init.Xavier())
+        mod.set_params({"fc_weight": mx.nd.array(np.full((10, 1), 0.05,
+                                                         np.float32)),
+                        "fc_bias": mx.nd.zeros((10,))}, {},
+                       force_init=True)
+        mod.init_optimizer(optimizer="sgd", optimizer_params=_PS_OPT)
+        return mod
+
+    batches = _ps_batches(len(keys), seed=3, bucket_keys=keys)
+    fitted, manual = module(), module()
+    seen = []
+    h0, m0 = _ps_counts()
+
+    def watch(param):
+        me = param.locals["self"]
+        assert me._curr_bucket_key == keys[param.nbatch]
+        seen.append(me.get_outputs()[0].asnumpy())
+
+    _ps_fit(fitted, batches, watch)
+    h1, m1 = _ps_counts()
+    assert (h1 - h0, m1 - m0) == (2 * len(keys), 0)
+    want = []
+    for db in batches:
+        manual.forward_backward(db)
+        manual.update()
+        want.append(manual.get_outputs()[0].asnumpy())
+    for got, ref in zip(seen, want):
+        assert np.array_equal(got, ref)
+    pa, pb = fitted.get_params()[0], manual.get_params()[0]
+    for k in pa:
+        assert np.array_equal(pa[k].asnumpy(), pb[k].asnumpy()), k

@@ -236,3 +236,86 @@ def test_executor_manager_bucketed_updates_propagate():
             assert not np.allclose(w_now, w_before), \
                 "updates lost across bucket switch"
         w_before = w_now
+
+
+# ---------------------------------------------------------------------------
+# Module.prepare pre-stages the next batch over the dp mesh (the helpers and
+# their one-device twins: tests/test_module.py)
+
+from test_module import (_check_batch_written_after_prepare,     # noqa: E402
+                         _check_fit_is_the_manual_loop,
+                         _check_forward_after_fit_was_cut, _ps_batches,
+                         _ps_counts, _ps_manual, _ps_module)
+
+
+def _four():
+    return [mx.cpu(i) for i in range(4)]
+
+
+def _dp_sharding(mod, ndim):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return NamedSharding(mod._exec._dp_mesh, P("dp", *([None] * (ndim - 1))))
+
+
+def test_prestage_fit_is_bitwise_the_manual_loop_on_a_dp_mesh():
+    mod = _check_fit_is_the_manual_loop(_four())
+    data = mod._exec.arg_dict["data"]._data
+    assert data.sharding == _dp_sharding(mod, 2)
+    assert len(data.devices()) == 4 and data.committed
+
+
+def test_prestage_forward_after_fit_was_cut_on_a_dp_mesh():
+    _check_forward_after_fit_was_cut(_four(), hidden=41)
+
+
+def test_prestage_batch_written_after_prepare_misses_on_a_dp_mesh():
+    _check_batch_written_after_prepare(_four())
+
+
+def test_prestage_places_with_the_steps_own_sharding():
+    """What prepare places is what the step would place: the same
+    committed dp sharding, and nothing bound moves before the step."""
+    batch, = _ps_batches(1, seed=2)
+    mod = _ps_module(_four())
+    bound = mod._exec.arg_dict["data"]._data
+    mod.prepare(batch)
+    source, placed = mod._exec._prestaged["data"]
+    assert source is batch.data[0]._data
+    assert placed.sharding == _dp_sharding(mod, 2) and placed.committed
+    assert mod._exec.arg_dict["data"]._data is bound
+    h0, m0 = _ps_counts()
+    mod.forward_backward(batch)
+    mod.update()
+    assert _ps_counts() == (h0 + 2, m0)
+    assert mod._exec.arg_dict["data"]._data is placed
+
+
+def test_prestage_set_dp_mesh_between_prepare_and_step_misses():
+    """A new mesh empties the look-aside; and were an entry left from
+    another layout, its sharding would refuse it."""
+    import jax
+    from jax.sharding import Mesh
+    batch, = _ps_batches(1, seed=4)
+    two = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    names = ["data", "softmax_label"]
+    mod = _ps_module(_four())
+    mod.prepare(batch)
+    held = dict(mod._exec._prestaged)
+    mod._exec.set_dp_mesh(two, names)
+    assert mod._exec._prestaged == {}
+    mod._exec._prestaged = held              # as if it had survived
+    h0, m0 = _ps_counts()
+    mod.forward_backward(batch)
+    mod.update()
+    assert _ps_counts() == (h0, m0 + 2)
+    data = mod._exec.arg_dict["data"]._data
+    assert data.sharding == _dp_sharding(mod, 2)
+    assert data.devices() == set(jax.devices()[:2]) and data.committed
+    ref = _ps_module(_four())
+    ref._exec.set_dp_mesh(two, names)
+    _ps_manual(ref, [batch])
+    assert np.array_equal(mod.get_outputs()[0].asnumpy(),
+                          ref.get_outputs()[0].asnumpy())
+    for k, v in mod.get_params()[0].items():
+        assert np.array_equal(v.asnumpy(),
+                              ref.get_params()[0][k].asnumpy()), k

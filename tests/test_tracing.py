@@ -754,9 +754,12 @@ def test_fit_step_logs_the_eight_training_spans_under_train_step():
     log = tr.span_log()
     roots = [r for r in log if r["name"] == "train.step"]
     assert [r["attrs"]["nbatch"] for r in roots] == seen == [0, 1, 2, 3]
-    last = roots[-1]                    # a warm step: nothing compiles
+    # a warm step that has a successor: nothing compiles, and the next
+    # batch is fetched and pre-staged in it (the epoch's last has none)
+    last = roots[-2]
     step = [r for r in log if r["trace_id"] == last["trace_id"]]
-    assert sorted(r["name"] for r in step) == sorted(TRAIN_SPANS)
+    assert sorted(r["name"] for r in step) \
+        == sorted(list(TRAIN_SPANS) + ["executor.stage_input"])
     by_id = dict((r["span_id"], r) for r in step)
     for r in step:
         up = r
@@ -765,15 +768,35 @@ def test_fit_step_logs_the_eight_training_spans_under_train_step():
         assert up is last
         # children lie inside the root: they tile the step
         assert last["t0"] <= r["t0"] and r["t1"] <= last["t1"]
-    # the deferred fused step runs under the update: stage, then call
-    staged, = [r for r in step if r["name"] == "executor.stage_input"]
+    # the deferred fused step runs under the update: bind what the step
+    # before placed ahead, then call
+    bound, ahead = sorted((r for r in step
+                           if r["name"] == "executor.stage_input"),
+                          key=lambda r: r["t0"])
     call, = [r for r in step if r["name"] == "executor.train_step"]
     update, = [r for r in step if r["name"] == "train.update"]
-    assert staged["parent_id"] == call["parent_id"] == update["span_id"]
-    assert staged["t1"] <= call["t0"]
-    # the root covers the callbacks
+    assert bound["parent_id"] == call["parent_id"] == update["span_id"]
+    assert bound["attrs"] == {"prestaged": True}
+    assert bound["t1"] <= call["t0"]
+    # the put for the NEXT step lies behind this step's call, under the
+    # same root, after the data wait and before the metric's sync
+    wait, = [r for r in step if r["name"] == "train.data_wait"]
+    metric, = [r for r in step if r["name"] == "train.update_metric"]
+    assert ahead["parent_id"] == last["span_id"]
+    assert ahead["attrs"] == {"ahead": True}
+    assert call["t1"] <= update["t1"] <= wait["t0"]
+    assert wait["t1"] <= ahead["t0"] and ahead["t1"] <= metric["t0"]
+    # the root covers the callbacks, and there is exactly one span of them
     cb, = [r for r in step if r["name"] == "train.callbacks"]
-    assert last["t0"] < cb["t0"] and cb["t1"] <= last["t1"]
+    assert metric["t1"] <= cb["t0"] and cb["t1"] <= last["t1"]
+    # the epoch's last step stages nothing ahead; its first bound what
+    # was prepared where the batch was fetched, outside any step
+    final = [r for r in log if r["trace_id"] == roots[-1]["trace_id"]]
+    assert sorted(r["name"] for r in final) == sorted(TRAIN_SPANS)
+    first, = [r for r in log if r["trace_id"] == roots[0]["trace_id"]
+              and r["name"] == "executor.stage_input"
+              and "prestaged" in r["attrs"]]
+    assert first["attrs"]["prestaged"] is True
 
 
 def test_callback_exception_closes_step_and_callbacks_spans():
