@@ -311,6 +311,7 @@ def kernels_phase():
     fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
     i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
     mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
+    ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
     rng = np.random.RandomState(0)
     # on the chip: interpret=None, the production default, which must
     # resolve to Mosaic (asserted per kernel through _mosaic_in); in the
@@ -544,6 +545,62 @@ def kernels_phase():
                  r, gs, a, b_, c, t, interpret=interp, lead=(0, 1))[:u],
              twin, (rows, sizes, wg, wu, wd), 2e-2)
 
+    # -- ... with f TILED (an expert of 7168 x 2048 does not fit VMEM) and
+    #    a SiLU gate: 16 held experts, a decode step's rows, layer 1 of 2
+    n, d, f, e, tile = (24, 32, 256, 3, 16) if TINY else \
+        (64, 7168, 2048, 16, 128)
+    dt = jnp.float32 if TINY else jnp.bfloat16
+    wg, wu = (jnp.asarray(rng.randn(1, 2, e, d, f) * 0.02, dt)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(1, 2, e, f, d) * 0.02, dt)
+    chosen = jnp.asarray(rng.randint(0, e + 5, (n, 8)), jnp.int32)
+    src, _dest, sizes, _counts = sorted_dispatch(chosen, e, tile, first=2)
+    rows = jnp.asarray(rng.randn(n, d), dt)[src]
+    used = int(sizes.sum())
+
+    def tiled_twin(r, gs, a, b_, c, u=used):
+        with jax.default_matmul_precision("bfloat16"):
+            return mf._moe_grouped_ffn_xla(r, gs, a, b_, c, (0, 1),
+                                           "silu")[:u]
+
+    case("moe_grouped_ffn[f-tiled,silu,n%dtile%d]" % (n, tile),
+         lambda r, gs, a, b_, c, u=used: mf.moe_grouped_ffn(
+             r, gs, a, b_, c, tile, interpret=interp, lead=(0, 1),
+             act="silu", tf=128 if TINY else None)[:u],
+         tiled_twin, (rows, sizes, wg, wu, wd), 2e-2)
+
+    # -- latent attention (MLA): the absorbed decode over a whole paged
+    #    pool by layer index, and the decompressed causal prefill
+    b, heads, rank, rope, ps, entries, layers = (3, 4, 128, 64, 8, 4, 2) \
+        if TINY else (16, 128, 512, 64, 512, 16, 3)
+    q = jnp.asarray(rng.randn(b, heads, rank + rope), dt)
+    pages = jnp.asarray(rng.randn(*ml.latent_pool_shape(
+        layers, b * entries + 1, ps, rank + rope)), dt)
+    bt = jnp.asarray(1 + rng.permutation(b * entries).reshape(b, entries),
+                     jnp.int32)
+    ln = jnp.asarray(rng.randint(1, entries * ps, size=(b,)), jnp.int32)
+    scale = (128 + rope) ** -0.5 * 1.874
+    case("mla_paged_decode[layer%dof%d,b%dh%d,page%d]"
+         % (layers - 1, layers, b, heads, ps),
+         lambda q, pages, bt, ln: ml.mla_paged_decode(
+             q, pages, bt, ln, scale, rank, interpret=interp,
+             layer=layers - 1),
+         lambda q, pages, bt, ln: ml._mla_paged_decode_xla(
+             q, pages, bt, ln, scale, rank, layers - 1),
+         (q, pages, bt, ln), 2e-2)
+    b, heads, s, dn, dv = (2, 3, 32, 16, 16) if TINY else (1, 16, 2048, 128,
+                                                           128)
+    rope = 8 if TINY else 64
+    arr = lambda *shape: jnp.asarray(rng.randn(*shape), dt)
+    case("mla_flash_prefill[b%dh%ds%d]" % (b, heads, s),
+         lambda *a: ml.mla_flash_prefill(
+             *a, scale, interpret=interp, block_q=8 if TINY else 512,
+             block_k=8 if TINY else 512),
+         lambda *a: ml._mla_flash_prefill_xla(*a, scale),
+         (arr(b, heads, s, dn), arr(b, heads, s, rope),
+          arr(b, heads, s, dn), arr(b, s, rope), arr(b, heads, s, dv)),
+         2e-2)
+
     # -- int8 matmul + im2col conv: ResNet-50's FC and its first 3x3 conv
     mm = [(8, 40, 12)] if TINY else [(32, 2048, 1000),
                                     (32 * 56 * 56, 576, 64)]
@@ -566,7 +623,7 @@ def kernels_phase():
          (qx, wq, sc), 0.0, exact=True)
 
     exported = set()
-    for mod in (fa, fu, i8, mf):
+    for mod in (fa, fu, i8, mf, ml):
         exported.update(mod.PALLAS_KERNELS)
     covered = set(n.split("[")[0] for n in report)
     check(covered == exported, "kernels: exported %s, exercised %s"

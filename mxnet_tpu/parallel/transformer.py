@@ -24,6 +24,7 @@ code runs any slice of the 5D configuration.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 
 import jax
@@ -34,12 +35,13 @@ from jax import shard_map
 
 from ..ops.pallas.flash_attention import causal_mask as _causal_mask, on_tpu
 from .ring_attention import _ring_attention_local
-from .moe import moe_ffn_sorted, top_k_gating
+from .moe import group_limited_routing, moe_ffn_sorted, top_k_gating
 
 __all__ = ["TransformerConfig", "init_transformer_params",
            "make_transformer_train_step", "transformer_forward_single",
            "init_kv_cache", "init_kv_pages", "PagedKVCache",
-           "HybridKVCache", "kv_layer_kinds", "paged_cache", "cache_pools",
+           "HybridKVCache", "LatentKVCache", "kv_layer_kinds", "paged_cache",
+           "cache_pools",
            "transformer_decode_step", "transformer_decode_step_paged",
            "transformer_prefill", "transformer_prefill_paged",
            "transformer_generate"]
@@ -53,6 +55,62 @@ def _kv_heads(cfg):
 
 def _head_dim(cfg):
     return cfg.head_dim or cfg.d_model // cfg.n_heads
+
+
+def _is_mla(cfg):
+    """Latent attention (MLA): the layer caches one compressed vector a
+    token and no per-head K and V."""
+    return bool(cfg.kv_lora_rank)
+
+
+def _drop_free(cfg):
+    """The sorted, drop-free expert layer (any router but "capacity")."""
+    return bool(cfg.num_experts) and cfg.moe_router != "capacity"
+
+
+def _moe_first(cfg):
+    """The first expert this device holds (``moe_local_experts``)."""
+    return int(cfg.moe_local_experts[0]) if cfg.moe_local_experts else 0
+
+
+def _moe_held(cfg):
+    """How many of the router's ``num_experts`` this device holds."""
+    return (int(cfg.moe_local_experts[1]) if cfg.moe_local_experts
+            else cfg.num_experts)
+
+
+def _yarn_mscale(cfg):
+    """YaRN's attention temperature ``0.1 mscale_all_dim ln(factor) + 1``
+    (1 without ``rope_scaling``): the softmax scale carries its square."""
+    rs = cfg.rope_scaling
+    if not rs or rs["factor"] <= 1:
+        return 1.0
+    return 0.1 * rs.get("mscale_all_dim", 0) * math.log(rs["factor"]) + 1.0
+
+
+def _rope_inv_freq(cfg, dim):
+    """(dim / 2,) float32 rotation frequencies: ``rope_base ** (-2i /
+    dim)``, or under ``rope_scaling`` (YaRN, arXiv:2309.00071) the blend
+    of those and their ``factor``-fold interpolation: dimensions that
+    turn more than ``beta_fast`` times over the original length keep
+    theirs, those that turn less than ``beta_slow`` times are
+    interpolated, a linear ramp between."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    freq = cfg.rope_base ** (-2.0 * i / dim)
+    rs = cfg.rope_scaling
+    if not rs:
+        return freq.astype(np.float32)
+
+    def turns_dim(turns):
+        return dim * math.log(rs["original_max_position_embeddings"]
+                              / (turns * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_base))
+
+    low = max(math.floor(turns_dim(rs["beta_fast"])), 0)
+    high = min(math.ceil(turns_dim(rs["beta_slow"])), dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0, 1)
+    return (freq / rs["factor"] * ramp + freq * (1 - ramp)).astype(
+        np.float32)
 
 
 def _layer_rule(cfg, li):
@@ -91,16 +149,62 @@ def _validate_config(cfg):
             raise ValueError("n_heads=%d must divide by n_kv_heads=%d"
                              % (cfg.n_heads, kvh))
     for name, allowed in (("norm", ("layernorm", "rmsnorm")),
-                          ("moe_router", ("capacity", "topk")),
-                          ("moe_router_input", ("ffn", "layer"))):
+                          ("moe_router", ("capacity", "topk", "noaux_tc")),
+                          ("moe_router_input", ("ffn", "layer")),
+                          ("gate_act", ("relu", "silu"))):
         if getattr(cfg, name) not in allowed:
             raise ValueError("%s=%r is not one of %s"
                              % (name, getattr(cfg, name), allowed))
-    if cfg.moe_router == "topk" and not cfg.num_experts:
-        raise ValueError("moe_router='topk' needs num_experts > 0")
+    if cfg.moe_router != "capacity" and not cfg.num_experts:
+        raise ValueError("moe_router=%r needs num_experts > 0"
+                         % cfg.moe_router)
     if cfg.moe_router_input == "layer" and cfg.moe_router != "topk":
         raise ValueError("moe_router_input='layer' needs "
                          "moe_router='topk'")
+    if _is_mla(cfg):
+        for name in ("q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                     "v_head_dim"):
+            if getattr(cfg, name) < 1:
+                raise ValueError("kv_lora_rank=%d (latent attention) "
+                                 "needs %s > 0" % (cfg.kv_lora_rank, name))
+        if cfg.sliding_window is not None or cfg.n_kv_heads is not None:
+            raise ValueError("latent attention has one shared latent and "
+                             "no window: n_kv_heads and sliding_window "
+                             "must be None")
+    rs = cfg.rope_scaling
+    if rs and rs.get("type") != "yarn":
+        raise ValueError("rope_scaling type %r: only 'yarn' is computed"
+                         % (rs.get("type"),))
+    if rs and rs.get("mscale", 1) != rs.get("mscale_all_dim", 0):
+        raise ValueError("rope_scaling mscale=%r != mscale_all_dim=%r: "
+                         "their ratio would scale cos and sin, which is "
+                         "not computed (the published models have them "
+                         "equal)" % (rs.get("mscale", 1),
+                                     rs.get("mscale_all_dim", 0)))
+    if not 0 <= cfg.dense_layers <= cfg.n_layers:
+        raise ValueError("dense_layers=%d of n_layers=%d"
+                         % (cfg.dense_layers, cfg.n_layers))
+    if cfg.dense_layers and not (_drop_free(cfg) and cfg.d_ff_dense > 0):
+        raise ValueError("dense_layers=%d leading gated FFN layers need "
+                         "d_ff_dense > 0 and a drop-free expert layer "
+                         "after them" % cfg.dense_layers)
+    if (cfg.moe_shared_width or cfg.moe_local_experts) \
+            and not _drop_free(cfg):
+        raise ValueError("moe_shared_width / moe_local_experts belong to "
+                         "the drop-free expert layer (moe_router 'topk' or "
+                         "'noaux_tc')")
+    if cfg.moe_router == "noaux_tc" and (
+            cfg.num_experts % cfg.moe_n_groups
+            or not 1 <= cfg.moe_topk_groups <= cfg.moe_n_groups):
+        raise ValueError("noaux_tc: %d experts in %d groups, %d kept"
+                         % (cfg.num_experts, cfg.moe_n_groups,
+                            cfg.moe_topk_groups))
+    if cfg.moe_local_experts and not (
+            0 <= _moe_first(cfg) and _moe_held(cfg) >= 1
+            and _moe_first(cfg) + _moe_held(cfg) <= cfg.num_experts):
+        raise ValueError("moe_local_experts=%r (first, count) is not a "
+                         "run of the %d experts"
+                         % (cfg.moe_local_experts, cfg.num_experts))
     for name in ("window_layout", "rope_layout"):
         layout = getattr(cfg, name)
         if layout is not None and len(layout) < cfg.n_layers:
@@ -113,7 +217,13 @@ def _validate_config(cfg):
 _TRAINABLE = {"head_dim": None, "norm": "layernorm",
               "tie_embeddings": True, "moe_router": "capacity",
               "moe_router_input": "ffn", "sliding_window": None,
-              "window_layout": None, "rope_layout": None}
+              "window_layout": None, "rope_layout": None,
+              "kv_lora_rank": 0, "q_lora_rank": 0, "qk_nope_head_dim": 0,
+              "qk_rope_head_dim": 0, "v_head_dim": 0, "rope_scaling": None,
+              "dense_layers": 0, "d_ff_dense": 0, "gate_act": "relu",
+              "moe_shared_width": 0, "moe_n_groups": 1,
+              "moe_topk_groups": 1, "moe_routed_scale": 1.0,
+              "moe_local_experts": None}
 
 
 def _validate_trainable(cfg):
@@ -191,6 +301,40 @@ class TransformerConfig:
     sliding_window: int = None
     window_layout: tuple = None
     rope_layout: tuple = None
+    # -- latent attention (MLA): kv_lora_rank > 0 turns it on. Queries are
+    # compressed to q_lora_rank, keys and values to kv_lora_rank (what
+    # the cache holds, with ONE rotated key of qk_rope_head_dim for all
+    # heads); a head's key is qk_nope_head_dim + qk_rope_head_dim wide,
+    # its value v_head_dim (n_heads of them; head_dim is not read)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN, as the published config states it: {"type": "yarn", "factor",
+    # "beta_fast", "beta_slow", "original_max_position_embeddings",
+    # "mscale", "mscale_all_dim"} — blended frequencies, and mscale ** 2
+    # on the softmax scale
+    rope_scaling: dict = None
+    # -- two kinds of FFN in one model: the first dense_layers layers have
+    # a dense gated FFN of width d_ff_dense (params["dense_layers"], a
+    # stack of its own), the rest the expert layer (params["layers"])
+    dense_layers: int = 0
+    d_ff_dense: int = 0
+    # the gate of every gated FFN (experts, shared expert, dense_layers)
+    gate_act: str = "relu"        # | "silu"
+    # a shared expert of this width beside the routed ones (0 = none)
+    moe_shared_width: int = 0
+    # moe_router "noaux_tc" (parallel/moe.py:group_limited_routing):
+    # sigmoid scores, a bias in the choice, moe_n_groups groups of which
+    # moe_topk_groups stay, weights normalised and times moe_routed_scale
+    moe_n_groups: int = 1
+    moe_topk_groups: int = 1
+    moe_routed_scale: float = 1.0
+    # (first, count): the experts of the router's num_experts THIS device
+    # holds (None = all). The router scores them all; the layer computes
+    # its own experts' part of the sum (and the shared expert)
+    moe_local_experts: tuple = None
 
 
 # ---------------------------------------------------------------------------
@@ -202,13 +346,27 @@ def _param_specs(cfg, pp):
     lyr = {
         "ln1_g": P("pp", None, None), "ln1_b": P("pp", None, None),
         "ln2_g": P("pp", None, None), "ln2_b": P("pp", None, None),
-        "wq": P("pp", None, None, "tp"), "wk": P("pp", None, None, "tp"),
-        "wv": P("pp", None, None, "tp"), "wo": P("pp", None, "tp", None),
     }
+    if _is_mla(cfg):
+        lyr.update(dict((name, P("pp", None, None, None))
+                        for name in _MLA_MAPS))
+        lyr.update({"q_ln_g": P("pp", None, None),
+                    "kv_ln_g": P("pp", None, None)})
+    else:
+        lyr.update({
+            "wq": P("pp", None, None, "tp"), "wk": P("pp", None, None, "tp"),
+            "wv": P("pp", None, None, "tp"),
+            "wo": P("pp", None, "tp", None)})
+    dense = dict(lyr)                  # a leading dense layer's (below)
     if cfg.num_experts:
         lyr["gate"] = P("pp", None, None, None)
         for name in _expert_names(cfg):
             lyr[name] = P("pp", None, "ep", None, None)
+        if cfg.moe_router == "noaux_tc":
+            lyr["gate_bias"] = P("pp", None, None)
+        if cfg.moe_shared_width:
+            for name in _GATED:
+                lyr["ws_" + name] = P("pp", None, None, None)
     else:
         lyr.update({"w1": P("pp", None, None, "tp"),
                     "w2": P("pp", None, "tp", None)})
@@ -217,9 +375,14 @@ def _param_specs(cfg, pp):
         "lnf_g": P(None,), "lnf_b": P(None,),
         "layers": lyr,
     }
+    if cfg.dense_layers:
+        for name in _GATED:
+            dense["w_" + name] = P("pp", None, None, None)
+        specs["dense_layers"] = dense
     if cfg.norm == "rmsnorm":
-        for name in ("ln1_b", "ln2_b"):
-            del lyr[name]
+        for stack in (lyr, dense):
+            for name in ("ln1_b", "ln2_b"):
+                del stack[name]
         del specs["lnf_b"]
     if not cfg.tie_embeddings:
         specs["head"] = P(None, None)
@@ -228,10 +391,14 @@ def _param_specs(cfg, pp):
     return specs
 
 
+_MLA_MAPS = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+_GATED = ("gate", "up", "down")        # the three maps of a gated FFN
+
+
 def _expert_names(cfg):
     """The stacked expert maps of a layer: gate, up and down of the
-    drop-free router's gated experts, or the two of a GELU one."""
-    return (("we_gate", "we_up", "we_down") if cfg.moe_router == "topk"
+    drop-free routers' gated experts, or the two of a GELU one."""
+    return (("we_gate", "we_up", "we_down") if _drop_free(cfg)
             else ("we1", "we2"))
 
 
@@ -243,8 +410,10 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     """
     _validate_config(cfg)
     pp = mesh.shape.get("pp", 1)
-    assert cfg.n_layers % pp == 0, "n_layers must divide pp"
-    lps = cfg.n_layers // pp
+    assert not cfg.dense_layers or pp == 1, "dense_layers needs pp == 1"
+    assert (cfg.n_layers - cfg.dense_layers) % pp == 0, \
+        "n_layers must divide pp"
+    lps = (cfg.n_layers - cfg.dense_layers) // pp
     rng = np.random.RandomState(seed)
     d, f, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
     dq = cfg.n_heads * _head_dim(cfg)
@@ -254,22 +423,46 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     def rand(*shape):
         return jnp.asarray(rng.randn(*shape) * s, cfg.dtype)
 
-    layers = {
-        "ln1_g": jnp.ones((pp, lps, d), cfg.dtype),
-        "ln1_b": jnp.zeros((pp, lps, d), cfg.dtype),
-        "ln2_g": jnp.ones((pp, lps, d), cfg.dtype),
-        "ln2_b": jnp.zeros((pp, lps, d), cfg.dtype),
-        "wq": rand(pp, lps, d, dq),
-        "wk": rand(pp, lps, d, dkv),
-        "wv": rand(pp, lps, d, dkv),
-        "wo": rand(pp, lps, dq, d),
-    }
-    if cfg.num_experts:
+    def gated(prefix, n, width, *lead):
+        return {prefix + "gate": rand(pp, n, *lead, d, width),
+                prefix + "up": rand(pp, n, *lead, d, width),
+                prefix + "down": rand(pp, n, *lead, width, d)}
+
+    def block(n):
+        """Norms and attention maps of a stack of ``n`` layers."""
+        out = {"ln1_g": jnp.ones((pp, n, d), cfg.dtype),
+               "ln1_b": jnp.zeros((pp, n, d), cfg.dtype),
+               "ln2_g": jnp.ones((pp, n, d), cfg.dtype),
+               "ln2_b": jnp.zeros((pp, n, d), cfg.dtype)}
+        if _is_mla(cfg):
+            rq, rkv, nh = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_heads
+            dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+            out.update({
+                "wq_a": rand(pp, n, d, rq),
+                "q_ln_g": jnp.ones((pp, n, rq), cfg.dtype),
+                "wq_b": rand(pp, n, rq, nh * (dn + dr)),
+                "wkv_a": rand(pp, n, d, rkv + dr),
+                "kv_ln_g": jnp.ones((pp, n, rkv), cfg.dtype),
+                "wkv_b": rand(pp, n, rkv, nh * (dn + dv)),
+                "wo": rand(pp, n, nh * dv, d)})
+        else:
+            out.update({"wq": rand(pp, n, d, dq), "wk": rand(pp, n, d, dkv),
+                        "wv": rand(pp, n, d, dkv), "wo": rand(pp, n, dq, d)})
+        return out
+
+    layers = block(lps)
+    if _drop_free(cfg):
         layers["gate"] = rand(pp, lps, d, cfg.num_experts)
-        for name in _expert_names(cfg):
-            layers[name] = (rand(pp, lps, cfg.num_experts, f, d)
-                            if name in ("we2", "we_down")
-                            else rand(pp, lps, cfg.num_experts, d, f))
+        layers.update(gated("we_", lps, f, _moe_held(cfg)))
+        if cfg.moe_router == "noaux_tc":
+            layers["gate_bias"] = rand(pp, lps, cfg.num_experts)
+        if cfg.moe_shared_width:
+            layers.update(gated("ws_", lps, cfg.moe_shared_width))
+    elif cfg.num_experts:
+        layers["gate"] = rand(pp, lps, d, cfg.num_experts)
+        layers["we1"] = rand(pp, lps, cfg.num_experts, d, f)
+        layers["we2"] = rand(pp, lps, cfg.num_experts, f, d)
     else:
         layers["w1"] = rand(pp, lps, d, f)
         layers["w2"] = rand(pp, lps, f, d)
@@ -279,6 +472,10 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
         "lnf_b": jnp.zeros((d,), cfg.dtype),
         "layers": layers,
     }
+    if cfg.dense_layers:
+        params["dense_layers"] = dict(
+            block(cfg.dense_layers),
+            **gated("w_", cfg.dense_layers, cfg.d_ff_dense))
     if not cfg.tie_embeddings:
         params["head"] = rand(d, V)
     if cfg.pos_type == "learned":
@@ -287,8 +484,10 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     specs = _param_specs(cfg, pp)
     # an RMSNorm has no shift: drop what the specs do not name
     params = {k: v for k, v in params.items() if k in specs}
-    params["layers"] = {k: v for k, v in layers.items()
-                        if k in specs["layers"]}
+    for stack in ("layers", "dense_layers"):
+        if stack in params:
+            params[stack] = {k: v for k, v in params[stack].items()
+                             if k in specs[stack]}
     shard = {k: (jax.tree_util.tree_map(lambda sp: NamedSharding(mesh, sp),
                                         specs[k])
                  if isinstance(specs[k], dict) else
@@ -346,18 +545,30 @@ def _ffn(cfg, lp, h, x_in, layers, at):
     stats)``; stats is None for a dense FFN, else ``(experts, active)``
     of the drop-free router (None, None for the capacity router, which
     reports none)."""
+    if "w_gate" in lp:          # a layer of the leading dense stack
+        return _gated_ffn(cfg, lp, "w_", h), None
     if not cfg.num_experts:
         return jax.nn.gelu(h @ lp["w1"]) @ lp["w2"], None
     d = h.shape[-1]
     tok = h.reshape(-1, d)
-    if cfg.moe_router == "topk":
+    if _drop_free(cfg):
         src = x_in if cfg.moe_router_input == "layer" else h
-        # the 64 logits decide by their order: accumulate in float32
+        # the logits decide by their order: accumulate in float32
         logits = jnp.dot(src.reshape(-1, d), lp["gate"],
                          preferred_element_type=jnp.float32)
+        route = None
+        if cfg.moe_router == "noaux_tc":
+            route = functools.partial(
+                group_limited_routing, bias=lp["gate_bias"],
+                k=cfg.moe_top_k, n_groups=cfg.moe_n_groups,
+                topk_groups=cfg.moe_topk_groups,
+                scale=cfg.moe_routed_scale)
         out, experts, active = moe_ffn_sorted(
             tok, logits, layers["we_gate"], layers["we_up"],
-            layers["we_down"], cfg.moe_top_k, lead=at)
+            layers["we_down"], cfg.moe_top_k, lead=at, route=route,
+            first=_moe_first(cfg), act=cfg.gate_act)
+        if cfg.moe_shared_width:
+            out = out + _gated_ffn(cfg, lp, "ws_", tok)
         return out.reshape(h.shape), (experts, active)
     logits = tok @ lp["gate"]
     cap = max(1, int(cfg.capacity_factor * tok.shape[0]
@@ -371,13 +582,40 @@ def _ffn(cfg, lp, h, x_in, layers, at):
     return f.reshape(h.shape), (None, None)
 
 
+def _gated_ffn(cfg, lp, prefix, h):
+    """``(act(h Wg) * (h Wu)) Wd`` with the maps ``lp[prefix + "gate" |
+    "up" | "down"]``: a dense gated FFN, or a shared expert."""
+    act = jax.nn.silu if cfg.gate_act == "silu" else jax.nn.relu
+    return (act(h @ lp[prefix + "gate"]) * (h @ lp[prefix + "up"])) \
+        @ lp[prefix + "down"]
+
+
+def _iter_layers(params):
+    """The model's layers in order: ``(flat index, the stack that holds
+    the layer, its (stage, layer) there, its parameters)``. A model with
+    two kinds of FFN keeps its leading dense layers in a stack of their
+    own, ``params["dense_layers"]``, before ``params["layers"]``."""
+    li_flat = 0
+    for name in ("dense_layers", "layers"):
+        stack = params.get(name)
+        if stack is None:
+            continue
+        pp, lps = jax.tree_util.tree_leaves(stack)[0].shape[:2]
+        for st in range(pp):
+            for li in range(lps):
+                yield (li_flat, stack, (st, li), jax.tree_util.tree_map(
+                    lambda p: p[st, li], stack))
+                li_flat += 1
+
+
 def _stats(cfg, per_layer):
     """What a forward reports beside its logits: for the drop-free
-    router, per layer the chosen experts (L, k, n) — choice-major, as
-    ``moe_ffn_sorted`` returns them — and the number of experts that
-    received a row (L,); else nothing."""
-    if not (cfg.num_experts and cfg.moe_router == "topk"):
+    routers, per expert layer the chosen experts (L, k, n) — choice-
+    major, as ``moe_ffn_sorted`` returns them — and the number of held
+    experts that received a row (L,); else nothing."""
+    if not _drop_free(cfg):
         return {}
+    per_layer = [p for p in per_layer if p is not None]
     return {"moe_experts": jnp.stack([e for e, _a in per_layer]),
             "moe_active_experts": jnp.stack(
                 [a for _e, a in per_layer]).astype(jnp.int32)}
@@ -650,6 +888,154 @@ def make_transformer_train_step(cfg: TransformerConfig, mesh: Mesh,
     return jax.jit(loop, donate_argnums=(0,))
 
 
+# ---------------------------------------------------------------------------
+# latent attention (MLA): one function a mechanism, shared by the whole-
+# sequence forward, the prefill and the decode step
+# ---------------------------------------------------------------------------
+
+def _rope_rows(t, pos, inv_freq):
+    """t (..., heads, hd), pos (...) or broadcastable to it: each row's
+    heads rotate by the row's position, pairs (i, i + hd/2)."""
+    half = t.shape[-1] // 2
+    ang = jnp.asarray(pos)[..., None, None].astype(jnp.float32) \
+        * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang).astype(t.dtype), jnp.sin(ang).astype(t.dtype)
+    t1, t2 = t[..., :half], t[..., half:]
+    return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos], -1)
+
+
+def _mla_scale(cfg):
+    """The softmax scale: (nope + rope) ** -0.5, times YaRN's mscale²."""
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 \
+        * _yarn_mscale(cfg) ** 2
+
+
+def _mla_compress(cfg, lp, h, pos):
+    """The layer's two down-projections of its normalised input ``h``
+    (..., d) at positions ``pos`` (...): ``(c_q, latent)`` — the
+    normalised compressed query (..., q_lora_rank) and what the cache
+    holds of the token, ``[RMSNorm(c_KV) ; rotated k_r]`` (...,
+    kv_lora_rank + rope)."""
+    r = cfg.kv_lora_rank
+    c_q = _norm(cfg, lp, "q_ln", h @ lp["wq_a"])
+    down = h @ lp["wkv_a"]
+    k_r = _rope_rows(down[..., None, r:], pos,
+                     _rope_inv_freq(cfg, cfg.qk_rope_head_dim))[..., 0, :]
+    return c_q, jnp.concatenate(
+        [_norm(cfg, lp, "kv_ln", down[..., :r]), k_r], -1)
+
+
+def _mla_queries(cfg, wq_b, c_q, pos):
+    """``(q_nope (..., heads, nope), q_rope (..., heads, rope))`` of the
+    heads whose columns ``wq_b`` holds; q_rope rotated by ``pos``."""
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (c_q @ wq_b).reshape(c_q.shape[:-1] + (-1, dn + dr))
+    return q[..., :dn], _rope_rows(q[..., dn:], pos,
+                                   _rope_inv_freq(cfg, dr))
+
+
+# head-rows (heads x positions) one decompressed prefill attention holds
+# at a time: a longer prompt's heads go through in groups, one after the
+# other, so that its per-head queries, keys, values and outputs (five
+# arrays of 128 heads x 16384 positions are 2.4 GB in bf16) stay a
+# quarter of that
+_MLA_PREFILL_HEAD_ROWS = 128 * 4096
+
+
+def _mla_attend_prompt(cfg, lp, c_q, latent):
+    """The DECOMPRESSED attend over whole sequences: c_q (b, s, q_rank),
+    latent (b, s, kv_rank + rope) -> the layer's attention output (b, s,
+    d). Per head, keys ``[c_KV W_UK ; k_r]`` against values ``c_KV
+    W_UV``, causal (``ops/pallas/mla_attention.mla_flash_prefill``; its
+    lax twin off the TPU)."""
+    from ..ops.pallas.mla_attention import mla_flash_prefill
+    b, s, _ = c_q.shape
+    r, nh = cfg.kv_lora_rank, cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    c_kv, k_r = latent[..., :r], latent[..., r:]
+    groups = 1
+    while nh % (2 * groups) == 0 \
+            and nh // groups * s > _MLA_PREFILL_HEAD_ROWS:
+        groups *= 2
+    hg = nh // groups
+    wq_b = lp["wq_b"].reshape(-1, groups, hg * (dn + dr))
+    wkv_b = lp["wkv_b"].reshape(r, groups, hg, dn + dv)
+    pos = jnp.arange(s)[None, :]
+
+    def attend(g):
+        q_nope, q_rope = _mla_queries(cfg, wq_b[:, g], c_q, pos)
+        w = wkv_b[:, g]                                  # (r, hg, dn + dv)
+        return mla_flash_prefill(
+            q_nope.transpose(0, 2, 1, 3), q_rope.transpose(0, 2, 1, 3),
+            jnp.einsum("bsr,rhn->bhsn", c_kv, w[..., :dn]), k_r,
+            jnp.einsum("bsr,rhv->bhsv", c_kv, w[..., dn:]),
+            _mla_scale(cfg))                             # (b, hg, s, dv)
+
+    if groups == 1:
+        o = attend(0)[None]
+    else:
+        o = jax.lax.map(attend, jnp.arange(groups))      # (G, b, hg, s, dv)
+    return jnp.einsum("gbhsv,ghvd->bsd", o,
+                      lp["wo"].reshape(groups, hg, dv, -1))
+
+
+def _mla_attend_latent(cfg, lp, c_q, cache, li, pos_b):
+    """The ABSORBED attend of one token a row over layer ``li`` of a
+    :class:`LatentKVCache`: c_q (b, q_rank) at positions ``pos_b`` ->
+    (b, d). ``W_UK`` is folded into the query and ``W_UV`` applied to
+    the attended latent, so nothing is decompressed per cached token —
+    the same mathematics as :func:`_mla_attend_prompt`."""
+    from ..ops.pallas.mla_attention import mla_paged_decode
+    r, nh = cfg.kv_lora_rank, cfg.n_heads
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    q_nope, q_rope = _mla_queries(cfg, lp["wq_b"], c_q, pos_b)
+    w = lp["wkv_b"].reshape(r, nh, dn + dv)
+    q = jnp.concatenate(
+        [jnp.einsum("bhn,rhn->bhr", q_nope, w[..., :dn]), q_rope], -1)
+    o_lat = mla_paged_decode(q, cache.pages, cache.block_tables, pos_b + 1,
+                             _mla_scale(cfg), r, layer=li)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat, w[..., dn:])
+    return o.reshape(o.shape[0], nh * dv) @ lp["wo"]
+
+
+def _latent_write_token(cache, li, latent_t, pos_b):
+    """One token's latent (b, width) into its row's page at ``pos_b``:
+    the lane tile (width, lt) that holds the position is read, one lane
+    replaced, and written back whole — its indices (layer, page, tile)
+    are all untiled dims, so the pool stays in place."""
+    from ..ops.pallas.mla_attention import lane_tile
+    ps = cache.page_size
+    lt = lane_tile(ps)
+    page = jnp.take_along_axis(
+        cache.block_tables, (pos_b // ps)[:, None], axis=1)[:, 0]
+    tile, lane = pos_b % ps // lt, pos_b % lt
+    new = jnp.where(
+        (jnp.arange(lt)[None, :] == lane[:, None])[:, None, :],
+        latent_t[:, :, None].astype(cache.pages.dtype),
+        cache.pages[li, page, tile])                     # (b, width, lt)
+    return LatentKVCache(cache.pages.at[li, page, tile].set(new),
+                         cache.block_tables, cache.page_size)
+
+
+def _latent_write_prompt(cache, li, latent, lengths):
+    """A prompt's latents (b, s, width) into its rows' pages: the pages
+    that hold a real position (by ``lengths``; the padded tail goes to
+    the null page 0), so a row needs pages for its tokens, not for its
+    bucket."""
+    from ..ops.pallas.flash_attention import prefill_page_dest
+    from ..ops.pallas.mla_attention import pages_of_latents
+    ps = cache.page_size
+    s = latent.shape[1]
+    if s % ps:
+        raise ValueError("prefill bucket %d is not a multiple of "
+                         "page_size %d" % (s, ps))
+    dest = prefill_page_dest(cache.block_tables, s // ps, ps, lengths)
+    return LatentKVCache(
+        cache.pages.at[li, dest].set(
+            pages_of_latents(latent, ps).astype(cache.pages.dtype)),
+        cache.block_tables, cache.page_size)
+
+
 def transformer_forward_single(params, tokens, cfg: TransformerConfig,
                                with_stats=False):
     """Single-device reference forward (used by tests to validate the
@@ -659,17 +1045,18 @@ def transformer_forward_single(params, tokens, cfg: TransformerConfig,
     x = params["embed"][tokens]
     if cfg.pos_type == "learned":
         x = x + params["pos"][: tokens.shape[1]]
-    layers = params["layers"]
-    pp, lps = jax.tree_util.tree_leaves(layers)[0].shape[:2]
     hd = _head_dim(cfg)
     groups = cfg.n_heads // _kv_heads(cfg)
     per_layer = []
-    for st in range(pp):
-        for li in range(lps):
-            lp = jax.tree_util.tree_map(lambda p: p[st, li], layers)
-            rotary, window = _layer_rule(cfg, st * lps + li)
-            h = _norm(cfg, lp, "ln1", x)
-            b, s, d = h.shape
+    for li_flat, layers, at, lp in _iter_layers(params):
+        h = _norm(cfg, lp, "ln1", x)
+        b, s, d = h.shape
+        x_in = x
+        if _is_mla(cfg):
+            x = x + _mla_attend_prompt(
+                cfg, lp, *_mla_compress(cfg, lp, h, jnp.arange(s)[None, :]))
+        else:
+            rotary, window = _layer_rule(cfg, li_flat)
             q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
             k = _expand_kv((h @ lp["wk"]).reshape(b, s, _kv_heads(cfg),
                                                   hd), groups, 2)
@@ -682,12 +1069,10 @@ def transformer_forward_single(params, tokens, cfg: TransformerConfig,
             sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
             sc = jnp.where(_causal_mask(s, window), sc, -1e30)
             o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-            x_in = x
             x = x + o.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
-            f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in,
-                           layers, (st, li))
-            per_layer.append(st_l)
-            x = x + f
+        f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
+        per_layer.append(st_l)
+        x = x + f
     logits = _logits(cfg, params, x)
     return (logits, _stats(cfg, per_layer)) if with_stats else logits
 
@@ -717,6 +1102,10 @@ def init_kv_cache(cfg: TransformerConfig, batch, max_len=None):
     GQA stores only the shared heads, an n_heads/n_kv_heads memory
     saving at long context."""
     max_len = max_len or cfg.max_len
+    if _is_mla(cfg):
+        raise ValueError("a latent-attention model (kv_lora_rank > 0) has "
+                         "no dense K/V strip: it decodes over the paged "
+                         "latent cache (init_kv_pages)")
     hd = _head_dim(cfg)
     # layer stacking mirrors the params layout (pp, lps, ...)
     n_l = cfg.n_layers
@@ -792,10 +1181,54 @@ jax.tree_util.register_pytree_node(
     lambda _aux, ch: HybridKVCache(ch[0], ch[1]))
 
 
+class LatentKVCache(object):
+    """The paged cache of a latent-attention (MLA) model: ONE pool and
+    no V pool. A token's vector in a layer is its normalised compressed
+    KV followed by the rotated key all heads share, kv_lora_rank +
+    qk_rope_head_dim values; ``pages`` holds them transposed, in lane
+    tiles: (layers, num_pages, page_size / lt, width, lt)
+    (``ops/pallas/mla_attention.py`` says why);
+    ``block_tables`` as in :class:`PagedKVCache`. The prefill writes
+    only the pages that hold a real position (by ``lengths``), and
+    attends the prompt decompressed per head; the decode step attends the
+    pool absorbed, in place by layer index
+    (``ops/pallas/mla_attention.py``). In a program's arguments the
+    pool rides where ``k_pages`` does, with ``v_pages`` None."""
+
+    __slots__ = ("pages", "block_tables", "page_size")
+
+    def __init__(self, pages, block_tables, page_size):
+        self.pages = pages
+        self.block_tables = block_tables
+        self.page_size = int(page_size)
+
+    @property
+    def max_context(self):
+        return self.block_tables.shape[1] * self.page_size
+
+
+jax.tree_util.register_pytree_node(
+    LatentKVCache,
+    lambda c: ((c.pages, c.block_tables), c.page_size),
+    lambda ps, ch: LatentKVCache(ch[0], ch[1], ps))
+
+
+def _check_latent(cfg, cache):
+    if _is_mla(cfg) != isinstance(cache, LatentKVCache):
+        raise ValueError(
+            "a latent-attention model (kv_lora_rank > 0) runs over a "
+            "LatentKVCache and no other model does (init_kv_pages + "
+            "paged_cache build the right one); got %s"
+            % type(cache).__name__)
+
+
 def paged_cache(k_pages, v_pages, block_tables, page_size):
     """The cache view a program builds from its arguments: one pool and
-    one table, or for a model with window layers a ``(full, window)``
-    pair of each (what :func:`init_kv_pages` returns for a pair)."""
+    one table, for a model with window layers a ``(full, window)`` pair
+    of each, for a latent-attention model its one latent pool and None
+    (what :func:`init_kv_pages` returns in each case)."""
+    if v_pages is None:
+        return LatentKVCache(k_pages, block_tables, page_size)
     if isinstance(k_pages, (tuple, list)):
         return HybridKVCache(*(PagedKVCache(k, v, bt, page_size)
                                for k, v, bt in zip(k_pages, v_pages,
@@ -805,6 +1238,8 @@ def paged_cache(k_pages, v_pages, block_tables, page_size):
 
 def cache_pools(cache):
     """``(k_pages, v_pages)`` back out of :func:`paged_cache`'s view."""
+    if isinstance(cache, LatentKVCache):
+        return cache.pages, None
     if isinstance(cache, HybridKVCache):
         return ((cache.full.k_pages, cache.window.k_pages),
                 (cache.full.v_pages, cache.window.v_pages))
@@ -829,7 +1264,15 @@ def init_kv_pages(cfg: TransformerConfig, num_pages, page_size):
     HBM cost is 2 * layers * num_pages * page_size * kv_heads * hd *
     itemsize, independent of live traffic. ``num_pages`` as a ``(full,
     window)`` pair gives a pair of each, one pool a kind of layer
-    (:class:`HybridKVCache`)."""
+    (:class:`HybridKVCache`). A latent-attention model has ONE pool of
+    kv_lora_rank + qk_rope_head_dim values a token a layer
+    (``mla_attention.latent_pool_shape``) and no V pool: ``(pages,
+    None)`` (:class:`LatentKVCache`)."""
+    if _is_mla(cfg):
+        from ..ops.pallas.mla_attention import latent_pool_shape
+        return jnp.zeros(latent_pool_shape(
+            cfg.n_layers, num_pages, page_size,
+            cfg.kv_lora_rank + cfg.qk_rope_head_dim), cfg.dtype), None
     hd = _head_dim(cfg)
 
     def pool(n_layers, n_pages):
@@ -948,22 +1391,24 @@ def transformer_decode_step(params, cache, tokens_t, pos,
     layout) and never again. A model with window layers takes a
     :class:`HybridKVCache` (or the dense dict, which keeps everything
     and masks). ``with_stats`` also returns :func:`_stats`' dict."""
-    layers = params["layers"]
-    pp, lps = jax.tree_util.tree_leaves(layers)[0].shape[:2]
     hd = _head_dim(cfg)
     b = tokens_t.shape[0]
     pos_b = _positions_vec(pos, b)
+    _check_latent(cfg, cache)
 
     x = params["embed"][tokens_t]                     # (b, d)
     if cfg.pos_type == "learned":
         x = x + params["pos"][pos_b]                  # (b, d) gather
-    li_flat = 0
     per_layer = []
-    for st in range(pp):
-        for li in range(lps):
-            lp = jax.tree_util.tree_map(lambda p: p[st, li], layers)
+    for li_flat, layers, at, lp in _iter_layers(params):
+        h = _norm(cfg, lp, "ln1", x)
+        x_in = x
+        if _is_mla(cfg):
+            c_q, latent_t = _mla_compress(cfg, lp, h, pos_b)
+            cache = _latent_write_token(cache, li_flat, latent_t, pos_b)
+            x = x + _mla_attend_latent(cfg, lp, c_q, cache, li_flat, pos_b)
+        else:
             rotary, window = _layer_rule(cfg, li_flat)
-            h = _norm(cfg, lp, "ln1", x)
             q = (h @ lp["wq"]).reshape(b, cfg.n_heads, hd)
             k_t = (h @ lp["wk"]).reshape(b, _kv_heads(cfg), hd)
             v_t = (h @ lp["wv"]).reshape(b, _kv_heads(cfg), hd)
@@ -975,13 +1420,10 @@ def transformer_decode_step(params, cache, tokens_t, pos,
                                       window)
             o = _cache_attend(part, part_li, q, pos_b, cfg, window)
             cache = put(part)
-            x_in = x
             x = x + o @ lp["wo"]
-            f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in,
-                           layers, (st, li))
-            per_layer.append(st_l)
-            x = x + f
-            li_flat += 1
+        f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
+        per_layer.append(st_l)
+        x = x + f
     logits = _logits(cfg, params, x)
     if with_stats:
         return logits, cache, _stats(cfg, per_layer)
@@ -1031,26 +1473,33 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
     ``lengths`` (b,) the returned logits are each row's last REAL
     position (right-padded ragged prompts); without, position -1."""
     b, s = tokens.shape
-    layers = params["layers"]
-    pp, lps = jax.tree_util.tree_leaves(layers)[0].shape[:2]
     hd = _head_dim(cfg)
+    _check_latent(cfg, cache)
     if lengths is not None:
         lengths = jnp.asarray(lengths, jnp.int32)
-    # a hybrid cache's rows hold pages for their tokens, not for their
-    # bucket: its page writes go by the real lengths
-    write_lengths = lengths if isinstance(cache, HybridKVCache) else None
+    # a hybrid cache's rows (and a latent one's) hold pages for their
+    # tokens, not for their bucket: its page writes go by the real lengths
+    write_lengths = lengths if isinstance(
+        cache, (HybridKVCache, LatentKVCache)) else None
 
     x = params["embed"][tokens]
     if cfg.pos_type == "learned":
         x = x + params["pos"][:s]
     mask = jnp.tril(jnp.ones((s, s), bool))
-    li_flat = 0
     per_layer = []
-    for st in range(pp):
-        for li in range(lps):
-            lp = jax.tree_util.tree_map(lambda p: p[st, li], layers)
+    for li_flat, layers, at, lp in _iter_layers(params):
+        h = _norm(cfg, lp, "ln1", x)
+        x_in = x
+        if _is_mla(cfg):
+            # the cache takes the latents; the attend decompresses the
+            # very same ones per head (the decode step attends them
+            # absorbed: two paths over one cache)
+            c_q, latent = _mla_compress(cfg, lp, h, jnp.arange(s)[None, :])
+            cache = _latent_write_prompt(cache, li_flat, latent,
+                                         write_lengths)
+            x = x + _mla_attend_prompt(cfg, lp, c_q, latent)
+        else:
             rotary, window = _layer_rule(cfg, li_flat)
-            h = _norm(cfg, lp, "ln1", x)
             q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
             kg = (h @ lp["wk"]).reshape(b, s, _kv_heads(cfg), hd)
             vg = (h @ lp["wv"]).reshape(b, s, _kv_heads(cfg), hd)
@@ -1089,13 +1538,10 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
                 o = jnp.einsum("bhqk,bkhd->bqhd",
                                jax.nn.softmax(sc, -1), v)
             cache = put(part)
-            x_in = x
             x = x + o.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
-            f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in,
-                           layers, (st, li))
-            per_layer.append(st_l)
-            x = x + f
-            li_flat += 1
+        f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
+        per_layer.append(st_l)
+        x = x + f
     if lengths is None:
         xl = x[:, -1]
     else:

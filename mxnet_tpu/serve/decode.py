@@ -284,13 +284,24 @@ class DecodeEngine(object):
                 self._cfg.window_pages = (self._cfg.slots
                                           * self._ring_pages + 1)
             self._wpool = PagePool(self._cfg.window_pages, kind="window")
-        self._pool = PagePool(self._cfg.num_pages,
-                              kind="global" if self._window else None)
-        # the drop-free expert router reports, in the tokens' own fetch,
+        # a latent-attention model keeps ONE pool of compressed vectors
+        # and no V pool (transformer.LatentKVCache): the programs' v_pages
+        # argument is None, and a request holds pages for its positions,
+        # not for its prefill bucket
+        self._latent = bool(model_cfg.kv_lora_rank)
+        self._pool = PagePool(
+            self._cfg.num_pages,
+            kind="latent" if self._latent
+            else "global" if self._window else None)
+        # the drop-free expert routers report, in the tokens' own fetch,
         # how many experts each layer's call touched and which ones each
         # row chose
         self._moe = (bool(model_cfg.num_experts)
-                     and model_cfg.moe_router == "topk")
+                     and model_cfg.moe_router != "capacity")
+        self._moe_layers = model_cfg.n_layers - model_cfg.dense_layers
+        # the experts this engine's device holds, of the router's
+        local = model_cfg.moe_local_experts or (0, model_cfg.num_experts)
+        self._moe_first, self._moe_end = local[0], local[0] + local[1]
         self._k_pages, self._v_pages = self._fresh_pools()
         self._prefill_progs = {}
         self._step_progs = {}
@@ -332,12 +343,19 @@ class DecodeEngine(object):
             "(the global layers' pool of a model with window layers)")
         self._m_pages_free = _tm.gauge(
             "decode/pages_free", "Free KV-cache pages by the kind of "
-            "layer whose pool they are in (global | window)", ("kind",))
+            "layer whose pool they are in (global | window | latent: a "
+            "latent-attention model's one pool, which keeps every "
+            "position and so reads as global too)", ("kind",))
         self._m_moe_rows = _tm.counter(
             "decode/moe_assignments_total",
             "Token-to-expert assignments of the prompts prefilled and "
             "the tokens decoded (top-k a token a layer; the padding of "
             "a bucket and dummy slots are not counted)")
+        self._m_moe_absent = _tm.counter(
+            "decode/moe_absent_assignments_total",
+            "Of decode/moe_assignments_total, those whose expert this "
+            "engine's device does not hold (moe_local_experts): they took "
+            "no row here")
         self._m_moe_active = _tm.counter(
             "decode/moe_expert_activations_total",
             "Experts that received at least one row (padding included), "
@@ -368,6 +386,8 @@ class DecodeEngine(object):
     def _note_free(self):
         self._m_free.set(self._pool.free_pages)
         self._m_pages_free.labels("global").set(self._pool.free_pages)
+        if self._latent:
+            self._m_pages_free.labels("latent").set(self._pool.free_pages)
         if self._wpool is not None:
             self._m_pages_free.labels("window").set(
                 self._wpool.free_pages)
@@ -637,7 +657,7 @@ class DecodeEngine(object):
         # pool at most a ring
         ps = self._cfg.page_size
         n_pages = pages_needed(plen + max_new, ps)
-        if not self._window:
+        if not (self._window or self._latent):
             n_pages = max(n_pages, pages_needed(pick_bucket(
                 plen, self._cfg.prefill_buckets), ps))
         with self._cond:
@@ -914,11 +934,17 @@ class DecodeEngine(object):
             if self._window:
                 page_ids = (sess.block_table[:n_pb],
                             sess.window_block_table)
+            elif self._latent:       # the pages past the request's own
+                page_ids = sess.block_table[:n_pb].copy()    # are null
             else:
                 page_ids = _np.asarray(sess.page_ids[:n_pb], _np.int32)
         padded = _np.zeros((1, bucket), _np.int32)
         padded[0, :sess.prompt_len] = sess.prompt
         with _tr.child_span("decode.prefill") as span:
+            if self._latent:
+                # latents this prefill writes: a token a layer
+                span.set_attr("latent_context_tokens",
+                              sess.prompt_len * self._model_cfg.n_layers)
             t0 = _tm.monotonic()
             out, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
                 self._params, self._k_pages, self._v_pages, page_ids,
@@ -948,21 +974,29 @@ class DecodeEngine(object):
 
     def _note_moe(self, span, stats, width, real):
         """What a call's expert layers did, from the numbers its program
-        returned after the tokens (``stats``: per layer the count of
-        experts that received a row, then the experts chosen for each of
-        the ``width`` rows the program ran): counts the assignments of
-        the ``real`` rows (padding and dummy slots are no work) and the
-        experts touched, puts both on the call's span, and returns the
-        choices as ``(layers, width, top_k)``."""
-        layers, k = self._model_cfg.n_layers, self._model_cfg.moe_top_k
-        n_rows = real * k * layers
+        returned after the tokens (``stats``: per expert layer the count
+        of held experts that received a row, then the experts chosen for
+        each of the ``width`` rows the program ran): counts the
+        assignments of the ``real`` rows (padding and dummy slots are no
+        work), those of them that landed on an expert held here — the
+        rows the grouped product computed — and the experts touched, puts
+        them on the call's span, and returns the choices as ``(layers,
+        width, top_k)``."""
+        layers, k = self._moe_layers, self._model_cfg.moe_top_k
+        experts = stats[layers:].reshape(layers, k, width).transpose(
+            0, 2, 1).astype(_np.int16)
+        chosen = experts[:, :real]
+        n_assigned = real * k * layers
+        n_rows = int(_np.count_nonzero(
+            (chosen >= self._moe_first) & (chosen < self._moe_end)))
         n_active = int(stats[:layers].sum())
-        self._m_moe_rows.inc(n_rows)
+        self._m_moe_rows.inc(n_assigned)
+        self._m_moe_absent.inc(n_assigned - n_rows)
         self._m_moe_active.inc(n_active)
+        span.set_attr("moe_assignments", n_assigned)
         span.set_attr("moe_rows", n_rows)
         span.set_attr("moe_active_experts", n_active)
-        return stats[layers:].reshape(layers, k, width).transpose(
-            0, 2, 1).astype(_np.int16)
+        return experts
 
     def _emit_locked(self, sess, tok):
         """Deliver one token; retire the session once it hits its
@@ -1003,6 +1037,12 @@ class DecodeEngine(object):
                  "window_context_tokens": int(
                      _np.minimum(context, self._window).sum()
                      if self._window else context.sum())}
+        if self._latent:
+            # cached vectors the step's absorbed attends read, and the
+            # queries they are read for, all layers
+            attrs["latent_context_tokens"] = int(
+                context.sum()) * self._model_cfg.n_layers
+            attrs["latent_rows"] = len(live) * self._model_cfg.n_layers
         with _tr.child_span("decode.step", attrs=attrs) as span:
             t0 = _tm.monotonic()
             toks, self._k_pages, self._v_pages = self._step_prog(nslots)(

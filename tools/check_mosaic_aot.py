@@ -29,6 +29,7 @@ fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
 fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
 i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
 mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
+ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
 
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 SGD_H = {"lr": 0.1, "wd": 1e-4, "rescale_grad": 1 / 32, "momentum": 0.9}
@@ -141,6 +142,33 @@ def cases():
                lambda x, gs, wg, wu, wd, t=tile: mf.moe_grouped_ffn(
                    x, gs, wg, wu, wd, t, interpret=False, lead=(0, 1)),
                [((rows, 2560), BF16), ((64,), I32), w_in, w_in, w_out])
+    # 16 held experts of 7168 x 2048 (88 MB each: f is tiled in blocks of
+    # 512 columns), SiLU gate: a 64-slot decode step's and a 1024-token
+    # prefill chunk's assignments in tiles of 128 (layer 3 of a stack of 4)
+    for rows in (2432, 10112):
+        w_in, w_out = ((1, 4, 16, 7168, 2048), BF16), ((1, 4, 16, 2048, 7168),
+                                                       BF16)
+        yield ("moe_grouped_ffn f-tiled silu rows%d tile128" % rows,
+               lambda x, gs, wg, wu, wd: mf.moe_grouped_ffn(
+                   x, gs, wg, wu, wd, 128, interpret=False, lead=(0, 3),
+                   act="silu"),
+               [((rows, 7168), BF16), ((16,), I32), w_in, w_in, w_out])
+    # latent attention at the served widths: 64 rows of 128 heads over a
+    # whole pool of 5 layers x 897 pages of 4 lane tiles (576, 128) (absorbed,
+    # layer 4); a 16384-token prompt's 32 heads (a group) decompressed
+    latent_pool = ((5, 897, 4, 576, 128), BF16)
+    yield ("mla_paged_decode layer4of5 b64h128 rank512+64 page512",
+           lambda q, pages, bt, ln: ml.mla_paged_decode(
+               q, pages, bt, ln, 0.135, 512, interpret=False, layer=4),
+           [((64, 128, 576), BF16), latent_pool, ((64, 32), I32),
+            ((64,), I32)])
+    for s, heads in ((16384, 32), (512, 128)):
+        yield ("mla_flash_prefill s%dh%d nope128 rope64 v128" % (s, heads),
+               lambda qn, qr, kn, kr, v: ml.mla_flash_prefill(
+                   qn, qr, kn, kr, v, 0.135, interpret=False),
+               [((1, heads, s, 128), BF16), ((1, heads, s, 64), BF16),
+                ((1, heads, s, 128), BF16), ((1, s, 64), BF16),
+                ((1, heads, s, 128), BF16)])
     yield ("int8_conv_im2col b32c64 56x56 3x3",
            lambda q, w, s: i8.int8_conv_im2col(
                q, w, s, (1, 1), (1, 1), (1, 1), interpret=False),

@@ -9,8 +9,9 @@ paged kernels therefore take the pool whole with the layer's index
 (``ops/pallas/flash_attention.py``). This tool compiles a ``DecodeEngine``'s
 decode-step and prefill programs for a DESCRIBED TPU v5e (the
 ``tools/check_mosaic_aot.py`` trick) with the TPU branches taken, at a
-small model whose pool dwarfs its activations, once with one pool and once
-with a pool a kind of layer, and reads the compiled module:
+small model whose pool dwarfs its activations, once with one pool, once
+with a pool a kind of layer and once with a latent-attention model's one
+latent pool (no V pool), and reads the compiled module:
 
 * no instruction of the entry computation other than a Mosaic call produces
   an array of one layer's pool shape (``layer_copies``);
@@ -51,9 +52,20 @@ HYBRID = dict(vocab_size=512, d_model=256, n_heads=4, n_kv_heads=2,
               pos_type="rope", norm="rmsnorm", tie_embeddings=False,
               sliding_window=64, window_layout=(0, 1, 0, 1),
               rope_layout=(0, 1, 0, 1), dtype=jnp.bfloat16)
+# latent attention at the served latent width (512 + 64) and page (512):
+# one pool, no V pool, written by XLA scatters and read by the kernel
+LATENT = dict(vocab_size=512, d_model=256, n_heads=4, n_layers=3, d_ff=128,
+              max_len=2048, pos_type="rope", norm="rmsnorm",
+              tie_embeddings=False, num_experts=8, moe_top_k=2,
+              moe_router="noaux_tc", moe_n_groups=2, moe_topk_groups=1,
+              kv_lora_rank=512, q_lora_rank=256, qk_nope_head_dim=128,
+              qk_rope_head_dim=64, v_head_dim=128, dense_layers=1,
+              d_ff_dense=256, gate_act="silu", moe_shared_width=128,
+              moe_local_experts=(0, 4), dtype=jnp.bfloat16)
 # page counts no other array of the programs has a dimension of
-CONFIGS = (("one_pool", DENSE, 16411, None),
-           ("pool_a_kind", HYBRID, 16411, 16417))
+CONFIGS = (("one_pool", DENSE, 16411, None, PAGE, CONTEXT),
+           ("pool_a_kind", HYBRID, 16411, 16417, PAGE, CONTEXT),
+           ("latent_pool", LATENT, 211, None, 512, 2048))
 
 # value names, result types and opcodes of an HLO text's instructions
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
@@ -130,18 +142,18 @@ def main():
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1, 1, 1),
                 ("dp", "sp", "tp", "pp", "ep"))
     ok = True
-    for name, model, pages, window_pages in CONFIGS:
+    for name, model, pages, window_pages, page, context in CONFIGS:
         cfg = TransformerConfig(**model)
         params = on_chip(jax.eval_shape(
             lambda: init_transformer_params(cfg, mesh, seed=0)[0]))
         # a two-page engine; its programs take the pools as arguments and
         # are lowered at the size under test (bench/aot_check.py's way)
         engine = DecodeEngine(params, cfg, DecodeConfig(
-            slots=SLOTS, page_size=PAGE, num_pages=2, max_context=CONTEXT,
+            slots=SLOTS, page_size=page, num_pages=2, max_context=context,
             window_pages=2 if window_pages else None))
         dcfg = engine.config
         k_pool, v_pool = on_chip(jax.eval_shape(lambda: init_kv_pages(
-            cfg, (pages, window_pages) if window_pages else pages, PAGE)))
+            cfg, (pages, window_pages) if window_pages else pages, page)))
         bucket, slots = dcfg.prefill_buckets[-1], dcfg.slot_buckets[-1]
         ring = engine._ring_pages or 0
         real_backend = jax.default_backend
@@ -154,7 +166,7 @@ def main():
                 i32(slots), i32(slots)).compile()
             prefill = engine._prefill_prog(bucket).lower(
                 params, k_pool, v_pool,
-                engine._tables(i32(bucket // PAGE), i32(ring)),
+                engine._tables(i32(bucket // page), i32(ring)),
                 i32(1, bucket), i32(1)).compile()
         finally:
             jax.default_backend = real_backend
