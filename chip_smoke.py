@@ -272,17 +272,17 @@ def train_phase():
     check(hbm is None or hbm >= nbytes,
           "train: device reports %s bytes in use, the step's arrays alone "
           "are %d" % (hbm, nbytes))
-    # the update rule the step traced must be the Mosaic kernel on TPU
+    # the update rule the step traced is plain XLA: an elementwise fusion
+    # over each parameter in the layout it has, no custom call to feed
     rule = mod._optimizer.fused_rule()
     idx = len(mod._param_names) - 1
     w = mod._exec.arg_dict[mod._param_names[idx]]._data
     st = tuple(a._data for a in mx.optimizer.fused_state_arrays(
         mod._updater.states[idx]))
     hyper = mod._optimizer.fused_hyper(idx)
-    mosaic = _mosaic_in(rule, w, w, st, hyper)
-    check(mosaic == (not TINY),
-          "train: update rule %s lowers %s a Mosaic kernel on platform %s"
-          % (rule.__name__, "to" if mosaic else "WITHOUT", dev.platform))
+    check(not _mosaic_in(rule, w, w, st, hyper),
+          "train: update rule %s lowers to a Mosaic kernel on platform %s"
+          % (rule.__name__, dev.platform))
     steady = sorted(watch.wall[WARMUP_STEPS:])
     status("train", "passed", arrays=len(arrays), on_device=str(dev),
            state_mbytes=round(nbytes / 1e6, 1),
@@ -293,7 +293,7 @@ def train_phase():
            steady_ms_per_step=round(steady[len(steady) // 2] * 1e3, 2),
            phase_wall_s=round(wall, 2), real_compiles=real, disk_hits=disk,
            step_program="loaded from the compile cache" if real == 0
-           else "compiled", update_rule=rule.__name__, mosaic_update=mosaic)
+           else "compiled", update_rule=rule.__name__)
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +308,6 @@ def _rel_err(got, ref):
 
 def kernels_phase():
     fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
-    fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
     i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
     mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
     ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
@@ -342,38 +341,6 @@ def kernels_phase():
                   "kernels: %s did not lower to a Mosaic kernel" % name)
         report[name] = {"max_rel_err": max(errs),
                         "first_call_s": round(wall, 2)}
-
-    # -- fused optimizer updates: the largest conv weight, the FC, a BN
-    #    gamma of ResNet-50 (every parameter of the train phase goes
-    #    through sgd_fused_update)
-    sgd_h = {"lr": 0.1, "wd": 1e-4, "rescale_grad": 1.0 / 32,
-             "momentum": 0.9}
-    adam_h = {"lr": 1e-3, "wd": 1e-4, "rescale_grad": 1.0 / 32,
-              "beta1": 0.9, "one_minus_beta1": 0.1, "beta2": 0.999,
-              "one_minus_beta2": 1e-3, "epsilon": 1e-8}
-    shapes = [(8, 8, 3, 3), (10, 16), (4,)] if TINY else \
-        [(512, 512, 3, 3), (1000, 2048), (64,)]
-    for shape in shapes:
-        tag = "x".join(str(d) for d in shape)
-        w, g, m = f32(*shape), f32(*shape), f32(*shape)
-        v = jnp.abs(f32(*shape))
-        case("sgd_fused_update[%s]" % tag,
-             lambda w, g, m: fu.sgd_fused_update(w, g, (m,), sgd_h,
-                                                 interpret=interp),
-             lambda w, g, m: fu._sgd_fused_xla(w, g, (m,), sgd_h),
-             (w, g, m), 1e-5)
-        case("adam_fused_update[%s]" % tag,
-             lambda w, g, m, v: fu.adam_fused_update(w, g, (m, v), adam_h,
-                                                     interpret=interp),
-             lambda w, g, m, v: fu._adam_fused_xla(w, g, (m, v), adam_h),
-             (w, g, m, v), 1e-5)
-    clip_h = dict(sgd_h, clip_gradient=0.01)
-    w, g, m = f32(*shapes[0]), f32(*shapes[0]), f32(*shapes[0])
-    case("sgd_fused_update[clip_gradient]",
-         lambda w, g, m: fu.sgd_fused_update(w, g, (m,), clip_h,
-                                             interpret=interp),
-         lambda w, g, m: fu._sgd_fused_xla(w, g, (m,), clip_h),
-         (w, g, m), 1e-5)
 
     # -- flash attention: train_transformer_lm shapes (b8, 16 heads, seq
     #    1024, head_dim 64), bf16, and head_dim 128
@@ -623,7 +590,7 @@ def kernels_phase():
          (qx, wq, sc), 0.0, exact=True)
 
     exported = set()
-    for mod in (fa, fu, i8, mf, ml):
+    for mod in (fa, i8, mf, ml):
         exported.update(mod.PALLAS_KERNELS)
     covered = set(n.split("[")[0] for n in report)
     check(covered == exported, "kernels: exported %s, exercised %s"

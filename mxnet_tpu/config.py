@@ -112,14 +112,6 @@ VARS = {
                          "local-update paths). 0 restores the separate "
                          "forward/vjp programs plus per-parameter update "
                          "dispatches."),
-    "MXNET_PALLAS_FUSED_UPDATE": (bool, True,
-                                  "Route SGD-momentum/Adam fused update "
-                                  "rules through the Pallas "
-                                  "ops/pallas/fused_update.py kernels "
-                                  "(Mosaic on TPU; off-TPU the kernels "
-                                  "dispatch to their lax twins, so 0 vs "
-                                  "1 is a no-op on CPU). 0 pins the "
-                                  "plain lax rules everywhere."),
     "MXNET_INT8_CONV_IM2COL": (bool, False,
                                "Force _contrib_quantized_conv_int8 "
                                "through the im2col + Pallas int8-matmul "

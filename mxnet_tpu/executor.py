@@ -539,7 +539,11 @@ class Executor(object):
         HBM update with no per-parameter copies. The hyper-parameters
         arrive as ONE float32 array (a row per name in ``update_names``,
         a column per key in ``hyper_keys``); each rule call reads its
-        row as a dict of scalars in its weight's dtype.
+        row as a dict of scalars in its weight's dtype. The rule is
+        elementwise, so XLA fuses it over each parameter in the layout it
+        has; under the dp mesh every operand of it is replicated (GSPMD
+        all-reduces the gradient on its way in), so each device runs it
+        on its own replica as it stands.
 
         ``numerics`` != 'off' folds the health sentinels into the SAME
         program: a loss proxy (mean of the first output), the global
@@ -555,16 +559,6 @@ class Executor(object):
         import jax.numpy as jnp
         from .optimizer import unpack_fused_hyper
         fn = _graph_eval_fn(self._symbol, True)
-        if self._dp_mesh is not None:
-            # Under the dp mesh every operand of the update is replicated
-            # (GSPMD all-reduces the gradient on its way in), so each
-            # device runs the rule on its own replica. The explicit
-            # shard_map is what lets the Pallas update kernels in at
-            # all: GSPMD cannot partition a Mosaic custom call.
-            from jax.sharding import PartitionSpec as P
-            rule = jax.shard_map(rule, mesh=self._dp_mesh,
-                                 in_specs=(P(), P(), P(), P()),
-                                 out_specs=P(), check_vma=False)
 
         def _sentinel(gs, outs):
             # step mode costs ONE reduction pass over each gradient:

@@ -146,13 +146,13 @@ class Trainer(object):
         # per parameter. fused_apply declines (→ per-param fallback) for
         # sparse grads, multi-precision, optimizers without a pure rule,
         # dist_* kvstores, or MXNET_FUSED_STEP=0.
-        if items and self._fused_update_ok() \
+        if items and self._fused_apply_ok() \
                 and opt_mod.fused_apply(self._optimizer, items):
             return
         for i, weight, grad, state in items:
             self._optimizer.update_multi_precision(i, weight, grad, state)
 
-    def _fused_update_ok(self):
+    def _fused_apply_ok(self):
         from ..model import fused_step_supported
         return fused_step_supported(self._optimizer, self._kvstore,
                                     self._update_on_kvstore,

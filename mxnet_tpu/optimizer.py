@@ -44,7 +44,13 @@ __all__ = ["Optimizer", "SGD", "Signum", "NAG", "Adam", "AdaGrad", "RMSProp",
 # (e.g. Adam's ``1 - beta1``) is folded HOST-side into ``hyper`` here, so
 # a fused train step is bitwise-identical to the unfused
 # forward/vjp/per-param-kernel sequence (asserted by
-# tests/test_fused_step.py).
+# tests/test_fused_step.py). Every rule is elementwise over operands of
+# ONE shape and nothing else: XLA's own fusion then reads and writes
+# weight, gradient and state where they lie, in whatever layout they
+# have, in place under the step's donation. A rule that flattens, pads
+# or reshapes its operands pays a physical relayout of each on the TPU
+# (a (512, 512, 3, 3) weight flattened is a transpose with a minor
+# dimension of 3); tests/test_fused_step.py holds every rule to that.
 # ---------------------------------------------------------------------------
 
 def _rule_prep(g, h):
@@ -94,24 +100,6 @@ def _adam_fused(w, g, state, h):
     var_new = h["beta2"] * var + h["one_minus_beta2"] * jnp.square(g)
     return (w - h["lr"] * mean_new / (jnp.sqrt(var_new) + h["epsilon"]),
             (mean_new, var_new))
-
-
-def _sgd_fused_pallas(w, g, state, h):
-    """:func:`_sgd_fused` as a single VMEM-resident Pallas kernel
-    (ops/pallas/fused_update.py) — the weight/state tiles make one HBM
-    round-trip instead of one per fused-multiply stage. Off-TPU the
-    kernel dispatcher runs ``_sgd_fused`` itself, so this rule IS the
-    lax rule everywhere tier-1 runs; on TPU the kernel body evaluates
-    the same rule on VMEM refs (bitwise by construction)."""
-    from .ops.pallas.fused_update import sgd_fused_update
-    return sgd_fused_update(w, g, state, h)
-
-
-def _adam_fused_pallas(w, g, state, h):
-    """:func:`_adam_fused` as a single VMEM-resident Pallas kernel —
-    see :func:`_sgd_fused_pallas` for the contract."""
-    from .ops.pallas.fused_update import adam_fused_update
-    return adam_fused_update(w, g, state, h)
 
 
 def _adagrad_fused(w, g, state, h):
@@ -448,9 +436,6 @@ class SGD(Optimizer):
         return zeros(weight.shape, ctx=weight.context, dtype=weight.dtype)
 
     def fused_rule(self):
-        from . import config
-        if config.get("MXNET_PALLAS_FUSED_UPDATE"):
-            return _sgd_fused_pallas
         return _sgd_fused
 
     def fused_hyper(self, index):
@@ -587,9 +572,6 @@ class Adam(Optimizer):
                 zeros(weight.shape, ctx=weight.context, dtype=weight.dtype))
 
     def fused_rule(self):
-        from . import config
-        if config.get("MXNET_PALLAS_FUSED_UPDATE"):
-            return _adam_fused_pallas
         return _adam_fused
 
     def fused_hyper(self, index):

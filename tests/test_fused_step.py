@@ -397,7 +397,7 @@ def test_backward_add_accumulates_inside_program():
     np.testing.assert_allclose(g2, 2 * g1, rtol=1e-6, atol=1e-7)
 
 
-def test_trainer_fused_update(monkeypatch):
+def test_trainer_fused_apply(monkeypatch):
     """Gluon Trainer.step: the whole-pytree fused update matches the
     per-parameter path and costs one dispatch."""
     from mxnet_tpu import autograd, gluon
@@ -620,6 +620,123 @@ def test_packed_step_bitwise_matches_scalar_rule(optimizer, opt_params):
         assert len(got_s[i]) == len(s[i])
         for a, b in zip(got_s[i], s[i]):
             assert np.array_equal(a, np.asarray(b)), (optimizer, i)
+
+
+# ---------------------------------------------------------------------------
+# the update runs in each parameter's own layout: a rule is elementwise over
+# operands of ONE shape (XLA's fusion reads and writes them where they lie),
+# and the step program wraps it in nothing
+# ---------------------------------------------------------------------------
+
+# what the shape check below cannot see (a kernel, a reversal or a square
+# transpose keeps the shape) and, for the message, what it can
+_RELAYOUT_PRIMITIVES = {
+    "reshape", "pad", "slice", "dynamic_slice", "dynamic_update_slice",
+    "concatenate", "transpose", "squeeze", "expand_dims", "gather",
+    "scatter", "rev", "pallas_call", "custom_call", "shard_map"}
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold
+    (``jnp.clip`` and ``jnp.where`` trace as nested ``pjit`` calls)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _walk_eqns(inner)
+
+
+@pytest.mark.parametrize("shape", [(64, 3, 7, 7), (7,)])
+@pytest.mark.parametrize("optimizer,opt_params", FUSED_RULE_OPTIMIZERS)
+def test_fused_rule_is_elementwise_in_the_operands_layout(optimizer,
+                                                          opt_params, shape):
+    """``jax.make_jaxpr`` of every ``fused_rule``: nothing flattens, pads,
+    reshapes, slices or transposes a weight, a gradient or a state, and
+    nothing calls a kernel — each value is the parameter's shape or a
+    scalar — and weight and states come back in the operand's shape and
+    dtype. On the TPU a reshape of a (512, 512, 3, 3) weight is a physical
+    relayout (157 parameters' worth cost 53 ms a step until PR 33)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import optimizer as opt
+    o = opt.create(optimizer, **opt_params)
+    w = mx.nd.zeros(shape)
+    state = tuple(a._data for a in opt.fused_state_arrays(
+        o.create_state(0, w)))
+    hyper = {k: jnp.asarray(v, jnp.float32)
+             for k, v in o.fused_hyper(0).items()}
+    rule = o.fused_rule()
+    closed = jax.make_jaxpr(rule)(w._data, w._data, state, hyper)
+    seen = set()
+    for eqn in _walk_eqns(closed.jaxpr):
+        seen.add(eqn.primitive.name)
+        for var in eqn.outvars:
+            assert var.aval.shape in (shape, ()), (eqn.primitive.name,
+                                                   var.aval.shape)
+    assert not seen & _RELAYOUT_PRIMITIVES, seen & _RELAYOUT_PRIMITIVES
+    new_w, new_s = jax.eval_shape(rule, w._data, w._data, state, hyper)
+    assert (new_w.shape, new_w.dtype) == (shape, w._data.dtype)
+    assert len(new_s) == len(state)
+    for a in new_s:
+        assert (a.shape, a.dtype) == (shape, w._data.dtype)
+
+
+def _conv_bn_fc_sym():
+    net = mx.sym.Variable("data")
+    net = mx.sym.Convolution(net, name="conv", num_filter=8, kernel=(3, 3),
+                             pad=(1, 1), no_bias=True)
+    net = mx.sym.BatchNorm(net, name="bn", fix_gamma=False)
+    net = mx.sym.Activation(net, name="relu", act_type="relu")
+    net = mx.sym.Pooling(net, name="pool", global_pool=True, kernel=(1, 1),
+                         pool_type="avg")
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), name="fc", num_hidden=5)
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_step_program_wraps_the_update_in_nothing(monkeypatch, devices):
+    """The lowered text of ``Executor.train_step`` for a conv-BN-FC symbol,
+    alone and under a two-device dp mesh: no custom call but GSPMD's own
+    sharding annotations (so no kernel in the update tail for a reshape to
+    feed), and no ``shard_map`` / manual-sharding region — every operand of
+    the rule is replicated, so GSPMD runs it on each replica as it is."""
+    import re
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    contexts = mx.cpu(0) if devices == 1 else \
+        [mx.cpu(i) for i in range(devices)]
+    mod = Module(_conv_bn_fc_sym(), context=contexts)
+    mod.bind(data_shapes=[("data", (8, 3, 8, 8))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9, "wd": 1e-4})
+    rng = np.random.RandomState(0)
+    db = io.DataBatch(
+        data=[mx.nd.array(rng.randn(8, 3, 8, 8).astype(np.float32))],
+        label=[mx.nd.array(rng.randint(0, 5, 8).astype(np.float32))])
+    mod.forward_backward(db)
+    mod.update()
+    exe = mod._exec
+    ((key, fn),) = exe._fused_jitted.items()
+    texts = []
+
+    def lowered_then_run(*args):
+        texts.append(fn.lower(*args).as_text())       # before the donation
+        return fn(*args)
+    exe._fused_jitted[key] = lowered_then_run
+    mod.forward_backward(db)
+    mod.update()
+    (text,) = texts
+    assert "stablehlo.convolution" in text          # the step, not a stub
+    targets = set(re.findall(r"custom_call\s*@(\w+)", text))
+    assert targets <= {"Sharding"}, targets
+    for manual in ("shard_map", "manual_computation", "SPMDFullToShardShape",
+                   "SPMDShardToFullShape", "manual_axes"):
+        assert manual not in text, manual
+    if devices > 1:
+        assert "sharding" in text                   # the mesh is there
 
 
 def test_train_step_hands_over_one_hyper_array(monkeypatch):

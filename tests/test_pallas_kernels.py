@@ -1,14 +1,14 @@
 """Pallas hot-path burn-down: interpret-mode parity for the PR-17
-kernels (flash prefill attention with fused page write, fused
-SGD/Adam optimizer update, int8 im2col conv) plus the kernel-contract
-lint and the warmed-dispatch compile gate.
+kernels (flash prefill attention with fused page write, int8 im2col
+conv) plus the kernel-contract lint and the warmed-dispatch compile
+gate.
 
 Every kernel under ops/pallas/ is pinned to its pure-lax twin
 (PALLAS_KERNELS registry): the Pallas interpreter result must match
-the twin — bitwise for integer math and page copies, ULP-bounded for
-float update rules, allclose at float32 round-off for online-softmax
-attention — and the off-TPU default dispatch must BE the twin (so
-tier-1 CPU numerics never change).
+the twin — bitwise for integer math and page copies, allclose at
+float32 round-off for online-softmax attention — and the off-TPU
+default dispatch must BE the twin (so tier-1 CPU numerics never
+change).
 """
 import importlib.util
 import os
@@ -24,9 +24,6 @@ import jax.numpy as jnp  # noqa: E402
 from mxnet_tpu.ops.pallas.flash_attention import (  # noqa: E402
     _flash_fwd_xla, _flash_prefill_xla, flash_attention,
     flash_prefill_paged)
-from mxnet_tpu.ops.pallas import fused_update as fu  # noqa: E402
-from mxnet_tpu.ops.pallas.fused_update import (  # noqa: E402
-    _adam_fused_xla, _sgd_fused_xla, adam_fused_update, sgd_fused_update)
 from mxnet_tpu.ops.pallas.int8_matmul import (  # noqa: E402
     _int8_conv_xla, int8_conv_im2col)
 
@@ -194,102 +191,6 @@ def test_flash_prefill_validations():
 
 
 # ---------------------------------------------------------------------------
-# fused optimizer update
-# ---------------------------------------------------------------------------
-
-_SGD_H = {"lr": 0.05, "wd": 1e-4, "rescale_grad": 1.0 / 32,
-          "momentum": 0.9, "clip_gradient": 1.0}
-_ADAM_H = {"lr": 1e-3, "wd": 1e-4, "rescale_grad": 1.0,
-           "beta1": 0.9, "one_minus_beta1": 0.1,
-           "beta2": 0.999, "one_minus_beta2": 0.001,
-           "epsilon": 1e-8}
-
-
-def _wg(seed, shape):
-    rng = np.random.RandomState(seed)
-    return (jnp.asarray(rng.randn(*shape).astype(np.float32)),
-            jnp.asarray(rng.randn(*shape).astype(np.float32)),
-            jnp.asarray(rng.randn(*shape).astype(np.float32)))
-
-
-def _check_update(rule, h, w, g, state, out_w, out_s):
-    """Interpret-mode parity vs the jitted lax twin, pinned in ULPs:
-    XLA:CPU's FMA-contraction choices depend on operand shape/layout,
-    so the interpreter's (rows, 128) ref plumbing can shift state by a
-    ULP, which ``w + mom`` amplifies to a few ULPs of the (smaller)
-    weight. The BITWISE guarantee lives in the dispatcher — off-TPU the
-    public entry points run the twin itself (asserted in
-    test_fused_rule_knob_selects_pallas)."""
-    ref_w, ref_s = jax.jit(
-        lambda w, g, s: rule(w, g, s, h))(w, g, tuple(state))
-    np.testing.assert_array_max_ulp(np.asarray(out_w),
-                                    np.asarray(ref_w), maxulp=16)
-    for a, b in zip(out_s, ref_s):
-        np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b),
-                                        maxulp=2)
-
-
-@pytest.mark.parametrize("shape", [(1,), (7,), (128, 130), (3, 5, 17)])
-def test_sgd_fused_update_interpret_parity(shape):
-    w, g, m = _wg(5, shape)
-    out_w, out_s = sgd_fused_update(w, g, (m,), _SGD_H, interpret=True)
-    _check_update(_sgd_fused_xla, _SGD_H, w, g, (m,), out_w, out_s)
-
-
-def test_sgd_fused_update_stateless_interpret_parity():
-    h = {"lr": 0.05, "wd": 1e-4, "rescale_grad": 1.0}
-    w, g, _ = _wg(6, (33, 9))
-    out_w, out_s = sgd_fused_update(w, g, (), h, interpret=True)
-    assert out_s == ()
-    _check_update(_sgd_fused_xla, h, w, g, (), out_w, out_s)
-
-
-@pytest.mark.parametrize("shape", [(1,), (64, 33), (2, 3, 40)])
-def test_adam_fused_update_interpret_parity(shape):
-    w, g, mean = _wg(7, shape)
-    var = jnp.abs(_wg(8, shape)[0])
-    out_w, out_s = adam_fused_update(w, g, (mean, var), _ADAM_H,
-                                     interpret=True)
-    _check_update(_adam_fused_xla, _ADAM_H, w, g, (mean, var),
-                  out_w, out_s)
-
-
-def test_fused_update_hyper_change_no_recompile():
-    """Hypers ride in as a stacked f32 vector, so sweeping lr/wd must
-    not grow the jit cache (the zero-compiles-after-warmup contract of
-    the fused train step)."""
-    w, g, m = _wg(9, (64, 33))
-    h = dict(_SGD_H)
-    sgd_fused_update(w, g, (m,), h, interpret=True)
-    size = fu._fused_update._cache_size()
-    for lr in (0.1, 0.01, 0.003):
-        h = dict(h, lr=lr, wd=lr / 10)
-        sgd_fused_update(w, g, (m,), h, interpret=True)
-    assert fu._fused_update._cache_size() == size
-
-
-def test_fused_rule_knob_selects_pallas(monkeypatch):
-    from mxnet_tpu.optimizer import (Adam, SGD, _adam_fused,
-                                     _adam_fused_pallas, _sgd_fused,
-                                     _sgd_fused_pallas)
-    monkeypatch.setenv("MXNET_PALLAS_FUSED_UPDATE", "0")
-    assert SGD(momentum=0.9).fused_rule() is _sgd_fused
-    assert Adam().fused_rule() is _adam_fused
-    monkeypatch.setenv("MXNET_PALLAS_FUSED_UPDATE", "1")
-    assert SGD(momentum=0.9).fused_rule() is _sgd_fused_pallas
-    assert Adam().fused_rule() is _adam_fused_pallas
-    # off-TPU the pallas rule dispatches straight to the lax rule, so
-    # tier-1 training numerics are bitwise-unchanged by the knob
-    if jax.default_backend() != "tpu":
-        w, g, m = _wg(10, (17, 5))
-        a_w, a_s = _sgd_fused_pallas(w, g, (m,), _SGD_H)
-        b_w, b_s = _sgd_fused(w, g, (m,), _SGD_H)
-        np.testing.assert_array_equal(np.asarray(a_w), np.asarray(b_w))
-        np.testing.assert_array_equal(np.asarray(a_s[0]),
-                                      np.asarray(b_s[0]))
-
-
-# ---------------------------------------------------------------------------
 # int8 im2col conv
 # ---------------------------------------------------------------------------
 
@@ -365,25 +266,15 @@ def test_kernel_contract_lint():
 
 def _warmed_dispatch_case(kernel):
     """(production dispatch, its arguments) of one burned-down kernel."""
-    from mxnet_tpu.optimizer import _adam_fused_pallas, _sgd_fused_pallas
     if kernel == "flash_prefill_paged":
         return flash_prefill_paged, _prefill_case(3, 2, 32, 4, 2, 16, 8, 11)
-    if kernel == "sgd_fused_update":
-        w, g, m = _wg(13, (64, 33))
-        return (lambda w, g, m: _sgd_fused_pallas(w, g, (m,), _SGD_H),
-                (w, g, m))
-    if kernel == "adam_fused_update":
-        w, g, mean = _wg(14, (64, 33))
-        return (lambda w, g, m, v: _adam_fused_pallas(w, g, (m, v), _ADAM_H),
-                (w, g, mean, jnp.abs(mean)))
     q, wq, scale = _conv_case(15, 2, 3, 8, 4, (3, 3, 3))
     return (lambda q, wq, scale: int8_conv_im2col(
         q, wq, scale, (1, 1), (1, 1), (1, 1), 1), (q, wq, scale))
 
 
 @pytest.mark.parametrize("kernel", [
-    "flash_prefill_paged", "sgd_fused_update", "adam_fused_update",
-    "int8_conv_im2col"])
+    "flash_prefill_paged", "int8_conv_im2col"])
 def test_warmed_pallas_dispatch_compiles_nothing(kernel):
     """A kernel's production dispatch, jitted and called once, leaks no
     counted backend compile into the calls that follow."""

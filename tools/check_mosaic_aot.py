@@ -26,30 +26,16 @@ import jax.numpy as jnp                                       # noqa: E402
 from jax.experimental import topologies                       # noqa: E402
 
 fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
-fu = importlib.import_module("mxnet_tpu.ops.pallas.fused_update")
 i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
 mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
 ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
 
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
-SGD_H = {"lr": 0.1, "wd": 1e-4, "rescale_grad": 1 / 32, "momentum": 0.9}
-ADAM_H = {"lr": 1e-3, "wd": 1e-4, "rescale_grad": 1 / 32, "beta1": 0.9,
-          "one_minus_beta1": 0.1, "beta2": 0.999, "one_minus_beta2": 1e-3,
-          "epsilon": 1e-8}
 
 
 def cases():
     """(name, fn, [(shape, dtype), ...]) at the production callers'
     shapes (chip_smoke.py runs the same ones on the chip)."""
-    for shape in ((512, 512, 3, 3), (1000, 2048), (64,)):
-        yield ("sgd_fused_update %s" % (shape,),
-               lambda w, g, m: fu.sgd_fused_update(w, g, (m,), SGD_H,
-                                                   interpret=False),
-               [(shape, F32)] * 3)
-        yield ("adam_fused_update %s" % (shape,),
-               lambda w, g, m, v: fu.adam_fused_update(
-                   w, g, (m, v), ADAM_H, interpret=False),
-               [(shape, F32)] * 4)
     for b, h, s, d, dt in ((8, 16, 1024, 64, F32), (8, 16, 1024, 64, BF16),
                            (2, 8, 1024, 128, BF16), (2, 4, 100, 64, F32)):
         yield ("flash_attention b%dh%ds%dd%d %s" % (b, h, s, d, dt.__name__),
