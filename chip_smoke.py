@@ -15,7 +15,9 @@ One process, which touches JAX itself and starts no other. Phases:
              SGD-momentum, kvstore ``device``, one chip, one synthetic
              batch repeated.
 ``kernels``  every exported Pallas kernel compiled by Mosaic at the
-             shapes its production caller uses, against its lax twin.
+             shapes its production caller uses, against its lax twin
+             (``--cases gdn_,hd256`` runs only the cases whose name holds
+             one of the parts: a partial run, for a short chip budget).
 ``trace_clock``  a profiler trace around three fused steps of the same
              path: the program's spans are events of the trace's host
              plane; prints which Python clock that plane is on, and holds
@@ -54,8 +56,14 @@ def _parse():
                     help="comma list of phases to run (debugging aid that "
                          "saves chip time; a partial run never prints the "
                          "final ok verdict)")
+    ap.add_argument("--cases", default="",
+                    help="comma list of name parts: the kernels phase runs "
+                         "only the cases whose name holds one (a cold run "
+                         "of all of them outlasts 13 minutes); like "
+                         "--phases it makes the run partial: no verdict")
     args = ap.parse_args()
     args.phases = args.phases.split(",")
+    args.cases = [c for c in args.cases.split(",") if c]
     unknown = set(args.phases) - set(PHASES)
     if unknown:
         ap.error("unknown phase(s): %s" % sorted(unknown))
@@ -311,6 +319,7 @@ def kernels_phase():
     i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
     mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
     ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
+    gd = importlib.import_module("mxnet_tpu.ops.pallas.gated_delta")
     rng = np.random.RandomState(0)
     # on the chip: interpret=None, the production default, which must
     # resolve to Mosaic (asserted per kernel through _mosaic_in); in the
@@ -322,6 +331,8 @@ def kernels_phase():
         return jnp.asarray(rng.randn(*shape), jnp.float32)
 
     def case(name, kernel, twin, args, tol, exact=False):
+        if ARGS.cases and not any(c in name for c in ARGS.cases):
+            return
         t0 = time.perf_counter()
         got = jax.block_until_ready(kernel(*args))
         wall = time.perf_counter() - t0
@@ -433,7 +444,10 @@ def kernels_phase():
     whole = [(3, 2, 2, 2, 8, 4, 2, 9, None, 1),
              (3, 3, 2, 2, 8, 4, 3, 12, 8, 2)] if TINY else \
         [(24, 32, 16, 1, 128, 16, 128, 513, None, 23),
-         (9, 32, 4, 7, 128, 16, 257, 4096, 4096, 8)]
+         (9, 32, 4, 7, 128, 16, 257, 4096, 4096, 8),
+         # a linear-attention model's 3 full layers: 2 KV heads of 256
+         # serving 8 query heads each, pages of 512 tokens
+         (3, 32, 2, 8, 256, 512, 32, 129, None, 2)]
     dt = jnp.float32 if TINY else jnp.bfloat16
     for layers, b, kvh, g, hd, ps, ppseq, npages, window, layer in whole:
         keys = jax.random.split(jax.random.PRNGKey(layer), 3)
@@ -455,7 +469,8 @@ def kernels_phase():
     whole = [(3, 16, 4, 2, 8, 4, 4, None, 1),
              (3, 32, 4, 2, 128, 4, 3, 8, 2)] if TINY else \
         [(24, 2048, 16, 16, 128, 16, 128, None, 23),
-         (9, 4608, 28, 4, 128, 16, 257, 4096, 8)]
+         (9, 4608, 28, 4, 128, 16, 257, 4096, 8),
+         (3, 2048, 16, 2, 256, 512, 4, None, 2)]
     for layers, s, nh, kvh, hd, ps, entries, window, layer in whole:
         keys = jax.random.split(jax.random.PRNGKey(100 + layer), 5)
         q = jax.random.normal(keys[0], (1, s, nh, hd), dt)
@@ -568,6 +583,46 @@ def kernels_phase():
           arr(b, heads, s, dn), arr(b, s, rope), arr(b, heads, s, dv)),
          2e-2)
 
+    # -- Gated DeltaNet (linear attention): one token a row against rows of
+    #    a whole state pool, in place by layer and state row (two rows
+    #    share the null row 0, as dummy slots do); a prompt in chunks of 64
+    #    whose length is no multiple of the chunk, with a padded tail
+    b, kh, vh, width, layers, pool_rows = (3, 1, 8, 128, 2, 4) if TINY \
+        else (16, 16, 32, 128, 3, 17)
+
+    def unit(t):
+        return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+    pool = f32(layers, pool_rows, vh, width, width)
+    rows = jnp.asarray([0, 0] + list(range(1, b - 1)), jnp.int32)
+    args = (unit(f32(b, kh, width)) * width ** -0.5, unit(f32(b, kh, width)),
+            f32(b, vh, width).astype(dt), -jnp.exp(f32(b, vh)),
+            jax.nn.sigmoid(f32(b, vh)), pool, rows)
+
+    def real_rows(out):
+        # the null row takes whichever of its writers came last
+        return out[0][2:], out[1][:, 1:]
+
+    case("gdn_recurrent_step[layer%dof%d,b%dvh%dd%d]"
+         % (layers - 1, layers, b, vh, width),
+         lambda *a: real_rows(gd.gdn_recurrent_step(
+             *a, layer=layers - 1, interpret=interp)),
+         lambda *a: real_rows(gd._gdn_recurrent_xla(*a, layers - 1)),
+         args, 1e-4)
+    s, real = (100, 71) if TINY else (2048, 1999)
+    kh, vh = (1, 2) if TINY else (16, 32)
+    live = (jnp.arange(s) < real)[None, :, None]
+    args = (unit(f32(1, s, kh, width)) * width ** -0.5,
+            unit(f32(1, s, kh, width)), f32(1, s, vh, width).astype(dt),
+            jnp.where(live, -jnp.exp(f32(1, s, vh) * 2.0), 0.0),
+            jnp.where(live, jax.nn.sigmoid(f32(1, s, vh)), 0.0))
+    case("gdn_chunk_prefill[s%dkh%dvh%dd%d]" % (s, kh, vh, width),
+         lambda *a: (lambda o, st: (o[:, :real], st))(
+             *gd.gdn_chunk_prefill(*a, interpret=interp)),
+         lambda *a: (lambda o, st: (o[:, :real], st))(
+             *gd._gdn_chunk_xla(*a)),
+         args, 2e-3)
+
     # -- int8 matmul + im2col conv: ResNet-50's FC and its first 3x3 conv
     mm = [(8, 40, 12)] if TINY else [(32, 2048, 1000),
                                     (32 * 56 * 56, 576, 64)]
@@ -590,10 +645,11 @@ def kernels_phase():
          (qx, wq, sc), 0.0, exact=True)
 
     exported = set()
-    for mod in (fa, i8, mf, ml):
+    for mod in (fa, i8, mf, ml, gd):
         exported.update(mod.PALLAS_KERNELS)
     covered = set(n.split("[")[0] for n in report)
-    check(covered == exported, "kernels: exported %s, exercised %s"
+    check(ARGS.cases or covered == exported,
+          "kernels: exported %s, exercised %s"
           % (sorted(exported), sorted(covered)))
     status("kernels", "passed", cases=len(report),
            mode="interpret (rehearsal)" if TINY else "mosaic",
@@ -825,7 +881,7 @@ def main():
              time.perf_counter() - t0,
              "; REHEARSAL on the host CPU — nothing about the device was "
              "proven" if TINY else ""), flush=True)
-    if set(ARGS.phases) != set(PHASES):
+    if set(ARGS.phases) != set(PHASES) or ARGS.cases:
         sys.exit("chip_smoke: partial run (--phases %s): no verdict"
                  % ",".join(ARGS.phases))
     result = {"ok": True, "device": {"platform": d0.platform,

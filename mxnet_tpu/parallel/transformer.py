@@ -40,7 +40,8 @@ from .moe import group_limited_routing, moe_ffn_sorted, top_k_gating
 __all__ = ["TransformerConfig", "init_transformer_params",
            "make_transformer_train_step", "transformer_forward_single",
            "init_kv_cache", "init_kv_pages", "PagedKVCache",
-           "HybridKVCache", "LatentKVCache", "kv_layer_kinds", "paged_cache",
+           "HybridKVCache", "LatentKVCache", "LinearStateCache",
+           "kv_layer_kinds", "paged_cache",
            "cache_pools",
            "transformer_decode_step", "transformer_decode_step_paged",
            "transformer_prefill", "transformer_prefill_paged",
@@ -79,6 +80,12 @@ def _moe_held(cfg):
             else cfg.num_experts)
 
 
+def _has_linear(cfg):
+    """Whether some layer is a linear-attention (Gated DeltaNet) layer."""
+    return cfg.linear_layout is not None and any(
+        cfg.linear_layout[:cfg.n_layers])
+
+
 def _yarn_mscale(cfg):
     """YaRN's attention temperature ``0.1 mscale_all_dim ln(factor) + 1``
     (1 without ``rope_scaling``): the softmax scale carries its square."""
@@ -114,23 +121,29 @@ def _rope_inv_freq(cfg, dim):
 
 
 def _layer_rule(cfg, li):
-    """``(rotary, window)`` of layer ``li``: whether q/k rotate there
-    (``rope_layout``; every layer of a "rope" model without one) and
-    how far back it attends (``sliding_window`` where ``window_layout``
-    marks the layer, or everywhere without a layout; None = to the
-    start). The one place a layer's kind is decided."""
+    """``(kind, rotary, window)`` of layer ``li``: ``"linear"`` where
+    ``linear_layout`` marks a Gated DeltaNet layer (no keys, values,
+    rotation or window: a fixed-size state a sequence), else whether q/k
+    rotate there (``rope_layout``; every layer of a "rope" model without
+    one) and how far back it attends (``sliding_window`` where
+    ``window_layout`` marks the layer, or everywhere without a layout;
+    None = to the start), its kind ``"window"`` or ``"full"``. The one
+    place a layer's kind is decided."""
+    if cfg.linear_layout is not None and bool(cfg.linear_layout[li]):
+        return "linear", False, None
     rotary = cfg.pos_type == "rope" and (
         cfg.rope_layout is None or bool(cfg.rope_layout[li]))
     windowed = cfg.sliding_window is not None and (
         cfg.window_layout is None or bool(cfg.window_layout[li]))
-    return rotary, (int(cfg.sliding_window) if windowed else None)
+    return (("window", rotary, int(cfg.sliding_window)) if windowed
+            else ("full", rotary, None))
 
 
 def kv_layer_kinds(cfg):
-    """Per layer, ``"window"`` where the layer attends a sliding window
-    (its cache may forget older positions) else ``"full"``."""
-    return tuple("window" if _layer_rule(cfg, li)[1] else "full"
-                 for li in range(cfg.n_layers))
+    """Per layer, ``"linear"`` where the layer keeps a recurrent state and
+    no keys or values, ``"window"`` where it attends a sliding window
+    (its cache may forget older positions), else ``"full"``."""
+    return tuple(_layer_rule(cfg, li)[0] for li in range(cfg.n_layers))
 
 
 def _expand_kv(t, groups, head_axis):
@@ -205,7 +218,43 @@ def _validate_config(cfg):
         raise ValueError("moe_local_experts=%r (first, count) is not a "
                          "run of the %d experts"
                          % (cfg.moe_local_experts, cfg.num_experts))
-    for name in ("window_layout", "rope_layout"):
+    if _has_linear(cfg):
+        for name in ("linear_key_heads", "linear_value_heads",
+                     "linear_key_dim", "linear_value_dim"):
+            if getattr(cfg, name) < 1:
+                raise ValueError("linear_layout (Gated DeltaNet layers) "
+                                 "needs %s > 0" % name)
+        if cfg.linear_value_heads % cfg.linear_key_heads:
+            raise ValueError("linear_value_heads=%d must divide by "
+                             "linear_key_heads=%d"
+                             % (cfg.linear_value_heads,
+                                cfg.linear_key_heads))
+        if cfg.linear_conv_width < 2:
+            raise ValueError("linear_conv_width=%d: the causal convolution "
+                             "is at least 2 wide" % cfg.linear_conv_width)
+        if _is_mla(cfg) or cfg.sliding_window is not None \
+                or cfg.dense_layers:
+            raise ValueError("linear_layout beside latent attention, a "
+                             "sliding window or dense_layers is not "
+                             "computed: the state cache stands beside ONE "
+                             "paged pool of full-attention layers")
+    rot = _head_dim(cfg) * cfg.rotary_share
+    if not 0 < cfg.rotary_share <= 1 or cfg.pos_type == "rope" and (
+            rot != int(rot) or int(rot) % 2):
+        raise ValueError("rotary_share=%r of head_dim=%d is not an even "
+                         "number of dimensions"
+                         % (cfg.rotary_share, _head_dim(cfg)))
+    if _is_mla(cfg) and (cfg.qk_norm or cfg.attn_gate
+                         or cfg.rotary_share != 1.0):
+        raise ValueError("qk_norm, attn_gate and rotary_share belong to "
+                         "the per-head K/V attention, not to latent "
+                         "attention")
+    if cfg.norm_zero_centered and cfg.norm != "rmsnorm":
+        raise ValueError("norm_zero_centered (gain 1 + w) is an RMSNorm's")
+    if cfg.moe_shared_gate and not cfg.moe_shared_width:
+        raise ValueError("moe_shared_gate needs a shared expert "
+                         "(moe_shared_width > 0)")
+    for name in ("window_layout", "rope_layout", "linear_layout"):
         layout = getattr(cfg, name)
         if layout is not None and len(layout) < cfg.n_layers:
             raise ValueError("%s has %d entries for n_layers=%d"
@@ -223,7 +272,12 @@ _TRAINABLE = {"head_dim": None, "norm": "layernorm",
               "dense_layers": 0, "d_ff_dense": 0, "gate_act": "relu",
               "moe_shared_width": 0, "moe_n_groups": 1,
               "moe_topk_groups": 1, "moe_routed_scale": 1.0,
-              "moe_local_experts": None}
+              "moe_local_experts": None, "linear_layout": None,
+              "linear_key_heads": 0, "linear_value_heads": 0,
+              "linear_key_dim": 0, "linear_value_dim": 0,
+              "linear_conv_width": 0, "rotary_share": 1.0,
+              "qk_norm": False, "attn_gate": False,
+              "norm_zero_centered": False, "moe_shared_gate": False}
 
 
 def _validate_trainable(cfg):
@@ -234,13 +288,6 @@ def _validate_trainable(cfg):
                 "sharded training block computes %s=%r only (the "
                 "single-device forward, prefill and decode paths run "
                 "it)" % (name, getattr(cfg, name), name, only))
-
-
-def _rope_bshd(t, positions, base):
-    """RoPE for (b, s, h, hd) tensors: move heads out, rotate, move
-    back — the one place the layout convention lives."""
-    return _rope(t.transpose(0, 2, 1, 3), positions,
-                 base).transpose(0, 2, 1, 3)
 
 
 def _rope(t, positions, base):
@@ -335,6 +382,30 @@ class TransformerConfig:
     # holds (None = all). The router scores them all; the layer computes
     # its own experts' part of the sum (and the shared expert)
     moe_local_experts: tuple = None
+    # the shared expert's output times sigmoid(x w), w (d, 1)
+    moe_shared_gate: bool = False
+    # -- the per-head K/V attention's other shapes ---------------------------
+    # rotate only the first head_dim * rotary_share dimensions of q and k
+    rotary_share: float = 1.0
+    # an RMSNorm over head_dim on every head's q and k before the rotation
+    qk_norm: bool = False
+    # wq makes a gate beside each head's query ([query ; gate], 2 *
+    # head_dim a head); the attention's output is times sigmoid(gate)
+    attn_gate: bool = False
+    # every RMSNorm's gain is (1 + w), computed in float32
+    norm_zero_centered: bool = False
+    # -- linear attention (Gated DeltaNet, arXiv:2412.06464): a layer with
+    # linear_layout[l] has no keys or values but a recurrent state of
+    # linear_value_heads matrices (linear_key_dim, linear_value_dim) and
+    # the last linear_conv_width - 1 inputs of a causal depthwise
+    # convolution over its q, k, v channels (params["linear_layers"], a
+    # stack of its own; the other layers are params["layers"])
+    linear_layout: tuple = None
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_width: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -357,6 +428,10 @@ def _param_specs(cfg, pp):
             "wq": P("pp", None, None, "tp"), "wk": P("pp", None, None, "tp"),
             "wv": P("pp", None, None, "tp"),
             "wo": P("pp", None, "tp", None)})
+        if cfg.qk_norm:
+            lyr.update({"q_norm_g": P("pp", None, None),
+                        "k_norm_g": P("pp", None, None)})
+    mixer = set(lyr) - {"ln1_g", "ln1_b", "ln2_g", "ln2_b"}
     dense = dict(lyr)                  # a leading dense layer's (below)
     if cfg.num_experts:
         lyr["gate"] = P("pp", None, None, None)
@@ -367,6 +442,8 @@ def _param_specs(cfg, pp):
         if cfg.moe_shared_width:
             for name in _GATED:
                 lyr["ws_" + name] = P("pp", None, None, None)
+        if cfg.moe_shared_gate:
+            lyr["ws_sigmoid"] = P("pp", None, None, None)
     else:
         lyr.update({"w1": P("pp", None, None, "tp"),
                     "w2": P("pp", None, "tp", None)})
@@ -384,6 +461,13 @@ def _param_specs(cfg, pp):
             for name in ("ln1_b", "ln2_b"):
                 del stack[name]
         del specs["lnf_b"]
+    if _has_linear(cfg):
+        # a linear layer: the block's norms and FFN, and in place of the
+        # attention's maps a Gated DeltaNet's
+        specs["linear_layers"] = dict(
+            [(k, v) for k, v in lyr.items() if k not in mixer],
+            **dict((name, P(*("pp",) + (None,) * (rank + 1)))
+                   for name, rank in _GDN_PARAMS))
     if not cfg.tie_embeddings:
         specs["head"] = P(None, None)
     if cfg.pos_type == "learned":
@@ -392,6 +476,13 @@ def _param_specs(cfg, pp):
 
 
 _MLA_MAPS = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+# a Gated DeltaNet layer's parameters with their ranks behind (pp, layers):
+# the fused projections [q | k | v | z] and [b | a], the depthwise
+# convolution's taps (width, channels), the decay's A_log and dt_bias a
+# value head, the gated norm's gain over a value head, the output map
+_GDN_PARAMS = (("gdn_qkvz", 2), ("gdn_ba", 2), ("gdn_conv", 2),
+               ("gdn_a_log", 1), ("gdn_dt_bias", 1), ("gdn_norm_g", 1),
+               ("gdn_out", 2))
 _GATED = ("gate", "up", "down")        # the three maps of a gated FFN
 
 
@@ -413,11 +504,14 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     assert not cfg.dense_layers or pp == 1, "dense_layers needs pp == 1"
     assert (cfg.n_layers - cfg.dense_layers) % pp == 0, \
         "n_layers must divide pp"
-    lps = (cfg.n_layers - cfg.dense_layers) // pp
+    n_linear = kv_layer_kinds(cfg).count("linear")
+    assert not n_linear or pp == 1, "linear_layout needs pp == 1"
+    lps = (cfg.n_layers - cfg.dense_layers - n_linear) // pp
     rng = np.random.RandomState(seed)
     d, f, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
-    dq = cfg.n_heads * _head_dim(cfg)
-    dkv = _kv_heads(cfg) * _head_dim(cfg)
+    hd = _head_dim(cfg)
+    dq = cfg.n_heads * hd
+    dkv = _kv_heads(cfg) * hd
     s = 0.02
 
     def rand(*shape):
@@ -428,12 +522,20 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
                 prefix + "up": rand(pp, n, *lead, d, width),
                 prefix + "down": rand(pp, n, *lead, width, d)}
 
+    def gain(*shape):
+        """A norm's gain: ones, or drawn around 0 where it is (1 + w)."""
+        return rand(*shape) if cfg.norm_zero_centered \
+            else jnp.ones(shape, cfg.dtype)
+
+    def norms(n):
+        return {"ln1_g": gain(pp, n, d),
+                "ln1_b": jnp.zeros((pp, n, d), cfg.dtype),
+                "ln2_g": gain(pp, n, d),
+                "ln2_b": jnp.zeros((pp, n, d), cfg.dtype)}
+
     def block(n):
         """Norms and attention maps of a stack of ``n`` layers."""
-        out = {"ln1_g": jnp.ones((pp, n, d), cfg.dtype),
-               "ln1_b": jnp.zeros((pp, n, d), cfg.dtype),
-               "ln2_g": jnp.ones((pp, n, d), cfg.dtype),
-               "ln2_b": jnp.zeros((pp, n, d), cfg.dtype)}
+        out = norms(n)
         if _is_mla(cfg):
             rq, rkv, nh = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_heads
             dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -447,28 +549,55 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
                 "wkv_b": rand(pp, n, rkv, nh * (dn + dv)),
                 "wo": rand(pp, n, nh * dv, d)})
         else:
-            out.update({"wq": rand(pp, n, d, dq), "wk": rand(pp, n, d, dkv),
+            out.update({"wq": rand(pp, n, d,
+                                   dq * (2 if cfg.attn_gate else 1)),
+                        "wk": rand(pp, n, d, dkv),
                         "wv": rand(pp, n, d, dkv), "wo": rand(pp, n, dq, d)})
+            if cfg.qk_norm:
+                out.update({"q_norm_g": gain(pp, n, hd),
+                            "k_norm_g": gain(pp, n, hd)})
         return out
 
-    layers = block(lps)
-    if _drop_free(cfg):
-        layers["gate"] = rand(pp, lps, d, cfg.num_experts)
-        layers.update(gated("we_", lps, f, _moe_held(cfg)))
-        if cfg.moe_router == "noaux_tc":
-            layers["gate_bias"] = rand(pp, lps, cfg.num_experts)
-        if cfg.moe_shared_width:
-            layers.update(gated("ws_", lps, cfg.moe_shared_width))
-    elif cfg.num_experts:
-        layers["gate"] = rand(pp, lps, d, cfg.num_experts)
-        layers["we1"] = rand(pp, lps, cfg.num_experts, d, f)
-        layers["we2"] = rand(pp, lps, cfg.num_experts, f, d)
-    else:
-        layers["w1"] = rand(pp, lps, d, f)
-        layers["w2"] = rand(pp, lps, f, d)
+    def linear_block(n):
+        """Norms and Gated DeltaNet maps of a stack of ``n`` layers; the
+        decays' A_log drawn wide (memories of a token to hundreds)."""
+        dk_all = cfg.linear_key_heads * cfg.linear_key_dim
+        dv_all = cfg.linear_value_heads * cfg.linear_value_dim
+        vh = cfg.linear_value_heads
+        return dict(norms(n), **{
+            "gdn_qkvz": rand(pp, n, d, 2 * dk_all + 2 * dv_all),
+            "gdn_ba": rand(pp, n, d, 2 * vh),
+            "gdn_conv": jnp.asarray(
+                rng.randn(pp, n, cfg.linear_conv_width,
+                          2 * dk_all + dv_all) * 0.5, cfg.dtype),
+            "gdn_a_log": jnp.asarray(rng.randn(pp, n, vh) * 2.0, cfg.dtype),
+            "gdn_dt_bias": jnp.ones((pp, n, vh), cfg.dtype),
+            "gdn_norm_g": jnp.ones((pp, n, cfg.linear_value_dim),
+                                   cfg.dtype),
+            "gdn_out": rand(pp, n, dv_all, d)})
+
+    def ffn(n):
+        """The block's second half for a stack of ``n`` layers."""
+        if _drop_free(cfg):
+            out = {"gate": rand(pp, n, d, cfg.num_experts)}
+            out.update(gated("we_", n, f, _moe_held(cfg)))
+            if cfg.moe_router == "noaux_tc":
+                out["gate_bias"] = rand(pp, n, cfg.num_experts)
+            if cfg.moe_shared_width:
+                out.update(gated("ws_", n, cfg.moe_shared_width))
+            if cfg.moe_shared_gate:
+                out["ws_sigmoid"] = rand(pp, n, d, 1)
+            return out
+        if cfg.num_experts:
+            return {"gate": rand(pp, n, d, cfg.num_experts),
+                    "we1": rand(pp, n, cfg.num_experts, d, f),
+                    "we2": rand(pp, n, cfg.num_experts, f, d)}
+        return {"w1": rand(pp, n, d, f), "w2": rand(pp, n, f, d)}
+
+    layers = dict(block(lps), **ffn(lps))
     params = {
         "embed": rand(V, d),
-        "lnf_g": jnp.ones((d,), cfg.dtype),
+        "lnf_g": gain(d),
         "lnf_b": jnp.zeros((d,), cfg.dtype),
         "layers": layers,
     }
@@ -476,6 +605,9 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
         params["dense_layers"] = dict(
             block(cfg.dense_layers),
             **gated("w_", cfg.dense_layers, cfg.d_ff_dense))
+    if n_linear:
+        params["linear_layers"] = dict(linear_block(n_linear),
+                                       **ffn(n_linear))
     if not cfg.tie_embeddings:
         params["head"] = rand(d, V)
     if cfg.pos_type == "learned":
@@ -484,7 +616,7 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     specs = _param_specs(cfg, pp)
     # an RMSNorm has no shift: drop what the specs do not name
     params = {k: v for k, v in params.items() if k in specs}
-    for stack in ("layers", "dense_layers"):
+    for stack in ("layers", "dense_layers", "linear_layers"):
         if stack in params:
             params[stack] = {k: v for k, v in params[stack].items()
                              if k in specs[stack]}
@@ -523,8 +655,11 @@ def _norm(cfg, p, name, x):
     if cfg.norm == "rmsnorm":
         xf = x.astype(jnp.float32)
         ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
-        return (xf * jax.lax.rsqrt(ms + cfg.norm_eps)).astype(x.dtype) \
-            * p[name + "_g"]
+        xn = xf * jax.lax.rsqrt(ms + cfg.norm_eps)
+        if cfg.norm_zero_centered:      # the gain is (1 + w), in float32
+            return (xn * (1.0 + p[name + "_g"].astype(jnp.float32))) \
+                .astype(x.dtype)
+        return xn.astype(x.dtype) * p[name + "_g"]
     return _ln(x, p[name + "_g"], p[name + "_b"], cfg.norm_eps)
 
 
@@ -568,7 +703,12 @@ def _ffn(cfg, lp, h, x_in, layers, at):
             layers["we_down"], cfg.moe_top_k, lead=at, route=route,
             first=_moe_first(cfg), act=cfg.gate_act)
         if cfg.moe_shared_width:
-            out = out + _gated_ffn(cfg, lp, "ws_", tok)
+            shared = _gated_ffn(cfg, lp, "ws_", tok)
+            if cfg.moe_shared_gate:
+                shared = shared * jax.nn.sigmoid(jnp.dot(
+                    tok, lp["ws_sigmoid"],
+                    preferred_element_type=jnp.float32)).astype(tok.dtype)
+            out = out + shared
         return out.reshape(h.shape), (experts, active)
     logits = tok @ lp["gate"]
     cap = max(1, int(cfg.capacity_factor * tok.shape[0]
@@ -590,22 +730,24 @@ def _gated_ffn(cfg, lp, prefix, h):
         @ lp[prefix + "down"]
 
 
-def _iter_layers(params):
+def _iter_layers(params, cfg):
     """The model's layers in order: ``(flat index, the stack that holds
     the layer, its (stage, layer) there, its parameters)``. A model with
     two kinds of FFN keeps its leading dense layers in a stack of their
-    own, ``params["dense_layers"]``, before ``params["layers"]``."""
-    li_flat = 0
-    for name in ("dense_layers", "layers"):
-        stack = params.get(name)
-        if stack is None:
-            continue
-        pp, lps = jax.tree_util.tree_leaves(stack)[0].shape[:2]
-        for st in range(pp):
-            for li in range(lps):
-                yield (li_flat, stack, (st, li), jax.tree_util.tree_map(
-                    lambda p: p[st, li], stack))
-                li_flat += 1
+    own, ``params["dense_layers"]``, before ``params["layers"]``; one
+    with linear-attention layers keeps those in ``params["linear_layers"]
+    ``, among the others as ``linear_layout`` says."""
+    taken = {"dense_layers": 0, "linear_layers": 0, "layers": 0}
+    for li_flat in range(cfg.n_layers):
+        name = ("dense_layers" if li_flat < cfg.dense_layers
+                else "linear_layers"
+                if _layer_rule(cfg, li_flat)[0] == "linear" else "layers")
+        stack = params[name]
+        lps = jax.tree_util.tree_leaves(stack)[0].shape[1]
+        st, li = divmod(taken[name], lps)
+        taken[name] += 1
+        yield (li_flat, stack, (st, li), jax.tree_util.tree_map(
+            lambda p: p[st, li], stack))
 
 
 def _stats(cfg, per_layer):
@@ -1036,6 +1178,150 @@ def _latent_write_prompt(cache, li, latent, lengths):
         cache.block_tables, cache.page_size)
 
 
+# ---------------------------------------------------------------------------
+# the per-head K/V attention's projections, and linear attention (Gated
+# DeltaNet): one function a mechanism, shared by the whole-sequence
+# forward, the prefill and the decode step
+# ---------------------------------------------------------------------------
+
+def _rotate(cfg, t, pos):
+    """Rotary embedding of t (..., heads, hd) at positions ``pos`` (...):
+    the pairs (i, i + rot / 2) of the first ``rot = head_dim *
+    rotary_share`` dimensions turn (:func:`_rope_rows`), the rest pass."""
+    rot = int(t.shape[-1] * cfg.rotary_share)
+    half = rot // 2
+    freqs = cfg.rope_base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    turned = _rope_rows(t[..., :rot], pos, freqs)
+    if rot == t.shape[-1]:
+        return turned
+    return jnp.concatenate([turned, t[..., rot:]], -1)
+
+
+def _attn_qkv(cfg, lp, h, pos, rotary):
+    """A full or window layer's ``(q (..., heads, hd), k, v (..., kv
+    heads, hd), gate (..., heads * hd) or None)`` from its normalised
+    input ``h`` (..., d) at positions ``pos`` (...): the three maps; with
+    ``attn_gate`` a head's columns of ``wq`` are [query ; gate]; with
+    ``qk_norm`` an RMSNorm over every head of q and of k; the rotation
+    where the layer has one (:func:`_rotate`)."""
+    hd = _head_dim(cfg)
+    lead = h.shape[:-1]
+    q = (h @ lp["wq"]).reshape(lead + (cfg.n_heads, -1))
+    gate = None
+    if cfg.attn_gate:
+        q, gate = q[..., :hd], q[..., hd:].reshape(lead + (-1,))
+    k = (h @ lp["wk"]).reshape(lead + (_kv_heads(cfg), hd))
+    v = (h @ lp["wv"]).reshape(lead + (_kv_heads(cfg), hd))
+    if cfg.qk_norm:
+        q, k = _norm(cfg, lp, "q_norm", q), _norm(cfg, lp, "k_norm", k)
+    if rotary:
+        q, k = _rotate(cfg, q, pos), _rotate(cfg, k, pos)
+    return q, k, v, gate
+
+
+def _attn_out(lp, o, gate):
+    """The attention's output map over o (..., heads * hd), times the
+    sigmoid of the layer's gate where it has one."""
+    if gate is not None:
+        o = o * jax.nn.sigmoid(gate)
+    return o @ lp["wo"]
+
+
+def _gdn_inputs(cfg, lp, h, tail=None, lengths=None):
+    """A Gated DeltaNet layer's projections, convolution and gates over
+    its normalised input ``h`` (b, s, d). ``tail`` (b, width - 1,
+    channels): the inputs of the convolution before this call's first
+    position (None: the sequence starts here, zeros); ``lengths`` (b,):
+    the rows' real lengths (None: s). Returns ``(q, k (b, s, key heads,
+    dk), v, z (b, s, value heads, dv), g, beta (b, s, value heads)
+    float32, tail')``: q and k after the causal depthwise convolution and
+    SiLU, L2-normalised a head (q times dk ** -0.5 too); ``g = -exp(A_log)
+    softplus(a + dt_bias)`` the log of the state's decay and ``beta =
+    sigmoid(b)`` the write strength, both 0 at and past a row's length so
+    that the padding leaves the state as it is; ``tail'`` the last
+    ``width - 1`` inputs before the row's length (zeros on the left of a
+    row shorter than that)."""
+    b, s, _ = h.shape
+    kh, vh = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    taps = cfg.linear_conv_width
+    f32 = jnp.float32
+    qkvz = h @ lp["gdn_qkvz"]
+    ba = jnp.dot(h, lp["gdn_ba"], preferred_element_type=f32)
+    n_conv = 2 * kh * dk + vh * dv
+    x, z = qkvz[..., :n_conv], qkvz[..., n_conv:]
+    if tail is None:
+        tail = jnp.zeros((b, taps - 1, n_conv), x.dtype)
+    xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = lp["gdn_conv"].astype(f32)
+    y = sum(xp[:, j:j + s].astype(f32) * w[j] for j in range(taps))
+    y = jax.nn.silu(y).astype(h.dtype).astype(f32)
+    if lengths is None:
+        lengths = jnp.full((b,), s, jnp.int32)
+    # x position p sits at xp[p + taps - 1]: the tail ends at lengths - 1
+    new_tail = jnp.take_along_axis(
+        xp, (lengths[:, None] + jnp.arange(taps - 1))[:, :, None], axis=1)
+
+    def unit(t):                      # L2 norm over a head, eps 1e-6
+        return t * jax.lax.rsqrt(
+            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+    q = unit(y[..., :kh * dk].reshape(b, s, kh, dk)) * dk ** -0.5
+    k = unit(y[..., kh * dk:2 * kh * dk].reshape(b, s, kh, dk))
+    v = y[..., 2 * kh * dk:].reshape(b, s, vh, dv).astype(h.dtype)
+    real = (jnp.arange(s)[None, :] < lengths[:, None])[:, :, None]
+    g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
+        ba[..., vh:] + lp["gdn_dt_bias"].astype(f32))
+    beta = jax.nn.sigmoid(ba[..., :vh])
+    return (q, k, v, z.reshape(b, s, vh, dv), jnp.where(real, g, 0.0),
+            jnp.where(real, beta, 0.0), new_tail)
+
+
+def _gdn_out(cfg, lp, o, z):
+    """The layer's output from the rule's read-out ``o`` and the gate
+    ``z`` (..., value heads, dv): RMSNorm over a head with a plain gain
+    (not zero-centred), times SiLU(z) in float32, then the output map."""
+    of = o.astype(jnp.float32)
+    on = (of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True)
+                             + cfg.norm_eps)).astype(z.dtype) \
+        * lp["gdn_norm_g"]
+    gated = (on.astype(jnp.float32)
+             * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+    return gated.reshape(gated.shape[:-2] + (-1,)) @ lp["gdn_out"]
+
+
+def _gdn_prompt(cfg, lp, h, lengths=None):
+    """A Gated DeltaNet layer over whole sequences from their start: h
+    (b, s, d) -> ``(out (b, s, d), state (b, value heads, dk, dv) float32,
+    tail)`` — the state and the convolution's tail as they stand after
+    each row's ``lengths`` (the chunked rule,
+    ``ops/pallas/gated_delta.gdn_chunk_prefill``)."""
+    from ..ops.pallas.gated_delta import gdn_chunk_prefill
+    q, k, v, z, g, beta, tail = _gdn_inputs(cfg, lp, h, None, lengths)
+    o, state = gdn_chunk_prefill(q, k, v, g, beta)
+    return _gdn_out(cfg, lp, o, z), state, tail
+
+
+def _gdn_token(cfg, lp, h, cache, li):
+    """One token a row through linear layer ``li`` (its index among the
+    linear layers) of a :class:`LinearStateCache`: h (b, d) -> ``(out (b,
+    d), cache)``. Each row's state row is read, decayed, written to by
+    the delta rule and read out, in place in the pool (the recurrent
+    rule, ``ops/pallas/gated_delta.gdn_recurrent_step``); its
+    convolution tail takes the token."""
+    from ..ops.pallas.gated_delta import gdn_recurrent_step
+    b = h.shape[0]
+    rows = cache.rows
+    tail = cache.conv[li, rows].reshape(b, cfg.linear_conv_width - 1, -1)
+    q, k, v, z, g, beta, tail = _gdn_inputs(cfg, lp, h[:, None], tail)
+    o, state = gdn_recurrent_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0], cache.state, rows, layer=li)
+    conv = cache.conv.at[li, rows].set(
+        tail.reshape(b, -1).astype(cache.conv.dtype))
+    return (_gdn_out(cfg, lp, o.astype(h.dtype), z[:, 0]),
+            LinearStateCache(cache.full, state, conv, rows))
+
+
 def transformer_forward_single(params, tokens, cfg: TransformerConfig,
                                with_stats=False):
     """Single-device reference forward (used by tests to validate the
@@ -1048,28 +1334,24 @@ def transformer_forward_single(params, tokens, cfg: TransformerConfig,
     hd = _head_dim(cfg)
     groups = cfg.n_heads // _kv_heads(cfg)
     per_layer = []
-    for li_flat, layers, at, lp in _iter_layers(params):
+    for li_flat, layers, at, lp in _iter_layers(params, cfg):
         h = _norm(cfg, lp, "ln1", x)
         b, s, d = h.shape
         x_in = x
+        kind, rotary, window = _layer_rule(cfg, li_flat)
         if _is_mla(cfg):
             x = x + _mla_attend_prompt(
                 cfg, lp, *_mla_compress(cfg, lp, h, jnp.arange(s)[None, :]))
+        elif kind == "linear":
+            x = x + _gdn_prompt(cfg, lp, h)[0]
         else:
-            rotary, window = _layer_rule(cfg, li_flat)
-            q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
-            k = _expand_kv((h @ lp["wk"]).reshape(b, s, _kv_heads(cfg),
-                                                  hd), groups, 2)
-            v = _expand_kv((h @ lp["wv"]).reshape(b, s, _kv_heads(cfg),
-                                                  hd), groups, 2)
-            if rotary:
-                pos = jnp.arange(s)
-                q = _rope_bshd(q, pos, cfg.rope_base)
-                k = _rope_bshd(k, pos, cfg.rope_base)
+            q, k, v, gate = _attn_qkv(cfg, lp, h, jnp.arange(s)[None, :],
+                                      rotary)
+            k, v = _expand_kv(k, groups, 2), _expand_kv(v, groups, 2)
             sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
             sc = jnp.where(_causal_mask(s, window), sc, -1e30)
             o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-            x = x + o.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
+            x = x + _attn_out(lp, o.reshape(b, s, cfg.n_heads * hd), gate)
         f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
         per_layer.append(st_l)
         x = x + f
@@ -1106,6 +1388,10 @@ def init_kv_cache(cfg: TransformerConfig, batch, max_len=None):
         raise ValueError("a latent-attention model (kv_lora_rank > 0) has "
                          "no dense K/V strip: it decodes over the paged "
                          "latent cache (init_kv_pages)")
+    if _has_linear(cfg):
+        raise ValueError("a model with linear-attention layers has no "
+                         "dense K/V strip: it decodes over pages and state "
+                         "rows (init_kv_pages, LinearStateCache)")
     hd = _head_dim(cfg)
     # layer stacking mirrors the params layout (pp, lps, ...)
     n_l = cfg.n_layers
@@ -1213,6 +1499,38 @@ jax.tree_util.register_pytree_node(
     lambda ps, ch: LatentKVCache(ch[0], ch[1], ps))
 
 
+class LinearStateCache(object):
+    """The cache of a model with linear-attention (Gated DeltaNet) layers
+    among full-attention ones: ``full`` is a :class:`PagedKVCache` over
+    the full layers alone (in layer order, :func:`kv_layer_kinds`), and a
+    linear layer keeps, for every sequence, a fixed-size STATE in a row
+    of two pools: ``state`` (linear layers, rows, value heads, dk, dv)
+    float32, the recurrence's matrices, and ``conv`` (linear layers, rows,
+    (width - 1) * channels), the last inputs of the layer's causal
+    convolution, flat so that the minor dim fills whole lane tiles.
+    ``rows`` (b,) int32 names each batch row's state row; row 0 is the
+    NULL ROW, what a step bucket's dummy slots read and write, as dummy
+    slots write the null page. A state row does not grow with the
+    sequence, and is the sequence's for its life. In a program's
+    arguments the pools ride as pairs: ``k_pages = (full K, state)``,
+    ``v_pages = (full V, conv)``, ``block_tables = (full table, rows (b,
+    1))`` (:func:`paged_cache`, :func:`init_kv_pages`)."""
+
+    __slots__ = ("full", "state", "conv", "rows")
+
+    def __init__(self, full, state, conv, rows):
+        self.full = full
+        self.state = state
+        self.conv = conv
+        self.rows = rows
+
+
+jax.tree_util.register_pytree_node(
+    LinearStateCache,
+    lambda c: ((c.full, c.state, c.conv, c.rows), None),
+    lambda _aux, ch: LinearStateCache(*ch))
+
+
 def _check_latent(cfg, cache):
     if _is_mla(cfg) != isinstance(cache, LatentKVCache):
         raise ValueError(
@@ -1220,15 +1538,28 @@ def _check_latent(cfg, cache):
             "LatentKVCache and no other model does (init_kv_pages + "
             "paged_cache build the right one); got %s"
             % type(cache).__name__)
+    if _has_linear(cfg) != isinstance(cache, LinearStateCache):
+        raise ValueError(
+            "a model with linear-attention layers runs over a "
+            "LinearStateCache and no other model does (init_kv_pages + "
+            "paged_cache(cfg=) build the right one); got %s"
+            % type(cache).__name__)
 
 
-def paged_cache(k_pages, v_pages, block_tables, page_size):
+def paged_cache(k_pages, v_pages, block_tables, page_size, cfg=None):
     """The cache view a program builds from its arguments: one pool and
     one table, for a model with window layers a ``(full, window)`` pair
-    of each, for a latent-attention model its one latent pool and None
+    of each, for a latent-attention model its one latent pool and None,
+    for a model with linear layers (told by ``cfg``) the full layers'
+    pools paired with the state pools and the table with the state rows
     (what :func:`init_kv_pages` returns in each case)."""
     if v_pages is None:
         return LatentKVCache(k_pages, block_tables, page_size)
+    if cfg is not None and _has_linear(cfg):
+        return LinearStateCache(
+            PagedKVCache(k_pages[0], v_pages[0], block_tables[0],
+                         page_size),
+            k_pages[1], v_pages[1], block_tables[1].reshape(-1))
     if isinstance(k_pages, (tuple, list)):
         return HybridKVCache(*(PagedKVCache(k, v, bt, page_size)
                                for k, v, bt in zip(k_pages, v_pages,
@@ -1240,20 +1571,32 @@ def cache_pools(cache):
     """``(k_pages, v_pages)`` back out of :func:`paged_cache`'s view."""
     if isinstance(cache, LatentKVCache):
         return cache.pages, None
+    if isinstance(cache, LinearStateCache):
+        return ((cache.full.k_pages, cache.state),
+                (cache.full.v_pages, cache.conv))
     if isinstance(cache, HybridKVCache):
         return ((cache.full.k_pages, cache.window.k_pages),
                 (cache.full.v_pages, cache.window.v_pages))
     return cache.k_pages, cache.v_pages
 
 
+def _kind_index(cfg, li):
+    """Layer ``li``'s index among the layers of its own kind (the layer
+    axis of that kind's pools)."""
+    kinds = kv_layer_kinds(cfg)
+    return kinds[:li].count(kinds[li])
+
+
 def _layer_cache(cache, cfg, li):
     """``(cache that holds layer li, the layer's index in it, put)``;
     ``put(c)`` is the whole cache with that part replaced."""
-    if not isinstance(cache, HybridKVCache):
+    if not isinstance(cache, (HybridKVCache, LinearStateCache)):
         return cache, li, lambda c: c
-    kinds = kv_layer_kinds(cfg)
-    idx = kinds[:li].count(kinds[li])
-    if kinds[li] == "window":
+    idx = _kind_index(cfg, li)
+    if isinstance(cache, LinearStateCache):
+        return cache.full, idx, lambda c: LinearStateCache(
+            c, cache.state, cache.conv, cache.rows)
+    if kv_layer_kinds(cfg)[li] == "window":
         return cache.window, idx, lambda c: HybridKVCache(cache.full, c)
     return cache.full, idx, lambda c: HybridKVCache(c, cache.window)
 
@@ -1267,7 +1610,10 @@ def init_kv_pages(cfg: TransformerConfig, num_pages, page_size):
     (:class:`HybridKVCache`). A latent-attention model has ONE pool of
     kv_lora_rank + qk_rope_head_dim values a token a layer
     (``mla_attention.latent_pool_shape``) and no V pool: ``(pages,
-    None)`` (:class:`LatentKVCache`)."""
+    None)`` (:class:`LatentKVCache`). A model with linear layers takes
+    ``num_pages`` as ``(pages, state rows)`` and gives ``((K pool of the
+    full layers, state pool), (V pool, convolution tails))``
+    (:class:`LinearStateCache`; row 0 is the null row)."""
     if _is_mla(cfg):
         from ..ops.pallas.mla_attention import latent_pool_shape
         return jnp.zeros(latent_pool_shape(
@@ -1280,8 +1626,22 @@ def init_kv_pages(cfg: TransformerConfig, num_pages, page_size):
                  hd)
         return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
+    kinds = kv_layer_kinds(cfg)
+    if "linear" in kinds:
+        if not isinstance(num_pages, (tuple, list)):
+            raise ValueError("a model with linear layers needs num_pages "
+                             "as (pages of the full layers, state rows)")
+        n_lin, rows = kinds.count("linear"), int(num_pages[1])
+        kf, vf = pool(kinds.count("full"), num_pages[0])
+        channels = (2 * cfg.linear_key_heads * cfg.linear_key_dim
+                    + cfg.linear_value_heads * cfg.linear_value_dim)
+        return ((kf, jnp.zeros((n_lin, rows, cfg.linear_value_heads,
+                                cfg.linear_key_dim, cfg.linear_value_dim),
+                               jnp.float32)),
+                (vf, jnp.zeros((n_lin, rows,
+                                (cfg.linear_conv_width - 1) * channels),
+                               cfg.dtype)))
     if isinstance(num_pages, (tuple, list)):
-        kinds = kv_layer_kinds(cfg)
         (kf, vf), (kw, vw) = (pool(kinds.count(kind), n) for kind, n in
                               zip(("full", "window"), num_pages))
         return (kf, kw), (vf, vw)
@@ -1295,12 +1655,6 @@ def _positions_vec(pos, b):
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (b,))
     return pos
-
-
-def _rope_token(t, pos_b, base):
-    """RoPE for one token per row: t (b, heads, hd), pos_b (b,)."""
-    return _rope(t[..., None, :], pos_b[:, None, None],
-                 base)[..., 0, :]
 
 
 def _cache_write_token(cache, li, k_t, v_t, pos_b, window=None):
@@ -1391,7 +1745,6 @@ def transformer_decode_step(params, cache, tokens_t, pos,
     layout) and never again. A model with window layers takes a
     :class:`HybridKVCache` (or the dense dict, which keeps everything
     and masks). ``with_stats`` also returns :func:`_stats`' dict."""
-    hd = _head_dim(cfg)
     b = tokens_t.shape[0]
     pos_b = _positions_vec(pos, b)
     _check_latent(cfg, cache)
@@ -1400,27 +1753,26 @@ def transformer_decode_step(params, cache, tokens_t, pos,
     if cfg.pos_type == "learned":
         x = x + params["pos"][pos_b]                  # (b, d) gather
     per_layer = []
-    for li_flat, layers, at, lp in _iter_layers(params):
+    for li_flat, layers, at, lp in _iter_layers(params, cfg):
         h = _norm(cfg, lp, "ln1", x)
         x_in = x
+        kind, rotary, window = _layer_rule(cfg, li_flat)
         if _is_mla(cfg):
             c_q, latent_t = _mla_compress(cfg, lp, h, pos_b)
             cache = _latent_write_token(cache, li_flat, latent_t, pos_b)
             x = x + _mla_attend_latent(cfg, lp, c_q, cache, li_flat, pos_b)
+        elif kind == "linear":
+            out, cache = _gdn_token(cfg, lp, h, cache,
+                                    _kind_index(cfg, li_flat))
+            x = x + out
         else:
-            rotary, window = _layer_rule(cfg, li_flat)
-            q = (h @ lp["wq"]).reshape(b, cfg.n_heads, hd)
-            k_t = (h @ lp["wk"]).reshape(b, _kv_heads(cfg), hd)
-            v_t = (h @ lp["wv"]).reshape(b, _kv_heads(cfg), hd)
-            if rotary:
-                q = _rope_token(q, pos_b, cfg.rope_base)
-                k_t = _rope_token(k_t, pos_b, cfg.rope_base)
+            q, k_t, v_t, gate = _attn_qkv(cfg, lp, h, pos_b, rotary)
             part, part_li, put = _layer_cache(cache, cfg, li_flat)
             part = _cache_write_token(part, part_li, k_t, v_t, pos_b,
                                       window)
             o = _cache_attend(part, part_li, q, pos_b, cfg, window)
             cache = put(part)
-            x = x + o @ lp["wo"]
+            x = x + _attn_out(lp, o, gate)
         f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
         per_layer.append(st_l)
         x = x + f
@@ -1480,16 +1832,17 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
     # a hybrid cache's rows (and a latent one's) hold pages for their
     # tokens, not for their bucket: its page writes go by the real lengths
     write_lengths = lengths if isinstance(
-        cache, (HybridKVCache, LatentKVCache)) else None
+        cache, (HybridKVCache, LatentKVCache, LinearStateCache)) else None
 
     x = params["embed"][tokens]
     if cfg.pos_type == "learned":
         x = x + params["pos"][:s]
     mask = jnp.tril(jnp.ones((s, s), bool))
     per_layer = []
-    for li_flat, layers, at, lp in _iter_layers(params):
+    for li_flat, layers, at, lp in _iter_layers(params, cfg):
         h = _norm(cfg, lp, "ln1", x)
         x_in = x
+        kind, rotary, window = _layer_rule(cfg, li_flat)
         if _is_mla(cfg):
             # the cache takes the latents; the attend decompresses the
             # very same ones per head (the decode step attends them
@@ -1498,17 +1851,22 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
             cache = _latent_write_prompt(cache, li_flat, latent,
                                          write_lengths)
             x = x + _mla_attend_prompt(cfg, lp, c_q, latent)
+        elif kind == "linear":
+            # the rows' state and convolution tail as they stand after
+            # each prompt's REAL length go into the rows' state rows
+            out, state, tail = _gdn_prompt(cfg, lp, h, lengths)
+            lin = _kind_index(cfg, li_flat)
+            cache = LinearStateCache(
+                cache.full, cache.state.at[lin, cache.rows].set(state),
+                cache.conv.at[lin, cache.rows].set(
+                    tail.reshape(b, -1).astype(cache.conv.dtype)),
+                cache.rows)
+            x = x + out
         else:
-            rotary, window = _layer_rule(cfg, li_flat)
-            q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
-            kg = (h @ lp["wk"]).reshape(b, s, _kv_heads(cfg), hd)
-            vg = (h @ lp["wv"]).reshape(b, s, _kv_heads(cfg), hd)
-            if rotary:
-                # rotate BEFORE caching: decode stores rotated keys, so
-                # prefill must too (q rotates here as well)
-                pos = jnp.arange(s)
-                q = _rope_bshd(q, pos, cfg.rope_base)
-                kg = _rope_bshd(kg, pos, cfg.rope_base)
+            # rotate BEFORE caching: decode stores rotated keys, so
+            # prefill must too (q rotates here as well)
+            q, kg, vg, gate = _attn_qkv(cfg, lp, h, jnp.arange(s)[None, :],
+                                        rotary)
             part, part_li, put = _layer_cache(cache, cfg, li_flat)
             if isinstance(part, PagedKVCache) and on_tpu(q):
                 # fused Pallas prefill: one program computes the causal
@@ -1538,7 +1896,7 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
                 o = jnp.einsum("bhqk,bkhd->bqhd",
                                jax.nn.softmax(sc, -1), v)
             cache = put(part)
-            x = x + o.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
+            x = x + _attn_out(lp, o.reshape(b, s, cfg.n_heads * hd), gate)
         f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
         per_layer.append(st_l)
         x = x + f

@@ -156,7 +156,7 @@ class DecodeSession(object):
     __slots__ = ("prompt", "prompt_len", "max_new_tokens", "stop_token",
                  "deadline", "t_enq", "t_admit", "t_first", "t_done",
                  "tctx", "page_ids", "block_table", "window_page_ids",
-                 "window_block_table", "pos", "last_token",
+                 "window_block_table", "state_row", "pos", "last_token",
                  "generated", "out_tokens", "expert_choices", "error",
                  "_q", "_finished")
 
@@ -176,6 +176,8 @@ class DecodeSession(object):
         self.block_table = None
         self.window_page_ids = None      # a model with window layers:
         self.window_block_table = None   # its ring in their pool
+        self.state_row = None            # a model with linear layers: its
+                                         # row of their state pools
         self.pos = 0                     # next position to WRITE
         self.last_token = None           # feeds the next decode step
         self.generated = 0
@@ -273,9 +275,19 @@ class DecodeEngine(object):
         # its global layers' pages as ever, its window layers' in a
         # pool of their own where a sequence holds a RING of ring_pages
         # entries (serve/kv_pages.py, transformer.HybridKVCache)
-        self._window = ("window" in kv_layer_kinds(model_cfg)
+        kinds = kv_layer_kinds(model_cfg)
+        self._window = ("window" in kinds
                         and int(model_cfg.sliding_window))
         self._ring_pages = self._wpool = None
+        # a model with linear-attention layers keeps a second kind of
+        # state beside its full layers' pages: a fixed-size STATE ROW a
+        # live session, in pools of their own (transformer.
+        # LinearStateCache). A session in a slot holds one and a queued
+        # one none, so there is a row a slot and row 0, the null row of a
+        # step bucket's dummy slots: nothing to configure
+        self._linear_layers = kinds.count("linear")
+        self._state_rows = (PagePool(self._cfg.slots + 1, kind="state")
+                            if self._linear_layers else None)
         if self._window:
             self._ring_pages = min(
                 pages_needed(self._window, self._cfg.page_size) + 1,
@@ -346,6 +358,10 @@ class DecodeEngine(object):
             "layer whose pool they are in (global | window | latent: a "
             "latent-attention model's one pool, which keeps every "
             "position and so reads as global too)", ("kind",))
+        self._m_state_free = _tm.gauge(
+            "decode/state_rows_free", "Free state rows of a model with "
+            "linear-attention layers (a live session holds one for its "
+            "life; the null row is not counted)")
         self._m_moe_rows = _tm.counter(
             "decode/moe_assignments_total",
             "Token-to-expert assignments of the prompts prefilled and "
@@ -378,10 +394,12 @@ class DecodeEngine(object):
 
     def _fresh_pools(self):
         from ..parallel.transformer import init_kv_pages
-        return init_kv_pages(
-            self._model_cfg,
-            (self._cfg.num_pages, self._cfg.window_pages)
-            if self._window else self._cfg.num_pages, self._cfg.page_size)
+        pages = self._cfg.num_pages
+        if self._window:
+            pages = (pages, self._cfg.window_pages)
+        elif self._linear_layers:
+            pages = (pages, self._state_rows.num_pages)
+        return init_kv_pages(self._model_cfg, pages, self._cfg.page_size)
 
     def _note_free(self):
         self._m_free.set(self._pool.free_pages)
@@ -391,6 +409,8 @@ class DecodeEngine(object):
         if self._wpool is not None:
             self._m_pages_free.labels("window").set(
                 self._wpool.free_pages)
+        if self._linear_layers:
+            self._m_state_free.set(self._state_rows.free_pages)
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
@@ -461,7 +481,7 @@ class DecodeEngine(object):
         n_pb = bucket // self._cfg.page_size
         return (self._params, self._k_pages, self._v_pages,
                 self._tables(_np.zeros(n_pb, _np.int32),
-                             _np.zeros(self._ring_pages or 0, _np.int32)),
+                             self._second_table()),
                 _np.zeros((1, bucket), _np.int32),
                 _np.array([bucket], _np.int32))
 
@@ -469,7 +489,7 @@ class DecodeEngine(object):
         return (self._params, self._k_pages, self._v_pages,
                 self._tables(
                     _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32),
-                    _np.zeros((nslots, self._ring_pages or 0), _np.int32)),
+                    self._second_table(nslots)),
                 _np.zeros(nslots, _np.int32),
                 _np.zeros(nslots, _np.int32))
 
@@ -654,10 +674,12 @@ class DecodeEngine(object):
         # write covers the padded prompt) and prompt+max_new positions.
         # With window layers the prefill writes by the prompt's real
         # length: pages for the positions alone, and of the window
-        # pool at most a ring
+        # pool at most a ring. A model with linear layers reserves pages
+        # for its full layers' positions here, and takes its state row
+        # when it leaves the queue for a slot (``_schedule``)
         ps = self._cfg.page_size
         n_pages = pages_needed(plen + max_new, ps)
-        if not (self._window or self._latent):
+        if not (self._window or self._linear_layers or self._latent):
             n_pages = max(n_pages, pages_needed(pick_bucket(
                 plen, self._cfg.prefill_buckets), ps))
         with self._cond:
@@ -778,11 +800,25 @@ class DecodeEngine(object):
         if sess.window_page_ids:
             self._wpool.free(sess.window_page_ids)
             sess.window_page_ids = None
+        if sess.state_row is not None:
+            self._state_rows.free([sess.state_row])
+            sess.state_row = None
 
-    def _tables(self, full, ring):
+    def _tables(self, full, second):
         """Block tables as the programs take them: the one table, or
-        with window layers the (global, window-ring) pair."""
-        return (full, ring) if self._window else full
+        with window layers the (global, window-ring) pair, or with
+        linear layers the (full layers' table, state rows) pair."""
+        return ((full, second) if self._window or self._linear_layers
+                else full)
+
+    def _second_table(self, nslots=None):
+        """What rides beside the block table of one sequence (a prefill)
+        or of ``nslots`` rows (a step), zeroed: a window model's ring(s),
+        a linear model's state row(s) — row 0, the null row."""
+        lead = () if nslots is None else (nslots,)
+        if self._linear_layers:
+            return _np.zeros(lead, _np.int32)
+        return _np.zeros(lead + (self._ring_pages or 0,), _np.int32)
 
     def _retire_locked(self, sess, error=None):
         """Retire a session (caller holds the lock): slot freed for
@@ -856,6 +892,12 @@ class DecodeEngine(object):
             while (self._waiting
                    and len(self._live) < self._cfg.slots):
                 sess = self._waiting.popleft()
+                if self._linear_layers:
+                    # its state row, for its life in a slot: there is a
+                    # row a slot, so a free slot has a free row
+                    assert self._state_rows.free_pages, \
+                        "a free slot without a free state row"
+                    sess.state_row, = self._state_rows.alloc(1)
                 # joins the slot list BEFORE its prefill runs (so a
                 # concurrent close/crash-recover can't lose it);
                 # t_admit is None until the prefill lands, which
@@ -934,6 +976,9 @@ class DecodeEngine(object):
             if self._window:
                 page_ids = (sess.block_table[:n_pb],
                             sess.window_block_table)
+            elif self._linear_layers:
+                page_ids = (sess.block_table[:n_pb],
+                            _np.asarray(sess.state_row, _np.int32))
             elif self._latent:       # the pages past the request's own
                 page_ids = sess.block_table[:n_pb].copy()    # are null
             else:
@@ -945,6 +990,11 @@ class DecodeEngine(object):
                 # latents this prefill writes: a token a layer
                 span.set_attr("latent_context_tokens",
                               sess.prompt_len * self._model_cfg.n_layers)
+            if self._linear_layers:
+                # tokens the chunked rule runs over: the REAL prompt a
+                # linear layer (a bucket's padding is no work)
+                span.set_attr("linear_tokens",
+                              sess.prompt_len * self._linear_layers)
             t0 = _tm.monotonic()
             out, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
                 self._params, self._k_pages, self._v_pages, page_ids,
@@ -1021,13 +1071,17 @@ class DecodeEngine(object):
         tokens = _np.zeros(nslots, _np.int32)
         pos = _np.zeros(nslots, _np.int32)
         bt = _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32)
-        ring = _np.zeros((nslots, self._ring_pages or 0), _np.int32)
+        second = self._second_table(nslots)
         for i, sess in enumerate(live):
             tokens[i] = sess.last_token
             pos[i] = sess.pos
             bt[i] = sess.block_table
             if self._window:
-                ring[i] = sess.window_block_table
+                second[i] = sess.window_block_table
+            elif self._linear_layers:
+                # a session released since the snapshot (a concurrent
+                # cancel or close) steps on the null row
+                second[i] = sess.state_row or 0
         # context_tokens: the positions this step attends over, the
         # new token's own included — the attention kernel's work in a
         # global layer; window_context_tokens: the same in a window
@@ -1043,11 +1097,16 @@ class DecodeEngine(object):
             attrs["latent_context_tokens"] = int(
                 context.sum()) * self._model_cfg.n_layers
             attrs["latent_rows"] = len(live) * self._model_cfg.n_layers
+        if self._linear_layers:
+            # states the recurrent rule reads and writes: a REAL row a
+            # linear layer (dummy slots are no work); context_tokens is
+            # then the full layers' K/V alone
+            attrs["linear_rows"] = len(live) * self._linear_layers
         with _tr.child_span("decode.step", attrs=attrs) as span:
             t0 = _tm.monotonic()
             toks, self._k_pages, self._v_pages = self._step_prog(nslots)(
                 self._params, self._k_pages, self._v_pages,
-                self._tables(bt, ring), tokens, pos)
+                self._tables(bt, second), tokens, pos)
             toks = _np.asarray(toks)
             t1 = _tm.monotonic()
             if self._moe:
@@ -1100,7 +1159,7 @@ class DecodeEngine(object):
                          length):
                     paged = paged_cache(
                         k_pages, v_pages, jax.tree_util.tree_map(
-                            lambda t: t[None], page_ids), ps)
+                            lambda t: t[None], page_ids), ps, cfg)
                     logits, paged, stats = transformer_prefill_paged(
                         params, paged, tokens, length, cfg,
                         with_stats=True)
@@ -1137,7 +1196,7 @@ class DecodeEngine(object):
                 def prog(params, k_pages, v_pages, block_tables, tokens,
                          pos):
                     paged = paged_cache(k_pages, v_pages, block_tables,
-                                        ps)
+                                        ps, cfg)
                     logits, paged, stats = transformer_decode_step(
                         params, paged, tokens, pos, cfg, with_stats=True)
                     out = jnp.argmax(logits, -1).astype(jnp.int32)

@@ -29,6 +29,7 @@ fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
 i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
 mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
 ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
+gd = importlib.import_module("mxnet_tpu.ops.pallas.gated_delta")
 
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 
@@ -155,6 +156,38 @@ def cases():
                [((1, heads, s, 128), BF16), ((1, heads, s, 64), BF16),
                 ((1, heads, s, 128), BF16), ((1, s, 64), BF16),
                 ((1, heads, s, 128), BF16)])
+    # Gated DeltaNet at the served widths: 128 rows of 32 value heads of
+    # (128, 128) states in a whole pool of 9 layers x 129 rows (layer 8,
+    # in place); a 16384-token prompt's 16 key and 32 value heads in
+    # chunks of 64, and one whose length is no multiple of the chunk
+    state_pool = ((9, 129, 32, 128, 128), F32)
+    yield ("gdn_recurrent_step layer8of9 b128 kh16 vh32 d128",
+           lambda q, k, v, g, b, pool, rows: gd.gdn_recurrent_step(
+               q, k, v, g, b, pool, rows, layer=8, interpret=False),
+           [((128, 16, 128), F32), ((128, 16, 128), F32),
+            ((128, 32, 128), BF16), ((128, 32), F32), ((128, 32), F32),
+            state_pool, ((128,), I32)])
+    for s in (16384, 200):
+        yield ("gdn_chunk_prefill s%d kh16 vh32 d128 chunk64" % s,
+               lambda q, k, v, g, b: gd.gdn_chunk_prefill(
+                   q, k, v, g, b, interpret=False),
+               [((1, s, 16, 128), F32), ((1, s, 16, 128), F32),
+                ((1, s, 32, 128), BF16), ((1, s, 32), F32),
+                ((1, s, 32), F32)])
+    # the full layers beside them: 2 KV heads of 256 serving 8 query heads
+    # each, pages of 256 tokens in a whole pool of 3 layers (layer 2)
+    wide = ((3, 2345, 256, 2, 256), BF16)
+    yield ("paged_decode_attention layer2of3 b128kvh2g8hd256 page256",
+           lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+               q, kp, vp, bt, ln, interpret=False, layer=2),
+           [((128, 2, 8, 256), BF16), wide, wide, ((128, 64), I32),
+            ((128,), I32)])
+    kv = ((1, 16384, 2, 256), BF16)
+    yield ("flash_prefill_paged layer2of3 s16384nh16kvh2hd256 page256",
+           lambda q, k, v, kp, vp, bt, ln: fa.flash_prefill_paged(
+               q, k, v, kp, vp, bt, interpret=False, lengths=ln, layer=2),
+           [((1, 16384, 16, 256), BF16), kv, kv, wide, wide,
+            ((1, 64), I32), ((1,), I32)])
     yield ("int8_conv_im2col b32c64 56x56 3x3",
            lambda q, w, s: i8.int8_conv_im2col(
                q, w, s, (1, 1), (1, 1), (1, 1), interpret=False),

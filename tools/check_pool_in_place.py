@@ -10,8 +10,11 @@ paged kernels therefore take the pool whole with the layer's index
 decode-step and prefill programs for a DESCRIBED TPU v5e (the
 ``tools/check_mosaic_aot.py`` trick) with the TPU branches taken, at a
 small model whose pool dwarfs its activations, once with one pool, once
-with a pool a kind of layer and once with a latent-attention model's one
-latent pool (no V pool), and reads the compiled module:
+with a pool a kind of layer, once with a latent-attention model's one
+latent pool (no V pool) and once with a linear-attention model's state
+pools beside its full layers' pages (the recurrent kernel reads and writes
+a row's states in place; the convolution tails are gathered and scattered
+by XLA), and reads the compiled module:
 
 * no instruction of the entry computation other than a Mosaic call produces
   an array of one layer's pool shape (``layer_copies``);
@@ -62,10 +65,22 @@ LATENT = dict(vocab_size=512, d_model=256, n_heads=4, n_layers=3, d_ff=128,
               qk_rope_head_dim=64, v_head_dim=128, dense_layers=1,
               d_ff_dense=256, gate_act="silu", moe_shared_width=128,
               moe_local_experts=(0, 4), dtype=jnp.bfloat16)
+# Gated DeltaNet layers at the served state (128 x 128 a value head) beside
+# a gated full-attention layer: the second "pool" is the state rows
+LINEAR = dict(vocab_size=512, d_model=256, n_heads=4, n_kv_heads=2,
+              head_dim=128, n_layers=8, d_ff=128, max_len=CONTEXT,
+              pos_type="rope", norm="rmsnorm", tie_embeddings=False,
+              num_experts=8, moe_top_k=2, moe_router="topk",
+              gate_act="silu", moe_shared_width=128, moe_shared_gate=True,
+              rotary_share=0.25, qk_norm=True, attn_gate=True,
+              norm_zero_centered=True, linear_layout=(1, 1, 1, 0) * 2,
+              linear_key_heads=4, linear_value_heads=8, linear_key_dim=128,
+              linear_value_dim=128, linear_conv_width=4, dtype=jnp.bfloat16)
 # page counts no other array of the programs has a dimension of
 CONFIGS = (("one_pool", DENSE, 16411, None, PAGE, CONTEXT),
            ("pool_a_kind", HYBRID, 16411, 16417, PAGE, CONTEXT),
-           ("latent_pool", LATENT, 211, None, 512, 2048))
+           ("latent_pool", LATENT, 211, None, 512, 2048),
+           ("state_rows", LINEAR, 16411, 1031, PAGE, CONTEXT))
 
 # value names, result types and opcodes of an HLO text's instructions
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
@@ -142,7 +157,8 @@ def main():
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1, 1, 1),
                 ("dp", "sp", "tp", "pp", "ep"))
     ok = True
-    for name, model, pages, window_pages, page, context in CONFIGS:
+    # ``second``: the second pool's entries — window pages or state rows
+    for name, model, pages, second, page, context in CONFIGS:
         cfg = TransformerConfig(**model)
         params = on_chip(jax.eval_shape(
             lambda: init_transformer_params(cfg, mesh, seed=0)[0]))
@@ -150,23 +166,23 @@ def main():
         # are lowered at the size under test (bench/aot_check.py's way)
         engine = DecodeEngine(params, cfg, DecodeConfig(
             slots=SLOTS, page_size=page, num_pages=2, max_context=context,
-            window_pages=2 if window_pages else None))
+            window_pages=2 if "sliding_window" in model else None))
         dcfg = engine.config
         k_pool, v_pool = on_chip(jax.eval_shape(lambda: init_kv_pages(
-            cfg, (pages, window_pages) if window_pages else pages, page)))
+            cfg, (pages, second) if second else pages, page)))
         bucket, slots = dcfg.prefill_buckets[-1], dcfg.slot_buckets[-1]
-        ring = engine._ring_pages or 0
         real_backend = jax.default_backend
         jax.default_backend = lambda: "tpu"   # on_tpu(): the Mosaic kernels
         try:
             step = engine._step_prog(slots).lower(
                 params, k_pool, v_pool,
                 engine._tables(i32(slots, dcfg.pages_per_seq),
-                               i32(slots, ring)),
+                               on_chip(engine._second_table(slots))),
                 i32(slots), i32(slots)).compile()
             prefill = engine._prefill_prog(bucket).lower(
                 params, k_pool, v_pool,
-                engine._tables(i32(bucket // page), i32(ring)),
+                engine._tables(i32(bucket // page),
+                               on_chip(engine._second_table())),
                 i32(1, bucket), i32(1)).compile()
         finally:
             jax.default_backend = real_backend
