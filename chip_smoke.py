@@ -367,9 +367,10 @@ def kernels_phase():
                                                     1.0 / d ** 0.5)[0],
              (q, k, v), 2e-2)
 
-    # -- paged decode attention: page_size 16, GQA groups of 4 and 2
+    # -- paged decode attention: page_size 16, GQA groups of 4 and 2 (heads
+    #    of whole lane tiles: a narrower head is served by the twin, below)
     paged = [(2, 2, 2, 8, 4, 8, 2)] if TINY else \
-        [(8, 2, 4, 64, 16, 512, 16), (8, 4, 2, 128, 16, 512, 16)]
+        [(8, 2, 4, 128, 16, 512, 16), (8, 4, 2, 128, 16, 512, 16)]
     for b, kvh, g, hd, ps, npages, ppseq in paged:
         q = f32(b, kvh, g, hd)
         kp, vp = f32(npages, ps, kvh, hd), f32(npages, ps, kvh, hd)
@@ -383,6 +384,15 @@ def kernels_phase():
              lambda q, kp, vp, bt, ln, hd=hd: fa._paged_decode_xla(
                  q, kp, vp, bt, ln, 1.0 / hd ** 0.5),
              (q, kp, vp, bt, ln), 2e-2)
+
+    if not TINY:
+        narrow = (f32(8, 2, 4, 64), f32(64, 16, 2, 64), f32(64, 16, 2, 64),
+                  jnp.asarray(1 + np.arange(32).reshape(8, 4), jnp.int32),
+                  jnp.asarray(rng.randint(1, 65, size=(8,)), jnp.int32))
+        check(not _mosaic_in(fa.paged_decode_attention, *narrow),
+              "kernels: paged_decode_attention at head_dim 64 did not "
+              "dispatch to its twin")
+        jax.block_until_ready(fa.paged_decode_attention(*narrow))
 
     # -- the same against a RING of block-table entries under a window
     #    (rows not wrapped, wrapped once and several times)
@@ -445,6 +455,9 @@ def kernels_phase():
              (3, 3, 2, 2, 8, 4, 3, 12, 8, 2)] if TINY else \
         [(24, 32, 16, 1, 128, 16, 128, 513, None, 23),
          (9, 32, 4, 7, 128, 16, 257, 4096, 4096, 8),
+         # ... and its global layers: 1024 table entries a row, of which
+         # the walk visits the written ones, 16 pages a block
+         (3, 32, 4, 7, 128, 16, 1024, 2049, None, 2),
          # a linear-attention model's 3 full layers: 2 KV heads of 256
          # serving 8 query heads each, pages of 512 tokens
          (3, 32, 2, 8, 256, 512, 32, 129, None, 2)]

@@ -370,88 +370,211 @@ def _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
     return o.astype(q.dtype)
 
 
-def _paged_kernel(bt_ref, len_ref, _layer_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size, sm_scale, kv_heads,
-                  window=None):
-    """Grid (b, pages_per_seq): the trailing page dimension iterates
-    sequentially per sequence, accumulating an online softmax in VMEM
-    scratch exactly like the flash forward kernel — the block table is
-    scalar-prefetched so each step's page DMA is issued from
-    ``block_tables[b, p]`` before the body runs.
+# VMEM the walk's page blocks may claim: two slots each of K and V, an
+# eighth of a v5e core's 16 MiB scoped limit (the rest is q, o, the f32
+# working set of a block — its scores against every head — and the
+# compiler's own stack)
+_PAGED_VMEM_BUDGET = 2 << 20
+# ... and the most tokens one compute block holds, where a page is
+# smaller: longer blocks ran no faster on the chip, and what a row's last
+# block holds past the row's length is worked on for nothing
+_PAGED_BLOCK_TOKENS = 256
 
-    One step holds one whole page — every K/V head of it, block
-    ``(1, page_size, kv_heads, hd)`` of ONE layer of the whole pool (the
-    layer is a squeezed leading block dim that the index map fills in
-    from a third scalar-prefetched array, so every layer of a model runs
-    ONE kernel: a static index would be lowered once a layer, 24 times
-    the set-up) — and loops over the heads in the kernel. Mosaic refuses a per-head
-    block ``(1, page_size, 1, hd)`` (its second-minor dim is 1 of
-    ``kv_heads``: neither a multiple of 8 nor the full dim). Nothing is
-    done to the pool outside the kernel: XLA has no view of an array
-    for a custom call's operand, so a reshape is a relayout copy of the
-    WHOLE pool per call and a ``pool[layer]`` slice a copy of that
-    layer's share of it (201 MB a call at the served size: the whole
-    pool once a token step). The block index picks the layer and the
-    page instead, and moves exactly the live pages."""
-    b_i = pl.program_id(0)
-    p_i = pl.program_id(1)
-    n_p = pl.num_programs(1)
 
-    @pl.when(p_i == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+def _pages_per_block(page_size, kv_heads, head_dim, itemsize, n_entries):
+    """``P``, the pages one compute block of the decode walk holds: as
+    many as give a block of up to ``_PAGED_BLOCK_TOKENS`` tokens whose
+    four buffers (K and V, double-buffered) fit ``_PAGED_VMEM_BUDGET``,
+    by the page's bytes: 8 pages of 16 tokens x 16 heads x 128 in bf16
+    (64 KB), 16 of 16 x 4 x 128 (16 KB), one of 512 x 2 x 256 (512 KB);
+    never more than the table has entries."""
+    page_bytes = page_size * kv_heads * head_dim * itemsize
+    return max(1, min(_PAGED_VMEM_BUDGET // (4 * page_bytes),
+                      _PAGED_BLOCK_TOKENS // page_size, n_entries))
 
-    length = len_ref[b_i]
-    if window is None:
-        start = p_i * page_size
-        live = start < length
-    else:
-        # a ring: this entry holds the newest page congruent to it (see
-        # ring_positions); it counts while it was ever written and
-        # still reaches into the last ``window`` positions
-        a_last = (length - 1) // page_size
-        page = a_last - jax.lax.rem(a_last - p_i + n_p, n_p)
-        start = page * page_size
-        # (a live page always holds a visible key, so the running
-        # maximum is finite from the first page on, as without a window)
-        live = jnp.logical_and(page >= 0,
-                               start + page_size > length - window)
 
-    @pl.when(live)
-    def _body():
-        for h in range(kv_heads):
-            q = q_ref[0, h].astype(jnp.float32) * sm_scale       # (g, hd)
-            k = k_ref[0, :, h, :].astype(jnp.float32)            # (ps, hd)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)              # (g, ps)
-            kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            seen = kpos < length
-            if window is not None:
-                seen = jnp.logical_and(seen, kpos >= length - window)
-            s = jnp.where(seen, s, NEG_INF)
+def _pages_can_be_copied(kv_heads, head_dim, itemsize):
+    """Whether Mosaic can slice one page out of a pool in HBM, which the
+    decode walk's copies do: an HBM ref is sliced by whole memory tiles
+    of its two minor dims, so ``head_dim`` must fill 128-lane tiles and,
+    in a packed dtype, ``kv_heads`` the tile's rows (2, 4 or 8 of them:
+    the least power of two that holds the heads and a packed pair).
+    float32 pools take any head count. No served model falls outside;
+    a pool that does is read by the twin (``tools/check_mosaic_aot.py``
+    holds both sides of the rule against the compiler)."""
+    if head_dim % _LANES:
+        return False
+    if itemsize >= 4:
+        return True
+    rows = 4 // itemsize
+    while rows < min(kv_heads, 8):
+        rows *= 2
+    return kv_heads % rows == 0
 
-            m_prev = m_scr[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_scr[h, :, :1] + jnp.sum(p, -1, keepdims=True)
-            v = v_ref[0, :, h, :].astype(jnp.float32)            # (ps, hd)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # (g, hd)
-            acc_scr[h] = acc_scr[h] * alpha + pv
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    @pl.when(p_i == n_p - 1)
-    def _fin():
-        for h in range(kv_heads):
-            l = l_scr[h, :, :1]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
+def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sem, *, sm_scale, window=None):
+    """ONE invocation walks every row's LIVE pages, ``P`` pages a compute
+    block (``kbuf``/``vbuf`` are ``(2, P, page_size, kv_heads, hd)``: two
+    slots), accumulating an online softmax in float32 — running maximum,
+    sum and accumulator are the block loop's carry.
+
+    The walk. Row ``r`` of length ``n`` has written logical pages ``0 ..
+    (n - 1) // page_size``; under a ``window`` the table is a ring
+    (:func:`ring_positions`: page ``a`` at entry ``a % entries``) and
+    only the pages the ring still holds that reach into the last
+    ``window`` positions count. Those are the row's live pages, walked in
+    position order, ``cdiv(live, P)`` blocks a row: a dummy slot (length
+    1) costs one page, not a table's worth of grid steps over the null
+    page. The kernel issues its own page copies from the scalar-
+    prefetched block table — one DMA a live page, K and V each, into the
+    slot the arithmetic is not reading — and the first block of row ``r
+    + 1`` is in flight while row ``r`` finishes. A length under 1 reads
+    as 1 (every row owns a block, so the chain of copies never breaks).
+
+    The arithmetic. A block is two products for all its heads
+    (``q_ref``/``o_ref`` are ``(rows, kv_heads * g, hd)``): its
+    ``(tokens, kv_heads)`` rows flat against every query head, the scores
+    of another K/V head's rows masked out like the positions past the
+    length. Mosaic cannot take one head of a page without a strided copy
+    of it, and one-row products a head leave the MXU idle: even at a
+    group of one query head a K/V head, where fifteen sixteenths of the
+    scores are masked, the flat product runs at the speed of the copies.
+    Scores, maximum, sum and accumulator are float32, and the products
+    take float32 operands at the MXU's default precision, as the
+    grid-per-page kernel before this one did.
+
+    Nothing is done to the pool outside the kernel: it stays whole in
+    HBM, ``(layers, pages, page_size, kv_heads, hd)``, and the layer's
+    index is a third scalar-prefetched operand, so every layer of a
+    model runs ONE lowered kernel (a static index would be lowered once
+    a layer, 24 times the set-up). XLA has no view of an array for a
+    custom call's operand, so a reshape is a relayout copy of the WHOLE
+    pool per call and a ``pool[layer]`` slice a copy of that layer's
+    share of it (201 MB a call at the served size: the whole pool once a
+    token step)."""
+    n_rows, n_heads, hd = q_ref.shape
+    _, n_p, page_size, kvh = kbuf.shape[:4]
+    g = n_heads // kvh
+    layer = layer_ref[0]
+    n_entries = bt_ref.shape[1]
+
+    def live_pages(r):
+        """(length, first live logical page, how many) of row ``r``."""
+        length = jnp.maximum(len_ref[r], 1)
+        a_last = jax.lax.div(length - 1, page_size)
+        if window is None:
+            return length, 0, jnp.minimum(a_last + 1, n_entries)
+        a_first = jnp.maximum(
+            jax.lax.div(jnp.maximum(length - window, 0), page_size),
+            a_last - n_entries + 1)
+        return length, a_first, a_last - a_first + 1
+
+    def block_extent(r, i):
+        """(table entry of the first page, live pages) of block ``i`` of
+        row ``r``."""
+        _, a_first, n_live = live_pages(r)
+        e_0 = a_first + i * n_p
+        if window is not None:
+            e_0 = jax.lax.rem(e_0, n_entries)
+        return e_0, jnp.minimum(n_p, n_live - i * n_p)
+
+    def page_copies(page, slot, j):
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      kbuf.at[slot, j], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      vbuf.at[slot, j], sem.at[1, slot]))
+
+    def start_block(r, i, slot):
+        e_0, n = block_extent(r, i)
+
+        def one(j, carry):
+            entry = e_0 + j
+            if window is not None:      # the ring wraps inside a block
+                entry = jnp.where(entry >= n_entries, entry - n_entries,
+                                  entry)
+            for copy in page_copies(bt_ref[r, entry], slot, j):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    def wait_block(r, i, slot):
+        def one(j, carry):
+            # (a wait reads its descriptor's size and semaphore only)
+            for copy in page_copies(0, slot, j):
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, block_extent(r, i)[1], one, 0)
+
+    def attend_block(q, slot, pos_0, length, carry):
+        """One block, its (token, K/V head) rows flat against every
+        query head. ``q`` (kv_heads * g, hd), scaled."""
+        m, l, acc = carry
+        n_cols = n_p * page_size * kvh
+        k = kbuf[slot].reshape(n_cols, hd)
+        s = jax.lax.dot_general(
+            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (heads, cols)
+        # column c is position pos_0 + c // kvh of K/V head c % kvh;
+        # query head h reads K/V head h // g
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (n_heads, 1), 0)
+        seen = col < (length - pos_0) * kvh
+        if window is not None:
+            seen = jnp.logical_and(
+                seen, col >= (length - window - pos_0) * kvh)
+        seen = jnp.logical_and(
+            seen, jax.lax.rem(col, kvh) == jax.lax.div(row, g))
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)          # 0 where masked: m_new is finite
+        v = vbuf[slot].reshape(n_cols, hd)
+        pv = jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (heads, hd)
+        return (m_new, alpha * l + jnp.sum(p, -1, keepdims=True),
+                alpha * acc + pv)
+
+    # a row's last block may be partly copied: what is left in the slot
+    # there is masked out of the scores, but p = 0 times a NaN is a NaN,
+    # so the V slots start finite (what an earlier block left is a live
+    # page's, finite by the same argument)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    start_block(0, 0, 0)
+
+    def row(r, slot):
+        length, a_first, n_live = live_pages(r)
+        n_blocks = jax.lax.div(n_live + n_p - 1, n_p)
+        q = q_ref[r].astype(jnp.float32) * sm_scale
+
+        def block(i, carry):
+            slot, carry = carry[0], carry[1:]
+            last = i + 1 == n_blocks
+            nxt = jnp.where(last, r + 1, r)
+
+            @pl.when(nxt < n_rows)
+            def _prefetch():
+                start_block(nxt, jnp.where(last, 0, i + 1), 1 - slot)
+
+            wait_block(r, i, slot)
+            # (a block's first page is live and a live page always holds
+            # a visible key of every head, so the running maximum is
+            # finite from the first block on)
+            return (1 - slot,) + attend_block(
+                q, slot, (a_first + i * n_p) * page_size, length, carry)
+
+        slot, _, l, acc = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (slot, jnp.full((n_heads, 1), NEG_INF, jnp.float32),
+             jnp.zeros((n_heads, 1), jnp.float32),
+             jnp.zeros((n_heads, hd), jnp.float32)))
+        o_ref[r] = (acc / l).astype(o_ref.dtype)
+        return slot
+
+    jax.lax.fori_loop(0, n_rows, row, 0)
 
 
 def _check_layer(k_pages, layer):
@@ -496,18 +619,21 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     window : optional int — attend only the last ``window`` of them,
         and read the table as a RING: position ``p`` lives at entry
         ``(p // page_size) % pages_per_seq`` (a table as long as the
-        context is the special case that never wraps). The grid walks
-        the ring's entries, so a window layer costs its window, not
-        its context.
+        context is the special case that never wraps). The kernel
+        walks the ring's live entries, so a window layer costs its
+        window, not its context.
     layer : int — which layer of a whole pool is read. It reaches the
         kernel as a scalar-prefetched operand, so a model's layers share
         one lowered kernel.
 
     Returns (b, kv_heads, group, head_dim). Forward-only (serving);
-    no VJP is defined. On TPU this is a Mosaic kernel whose page DMAs
-    are issued from the scalar-prefetched block table, so HBM traffic
-    is exactly the live pages of each sequence; off-TPU (and under the
-    interpreter inside shard_map) the pure-lax gather twin runs —
+    no VJP is defined. On TPU this is a Mosaic kernel that walks each
+    row's live pages, several a compute block, copying them itself from
+    the scalar-prefetched block table (``_paged_kernel``): HBM traffic
+    and loop trips are exactly the live pages of each sequence. How many
+    pages make a block follows the page's bytes (``_pages_per_block``);
+    nothing chooses it. Off-TPU, and for a pool Mosaic cannot slice by
+    page (``_pages_can_be_copied``), the pure-lax gather twin runs —
     same contract, the tier-1 path.
     """
     b, kvh, g, hd = q.shape
@@ -516,15 +642,14 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         sm_scale = 1.0 / (hd ** 0.5)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    if interpret is None:
-        if not on_tpu(q):
-            # production off-TPU path: the XLA twin, not a python-
-            # interpreted per-page DMA emulation (interpret=True still
-            # forces the interpreter for kernel-logic tests)
-            return _paged_decode_xla(q, k_pages, v_pages, block_tables,
-                                     lengths, float(sm_scale), window,
-                                     layer)
-        interpret = False
+    # the twin: the production path off the TPU (not a python-interpreted
+    # per-page DMA emulation; interpret=True still forces the interpreter
+    # for kernel-logic tests), and on it for a pool Mosaic cannot slice
+    if (interpret is None and not on_tpu(q)) or (
+            not interpret and not _pages_can_be_copied(
+                kvh, hd, k_pages.dtype.itemsize)):
+        return _paged_decode_xla(q, k_pages, v_pages, block_tables, lengths,
+                                 float(sm_scale), window, layer)
     k_pages, v_pages, layer = _whole_pools(k_pages, v_pages, layer)
     return _paged_decode(q, k_pages, v_pages, block_tables, lengths, layer,
                          float(sm_scale), bool(interpret),
@@ -535,38 +660,31 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                                              "window"))
 def _paged_decode(q, k_pages, v_pages, block_tables, lengths, layer,
                   sm_scale, interpret, window):
+    """The jitted wrapper's NAME is what the device trace prints for the
+    custom call (``_paged_decode.N``) and what the benchmark's kernel
+    metrics look for: keep it."""
     b, kvh, g, hd = q.shape
     page_size = k_pages.shape[2]
-    n_pb = block_tables.shape[1]
-
-    def q_map(b_i, p_i, bt, ln, li):
-        return (b_i, 0, 0, 0)
-
-    def kv_map(b_i, p_i, bt, ln, li):
-        return (li[0], bt[b_i, p_i], 0, 0, 0)
-
-    kv_spec = pl.BlockSpec((None, 1, page_size, kvh, hd), kv_map)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, n_pb),
-        in_specs=[pl.BlockSpec((1, kvh, g, hd), q_map), kv_spec, kv_spec],
-        out_specs=pl.BlockSpec((1, kvh, g, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((kvh, g, _LANES), jnp.float32),
-            pltpu.VMEM((kvh, g, _LANES), jnp.float32),
-            pltpu.VMEM((kvh, g, hd), jnp.float32),
-        ],
-    )
+    n_p = _pages_per_block(page_size, kvh, hd, k_pages.dtype.itemsize,
+                           block_tables.shape[1])
+    # q and o whole in VMEM, their heads flat: a free reshape of two
+    # small arrays (the pools are the operands that are never reshaped)
+    qo_spec = pl.BlockSpec((b, kvh * g, hd), lambda *_: (0, 0, 0))
+    hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    buf = pltpu.VMEM((2, n_p, page_size, kvh, hd), k_pages.dtype)
     return pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=page_size,
-                          sm_scale=sm_scale, kv_heads=kvh, window=window),
-        grid_spec=spec,
+        functools.partial(_paged_kernel, sm_scale=sm_scale, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(1,),
+            in_specs=[qo_spec, hbm_spec, hbm_spec], out_specs=qo_spec,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
         out_shape=jax.ShapeDtypeStruct(
-            (b, kvh, g, hd), q.dtype, vma=_out_vma(q, k_pages, v_pages)),
+            (b, kvh * g, hd), q.dtype, vma=_out_vma(q, k_pages, v_pages)),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_tables, lengths, layer, q, k_pages, v_pages)
+    )(block_tables, lengths, layer, q.reshape(b, kvh * g, hd), k_pages,
+      v_pages).reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -248,21 +248,139 @@ def test_prefill_bucket_padding_is_invisible(model):
     assert_same_logits(l_pg, l_ref)
 
 
-def test_paged_attention_kernel_matches_xla_twin():
+def _walk_case(kvh, g, hd, ps, n_entries, lengths, layers=None, seed=5):
+    """q, pools, a block table whose rows own distinct pages (the entries
+    past a row's live prefix name the null page 0) and the lengths."""
+    rng = np.random.RandomState(seed)
+    b = len(lengths)
+    live = [-(-n // ps) for n in lengths]
+    n_pages = 1 + sum(live)
+    shape = (n_pages, ps, kvh, hd) if layers is None \
+        else (layers, n_pages, ps, kvh, hd)
+    q = jnp.asarray(rng.randn(b, kvh, g, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(*shape).astype(np.float32))
+    vp = jnp.asarray(rng.randn(*shape).astype(np.float32))
+    bt = np.zeros((b, n_entries), np.int32)
+    pages = iter(1 + rng.permutation(n_pages - 1))
+    for r, n in enumerate(live):
+        bt[r, :n] = [next(pages) for _ in range(n)]
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lengths, jnp.int32)
+
+
+# (kv_heads, group, head_dim, page_size, table entries, lengths, layers,
+# layer): the three served cells' shapes at small page counts. In float32
+# a block holds P = 4 pages at 16 heads of 128, 16 at 4 heads of 128, one
+# at pages of 512: lengths 1 (a dummy slot), around a page's and a block's
+# end, the full table
+_WALK_CASES = {
+    "two_rows": (2, 2, 8, 4, 2, [5, 7], None, None),
+    "chat_16x1x128": (16, 1, 128, 16, 20,
+                      [1, 15, 16, 17, 63, 64, 65, 320], None, None),
+    "chat_one_row": (16, 1, 128, 16, 20, [200], None, None),
+    "chat_dummy_rows": (16, 1, 128, 16, 20, [1, 1, 300, 1], None, None),
+    "chat_last_layer": (16, 1, 128, 16, 20, [129, 1, 16], 3, 2),
+    "mixed_4x7x128": (4, 7, 128, 16, 40,
+                      [1, 15, 16, 17, 255, 256, 257, 640], None, None),
+    "mixed_last_layer": (4, 7, 128, 16, 40, [513, 1], 2, 1),
+    "long_2x8x256_page512": (2, 8, 256, 512, 4,
+                             [1, 511, 512, 513, 2048], None, None),
+    "long_last_layer": (2, 8, 256, 512, 4, [1025, 1], 3, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_attention_kernel_matches_xla_twin(case):
     """The Pallas paged decode-attention kernel (interpret mode) agrees
     with its pure-lax gather twin — same contract the TPU path runs."""
     from mxnet_tpu.ops.pallas.flash_attention import (
         _paged_decode_xla, paged_decode_attention)
-    rng = np.random.RandomState(5)
-    q = jnp.asarray(rng.randn(2, 2, 2, 8).astype(np.float32))
-    kp = jnp.asarray(rng.randn(8, 4, 2, 8).astype(np.float32))
-    vp = jnp.asarray(rng.randn(8, 4, 2, 8).astype(np.float32))
-    bt = jnp.asarray(np.array([[1, 2], [3, 4]], np.int32))
-    ln = jnp.asarray(np.array([5, 7], np.int32))
-    ref = _paged_decode_xla(q, kp, vp, bt, ln, 1 / np.sqrt(8))
-    got = paged_decode_attention(q, kp, vp, bt, ln, interpret=True)
+    kvh, g, hd, ps, n_entries, lengths, layers, layer = _WALK_CASES[case]
+    q, kp, vp, bt, ln = _walk_case(kvh, g, hd, ps, n_entries, lengths,
+                                   layers)
+    ref = _paged_decode_xla(q, kp, vp, bt, ln, 1 / np.sqrt(hd), layer=layer)
+    got = paged_decode_attention(q, kp, vp, bt, ln, interpret=True,
+                                 layer=layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((16, 16, 128, 2, 128), 8),       # decode_chat: pages of 64 KB
+    ((16, 4, 128, 2, 1024), 16),      # decode_mixed_len: 16 KB, global
+    ((16, 4, 128, 2, 257), 16),       # ... and its rings
+    ((512, 2, 256, 2, 32), 1),        # decode_long_answers: 512 KB
+    ((16, 4, 128, 2, 5), 5),          # never more than the table holds
+    ((16, 2, 8, 4, 64), 16),          # the interpreter's small heads
+    ((16, 16, 128, 4, 20), 4),        # float32 pages are twice the bytes
+])
+def test_pages_per_block_follows_the_page_bytes(shape, want):
+    """``P`` is a function of the operands' shapes alone: a block of up
+    to 256 tokens whose four buffers fit the VMEM budget."""
+    from mxnet_tpu.ops.pallas.flash_attention import (
+        _PAGED_VMEM_BUDGET, _pages_per_block)
+    ps, kvh, hd, itemsize, n_entries = shape
+    got = _pages_per_block(*shape)
+    assert got == want
+    assert 4 * got * ps * kvh * hd * itemsize \
+        <= max(_PAGED_VMEM_BUDGET, 4 * ps * kvh * hd * itemsize)
+
+
+@pytest.mark.parametrize("kvh,hd,itemsize,want", [
+    (16, 128, 2, True), (4, 128, 2, True), (2, 256, 2, True),   # the cells
+    (24, 128, 2, True), (8, 128, 2, True),
+    (1, 128, 2, False), (3, 128, 2, False), (12, 128, 2, False),
+    (2, 64, 2, False), (2, 64, 4, False),         # a head under a lane tile
+    (1, 128, 4, True), (5, 128, 4, True),         # float32: any head count
+])
+def test_pools_the_decode_walk_cannot_copy_pages_of_get_the_twin(
+        kvh, hd, itemsize, want):
+    """Mosaic slices an HBM ref by whole memory tiles: the kernel runs
+    where a page's (kv_heads, head_dim) fills them, the twin elsewhere
+    (``tools/check_mosaic_aot.py`` compiles both sides for the v5e)."""
+    from mxnet_tpu.ops.pallas.flash_attention import _pages_can_be_copied
+    assert _pages_can_be_copied(kvh, hd, itemsize) is want
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_paged_attention_kernel_reads_live_pages_only(window):
+    """The walk copies the pages a row's live prefix names and no other:
+    with every other page of the pool NaN (the null page, the pages no
+    row owns and, under a window, the ring entries that fell out of it)
+    the kernel's output is finite and equals the twin's on the clean
+    pool."""
+    from mxnet_tpu.ops.pallas.flash_attention import (
+        _paged_decode_xla, paged_decode_attention)
+    rng = np.random.RandomState(11)
+    kvh, g, hd, ps = 4, 7, 128, 16
+    n_entries = 12 if window is None else 9
+    lengths = [1, 17, 100, 192] if window is None else [1, 60, 150, 1000]
+    b, n_pages = len(lengths), 1 + len(lengths) * n_entries + 3
+    q = jnp.asarray(rng.randn(b, kvh, g, hd).astype(np.float32))
+    kp = rng.randn(2, n_pages, ps, kvh, hd).astype(np.float32)
+    vp = rng.randn(2, n_pages, ps, kvh, hd).astype(np.float32)
+    bt = 1 + np.arange(b * n_entries, dtype=np.int32).reshape(b, n_entries)
+    live = np.zeros(n_pages, bool)
+    for r, n in enumerate(lengths):
+        a_last = (n - 1) // ps
+        if window is None:
+            bt[r, a_last + 1:] = 0           # unwritten entries: null page
+            live[bt[r, :a_last + 1]] = True
+        else:
+            first = max(0, (n - window) // ps, a_last - n_entries + 1)
+            for a in range(first, a_last + 1):
+                live[bt[r, a % n_entries]] = True
+    ln = jnp.asarray(lengths, jnp.int32)
+    want = _paged_decode_xla(q, jnp.asarray(kp), jnp.asarray(vp),
+                             jnp.asarray(bt), ln, 1 / np.sqrt(hd), window,
+                             layer=1)
+    kp[:, ~live] = np.nan
+    vp[:, ~live] = np.nan
+    kp[0], vp[0] = np.nan, np.nan            # and every other layer
+    got = np.asarray(paged_decode_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt), ln,
+        interpret=True, window=window, layer=1))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("layer", [0, 2])

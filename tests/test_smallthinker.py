@@ -328,21 +328,42 @@ def test_grouped_ffn_kernel_matches_its_twin():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_windowed_paged_decode_kernel_matches_its_twin():
+# (rows, kv_heads, group, head_dim, page_size, ring entries, window,
+# lengths): the first is the small ring of PR 26; the others SmallThinker's
+# window layers' shape — 4 K/V heads serving 7 query heads of 128 — on a
+# ring one compute block holds (9 entries) and one it does not (40 entries,
+# blocks of 16 pages): not wrapped, filled exactly, wrapped once and many
+# times, at a page's end and in its middle, a dummy slot
+_RING_CASES = {
+    "small": (2, 2, 8, 4, 3, 8, [5, 14, 39]),
+    "ring_in_one_block": (4, 7, 128, 16, 9, 128,
+                          [1, 100, 144, 145, 200, 1000, 1008]),
+    "ring_of_two_blocks": (4, 7, 128, 16, 40, 624,
+                           [1, 255, 256, 257, 640, 700, 5000]),
+    "one_row": (4, 7, 128, 16, 9, 128, [333]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RING_CASES))
+def test_windowed_paged_decode_kernel_matches_its_twin(case):
+    kvh, g, hd, ps, ring, window, lengths = _RING_CASES[case]
     rng = np.random.RandomState(5)
-    b, kvh, g, hd, ps, ring, window = 3, 2, 2, 8, 4, 3, 8
+    b = len(lengths)
     q = jnp.asarray(rng.randn(b, kvh, g, hd).astype(np.float32))
-    kp = jnp.asarray(rng.randn(12, ps, kvh, hd).astype(np.float32))
-    vp = jnp.asarray(rng.randn(12, ps, kvh, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(b * ring + 1, ps, kvh, hd)
+                     .astype(np.float32))
+    vp = jnp.asarray(rng.randn(b * ring + 1, ps, kvh, hd)
+                     .astype(np.float32))
     bt = jnp.asarray(1 + np.arange(b * ring, dtype=np.int32)
                      .reshape(b, ring))
-    # not wrapped, wrapped once, wrapped three times (mid-page)
-    ln = jnp.asarray(np.array([5, 14, 39], np.int32))
+    ln = jnp.asarray(np.array(lengths, np.int32))
     want = _paged_decode_xla(q, kp, vp, bt, ln, 1 / np.sqrt(hd), window)
     got = paged_decode_attention(q, kp, vp, bt, ln, interpret=True,
                                  window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+    if lengths[0] > min(window, ring * ps):
+        return
     # and the ring's twin is the plain twin while nothing has wrapped
     plain = _paged_decode_xla(q[:1], kp, vp, bt[:1], ln[:1],
                               1 / np.sqrt(hd))

@@ -44,6 +44,7 @@ def cases():
                                                   interpret=False),
                [((b, h, s, d), dt)] * 3)
     for dt in (F32, BF16):
+        # (heads narrower than a lane tile: the call compiles, as the twin)
         for b, kvh, g, hd in ((8, 2, 4, 64), (8, 4, 2, 128), (4, 2, 4, 32),
                               (8, 1, 8, 64), (8, 8, 1, 128)):
             pool = ((512, 16, kvh, hd), dt)
@@ -104,6 +105,27 @@ def cases():
                q, kp, vp, bt, ln, interpret=False, window=4096, layer=8),
            [((32, 4, 7, 128), BF16), whole, whole, ((32, 257), I32),
             ((32,), I32)])
+    # ... and its 3 global layers: 1024 table entries a row, walked 16
+    # pages a block (the window layers' rings too; 8 at the first cell's
+    # 64 KB pages)
+    global_pool = ((3, 6144, 16, 4, 128), BF16)
+    yield ("paged_decode_attention layer2of3 entries1024 b32kvh4g7hd128",
+           lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+               q, kp, vp, bt, ln, interpret=False, layer=2),
+           [((32, 4, 7, 128), BF16), global_pool, global_pool,
+            ((32, 1024), I32), ((32,), I32)])
+    # which pools the decode walk can copy pages out of: bf16 head counts
+    # that fill their memory tile's rows run the kernel, the others (and
+    # the narrow heads above) must still compile, as the twin
+    for kvh, g in ((2, 2), (24, 1), (1, 8), (3, 2), (6, 2), (12, 1)):
+        pool = ((2, 300, 16, kvh, 128), BF16)
+        yield ("paged_decode_attention b8kvh%dg%dhd128 bfloat16 %s"
+               % (kvh, g, "kernel" if fa._pages_can_be_copied(kvh, 128, 2)
+                  else "twin"),
+               lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+                   q, kp, vp, bt, ln, interpret=False, layer=1),
+               [((8, kvh, g, 128), BF16), pool, pool, ((8, 64), I32),
+                ((8,), I32)])
     kv = ((1, 16384, 4, 128), BF16)
     yield ("flash_prefill_paged layer8of9 window4096 ring257 s16384nh28kvh4",
            lambda q, k, v, kp, vp, bt, ln: fa.flash_prefill_paged(
@@ -181,6 +203,13 @@ def cases():
            lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
                q, kp, vp, bt, ln, interpret=False, layer=2),
            [((128, 2, 8, 256), BF16), wide, wide, ((128, 64), I32),
+            ((128,), I32)])
+    # ... and as the cell serves them, pages of 512: one page a block
+    wide512 = ((3, 1173, 512, 2, 256), BF16)
+    yield ("paged_decode_attention layer2of3 b128kvh2g8hd256 page512",
+           lambda q, kp, vp, bt, ln: fa.paged_decode_attention(
+               q, kp, vp, bt, ln, interpret=False, layer=2),
+           [((128, 2, 8, 256), BF16), wide512, wide512, ((128, 32), I32),
             ((128,), I32)])
     kv = ((1, 16384, 2, 256), BF16)
     yield ("flash_prefill_paged layer2of3 s16384nh16kvh2hd256 page256",
