@@ -32,7 +32,15 @@ whole batch hostage and a short request pays worst-case latency.
   ``decode.prefill`` / ``decode.schedule`` spans fan into every
   participating request trace exactly like ``serve.batch`` does, and
   the scheduler's own loop logs one ``decode.iteration`` per pass with
-  its prefills and its step under it, context or none.
+  its prefills and its step under it, context or none;
+* ONE STEP IN FLIGHT: while the next step's rows are exactly the rows
+  of the step on the device, the loop dispatches it with that step's
+  token array still on the device and only then fetches and delivers
+  the tokens, so the dispatch and the fetch lie behind the device's
+  work. It holds the dispatch until the step in flight is due to end
+  (less a lead a dispatch needs), so that a request arriving meanwhile
+  is prefilled right behind that step, not behind one more; any change
+  of the rows drains the pipeline into one step from host arrays.
 
 Decoding is greedy (argmax) — deliberately: the acceptance contract is
 that batched continuous decode is BITWISE-identical to per-request
@@ -41,8 +49,9 @@ decode, and tests/test_decode_serve.py asserts it token-for-token.
 
 Telemetry: ``decode/tokens_total``, ``decode/slot_occupancy``,
 ``decode/page_pool_free``, ``decode/prefill_seconds`` /
-``decode/step_seconds``, ``decode/preempted_total``,
-``decode/timeouts_total``, ``decode/worker_restarts_total``.
+``decode/step_seconds``, ``decode/steps_ahead_total``,
+``decode/preempted_total``, ``decode/timeouts_total``,
+``decode/worker_restarts_total``.
 Knobs: ``MXNET_DECODE_*`` (config.py). Docs: docs/decode_serving.md.
 """
 from __future__ import annotations
@@ -73,7 +82,51 @@ __all__ = ["DecodeConfig", "DecodeEngine", "DecodeSession"]
 # cover less than half of it, logs one warning with its split
 _STALL_S = 1.0
 
+# a step that goes ahead is dispatched a LEAD before the step in flight is
+# due to end. The lead covers the dispatch, the hold's wake-up and the time
+# a fetch takes to see a step end; the loop learns it from what it sees:
+# it grows by _LEAD_UP whenever a step found the step before it already
+# landed (the device idled), and shrinks by _LEAD_DOWN whenever it did not
+# — so that about one step in twenty-five finds the device idle — never
+# under _LEAD_MIN_S nor over a step
+_LEAD_MIN_S = 0.2e-3
+_LEAD_UP, _LEAD_DOWN = 1.25, 0.99
+# weight of a new reading in the step's device time (an exponential mean)
+_EWMA = 0.25
+
 _SENTINEL = object()
+
+
+class _Attrs(dict):
+    """The attributes of a span recorded after the fact, gathered as a
+    live span's are (``set_attr``)."""
+
+    __slots__ = ()
+
+    def set_attr(self, key, value):
+        self[key] = value
+        return self
+
+
+class _Step(object):
+    """One decode step's program call, from before its dispatch until its
+    tokens are delivered: its rows (live sessions in slot order) and the
+    host arrays it was called with, which the step after it reuses when
+    it goes ahead; its two outputs (``feed``: the tokens as the next step
+    takes them, on the device; ``out``: what is fetched, the tokens and
+    behind them what the expert layers did); the ``decode.iteration``
+    that dispatched it (``parent``), its span's attributes, and its
+    instants (``end``: when its device work is due to end)."""
+
+    __slots__ = ("rows", "parent", "nslots", "tables", "pos", "feed", "out",
+                 "attrs", "ahead", "t0", "t_dispatched", "end")
+
+    def __init__(self, rows, parent):
+        self.rows = rows
+        self.parent = parent
+        self.feed = self.out = None
+        self.ahead = False
+        self.end = 0.0
 
 
 def _prefill_variant():
@@ -345,6 +398,22 @@ class DecodeEngine(object):
         self._iter_hook = None
         # (t0, t_dispatched, t_fetched) of this pass's program calls
         self._calls = []
+        # decode steps dispatched and not yet delivered, oldest first (one
+        # between passes, two while one goes ahead of the other); the
+        # session whose prefill is in flight; sessions retired while one
+        # of those still writes their pages, which go back when it lands
+        self._flight = []
+        self._prefilling = None
+        self._held = []
+        # why the last step that did not go ahead could not (drained_for)
+        self._why = "idle"
+        # what the loop has seen: the device time of a step by slot
+        # bucket, the lead, and when a program's output last reached the
+        # host (and whether its fetch waited for the device)
+        self._step_s = {}
+        self._lead_s = _LEAD_MIN_S
+        self._t_landed = 0.0
+        self._landed_waited = False
 
         self._m_requests = _tm.counter(
             "decode/requests_total", "Decode requests admitted")
@@ -386,10 +455,18 @@ class DecodeEngine(object):
             "product had to read")
         self._m_prefill = _tm.histogram(
             "decode/prefill_seconds",
-            "Prefill wall time per admission (bucketed prompt forward)")
+            "Prefill wall time per admission (bucketed prompt forward): "
+            "its output's fetch less the later of its dispatch and the "
+            "fetch of the output before it")
         self._m_step = _tm.histogram(
             "decode/step_seconds",
-            "Decode step wall time (one token for every live slot)")
+            "Decode step wall time (one token for every live slot): its "
+            "tokens' fetch less the later of its dispatch and the fetch "
+            "of the output before it")
+        self._m_ahead = _tm.counter(
+            "decode/steps_ahead_total",
+            "Decode steps dispatched while the step before them was still "
+            "running on the device (with its token array as their input)")
         self._m_preempted = _tm.counter(
             "decode/preempted_total",
             "Sessions retired abnormally mid-decode (crash containment "
@@ -400,13 +477,19 @@ class DecodeEngine(object):
         self._note_free()
 
     def _fresh_pools(self):
+        """Zeroed pools, committed to their device: a program's outputs
+        are, so that a call on fresh pools keys the executable every
+        later call does (the buffers are not copied)."""
+        import jax
         from ..parallel.transformer import init_kv_pages
         pages = self._cfg.num_pages
         if self._window:
             pages = (pages, self._cfg.window_pages)
         elif self._linear_layers:
             pages = (pages, self._state_rows.num_pages)
-        return init_kv_pages(self._model_cfg, pages, self._cfg.page_size)
+        pools = init_kv_pages(self._model_cfg, pages, self._cfg.page_size)
+        self._dev = next(iter(jax.tree_util.tree_leaves(pools)[0].devices()))
+        return jax.device_put(pools, self._dev)
 
     def _note_free(self):
         self._m_free.set(self._pool.free_pages)
@@ -497,8 +580,16 @@ class DecodeEngine(object):
                 self._tables(
                     _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32),
                     self._second_table(nslots)),
-                _np.zeros(nslots, _np.int32),
+                self._on_device(_np.zeros(nslots, _np.int32)),
                 _np.zeros(nslots, _np.int32))
+
+    def _on_device(self, tokens):
+        """A step's tokens from the host, committed to the pools' device:
+        the form of the token array a step that goes ahead takes from the
+        step before it, so that both call ONE executable (a host array
+        would key another)."""
+        import jax
+        return jax.device_put(tokens, self._dev)
 
     def _precompile(self):
         """Compile the whole ladder while it is being lowered: this
@@ -551,10 +642,30 @@ class DecodeEngine(object):
                 prog, sargs,
                 pkey=_pg.ProgramKey("decode_step", self._graph_hash,
                                     {"slots": int(nslots)}))
-        toks, self._k_pages, self._v_pages = _pg.warm_twice(
+        feed, out, self._k_pages, self._v_pages = _pg.warm_twice(
             prog, sargs,
-            rebuild=lambda out, a: (a[0], out[1], out[2]) + a[3:])
-        _np.asarray(toks)
+            rebuild=lambda out, a: (a[0], out[2], out[3]) + a[3:])
+        _np.asarray(out)
+        # as a step that goes ahead runs: its tokens straight out of the
+        # step before it, each call queued behind the one before; timed
+        # as the loop sees such steps, fetch to fetch
+        tables, pos = sargs[3], sargs[5]
+        calls = []
+        for _ in range(3):
+            t0 = _tm.monotonic()
+            feed, out, self._k_pages, self._v_pages = prog(
+                self._params, self._k_pages, self._v_pages, tables, feed,
+                pos)
+            calls.append((t0, _tm.monotonic(), out))
+        fetched = []
+        for _t0, _td, out in calls:
+            _np.asarray(out)
+            fetched.append(_tm.monotonic())
+        self._step_s[nslots] = ((fetched[-1] - fetched[0])
+                                / (len(fetched) - 1))
+        # the lead starts at twice what such a dispatch took
+        self._lead_s = max(self._lead_s, 2.0 * sorted(
+            td - t0 for t0, td, _out in calls)[1])
 
     @property
     def ready(self):
@@ -734,9 +845,9 @@ class DecodeEngine(object):
 
         A waiting session releases its pages immediately (no compute
         ever touched them). A live one is marked failed and SWEPT by
-        the scheduler at the next iteration boundary: its pages may
-        still be written by in-flight compute this step, so freeing
-        them here could hand them to a new admission mid-write.
+        the scheduler at the next iteration boundary, and its pages go
+        back once the steps in flight that write them have landed:
+        freeing them here could hand them to a new admission mid-write.
         Returns True when this call cancelled the session."""
         err = MXNetError("decode session cancelled: %s" % reason)
         with self._cond:
@@ -785,7 +896,18 @@ class DecodeEngine(object):
 
     def _crash_recover(self, exc):
         err = MXNetError("decode step failed: %s" % exc)
+        # a call still in flight ends before its pages go back and its
+        # pools are dropped (the pools are the last call's outputs, so
+        # they wait for every call before it); one that failed has none
+        try:
+            import jax
+            jax.block_until_ready((self._k_pages, self._v_pages))
+        except Exception:
+            pass
         with self._cond:
+            del self._flight[:]
+            self._prefilling = None
+            self._release_held_locked()
             victims = list(self._live) + list(self._waiting)
             del self._live[:]
             self._waiting.clear()
@@ -801,6 +923,29 @@ class DecodeEngine(object):
         self._k_pages, self._v_pages = self._fresh_pools()
 
     def _release_pages(self, sess):
+        """Give a retired session's pages and state row back (caller holds
+        the lock), or, while a call in flight still writes them, keep
+        them until it has landed (``_release_held_locked``)."""
+        if (sess is self._prefilling
+                or any(sess in c.rows for c in self._flight)):
+            if sess not in self._held:
+                self._held.append(sess)
+            return
+        self._free_pages(sess)
+
+    def _release_held_locked(self):
+        if not self._held:
+            return
+        writing = set(s for c in self._flight for s in c.rows)
+        writing.add(self._prefilling)
+        still = [s for s in self._held if s in writing]
+        for sess in self._held:
+            if sess not in writing:
+                self._free_pages(sess)
+        self._held = still
+        self._note_free()
+
+    def _free_pages(self, sess):
         if sess.page_ids:
             self._pool.free(sess.page_ids)
             sess.page_ids = None
@@ -857,11 +1002,13 @@ class DecodeEngine(object):
             t_top = _tm.monotonic()
             hook = self._iter_hook
             if hook is not None:
+                self._land_all()         # the hook sees every token
                 hook()
             _fault.inject("decode.step")
             with self._cond:
                 wreq, self._warmup_req = self._warmup_req, None
             if wreq is not None:
+                self._land_all()
                 try:
                     self._do_warmup()
                 except BaseException as exc:
@@ -872,7 +1019,7 @@ class DecodeEngine(object):
             t_wait0 = None
             with self._cond:
                 while (not self._waiting and not self._live
-                       and self._warmup_req is None):
+                       and not self._flight and self._warmup_req is None):
                     if self._closing:
                         return
                     if t_wait0 is None:
@@ -889,18 +1036,111 @@ class DecodeEngine(object):
             # requests' traces. ``live``: sequences carried over from
             # the pass before — they wait while this pass admits and
             # prefills; ``t_wait0``: when the loop began to wait for
-            # work, on a pass that had to
+            # work, on a pass that had to; ``t_held``: when the pass's hold
+            # for the step in flight ended, on a pass that held
             attrs = {"live": carried}
             if t_wait0 is not None:
                 attrs["t_wait0"] = t_wait0
             t_pass0 = _tm.monotonic()
             self._calls = []
             with _tr.start_span("decode.iteration", ring=False,
-                                attrs=attrs):
-                for sess in self._schedule():
-                    self._prefill(sess)
-                self._step()
+                                attrs=attrs) as it:
+                self._pass(it)
             self._check_stall(t_top, waited, t_pass0, _tm.monotonic())
+
+    def _pass(self, it):
+        """One pass: the step in flight is followed by the next step of
+        the same rows, dispatched before its own tokens are fetched (it
+        goes AHEAD); or the loop drains — expiry and admission, each
+        admission's prefill queued right behind the step in flight, that
+        step's tokens, then a step of the rows live now from host arrays."""
+        why = self._why
+        if self._flight:
+            why = self._hold(it)
+            if why is None:
+                self._go_ahead(it)
+                return
+        admits = self._schedule()
+        for sess in admits:
+            self._prefill(sess)
+        self._land_all()
+        self._step(it, "admit" if admits else why)
+
+    def _lead(self):
+        """How long before the step in flight is due to end the next one
+        is dispatched (a method: a test holds the loop by replacing it)."""
+        return self._lead_s
+
+    def _hold(self, it):
+        """Wait until the step in flight is due to end less the lead a
+        dispatch needs, unless something changes the next step's rows
+        first (``submit``, ``cancel`` and ``close`` wake the loop); returns
+        why the next step cannot go ahead, or None."""
+        rec = self._flight[-1]
+        held = False
+        with self._cond:
+            why = self._cannot_go_ahead(rec)
+            while why is None:
+                left = rec.end - self._lead() - _tm.monotonic()
+                if left <= 0:
+                    break
+                held = True
+                self._cond.wait(left)
+                why = self._cannot_go_ahead(rec)
+        if held:
+            it.set_attr("t_held", _tm.monotonic())
+        return why
+
+    def _cannot_go_ahead(self, rec):
+        """Why the step after ``rec`` (in flight, its tokens undelivered)
+        cannot have exactly its rows, or None (caller holds the lock)."""
+        if self._iter_hook is not None:
+            return "hook"
+        if self._warmup_req is not None:
+            return "warmup"
+        now = _tm.monotonic()
+        for sess in rec.rows:
+            if sess.done:
+                return "retire" if sess.error is None else "cancel"
+            if sess.generated + 2 > sess.max_new_tokens:
+                return "retire"
+            if sess.deadline is not None and now > sess.deadline:
+                return "expire"
+        if self._waiting:
+            if len(self._live) + len(self._held) < self._cfg.slots:
+                return "admit"
+            if any(s.deadline is not None and now > s.deadline
+                   for s in self._waiting):
+                return "expire"
+        return None
+
+    def _settle(self, rec):
+        """After ``rec``'s dispatch (and the landing of the step before
+        it): when its successor is already known not to go ahead for a
+        reason other than an admission, land it now — the next pass would
+        only wait for it. An admission's prefill is queued behind it."""
+        with self._cond:
+            why = self._cannot_go_ahead(rec)
+        if why is not None and why != "admit":
+            self._land(rec)
+            self._why = why
+
+    def _go_ahead(self, it):
+        """Dispatch the step after the one in flight with its rows, their
+        tables, one position on and its token array as the tokens (a
+        bucket's dummy slots stay at position 0), then land the one in
+        flight."""
+        prev = self._flight[-1]
+        rec = self._dispatch(it, prev.rows, prev.nslots, prev.tables,
+                             prev.feed, prev.pos + (prev.pos > 0), prev)
+        self._land(prev)
+        rec.end = (self._t_landed if rec.ahead
+                   else rec.t_dispatched) + self._step_s[rec.nslots]
+        self._settle(rec)
+
+    def _land_all(self):
+        while self._flight:
+            self._land(self._flight[0])
 
     def _note_call(self, span, t0, t_dispatched, t_fetched):
         """A program call's instants: the call returned (arguments, the
@@ -957,8 +1197,10 @@ class DecodeEngine(object):
             t_sched0 = _tm.monotonic()
             evictions = self._expire_locked()
             admits = []
-            while (self._waiting
-                   and len(self._live) < self._cfg.slots):
+            # a retired session whose pages a step in flight still writes
+            # keeps its slot (and its state row) until that step lands
+            while (self._waiting and len(self._live) + len(self._held)
+                   < self._cfg.slots):
                 sess = self._waiting.popleft()
                 if self._linear_layers:
                     # its state row, for its life in a slot: there is a
@@ -981,13 +1223,12 @@ class DecodeEngine(object):
     def _expire_locked(self):
         """Fail sessions past their deadline (queued: before a prefill
         is wasted on them; live: the slot frees this iteration) and
-        sweep cancelled live sessions whose pages were kept until
-        in-flight compute landed. Returns the number evicted."""
+        sweep cancelled live sessions. Returns the number evicted."""
         now = _tm.monotonic()
         evicted = 0
         for sess in [s for s in self._live if s.done]:
-            # cancelled mid-decode: no compute is in flight between
-            # iterations, so the deferred page release is safe now
+            # cancelled mid-decode: its pages go back once no step in
+            # flight writes them (_release_pages)
             self._live.remove(sess)
             self._release_pages(sess)
             self._m_preempted.inc()
@@ -1033,12 +1274,14 @@ class DecodeEngine(object):
         power-of-two ladder bucket, run ONE batched causal forward
         that writes the prompt K/V into the session's pages, and emit
         the first generated token from the logits at the last real
-        position."""
+        position. A step in flight lands behind the prefill's dispatch:
+        the device runs the prefill right after that step."""
         bucket = pick_bucket(sess.prompt_len, self._cfg.prefill_buckets)
         n_pb = bucket // self._cfg.page_size
         with self._cond:
             if sess.done:                # failed concurrently (close/
                 return                   # cancel/deadline) pre-prefill
+            self._prefilling = sess
             # snapshot under the lock: a concurrent close may null
             # page_ids the instant the session is failed
             if self._window:
@@ -1068,6 +1311,7 @@ class DecodeEngine(object):
                 self._params, self._k_pages, self._v_pages, page_ids,
                 padded, _np.array([sess.prompt_len], _np.int32))
             t_dispatched = _tm.monotonic()
+            self._land_all()
             out = _np.asarray(out).reshape(-1)
             tok0 = int(out[0])
             t1 = _tm.monotonic()
@@ -1076,9 +1320,13 @@ class DecodeEngine(object):
                 experts = self._note_moe(span, out[1:], bucket,
                                          sess.prompt_len)
                 sess.expert_choices.append(experts[:, :sess.prompt_len])
+        # the time the prefill added to the loop (the step it was queued
+        # behind landed first)
+        added = t1 - max(t0, self._t_landed)
+        self._t_landed = t1
         self._m_prefill.observe(
-            t1 - t0, trace_id=sess.tctx.trace_id if sess.tctx else None)
-        _health.note_decode("prefill", bucket, t1 - t0,
+            added, trace_id=sess.tctx.trace_id if sess.tctx else None)
+        _health.note_decode("prefill", bucket, added,
                             self._prog_costs.get(("prefill", bucket)))
         if sess.tctx is not None and sess.tctx.sampled:
             _tr.record_span("decode.prefill", sess.tctx, t0, t1,
@@ -1086,6 +1334,8 @@ class DecodeEngine(object):
                             attrs={"bucket": bucket,
                                    "prompt_len": sess.prompt_len})
         with self._cond:
+            self._prefilling = None
+            self._release_held_locked()
             if sess.done:
                 return
             sess.t_admit = t0
@@ -1129,12 +1379,13 @@ class DecodeEngine(object):
                     and tok == sess.stop_token)):
             self._retire_locked(sess)
 
-    def _step(self):
-        """One decode iteration: every live slot advances one token
-        through the slot-bucket program (dummy slots write the null
-        page and are discarded)."""
+    def _step(self, it, why):
+        """A decode step of every live slot from host arrays (dummy slots
+        write the null page and are discarded), left in flight. ``why``:
+        why it does not go ahead of a step before it."""
         with self._cond:
-            live = [s for s in self._live if s.t_admit is not None]
+            live = [s for s in self._live
+                    if s.t_admit is not None and not s.done]
         if not live:
             return
         nslots = pick_bucket(len(live), self._cfg.slot_buckets)
@@ -1152,61 +1403,128 @@ class DecodeEngine(object):
                 # a session released since the snapshot (a concurrent
                 # cancel or close) steps on the null row
                 second[i] = sess.state_row or 0
+        rec = self._dispatch(it, live, nslots, self._tables(bt, second),
+                             self._on_device(tokens), pos, None, why)
+        rec.end = rec.t_dispatched + self._step_s.get(nslots, 0.0)
+        self._settle(rec)
+
+    def _dispatch(self, it, rows, nslots, tables, tokens, pos, prev,
+                  why=None):
+        """Call the slot bucket's step program; returns the call, in
+        flight. ``prev``: the step in flight whose token array
+        ``tokens`` is, when this one goes ahead of it."""
+        rec = _Step(rows, it.ctx)
+        with self._cond:
+            self._flight.append(rec)     # its rows' pages are written now
+        rec.nslots, rec.tables, rec.pos = nslots, tables, pos
         # context_tokens: the positions this step attends over, the
         # new token's own included — the attention kernel's work in a
         # global layer; window_context_tokens: the same in a window
         # layer, which sees at most its window of each row
-        context = pos[:len(live)] + 1
-        attrs = {"context_tokens": int(context.sum()),
-                 "window_context_tokens": int(
-                     _np.minimum(context, self._window).sum()
-                     if self._window else context.sum())}
+        context = pos[:len(rows)] + 1
+        attrs = rec.attrs = _Attrs(
+            context_tokens=int(context.sum()),
+            window_context_tokens=int(
+                _np.minimum(context, self._window).sum()
+                if self._window else context.sum()))
         if self._latent:
             # cached vectors the step's absorbed attends read, and the
             # queries they are read for, all layers
             attrs["latent_context_tokens"] = int(
                 context.sum()) * self._model_cfg.n_layers
-            attrs["latent_rows"] = len(live) * self._model_cfg.n_layers
+            attrs["latent_rows"] = len(rows) * self._model_cfg.n_layers
         if self._linear_layers:
             # states the recurrent rule reads and writes: a REAL row a
             # linear layer (dummy slots are no work); context_tokens is
             # then the full layers' K/V alone
-            attrs["linear_rows"] = len(live) * self._linear_layers
-        with _tr.child_span("decode.step", attrs=attrs) as span:
-            t0 = _tm.monotonic()
-            toks, self._k_pages, self._v_pages = self._step_prog(nslots)(
-                self._params, self._k_pages, self._v_pages,
-                self._tables(bt, second), tokens, pos)
-            t_dispatched = _tm.monotonic()
-            toks = _np.asarray(toks)
-            t1 = _tm.monotonic()
-            self._note_call(span, t0, t_dispatched, t1)
-            if self._moe:
-                experts = self._note_moe(span, toks[nslots:], nslots,
-                                         len(live))
-                for i, sess in enumerate(live):
-                    sess.expert_choices.append(experts[:, i:i + 1])
-        self._m_step.observe(t1 - t0)
-        _health.note_decode("step", nslots, t1 - t0,
-                            self._prog_costs.get(("step", nslots)))
+            attrs["linear_rows"] = len(rows) * self._linear_layers
+        rec.t0 = _tm.monotonic()
+        rec.feed, rec.out, self._k_pages, self._v_pages = \
+            self._step_prog(nslots)(self._params, self._k_pages,
+                                    self._v_pages, tables, tokens, pos)
+        rec.t_dispatched = _tm.monotonic()
+        # ahead: queued while the step before it still ran on the device
+        rec.ahead = prev is not None and not prev.out.is_ready()
+        attrs["ahead"] = rec.ahead
+        if rec.ahead:
+            self._m_ahead.inc()
+        else:
+            attrs["drained_for"] = why if prev is None else "late"
+        if prev is not None:
+            # a lead of a whole step holds nothing: no use growing past it
+            self._lead_s = max(_LEAD_MIN_S, min(
+                self._lead_s * (_LEAD_DOWN if rec.ahead else _LEAD_UP),
+                self._step_s.get(nslots, _LEAD_MIN_S)))
+        return rec
 
-        traced = [s for s in live
+    def _land(self, rec):
+        """Fetch a step's tokens and deliver them to its rows still live;
+        the step is no longer in flight, and the sessions whose pages it
+        was the last to write give them back. A row that ended in the
+        step before (its stop token, a cancel) was dead in this one: its
+        token is never emitted."""
+        waited = not rec.out.is_ready()
+        out = _np.asarray(rec.out)
+        t_fetched = _tm.monotonic()
+        # the device arrays go here, before the tokens do (a step that
+        # went ahead of this one holds its token array already)
+        rec.feed = rec.out = None
+        nslots, rows = rec.nslots, rec.rows
+        experts = None
+        if self._moe:
+            experts = self._note_moe(rec.attrs, out[nslots:], nslots,
+                                     len(rows))
+        self._note_call(rec.attrs, rec.t0, rec.t_dispatched, t_fetched)
+        # the time the step added to the loop: from the later of its own
+        # dispatch and the fetch of the output before it
+        added = t_fetched - max(rec.t0, self._t_landed)
+        self._learn(rec, waited, t_fetched)
+        self._m_step.observe(added)
+        _health.note_decode("step", nslots, added,
+                            self._prog_costs.get(("step", nslots)))
+        t1 = _tm.monotonic()
+        # recorded explicitly: the span may end in the pass after the one
+        # that dispatched it, which stays its parent
+        _tr.record_span("decode.step", rec.parent, rec.t0, t1,
+                        attrs=rec.attrs)
+        traced = [s for s in rows
                   if s.tctx is not None and s.tctx.sampled]
         if traced:
             sid = _tr.new_span_id()
-            attrs = {"slots": len(live), "bucket": nslots,
+            attrs = {"slots": len(rows), "bucket": nslots,
                      "live_pages": self._pool.used_pages}
             for sess in traced:
-                _tr.record_span("decode.step", sess.tctx, t0, t1,
+                _tr.record_span("decode.step", sess.tctx, rec.t0, t1,
                                 span_id=sid,
                                 parent_id=sess.tctx.span_id,
                                 attrs=attrs)
         with self._cond:
-            for i, sess in enumerate(live):
-                if sess.done:            # expired/retired concurrently
+            self._flight.remove(rec)
+            for i, sess in enumerate(rows):
+                if sess.done:            # ended before, or concurrently
                     continue
+                if experts is not None:
+                    sess.expert_choices.append(experts[:, i:i + 1])
                 sess.pos += 1
-                self._emit_locked(sess, int(toks[i]))
+                self._emit_locked(sess, int(out[i]))
+            self._release_held_locked()
+
+    def _learn(self, rec, waited, t_fetched):
+        """The device time of a step of this bucket, from what the loop
+        sees: a step queued while the one before it ran, whose fetch and
+        whose predecessor's fetch both waited for the device, took the
+        time between the two fetches; and no step took longer than from
+        its dispatch to its fetch, which brings an estimate down that a
+        step dispatched after its predecessor landed shows to be long."""
+        est = self._step_s.get(rec.nslots)
+        bound = t_fetched - rec.t0
+        if est is None:
+            est = bound
+        elif rec.ahead and waited and self._landed_waited:
+            est += _EWMA * ((t_fetched - self._t_landed) - est)
+        self._step_s[rec.nslots] = min(est, bound)
+        self._t_landed = t_fetched
+        self._landed_waited = waited
 
     # -- compiled programs -------------------------------------------------
     # both builders route through the process-wide compiled-program
@@ -1271,12 +1589,17 @@ class DecodeEngine(object):
                                         ps, cfg)
                     logits, paged, stats = transformer_decode_step(
                         params, paged, tokens, pos, cfg, with_stats=True)
-                    out = jnp.argmax(logits, -1).astype(jnp.int32)
+                    toks = jnp.argmax(logits, -1).astype(jnp.int32)
+                    out = toks
                     if moe:         # ... + layers * (1 + k * slots)
                         out = jnp.concatenate(
                             [out, stats["moe_active_experts"],
                              stats["moe_experts"].reshape(-1)])
-                    return (out,) + cache_pools(paged)
+                    # the tokens as the next step takes them when it goes
+                    # ahead: a dummy slot's (position 0) at 0, as a step
+                    # from host arrays has it
+                    feed = jnp.where(pos > 0, toks, 0)
+                    return (feed, out) + cache_pools(paged)
 
                 return prog
 

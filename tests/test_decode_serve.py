@@ -801,6 +801,288 @@ def test_engine_close_drain_completes_sessions(model):
         eng.submit([1])
 
 
+# ---------------------------------------------------------------------------
+# one step in flight: a step goes ahead of the one on the device
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def wide():
+    """A model whose decode step outlasts its dispatch on the host CPU, so
+    that steps really are queued behind running ones (params, config)."""
+    cfg = TransformerConfig(vocab_size=2048, d_model=128, n_heads=4,
+                            n_kv_heads=2, n_layers=2, d_ff=512, max_len=64,
+                            pos_type="rope")
+    dev = np.array(jax.devices()[:1]).reshape(1, 1, 1, 1, 1)
+    mesh = Mesh(dev, ("dp", "sp", "tp", "pp", "ep"))
+    params, _ = init_transformer_params(cfg, mesh, seed=5)
+    return params, cfg
+
+
+def _wide_engine(model, num_pages=64, timeout_ms=60000):
+    params, cfg = model
+    return DecodeEngine(params, cfg, DecodeConfig(
+        slots=4, page_size=PAGE, num_pages=num_pages, max_context=MAX_CTX,
+        queue_depth=16, max_new_tokens=16,
+        default_timeout_ms=timeout_ms)).start().warmup()
+
+
+@pytest.fixture(scope="module")
+def wide_engine(wide):
+    eng = _wide_engine(wide)
+    yield eng
+    eng.set_iteration_hook(None)
+    eng.close()
+
+
+def _engine_steps(since=0):
+    """The engine's own ``decode.step`` records (a request's copy carries
+    no ``ahead``), from the log's ``since``-th record on."""
+    return [r for r in tr.span_log()[since:]
+            if r["name"] == "decode.step" and "ahead" in r["attrs"]]
+
+
+def _until_stop(tokens, stop):
+    return tokens[:tokens.index(stop) + 1] if stop in tokens else tokens
+
+
+def _serve(eng, reqs, gaps):
+    """Submit ``(prompt, max_new, stop)`` requests from a client thread,
+    ``gaps`` seconds apart (arrivals while steps are in flight), and
+    return their streams."""
+    sessions = []
+
+    def client():
+        for (p, mn, stop), gap in zip(reqs, gaps):
+            time.sleep(gap)
+            sessions.append(eng.submit(p, mn, stop_token=stop))
+
+    t = threading.Thread(target=client)
+    t.start()
+    t.join()
+    return [s.result() for s in sessions]
+
+
+def test_steps_that_go_ahead_emit_the_synchronous_tokens(wide, wide_engine):
+    """The loop that dispatches a step before it fetches the one before
+    emits exactly what the synchronous loop (an iteration hook installed:
+    no step goes ahead) and the unbatched reference emit: mixed budgets,
+    arrivals while steps are in flight, the slot bucket moving 1 → 2 → 4
+    and back, and a stop token that ends a row while the step after it is
+    already queued — that dead step's token is never emitted."""
+    params, cfg = wide
+    rng = np.random.RandomState(7)
+    plain = [(list(rng.randint(0, 2048, (pl,))), mn) for pl, mn in
+             [(3, 12), (7, 5), (12, 9), (5, 14), (9, 2), (2, 16)]]
+    refs = [reference_decode(params, cfg, p, mn) for p, mn in plain]
+    # a stop token a few steps in: the first of the request's own tokens
+    # from its fourth on that it had not produced before
+    first = next(i for i in range(3, len(refs[0]))
+                 if refs[0][i] not in refs[0][:i])
+    stop = refs[0][first]
+    reqs = [(p, mn, stop if i == 0 else None)
+            for i, (p, mn) in enumerate(plain)]
+    want = [_until_stop(refs[0], stop)] + refs[1:]
+    gaps = [0.0, 0.002, 0.0, 0.004, 0.001, 0.006]
+    dead = []
+    land = wide_engine._land
+
+    def spy(rec):
+        # a row that ended by its stop token before this step landed: the
+        # step was dispatched ahead with it
+        dead.extend(s for s in rec.rows if s.done and s.error is None)
+        return land(rec)
+
+    wide_engine._land = spy
+    try:
+        ahead0 = tm.counter("decode/steps_ahead_total").value
+        since = len(tr.span_log())
+        pipelined = _serve(wide_engine, reqs, gaps)
+        steps = _engine_steps(since)
+        ahead = tm.counter("decode/steps_ahead_total").value - ahead0
+        wide_engine.set_iteration_hook(lambda: None)
+        synchronous = _serve(wide_engine, reqs, gaps)
+    finally:
+        wide_engine.set_iteration_hook(None)
+        del wide_engine._land
+    assert pipelined == synchronous == want
+    assert len(pipelined[0]) == first + 1 < 12
+    assert dead, "no step ran ahead of a row's stop token"
+    assert ahead == sum(s["attrs"]["ahead"] for s in steps) > 0
+    assert wide_engine._pool.used_pages == 0
+
+
+def test_a_request_arriving_in_the_hold_is_prefilled_behind_that_step(
+        wide, wide_engine):
+    """While the loop holds the next step's dispatch, a request that a
+    slot can take wakes it: its prefill is queued right behind the step in
+    flight (which lands between the prefill's dispatch and its fetch), no
+    step goes ahead in between, and the step after the prefill says why it
+    did not go ahead. Made deterministic by a lead that would hold each
+    dispatch for a minute."""
+    params, cfg = wide
+    long_p, short_p = [5, 6, 7], [9, 8]
+    sess = wide_engine.submit(long_p, max_new_tokens=16)
+    assert sess.next_token(timeout=30) is not None
+    assert sess.next_token(timeout=30) is not None
+    since = len(tr.span_log())
+    wide_engine._lead = lambda: -60.0
+    try:
+        # the loop reaches the hold and stays: the stream stands still
+        deadline = time.monotonic() + 10
+        while True:
+            n = sess.generated
+            time.sleep(0.2)
+            if sess.generated == n or time.monotonic() > deadline:
+                break
+        assert not sess.done and wide_engine._flight
+        t_sub = time.monotonic()
+        short = wide_engine.submit(short_p, max_new_tokens=3)
+        assert short.next_token(timeout=30) is not None
+        woke_in = time.monotonic() - t_sub
+    finally:
+        del wide_engine._lead
+        with wide_engine._cond:          # end a hold under the long lead
+            wide_engine._cond.notify_all()
+    assert woke_in < 10.0
+    assert short.result() == reference_decode(params, cfg, short_p, 3)
+    assert sess.result() == reference_decode(params, cfg, long_p, 16)
+    log = tr.span_log()[since:]
+    prefill, = [r for r in log if r["name"] == "decode.prefill"
+                and r["parent_id"] is not None and "t_fetched" in r["attrs"]]
+    held, = [r for r in log if r["name"] == "decode.iteration"
+             and r["span_id"] == prefill["parent_id"]]
+    assert "t_held" in held["attrs"]
+    steps = sorted(_engine_steps(since), key=lambda r: r["t0"])
+    before = [s for s in steps if s["t0"] < prefill["t0"]]
+    after = [s for s in steps if s["t0"] > prefill["t0"]]
+    flight = before[-1]              # the step in flight at the arrival
+    assert (prefill["attrs"]["t_dispatched"] <= flight["attrs"]["t_fetched"]
+            <= prefill["attrs"]["t_fetched"])
+    # nothing was queued between that step and the prefill
+    assert flight["parent_id"] != held["span_id"]
+    assert after[0]["attrs"]["ahead"] is False
+    assert after[0]["attrs"]["drained_for"] == "admit"
+    assert after[0]["parent_id"] == held["span_id"]
+
+
+def test_with_an_iteration_hook_no_step_goes_ahead(wide_engine):
+    """The deterministic-testing seam keeps each pass whole: with a hook
+    installed every step is fetched in the pass that dispatched it, none
+    goes ahead, and each says why (``hook``, or ``admit`` after a
+    prefill); ``decode/steps_ahead_total`` does not move."""
+    ahead0 = tm.counter("decode/steps_ahead_total").value
+    since = len(tr.span_log())
+    wide_engine.set_iteration_hook(lambda: None)
+    try:
+        sessions = [wide_engine.submit([i + 1, i + 2], max_new_tokens=6)
+                    for i in range(3)]
+        for s in sessions:
+            assert len(s.result()) == 6
+    finally:
+        wide_engine.set_iteration_hook(None)
+    steps = _engine_steps(since)
+    assert steps and not any(s["attrs"]["ahead"] for s in steps)
+    assert set(s["attrs"]["drained_for"] for s in steps) <= {"hook",
+                                                             "admit"}
+    assert "hook" in set(s["attrs"]["drained_for"] for s in steps)
+    assert tm.counter("decode/steps_ahead_total").value == ahead0
+    # each landed in the pass that dispatched it
+    its = dict((r["span_id"], r) for r in tr.span_log()[since:]
+               if r["name"] == "decode.iteration")
+    assert all(s["t1"] <= its[s["parent_id"]]["t1"] for s in steps)
+
+
+@pytest.mark.parametrize("how", ["cancel", "expire", "close"])
+def test_pages_go_back_only_after_the_step_that_writes_them_lands(wide, how):
+    """A row cancelled, past its deadline, or failed by ``close`` while a
+    step that writes its pages is in flight gives them back only once that
+    step has landed; an admission that then reuses those pages decodes as
+    the reference does."""
+    params, cfg = wide
+    # a pool of 8 pages: the first request's 7 must come back before the
+    # second's can be admitted
+    eng = _wide_engine(wide, num_pages=9,
+                       timeout_ms=300 if how == "expire" else 60000)
+    frees, deferred = [], []
+    free, release = eng._free_pages, eng._release_pages
+
+    def on_free(sess):
+        writing = [s for c in eng._flight for s in c.rows]
+        frees.append((sess, sess in writing or sess is eng._prefilling))
+        return free(sess)
+
+    def on_release(sess):
+        if any(sess in c.rows for c in eng._flight):
+            deferred.append(sess)
+        return release(sess)
+
+    eng._free_pages, eng._release_pages = on_free, on_release
+    try:
+        sess = eng.submit(list(range(1, 12)), max_new_tokens=16)
+        assert sess.next_token(timeout=30) is not None
+        if how == "cancel":
+            eng._lead = lambda: -60.0        # hold with a step in flight
+            time.sleep(0.3)
+            assert eng._flight and eng.cancel(sess, "test")
+        elif how == "expire":
+            eng._lead = lambda: -0.05        # every step outlasts 50 ms
+        else:
+            eng._lead = lambda: -60.0
+            time.sleep(0.3)
+            assert eng._flight
+            t = time.monotonic()
+            eng.close(drain=False)
+            assert time.monotonic() - t < 10.0
+        with pytest.raises(MXNetError):
+            sess.result()
+        deadline = time.monotonic() + 10
+        while eng._pool.used_pages and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert eng._pool.used_pages == 0
+        assert frees and not any(inflight for _s, inflight in frees)
+        if how != "expire":
+            # failed during a hold, with a step in flight: kept, then freed
+            assert deferred == [sess]
+        else:
+            # (the expiry may be seen in a hold or just after a dispatch)
+            assert deferred in ([sess], [])
+        if how != "close":
+            del eng._lead
+            # 8 pages: the pool's whole, the failed request's among them
+            out = eng.generate(list(range(20, 27)), max_new_tokens=16,
+                               timeout_ms=60000)
+            assert out == reference_decode(params, cfg,
+                                           list(range(20, 27)), 16)
+    finally:
+        eng.close(drain=False)
+
+
+def test_steps_that_go_ahead_compile_nothing(wide_engine):
+    """After warm-up a run whose steps go ahead (their tokens the program
+    output of the step before) lowers no new program: each slot bucket's
+    step program keeps the executables the warm-up left, and neither the
+    compile requests nor the registry's compiles and disk loads move."""
+    progs = dict((n, wide_engine._step_prog(n))
+                 for n in wide_engine.config.slot_buckets)
+    sizes = dict((n, p._cache_size()) for n, p in progs.items())
+    snap = (tm.snapshot()["backend_compile_total"],
+            tm.counter("programs/compile_total").value,
+            tm.counter("programs/disk_hits_total").value)
+    since = len(tr.span_log())
+    out = _serve(wide_engine, [([i + 3] * (i + 1), 4 + 3 * i, None)
+                               for i in range(4)], [0.0, 0.001, 0.0, 0.002])
+    assert [len(o) for o in out] == [4, 7, 10, 13]
+    steps = _engine_steps(since)
+    # steps went the ahead path (queued behind one still running, or one
+    # that had just landed)
+    assert any(s["attrs"]["ahead"] or s["attrs"].get("drained_for") == "late"
+               for s in steps)
+    assert dict((n, p._cache_size()) for n, p in progs.items()) == sizes
+    assert (tm.snapshot()["backend_compile_total"],
+            tm.counter("programs/compile_total").value,
+            tm.counter("programs/disk_hits_total").value) == snap
+
+
 def test_decode_config_validation():
     with pytest.raises(MXNetError):
         DecodeConfig(page_size=5, max_context=32)   # not a multiple

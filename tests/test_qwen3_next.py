@@ -614,6 +614,42 @@ def test_a_reused_state_row_carries_nothing_of_its_first_session(model):
         fresh.close()
 
 
+def test_a_state_row_a_dead_step_writes_goes_back_after_it_lands(model):
+    """One slot, one state row: the first request ends by its stop token
+    while the step after it is already queued with its row (a dead step
+    that still writes the row); the row goes back only once that step has
+    landed, and the queued request that takes it answers as it does
+    alone."""
+    params, cfg = model
+    dcfg = DecodeConfig(slots=1, page_size=PAGE, num_pages=20,
+                        max_context=64, queue_depth=4, max_new_tokens=12,
+                        default_timeout_ms=120000)
+    rng = np.random.RandomState(8)
+    first = rng.randint(0, 256, 30).tolist()
+    second = (rng.randint(0, 256, 2).tolist(), 12)
+    eng = DecodeEngine(params, cfg, dcfg).start().warmup()
+    frees = []
+    free = eng._free_pages
+
+    def on_free(sess):
+        frees.append(any(sess in c.rows for c in eng._flight))
+        return free(sess)
+
+    eng._free_pages = on_free
+    try:
+        alone = eng.submit(first, 12).result()
+        k = next(i for i in range(3, 12) if alone[i] not in alone[:i])
+        a = eng.submit(first, 12, stop_token=alone[k])
+        b = eng.submit(*second)
+        assert a.result() == alone[:k + 1]
+        got = b.result()
+        assert eng._state_rows.used_pages == 0 and eng._pool.used_pages == 0
+    finally:
+        eng.close()
+    assert frees and not any(frees)
+    assert got == _unbatched(params, cfg, dcfg, *second)
+
+
 def test_crash_recovery_zeroes_the_state_pools(model):
     from mxnet_tpu import fault
     params, cfg = model

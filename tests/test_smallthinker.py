@@ -204,6 +204,52 @@ def test_engine_serves_ragged_slots_as_the_reference(model):
                               np.sort(np.asarray(experts)[:, :-1], -1))
 
 
+def test_experts_of_steps_that_go_ahead_stay_with_their_rows(model):
+    """The loop that dispatches a step before it fetches the one before
+    hands each session exactly the tokens and the experts ITS rows chose,
+    position by position, that the synchronous loop does (an iteration
+    hook installed: no step goes ahead); a row that ends by its stop token
+    while the step after it is already queued gets no choice and no token
+    from that dead step."""
+    params, cfg = model
+    eng = DecodeEngine(params, cfg, DecodeConfig(
+        slots=4, page_size=PAGE, num_pages=80, window_pages=6 * RING + 1,
+        max_context=64, queue_depth=16, max_new_tokens=40,
+        default_timeout_ms=120000)).start().warmup()
+    rng = np.random.RandomState(11)
+    reqs = [(rng.randint(0, 256, n).tolist(), new) for n, new in
+            [(5, 20), (12, 9), (3, 14), (20, 6)]]
+
+    def serve(stop, hook=None):
+        eng.set_iteration_hook(hook)
+        try:
+            ss = [eng.submit(p, new, stop_token=stop if i == 0 else None)
+                  for i, (p, new) in enumerate(reqs)]
+            return [(s.result(), s.expert_choices) for s in ss]
+        finally:
+            eng.set_iteration_hook(None)
+
+    from mxnet_tpu import telemetry as tm
+    try:
+        free = serve(None)[0][0]
+        first = next(i for i in range(3, len(free))
+                     if free[i] not in free[:i])
+        ahead0 = tm.counter("decode/steps_ahead_total").value
+        piped = serve(free[first])
+        assert tm.counter("decode/steps_ahead_total").value > ahead0
+        sync = serve(free[first], hook=lambda: None)
+        assert eng._pool.used_pages == 0
+    finally:
+        eng.close()
+    assert piped[0][0] == free[:first + 1]
+    assert [c.shape[1] for c in piped[0][1]] \
+        == [len(reqs[0][0])] + [1] * first
+    for (ptoks, pexp), (stoks, sexp) in zip(piped, sync):
+        assert ptoks == stoks
+        assert len(pexp) == len(sexp)
+        assert all(np.array_equal(a, b) for a, b in zip(pexp, sexp))
+
+
 def test_engine_reports_experts_and_window_context(model):
     from mxnet_tpu import telemetry as tm, tracing as tr
     params, cfg = model

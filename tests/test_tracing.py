@@ -877,22 +877,30 @@ def test_decode_loop_logs_its_iterations_without_a_caller_context():
     steps = [r for r in log[:alone] if r["name"] == "decode.step"]
     # (a model without window layers sees the same under a window, and
     # a dense one carries none of the expert layer's moe_* stamps)
-    # (the call's instants aside: their own test is below)
-    instants = ("t_dispatched", "t_fetched")
+    # (the call's instants and whether it went ahead aside: their own
+    # tests are below and in test_decode_serve.py)
+    instants = ("t_dispatched", "t_fetched", "ahead", "drained_for")
     assert [dict((k, v) for k, v in s["attrs"].items() if k not in instants)
             for s in steps] == [
         {"context_tokens": n, "window_context_tokens": n}
         for n in (6, 7, 8)]
+    assert all(isinstance(s["attrs"]["ahead"], bool) for s in steps)
+    # the first follows the prefill: it cannot go ahead of a step
+    assert steps[0]["attrs"]["drained_for"] == "admit"
     assert len(kids(its[0], "decode.prefill")) == 1
     # the first pass followed the engine's wait for work
     assert sorted(its[0]["attrs"]) == ["live", "t_wait0"]
     assert its[0]["attrs"]["live"] == 0
-    # a pass's prefills and its step lie inside it, in that order
+    # a pass's prefills and the dispatch of its step lie inside it, in
+    # that order (a step left in flight lands in the pass after)
     for it in its:
         order = kids(it, "decode.prefill") + kids(it, "decode.step")
         assert order and len(kids(it, "decode.step")) <= 1
         assert all(a["t1"] <= b["t0"] for a, b in zip(order, order[1:]))
-        assert it["t0"] <= order[0]["t0"] and order[-1]["t1"] <= it["t1"]
+        assert it["t0"] <= order[0]["t0"]
+        assert all(r["t1"] <= it["t1"] for r in kids(it, "decode.prefill"))
+        assert all(r["attrs"]["t_dispatched"] <= it["t1"]
+                   for r in kids(it, "decode.step"))
     # two sequences in one step: both contexts are counted (prompts of 3
     # and 2: the first step over both attends over 4 + 3 positions)
     later = [r["attrs"]["context_tokens"] for r in log[alone:]
