@@ -320,6 +320,8 @@ def kernels_phase():
     mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
     ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
     gd = importlib.import_module("mxnet_tpu.ops.pallas.gated_delta")
+    mb = importlib.import_module("mxnet_tpu.ops.pallas.mamba")
+    da = importlib.import_module("mxnet_tpu.ops.pallas.diff_attention")
     rng = np.random.RandomState(0)
     # on the chip: interpret=None, the production default, which must
     # resolve to Mosaic (asserted per kernel through _mosaic_in); in the
@@ -636,6 +638,67 @@ def kernels_phase():
              *gd._gdn_chunk_xla(*a)),
          args, 2e-3)
 
+    # -- Mamba's selective scan: one token a row against rows of a whole
+    #    state pool, in place by layer and state row (two rows share the
+    #    null row 0); a prompt in chunks whose length is no multiple of the
+    #    chunk, its padded tail at step size 0
+    b, ch, ns, layers, pool_rows = (3, 128, 4, 2, 4) if TINY \
+        else (64, 5120, 16, 9, 65)
+    rows = jnp.asarray([0, 0] + list(range(1, b - 1)), jnp.int32)
+    decay = -jnp.exp(f32(ns, ch) * 0.5)
+    args = (f32(b, ch), jnp.abs(f32(b, ch)) * 0.1, f32(b, ns), f32(b, ns),
+            decay, f32(ch), f32(layers, pool_rows, ns, ch), rows)
+    case("mamba_recurrent_step[layer%dof%d,b%dn%dch%d]"
+         % (layers - 1, layers, b, ns, ch),
+         lambda *a: real_rows(mb.mamba_recurrent_step(
+             *a, layer=layers - 1, interpret=interp)),
+         lambda *a: real_rows(mb._mamba_step_xla(*a, layers - 1)),
+         args, 1e-4)
+    s, real = (100, 71) if TINY else (2048, 1999)
+    live = (jnp.arange(s) < real)[None, :, None]
+    args = (f32(1, s, ch), jnp.where(live, jnp.abs(f32(1, s, ch)) * 0.1,
+                                     0.0), f32(1, s, ns), f32(1, s, ns),
+            decay, f32(ch))
+    case("mamba_chunk_scan[s%dn%dch%d]" % (s, ns, ch),
+         lambda *a: (lambda y, st: (y[:, :real], st))(
+             *mb.mamba_chunk_scan(*a, interpret=interp)),
+         lambda *a: (lambda y, st: (y[:, :real], st))(
+             *mb._mamba_scan_xla(*a)),
+         args, 1e-3)
+
+    # -- differential attention over flat pages of K/V pairs: the one full
+    #    layer's pages and a window layer's rings of a whole pool, by layer;
+    #    a prompt's both maps, causal and windowed
+    b, pairs, ps, entries = (3, 2, 8, 6) if TINY else (32, 10, 128, 16)
+    window = 16 if TINY else 512
+    ring = window // ps + 1
+    for name, layers, width, win, most in (
+            ("full", 1, entries, None, entries * ps),
+            ("window", 3 if TINY else 8, ring, window, entries * ps)):
+        n_pages = b * width + 1          # a page a table entry, and null
+        pool = lambda: f32(layers, n_pages, ps * pairs, 128).astype(dt)
+        table = jnp.asarray(rng.randint(1, n_pages, (b, width)), jnp.int32)
+        lengths = jnp.asarray(rng.randint(1, most + 1, b), jnp.int32)
+        args = (f32(b, pairs, 4, 128), pool(), pool(), table, lengths)
+        case("diff_paged_decode[%s,layer%dof%d,b%dpairs%dpage%d]"
+             % (name, layers - 1, layers, b, pairs, ps),
+             lambda *a, win=win, layers=layers: da.diff_paged_decode(
+                 *a, ps, 0.125, window=win, layer=layers - 1,
+                 interpret=interp),
+             lambda *a, win=win, layers=layers: da._diff_paged_decode_xla(
+                 *a, 0.125, win, layers - 1),
+             args, 2e-2)
+    s = 64 if TINY else 2048
+    for win in (None, window):
+        args = (f32(1, s, 4 * pairs, 128).astype(dt),
+                f32(1, s, pairs, 128).astype(dt),
+                f32(1, s, pairs, 128).astype(dt))
+        case("diff_flash_prefill[s%dpairs%d,window%s]" % (s, pairs, win),
+             lambda *a, win=win: da.diff_flash_prefill(
+                 *a, 0.125, win, interpret=interp),
+             lambda *a, win=win: da._diff_prefill_xla(*a, 0.125, win),
+             args, 2e-2)
+
     # -- int8 matmul + im2col conv: ResNet-50's FC and its first 3x3 conv
     mm = [(8, 40, 12)] if TINY else [(32, 2048, 1000),
                                     (32 * 56 * 56, 576, 64)]
@@ -658,7 +721,7 @@ def kernels_phase():
          (qx, wq, sc), 0.0, exact=True)
 
     exported = set()
-    for mod in (fa, i8, mf, ml, gd):
+    for mod in (fa, i8, mf, ml, gd, mb, da):
         exported.update(mod.PALLAS_KERNELS)
     covered = set(n.split("[")[0] for n in report)
     check(ARGS.cases or covered == exported,

@@ -413,7 +413,8 @@ def _pages_can_be_copied(kv_heads, head_dim, itemsize):
 
 
 def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, sem, *, sm_scale, window=None):
+                  kbuf, vbuf, sem, *, sm_scale, window=None,
+                  kv_heads=None):
     """ONE invocation walks every row's LIVE pages, ``P`` pages a compute
     block (``kbuf``/``vbuf`` are ``(2, P, page_size, kv_heads, hd)``: two
     slots), accumulating an online softmax in float32 — running maximum,
@@ -452,9 +453,18 @@ def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     custom call's operand, so a reshape is a relayout copy of the WHOLE
     pool per call and a ``pool[layer]`` slice a copy of that layer's
     share of it (201 MB a call at the served size: the whole pool once a
-    token step)."""
+    token step).
+
+    ``kv_heads`` given: the pools' pages are FLAT, ``(layers, pages,
+    page_size * kv_heads, hd)`` — a page's (token, head) rows in the order
+    the block's flat product takes them anyway — for heads whose count
+    does not fill a memory tile's rows (``diff_attention.py``)."""
     n_rows, n_heads, hd = q_ref.shape
-    _, n_p, page_size, kvh = kbuf.shape[:4]
+    if kv_heads is None:
+        _, n_p, page_size, kvh = kbuf.shape[:4]
+    else:           # pages flat: (page_size * kv_heads, hd) rows a page
+        _, n_p, n_page_rows = kbuf.shape[:3]
+        kvh, page_size = kv_heads, n_page_rows // kv_heads
     g = n_heads // kvh
     layer = layer_ref[0]
     n_entries = bt_ref.shape[1]

@@ -86,6 +86,30 @@ def _has_linear(cfg):
         cfg.linear_layout[:cfg.n_layers])
 
 
+def _mamba_at(cfg, li):
+    """Whether ``mamba_layout`` marks layer ``li``."""
+    return cfg.mamba_layout is not None and bool(cfg.mamba_layout[li])
+
+
+def _has_state(cfg):
+    """Whether some layer keeps a fixed-size recurrent state a sequence
+    (a Gated DeltaNet or a Mamba layer): such a model's cache is a
+    :class:`LinearStateCache`."""
+    return _has_linear(cfg) or any(
+        k == "mamba" for k in kv_layer_kinds(cfg))
+
+
+def _yoco_sources(cfg):
+    """``(kv layer, memory layer)`` of a model with a cross-decoder
+    (``cross_from``): the last self-attention layer before it, whose K/V
+    every cross-attention layer reads, and the last Mamba layer before
+    it, whose scan output every gated memory unit reads."""
+    before = [_layer_rule(cfg, li)[0] for li in range(cfg.cross_from)]
+    kv = max(li for li, k in enumerate(before) if k in ("full", "window"))
+    mem = max([li for li, k in enumerate(before) if k == "mamba"] or [-1])
+    return kv, mem
+
+
 def _yarn_mscale(cfg):
     """YaRN's attention temperature ``0.1 mscale_all_dim ln(factor) + 1``
     (1 without ``rope_scaling``): the softmax scale carries its square."""
@@ -123,7 +147,12 @@ def _rope_inv_freq(cfg, dim):
 def _layer_rule(cfg, li):
     """``(kind, rotary, window)`` of layer ``li``: ``"linear"`` where
     ``linear_layout`` marks a Gated DeltaNet layer (no keys, values,
-    rotation or window: a fixed-size state a sequence), else whether q/k
+    rotation or window: a fixed-size state a sequence); from layer
+    ``cross_from`` on (a cross-decoder) ``"gmu"`` where ``mamba_layout``
+    marks the layer (a gated memory unit) and ``"cross"`` elsewhere
+    (cross-attention over another layer's K/V): neither owns a cache;
+    before it ``"mamba"`` where ``mamba_layout`` marks a Mamba layer (a
+    fixed-size state a sequence); else whether q/k
     rotate there (``rope_layout``; every layer of a "rope" model without
     one) and how far back it attends (``sliding_window`` where
     ``window_layout`` marks the layer, or everywhere without a layout;
@@ -131,6 +160,10 @@ def _layer_rule(cfg, li):
     place a layer's kind is decided."""
     if cfg.linear_layout is not None and bool(cfg.linear_layout[li]):
         return "linear", False, None
+    if cfg.cross_from is not None and li >= cfg.cross_from:
+        return ("gmu" if _mamba_at(cfg, li) else "cross"), False, None
+    if _mamba_at(cfg, li):
+        return "mamba", False, None
     rotary = cfg.pos_type == "rope" and (
         cfg.rope_layout is None or bool(cfg.rope_layout[li]))
     windowed = cfg.sliding_window is not None and (
@@ -140,9 +173,11 @@ def _layer_rule(cfg, li):
 
 
 def kv_layer_kinds(cfg):
-    """Per layer, ``"linear"`` where the layer keeps a recurrent state and
-    no keys or values, ``"window"`` where it attends a sliding window
-    (its cache may forget older positions), else ``"full"``."""
+    """Per layer, ``"linear"`` or ``"mamba"`` where the layer keeps a
+    recurrent state and no keys or values, ``"gmu"`` or ``"cross"`` where
+    it keeps nothing (a cross-decoder layer reads what an earlier layer
+    left), ``"window"`` where it attends a sliding window (its cache may
+    forget older positions), else ``"full"``."""
     return tuple(_layer_rule(cfg, li)[0] for li in range(cfg.n_layers))
 
 
@@ -153,6 +188,12 @@ def _expand_kv(t, groups, head_axis):
 
 
 def _validate_config(cfg):
+    for name in ("window_layout", "rope_layout", "linear_layout",
+                 "mamba_layout"):
+        layout = getattr(cfg, name)
+        if layout is not None and len(layout) < cfg.n_layers:
+            raise ValueError("%s has %d entries for n_layers=%d"
+                             % (name, len(layout), cfg.n_layers))
     kvh = cfg.n_kv_heads
     if kvh is not None:
         if not isinstance(kvh, int) or kvh < 1:
@@ -238,6 +279,44 @@ def _validate_config(cfg):
                              "sliding window or dense_layers is not "
                              "computed: the state cache stands beside ONE "
                              "paged pool of full-attention layers")
+    if cfg.mamba_layout is not None:
+        for name in ("mamba_d_state", "mamba_d_conv", "mamba_expand",
+                     "mamba_dt_rank"):
+            if getattr(cfg, name) < 1:
+                raise ValueError("mamba_layout (Mamba layers) needs %s > 0"
+                                 % name)
+        if cfg.mamba_d_conv < 2:
+            raise ValueError("mamba_d_conv=%d: the causal convolution is "
+                             "at least 2 wide" % cfg.mamba_d_conv)
+        if _has_linear(cfg) or _is_mla(cfg) or cfg.num_experts \
+                or cfg.dense_layers:
+            raise ValueError("mamba_layout beside linear_layout, latent "
+                             "attention, experts or dense_layers is not "
+                             "computed")
+    if cfg.cross_from is not None:
+        if not 0 < cfg.cross_from < cfg.n_layers:
+            raise ValueError("cross_from=%r of n_layers=%d"
+                             % (cfg.cross_from, cfg.n_layers))
+        kinds = [_layer_rule(cfg, li)[0] for li in range(cfg.n_layers)]
+        if kinds[cfg.cross_from - 1] != "full":
+            raise ValueError("cross_from=%d: the layer before the "
+                             "cross-decoder keeps the K/V its cross layers "
+                             "read, so it must be a full-attention layer"
+                             % cfg.cross_from)
+        if "gmu" in kinds and "mamba" not in kinds[:cfg.cross_from]:
+            raise ValueError("a gated memory unit reads the scan output "
+                             "of a Mamba layer before cross_from")
+    if cfg.diff_attn:
+        kvh = _kv_heads(cfg)
+        if cfg.n_heads % 2 or kvh % 2 or (cfg.n_heads // 2) % (kvh // 2):
+            raise ValueError("diff_attn pairs query heads and K/V heads: "
+                             "n_heads=%d and n_kv_heads=%d must be even, "
+                             "the pairs of one a multiple of the other's"
+                             % (cfg.n_heads, kvh))
+        if _is_mla(cfg) or cfg.pos_type == "rope" or cfg.qk_norm \
+                or cfg.attn_gate:
+            raise ValueError("diff_attn is computed without rotation, "
+                             "q/k norms, gates or latent attention")
     rot = _head_dim(cfg) * cfg.rotary_share
     if not 0 < cfg.rotary_share <= 1 or cfg.pos_type == "rope" and (
             rot != int(rot) or int(rot) % 2):
@@ -254,11 +333,6 @@ def _validate_config(cfg):
     if cfg.moe_shared_gate and not cfg.moe_shared_width:
         raise ValueError("moe_shared_gate needs a shared expert "
                          "(moe_shared_width > 0)")
-    for name in ("window_layout", "rope_layout", "linear_layout"):
-        layout = getattr(cfg, name)
-        if layout is not None and len(layout) < cfg.n_layers:
-            raise ValueError("%s has %d entries for n_layers=%d"
-                             % (name, len(layout), cfg.n_layers))
 
 
 # what the shard_map training block (``_block_local``) computes, by
@@ -277,7 +351,10 @@ _TRAINABLE = {"head_dim": None, "norm": "layernorm",
               "linear_key_dim": 0, "linear_value_dim": 0,
               "linear_conv_width": 0, "rotary_share": 1.0,
               "qk_norm": False, "attn_gate": False,
-              "norm_zero_centered": False, "moe_shared_gate": False}
+              "norm_zero_centered": False, "moe_shared_gate": False,
+              "mamba_layout": None, "mamba_d_state": 0, "mamba_d_conv": 0,
+              "mamba_expand": 0, "mamba_dt_rank": 0, "cross_from": None,
+              "diff_attn": False, "attn_bias": False, "ffn_gated": False}
 
 
 def _validate_trainable(cfg):
@@ -406,6 +483,31 @@ class TransformerConfig:
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv_width: int = 0
+    # -- a decoder-hybrid-decoder (SambaY, arXiv:2507.06607). Before layer
+    # cross_from, a layer with mamba_layout[l] is a Mamba-1 layer (the
+    # selective scan, arXiv:2312.00752): mamba_expand * d_model channels
+    # of mamba_d_state states each, a causal depthwise convolution
+    # mamba_d_conv wide, a step size through a rank-mamba_dt_rank map
+    # (params["mamba_layers"]). From layer cross_from on (YOCO's cross-
+    # decoder, arXiv:2405.05254) a mamba_layout layer is a gated memory
+    # unit over the last Mamba layer's scan output (params["gmu_layers"])
+    # and any other a cross-attention layer over the K/V of the full
+    # layer just before cross_from (params["cross_layers"])
+    mamba_layout: tuple = None
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
+    cross_from: int = None
+    # differential attention (arXiv:2410.05258) in every attention layer:
+    # query heads (2i, 2i + 1) and K heads (2j, 2j + 1) pair up, a pair's
+    # two V heads make one value twice as wide, and head i's output is
+    # RMSNorm((softmax_1 - lambda softmax_2) v) (1 - lambda_init)
+    diff_attn: bool = False
+    # biases on the attention's q/k/v maps and its output map
+    attn_bias: bool = False
+    # the dense FFN as (act(x W_gate) * (x W_up)) W_down, act = gate_act
+    ffn_gated: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -431,6 +533,12 @@ def _param_specs(cfg, pp):
         if cfg.qk_norm:
             lyr.update({"q_norm_g": P("pp", None, None),
                         "k_norm_g": P("pp", None, None)})
+        if cfg.attn_bias:
+            lyr.update(dict((name, P("pp", None, None))
+                            for name in ("bq", "bk", "bv", "bo")))
+        if cfg.diff_attn:
+            lyr.update(dict((name, P("pp", None, None))
+                            for name in _DIFF_PARAMS))
     mixer = set(lyr) - {"ln1_g", "ln1_b", "ln2_g", "ln2_b"}
     dense = dict(lyr)                  # a leading dense layer's (below)
     if cfg.num_experts:
@@ -444,6 +552,9 @@ def _param_specs(cfg, pp):
                 lyr["ws_" + name] = P("pp", None, None, None)
         if cfg.moe_shared_gate:
             lyr["ws_sigmoid"] = P("pp", None, None, None)
+    elif cfg.ffn_gated:
+        for name in _GATED:
+            lyr["w_" + name] = P("pp", None, None, None)
     else:
         lyr.update({"w1": P("pp", None, None, "tp"),
                     "w2": P("pp", None, "tp", None)})
@@ -468,6 +579,20 @@ def _param_specs(cfg, pp):
             [(k, v) for k, v in lyr.items() if k not in mixer],
             **dict((name, P(*("pp",) + (None,) * (rank + 1)))
                    for name, rank in _GDN_PARAMS))
+    rest = dict((k, v) for k, v in lyr.items() if k not in mixer)
+    kinds = kv_layer_kinds(cfg)
+    for kind, stack, maps in (("mamba", "mamba_layers", _MAMBA_PARAMS),
+                              ("gmu", "gmu_layers", _GMU_PARAMS)):
+        if kind in kinds:
+            specs[stack] = dict(rest, **dict(
+                (name, P(*("pp",) + (None,) * (rank + 1)))
+                for name, rank in maps))
+    if "cross" in kinds:
+        # a cross-attention layer: the queries and the output map of the
+        # self-attention's, no keys or values of its own
+        specs["cross_layers"] = dict(rest, **dict(
+            (k, v) for k, v in lyr.items()
+            if k in mixer and k not in ("wk", "wv", "bk", "bv")))
     if not cfg.tie_embeddings:
         specs["head"] = P(None, None)
     if cfg.pos_type == "learned":
@@ -484,6 +609,26 @@ _GDN_PARAMS = (("gdn_qkvz", 2), ("gdn_ba", 2), ("gdn_conv", 2),
                ("gdn_a_log", 1), ("gdn_dt_bias", 1), ("gdn_norm_g", 1),
                ("gdn_out", 2))
 _GATED = ("gate", "up", "down")        # the three maps of a gated FFN
+# a Mamba layer's parameters with their ranks behind (pp, layers): the
+# input map [x | z], the depthwise convolution's taps (width, channels)
+# and bias, the map to [delta | B | C], the step size's map and bias,
+# A_log (channels, d_state), the skip D, the output map
+_MAMBA_PARAMS = (("mb_in", 2), ("mb_conv", 2), ("mb_conv_b", 1),
+                 ("mb_x", 2), ("mb_dt", 2), ("mb_dt_b", 1), ("mb_a_log", 2),
+                 ("mb_d", 1), ("mb_out", 2))
+# a gated memory unit's two maps
+_GMU_PARAMS = (("gmu_in", 2), ("gmu_out", 2))
+# differential attention's lambda vectors (head_dim each) and the gain of
+# its RMSNorm over a pair's value (2 * head_dim)
+_DIFF_PARAMS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
+                "subln_g")
+
+
+# the parameter stacks a model may have, and the kind of layer each holds
+_STACKS = ("layers", "dense_layers", "linear_layers", "mamba_layers",
+           "gmu_layers", "cross_layers")
+_STACK_OF = {"linear": "linear_layers", "mamba": "mamba_layers",
+             "gmu": "gmu_layers", "cross": "cross_layers"}
 
 
 def _expert_names(cfg):
@@ -504,9 +649,13 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     assert not cfg.dense_layers or pp == 1, "dense_layers needs pp == 1"
     assert (cfg.n_layers - cfg.dense_layers) % pp == 0, \
         "n_layers must divide pp"
-    n_linear = kv_layer_kinds(cfg).count("linear")
-    assert not n_linear or pp == 1, "linear_layout needs pp == 1"
-    lps = (cfg.n_layers - cfg.dense_layers - n_linear) // pp
+    kinds = kv_layer_kinds(cfg)
+    n_linear = kinds.count("linear")
+    n_own = dict((k, kinds.count(k)) for k in ("mamba", "gmu", "cross"))
+    assert not (n_linear or any(n_own.values())) or pp == 1, \
+        "linear_layout and mamba_layout need pp == 1"
+    lps = (cfg.n_layers - cfg.dense_layers - n_linear
+           - sum(n_own.values())) // pp
     rng = np.random.RandomState(seed)
     d, f, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
     hd = _head_dim(cfg)
@@ -556,7 +705,41 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
             if cfg.qk_norm:
                 out.update({"q_norm_g": gain(pp, n, hd),
                             "k_norm_g": gain(pp, n, hd)})
+            if cfg.attn_bias:
+                out.update({"bq": rand(pp, n, dq), "bk": rand(pp, n, dkv),
+                            "bv": rand(pp, n, dkv), "bo": rand(pp, n, d)})
+            if cfg.diff_attn:
+                # lambda vectors normal(0, 0.1), as the paper draws them
+                out.update(dict((name, jnp.asarray(
+                    rng.randn(pp, n, hd) * 0.1, cfg.dtype))
+                    for name in _DIFF_PARAMS[:4]))
+                out["subln_g"] = jnp.ones((pp, n, 2 * hd), cfg.dtype)
         return out
+
+    def mamba_block(n):
+        """Norms and Mamba maps of a stack of ``n`` layers, set as Mamba
+        sets them: A_log = log(1 .. d_state) a channel, the step size's
+        bias the inverse softplus of steps spread log-evenly over [1e-3,
+        1e-1] across the channels, its map uniform within dt_rank **
+        -0.5, D ones."""
+        di, ns = cfg.mamba_expand * d, cfg.mamba_d_state
+        r, taps = cfg.mamba_dt_rank, cfg.mamba_d_conv
+        dt = np.broadcast_to(np.exp(np.linspace(np.log(1e-3), np.log(1e-1),
+                                                di)), (pp, n, di))
+        return dict(norms(n), **{
+            "mb_in": rand(pp, n, d, 2 * di),
+            "mb_conv": jnp.asarray(rng.randn(pp, n, taps, di) * taps ** -0.5,
+                                   cfg.dtype),
+            "mb_conv_b": rand(pp, n, di),
+            "mb_x": rand(pp, n, di, r + 2 * ns),
+            "mb_dt": jnp.asarray(rng.uniform(-1, 1, (pp, n, r, di))
+                                 * r ** -0.5, cfg.dtype),
+            "mb_dt_b": jnp.asarray(dt + np.log(-np.expm1(-dt)), cfg.dtype),
+            "mb_a_log": jnp.asarray(np.broadcast_to(
+                np.log(np.arange(1, ns + 1, dtype=np.float64)),
+                (pp, n, di, ns)), cfg.dtype),
+            "mb_d": jnp.ones((pp, n, di), cfg.dtype),
+            "mb_out": rand(pp, n, di, d)})
 
     def linear_block(n):
         """Norms and Gated DeltaNet maps of a stack of ``n`` layers; the
@@ -592,6 +775,8 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
             return {"gate": rand(pp, n, d, cfg.num_experts),
                     "we1": rand(pp, n, cfg.num_experts, d, f),
                     "we2": rand(pp, n, cfg.num_experts, f, d)}
+        if cfg.ffn_gated:
+            return gated("w_", n, f)
         return {"w1": rand(pp, n, d, f), "w2": rand(pp, n, f, d)}
 
     layers = dict(block(lps), **ffn(lps))
@@ -608,6 +793,17 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     if n_linear:
         params["linear_layers"] = dict(linear_block(n_linear),
                                        **ffn(n_linear))
+    if n_own["mamba"]:
+        params["mamba_layers"] = dict(mamba_block(n_own["mamba"]),
+                                      **ffn(n_own["mamba"]))
+    if n_own["gmu"]:
+        di = cfg.mamba_expand * d
+        params["gmu_layers"] = dict(
+            norms(n_own["gmu"]), gmu_in=rand(pp, n_own["gmu"], d, di),
+            gmu_out=rand(pp, n_own["gmu"], di, d), **ffn(n_own["gmu"]))
+    if n_own["cross"]:
+        params["cross_layers"] = dict(block(n_own["cross"]),
+                                      **ffn(n_own["cross"]))
     if not cfg.tie_embeddings:
         params["head"] = rand(d, V)
     if cfg.pos_type == "learned":
@@ -616,7 +812,7 @@ def init_transformer_params(cfg: TransformerConfig, mesh: Mesh, seed=0):
     specs = _param_specs(cfg, pp)
     # an RMSNorm has no shift: drop what the specs do not name
     params = {k: v for k, v in params.items() if k in specs}
-    for stack in ("layers", "dense_layers", "linear_layers"):
+    for stack in _STACKS:
         if stack in params:
             params[stack] = {k: v for k, v in params[stack].items()
                              if k in specs[stack]}
@@ -736,12 +932,14 @@ def _iter_layers(params, cfg):
     two kinds of FFN keeps its leading dense layers in a stack of their
     own, ``params["dense_layers"]``, before ``params["layers"]``; one
     with linear-attention layers keeps those in ``params["linear_layers"]
-    ``, among the others as ``linear_layout`` says."""
-    taken = {"dense_layers": 0, "linear_layers": 0, "layers": 0}
+    ``, among the others as ``linear_layout`` says; a decoder-hybrid-
+    decoder its Mamba, gated-memory and cross-attention layers in
+    ``params["mamba_layers"]``, ``["gmu_layers"]`` and
+    ``["cross_layers"]`` (:func:`_layer_rule`)."""
+    taken = dict.fromkeys(_STACKS, 0)
     for li_flat in range(cfg.n_layers):
         name = ("dense_layers" if li_flat < cfg.dense_layers
-                else "linear_layers"
-                if _layer_rule(cfg, li_flat)[0] == "linear" else "layers")
+                else _STACK_OF.get(_layer_rule(cfg, li_flat)[0], "layers"))
         stack = params[name]
         lps = jax.tree_util.tree_leaves(stack)[0].shape[1]
         st, li = divmod(taken[name], lps)
@@ -1322,6 +1520,228 @@ def _gdn_token(cfg, lp, h, cache, li):
             LinearStateCache(cache.full, state, conv, rows))
 
 
+# ---------------------------------------------------------------------------
+# the decoder-hybrid-decoder's mechanisms (SambaY): the Mamba layer, the
+# gated memory unit, differential attention — one function a mechanism,
+# shared by the whole-sequence forward, the prefill and the decode step
+# ---------------------------------------------------------------------------
+
+def _mamba_inputs(cfg, lp, h, tail=None, lengths=None):
+    """A Mamba layer's input map, causal depthwise convolution and step
+    sizes over its normalised input ``h`` (b, s, d). ``tail`` (b, d_conv
+    - 1, channels): the convolution's inputs before this call's first
+    position (None: the sequence starts here, zeros); ``lengths`` (b,):
+    the rows' real lengths (None: s). Returns ``(x (b, s, channels)
+    after the convolution's bias and SiLU, z (b, s, channels), delta (b,
+    s, channels) float32, B, C (b, s, d_state) float32, tail')``: ``delta
+    = softplus(dt_proj(dt) + b_dt)``, 0 at and past a row's length so
+    that the padding leaves the state as it is; ``tail'`` the last
+    ``d_conv - 1`` inputs before the row's length."""
+    b, s, _ = h.shape
+    di = cfg.mamba_expand * cfg.d_model
+    ns, r, taps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.mamba_d_conv
+    f32 = jnp.float32
+    xz = h @ lp["mb_in"]
+    x, z = xz[..., :di], xz[..., di:]
+    if tail is None:
+        tail = jnp.zeros((b, taps - 1, di), x.dtype)
+    xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = lp["mb_conv"].astype(f32)
+    y = sum(xp[:, j:j + s].astype(f32) * w[j] for j in range(taps))
+    x = jax.nn.silu(y + lp["mb_conv_b"].astype(f32)).astype(h.dtype)
+    if lengths is None:
+        lengths = jnp.full((b,), s, jnp.int32)
+    # x position p sits at xp[p + taps - 1]: the tail ends at lengths - 1
+    new_tail = jnp.take_along_axis(
+        xp, (lengths[:, None] + jnp.arange(taps - 1))[:, :, None], axis=1)
+    dbc = jnp.dot(x, lp["mb_x"], preferred_element_type=f32)
+    dt = jnp.dot(dbc[..., :r].astype(h.dtype), lp["mb_dt"],
+                 preferred_element_type=f32) + lp["mb_dt_b"].astype(f32)
+    real = (jnp.arange(s)[None, :] < lengths[:, None])[:, :, None]
+    delta = jnp.where(real, jax.nn.softplus(dt), 0.0)
+    return (x, z, delta, dbc[..., r:r + ns], dbc[..., r + ns:], new_tail)
+
+
+def _mamba_a(lp):
+    """``A = -exp(A_log)`` as the scan takes it: (d_state, channels)."""
+    return -jnp.exp(lp["mb_a_log"].astype(jnp.float32)).T
+
+
+def _mamba_out(lp, y, z):
+    """``(out, m)``: the scan's read-out gated, ``m = y * silu(z)`` (...,
+    channels) — what a gated memory unit reads of the layer — and the
+    output map of it."""
+    m = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))) \
+        .astype(z.dtype)
+    return m @ lp["mb_out"], m
+
+
+def _mamba_prompt(cfg, lp, h, lengths=None):
+    """A Mamba layer over whole sequences from their start: h (b, s, d) ->
+    ``(out (b, s, d), m (b, s, channels), state (b, d_state, channels)
+    float32, tail)`` — the state and the convolution's tail as they stand
+    after each row's ``lengths`` (the chunked scan,
+    ``ops/pallas/mamba.mamba_chunk_scan``)."""
+    from ..ops.pallas.mamba import mamba_chunk_scan
+    x, z, delta, bm, cm, tail = _mamba_inputs(cfg, lp, h, None, lengths)
+    y, state = mamba_chunk_scan(x, delta, bm, cm, _mamba_a(lp), lp["mb_d"])
+    out, m = _mamba_out(lp, y, z)
+    return out, m, state, tail
+
+
+def _mamba_token(cfg, lp, h, cache, li):
+    """One token a row through Mamba layer ``li`` (its index among the
+    Mamba layers) of a :class:`LinearStateCache`: h (b, d) -> ``(out (b,
+    d), m (b, channels), cache)``. Each row's state row is decayed,
+    written and read out in place in the pool (the recurrent rule,
+    ``ops/pallas/mamba.mamba_recurrent_step``); its convolution tail
+    takes the token."""
+    from ..ops.pallas.mamba import mamba_recurrent_step
+    b = h.shape[0]
+    rows = cache.rows
+    tail = cache.conv[li, rows].reshape(b, cfg.mamba_d_conv - 1, -1)
+    x, z, delta, bm, cm, tail = _mamba_inputs(cfg, lp, h[:, None], tail)
+    y, state = mamba_recurrent_step(x[:, 0], delta[:, 0], bm[:, 0],
+                                    cm[:, 0], _mamba_a(lp), lp["mb_d"],
+                                    cache.state, rows, layer=li)
+    conv = cache.conv.at[li, rows].set(
+        tail.reshape(b, -1).astype(cache.conv.dtype))
+    out, m = _mamba_out(lp, y, z[:, 0])
+    return out, m, LinearStateCache(cache.full, state, conv, rows)
+
+
+def _gmu(lp, h, m):
+    """A gated memory unit: ``(m * silu(h W_in)) W_out`` over the
+    normalised input ``h`` and the memory ``m`` (..., channels)."""
+    gate = jax.nn.silu(jnp.dot(h, lp["gmu_in"],
+                               preferred_element_type=jnp.float32))
+    return (m.astype(jnp.float32) * gate).astype(h.dtype) @ lp["gmu_out"]
+
+
+def _diff_qkv(cfg, lp, h):
+    """A differential-attention layer's ``(q (..., heads, hd), k, v (...,
+    kv heads, hd))`` from its normalised input ``h`` (..., d), biased
+    where the layer has biases; a cross-attention layer's k and v are
+    None (it reads another layer's)."""
+    hd = _head_dim(cfg)
+    lead = h.shape[:-1]
+
+    def proj(w, b):
+        t = h @ lp[w]
+        return (t + lp[b] if cfg.attn_bias else t).reshape(lead + (-1, hd))
+
+    if "wk" not in lp:
+        return proj("wq", "bq"), None, None
+    return proj("wq", "bq"), proj("wk", "bk"), proj("wv", "bv")
+
+
+def _diff_pairs(t):
+    """K or V heads (..., kv heads, hd) as pairs (..., kv heads / 2, 2 hd):
+    heads 2j and 2j + 1 side by side (a free reshape)."""
+    return t.reshape(t.shape[:-2] + (t.shape[-2] // 2, 2 * t.shape[-1]))
+
+
+def _diff_queries(q, pairs):
+    """Query heads (..., heads, hd) as rows against ``pairs`` K/V pairs
+    (..., pairs, 2 g, 2 hd), g differential heads a pair: row ``m g + e``
+    of pair j is differential head ``i = j g + e``'s query of map ``m``,
+    ``[q_2i | 0]`` (map 1 reads K head 2j) or ``[0 | q_2i+1]`` (map 2
+    reads K head 2j + 1). Against a pair's 2 hd wide key it scores its
+    own map alone, and its output is that map over the pair's whole
+    value: ONE grouped attention over the pairs gives both maps of every
+    head from one read of each key and value."""
+    lead, (nh, hd) = q.shape[:-2], q.shape[-2:]
+    g = nh // 2 // pairs
+    qr = q.reshape(lead + (pairs, g, 2, hd))
+    zero = jnp.zeros_like(qr[..., 0, :])
+    return jnp.stack([jnp.concatenate([qr[..., 0, :], zero], -1),
+                      jnp.concatenate([zero, qr[..., 1, :]], -1)],
+                     axis=-3).reshape(lead + (pairs, 2 * g, 2 * hd))
+
+
+def _diff_lambda(lp, li):
+    """``(lambda, lambda_init)`` of layer ``li`` (its depth): ``exp(l_q1 .
+    l_k1) - exp(l_q2 . l_k2) + lambda_init``, ``lambda_init = 0.8 - 0.6
+    exp(-0.3 li)``, float32."""
+    f32 = jnp.float32
+    init = 0.8 - 0.6 * math.exp(-0.3 * li)
+    dot = lambda a, b: jnp.sum(lp[a].astype(f32) * lp[b].astype(f32))
+    return (jnp.exp(dot("lambda_q1", "lambda_k1"))
+            - jnp.exp(dot("lambda_q2", "lambda_k2")) + init), init
+
+
+def _diff_out(cfg, lp, li, o):
+    """A differential-attention layer's output from the two maps' reads
+    ``o`` (..., pairs, 2 g, 2 hd) (:func:`_diff_queries`' rows): a head's
+    ``A1 v - lambda A2 v``, an RMSNorm over its 2 hd with a gain, times
+    ``1 - lambda_init``, the heads side by side through the biased output
+    map."""
+    f32 = jnp.float32
+    lead, (pairs, g2, w) = o.shape[:-3], o.shape[-3:]
+    o = o.reshape(lead + (pairs, 2, g2 // 2, w)).astype(f32)
+    lam, init = _diff_lambda(lp, li)
+    d = o[..., 0, :, :] - lam * o[..., 1, :, :]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + cfg.norm_eps)
+    d = d * lp["subln_g"].astype(f32) * (1.0 - init)
+    out = d.reshape(lead + (-1,)).astype(lp["wo"].dtype) @ lp["wo"]
+    return out + lp["bo"] if cfg.attn_bias else out
+
+
+def _diff_attend_seq(cfg, q, k, v, window=None, lengths=None):
+    """Differential attention over whole sequences in ``jax.numpy``: q
+    (b, s_q, heads, hd) at the LAST s_q positions of k, v (b, s, kv
+    heads, hd); causal, within ``window`` where given, keys past a row's
+    ``lengths`` masked. Returns the maps' reads (b, s_q, pairs, 2 g, 2
+    hd) for :func:`_diff_out`."""
+    pairs = k.shape[-2] // 2
+    qr = _diff_queries(q, pairs)
+    kp, vp = _diff_pairs(k), _diff_pairs(v)
+    s_q, s = q.shape[1], k.shape[1]
+    sc = jnp.einsum("bqpgd,bkpd->bpgqk", qr, kp) / np.sqrt(_head_dim(cfg))
+    qpos = jnp.arange(s - s_q, s)[:, None]
+    kpos = jnp.arange(s)[None, :]
+    seen = kpos <= qpos
+    if window is not None:
+        seen = jnp.logical_and(seen, qpos - kpos < window)
+    seen = seen[None, None, None]
+    if lengths is not None:
+        seen = jnp.logical_and(
+            seen, (kpos < lengths[:, None])[:, None, None, None])
+    sc = jnp.where(seen, sc, -1e30)
+    return jnp.einsum("bpgqk,bkpd->bqpgd", jax.nn.softmax(sc, -1), vp)
+
+
+def _diff_attend_prompt(cfg, q, k, v, window):
+    """The prefill's differential self-attention: (b, s, pairs, 2 g, 2
+    hd) as :func:`_diff_attend_seq`, through the flash prefill kernel on
+    the TPU (``ops/pallas/diff_attention.diff_flash_prefill``)."""
+    if not on_tpu(q):
+        return _diff_attend_seq(cfg, q, k, v, window)
+    from ..ops.pallas.diff_attention import diff_flash_prefill
+    pairs = k.shape[-2] // 2
+    qr = _diff_queries(q, pairs)
+    b, s = q.shape[:2]
+    o = diff_flash_prefill(qr.reshape(b, s, -1, qr.shape[-1]),
+                           _diff_pairs(k), _diff_pairs(v),
+                           1.0 / np.sqrt(_head_dim(cfg)), window)
+    return o.reshape(qr.shape)
+
+
+def _diff_attend_token(cfg, part, li, q, pos_b, window):
+    """One token a row of differential attention over layer ``li`` of a
+    paged cache of K/V pairs: q (b, heads, hd) -> (b, pairs, 2 g, 2 hd)
+    float32 (``ops/pallas/diff_attention.diff_paged_decode``: each live
+    page read once for both maps; the two maps' reads come out float32,
+    since their difference cancels most of them)."""
+    from ..ops.pallas.diff_attention import diff_paged_decode
+    pairs = _kv_heads(cfg) // 2
+    return diff_paged_decode(
+        _diff_queries(q.astype(jnp.float32), pairs), part.k_pages,
+        part.v_pages,
+        part.block_tables, pos_b + 1, part.page_size,
+        1.0 / np.sqrt(_head_dim(cfg)), window=window, layer=li)
+
+
 def transformer_forward_single(params, tokens, cfg: TransformerConfig,
                                with_stats=False):
     """Single-device reference forward (used by tests to validate the
@@ -1334,6 +1754,7 @@ def transformer_forward_single(params, tokens, cfg: TransformerConfig,
     hd = _head_dim(cfg)
     groups = cfg.n_heads // _kv_heads(cfg)
     per_layer = []
+    memory = shared = None      # what the cross-decoder reads
     for li_flat, layers, at, lp in _iter_layers(params, cfg):
         h = _norm(cfg, lp, "ln1", x)
         b, s, d = h.shape
@@ -1344,6 +1765,18 @@ def transformer_forward_single(params, tokens, cfg: TransformerConfig,
                 cfg, lp, *_mla_compress(cfg, lp, h, jnp.arange(s)[None, :]))
         elif kind == "linear":
             x = x + _gdn_prompt(cfg, lp, h)[0]
+        elif kind == "mamba":
+            out, memory, _state, _tail = _mamba_prompt(cfg, lp, h)
+            x = x + out
+        elif kind == "gmu":
+            x = x + _gmu(lp, h, memory)
+        elif cfg.diff_attn:
+            q, k, v = _diff_qkv(cfg, lp, h)
+            if k is None:
+                k, v = shared
+            shared = k, v
+            x = x + _diff_out(cfg, lp, li_flat,
+                              _diff_attend_seq(cfg, q, k, v, window))
         else:
             q, k, v, gate = _attn_qkv(cfg, lp, h, jnp.arange(s)[None, :],
                                       rotary)
@@ -1388,10 +1821,11 @@ def init_kv_cache(cfg: TransformerConfig, batch, max_len=None):
         raise ValueError("a latent-attention model (kv_lora_rank > 0) has "
                          "no dense K/V strip: it decodes over the paged "
                          "latent cache (init_kv_pages)")
-    if _has_linear(cfg):
-        raise ValueError("a model with linear-attention layers has no "
-                         "dense K/V strip: it decodes over pages and state "
-                         "rows (init_kv_pages, LinearStateCache)")
+    if _has_state(cfg):
+        raise ValueError("a model with recurrent (linear-attention or "
+                         "Mamba) layers has no dense K/V strip: it decodes "
+                         "over pages and state rows (init_kv_pages, "
+                         "LinearStateCache)")
     hd = _head_dim(cfg)
     # layer stacking mirrors the params layout (pp, lps, ...)
     n_l = cfg.n_layers
@@ -1405,9 +1839,12 @@ class PagedKVCache(object):
 
     ``k_pages``/``v_pages``: (layers, num_pages, page_size, kv_heads,
     hd) — the HBM pool, preallocated once and shared by every live
-    sequence. ``block_tables``: (b, pages_per_seq) int32 — position
-    ``p`` of row ``r`` lives at page ``block_tables[r, p // page_size]``
-    offset ``p % page_size``. A registered pytree (page_size is static
+    sequence; a differential-attention model's are (layers, num_pages,
+    page_size * kv pairs, 2 hd), a page's (token, pair) rows flat, so
+    that a page is whole memory tiles (:func:`init_kv_pages`).
+    ``block_tables``: (b, pages_per_seq) int32 — position ``p`` of row
+    ``r`` lives at page ``block_tables[r, p // page_size]`` offset ``p %
+    page_size``. A registered pytree (page_size is static
     aux data), so it traces straight through jit with the pool arrays
     donated.
 
@@ -1500,21 +1937,25 @@ jax.tree_util.register_pytree_node(
 
 
 class LinearStateCache(object):
-    """The cache of a model with linear-attention (Gated DeltaNet) layers
-    among full-attention ones: ``full`` is a :class:`PagedKVCache` over
-    the full layers alone (in layer order, :func:`kv_layer_kinds`), and a
-    linear layer keeps, for every sequence, a fixed-size STATE in a row
-    of two pools: ``state`` (linear layers, rows, value heads, dk, dv)
-    float32, the recurrence's matrices, and ``conv`` (linear layers, rows,
-    (width - 1) * channels), the last inputs of the layer's causal
-    convolution, flat so that the minor dim fills whole lane tiles.
+    """The cache of a model with recurrent layers (Gated DeltaNet or
+    Mamba) among attention ones: ``full`` is the cache of the attention
+    layers alone — a :class:`PagedKVCache` over the full layers (in layer
+    order, :func:`kv_layer_kinds`), or a :class:`HybridKVCache` where
+    there are window layers too — and a recurrent layer keeps, for every
+    sequence, a fixed-size STATE in a row of two pools: ``state`` (layers
+    of the kind, rows, ...) float32, the recurrence's matrices — (value
+    heads, dk, dv) a Gated DeltaNet layer's, (d_state, channels) a
+    Mamba layer's — and ``conv`` (layers, rows, (width - 1) * channels),
+    the last inputs of the layer's causal convolution, flat so that the
+    minor dim fills whole lane tiles.
     ``rows`` (b,) int32 names each batch row's state row; row 0 is the
     NULL ROW, what a step bucket's dummy slots read and write, as dummy
     slots write the null page. A state row does not grow with the
     sequence, and is the sequence's for its life. In a program's
     arguments the pools ride as pairs: ``k_pages = (full K, state)``,
     ``v_pages = (full V, conv)``, ``block_tables = (full table, rows (b,
-    1))`` (:func:`paged_cache`, :func:`init_kv_pages`)."""
+    1))``, where "full" is itself a (full, window) pair for a model with
+    window layers (:func:`paged_cache`, :func:`init_kv_pages`)."""
 
     __slots__ = ("full", "state", "conv", "rows")
 
@@ -1538,9 +1979,10 @@ def _check_latent(cfg, cache):
             "LatentKVCache and no other model does (init_kv_pages + "
             "paged_cache build the right one); got %s"
             % type(cache).__name__)
-    if _has_linear(cfg) != isinstance(cache, LinearStateCache):
+    if _has_state(cfg) != isinstance(cache, LinearStateCache):
         raise ValueError(
-            "a model with linear-attention layers runs over a "
+            "a model with recurrent (linear-attention or Mamba) layers "
+            "runs over a "
             "LinearStateCache and no other model does (init_kv_pages + "
             "paged_cache(cfg=) build the right one); got %s"
             % type(cache).__name__)
@@ -1550,15 +1992,16 @@ def paged_cache(k_pages, v_pages, block_tables, page_size, cfg=None):
     """The cache view a program builds from its arguments: one pool and
     one table, for a model with window layers a ``(full, window)`` pair
     of each, for a latent-attention model its one latent pool and None,
-    for a model with linear layers (told by ``cfg``) the full layers'
-    pools paired with the state pools and the table with the state rows
-    (what :func:`init_kv_pages` returns in each case)."""
+    for a model with recurrent layers (told by ``cfg``) the attention
+    layers' pools (one, or a pair) paired with the state pools and their
+    table(s) with the state rows (what :func:`init_kv_pages` returns in
+    each case)."""
     if v_pages is None:
         return LatentKVCache(k_pages, block_tables, page_size)
-    if cfg is not None and _has_linear(cfg):
+    if cfg is not None and _has_state(cfg):
         return LinearStateCache(
-            PagedKVCache(k_pages[0], v_pages[0], block_tables[0],
-                         page_size),
+            paged_cache(k_pages[0], v_pages[0], block_tables[0],
+                        page_size),
             k_pages[1], v_pages[1], block_tables[1].reshape(-1))
     if isinstance(k_pages, (tuple, list)):
         return HybridKVCache(*(PagedKVCache(k, v, bt, page_size)
@@ -1572,8 +2015,8 @@ def cache_pools(cache):
     if isinstance(cache, LatentKVCache):
         return cache.pages, None
     if isinstance(cache, LinearStateCache):
-        return ((cache.full.k_pages, cache.state),
-                (cache.full.v_pages, cache.conv))
+        k, v = cache_pools(cache.full)
+        return (k, cache.state), (v, cache.conv)
     if isinstance(cache, HybridKVCache):
         return ((cache.full.k_pages, cache.window.k_pages),
                 (cache.full.v_pages, cache.window.v_pages))
@@ -1589,14 +2032,24 @@ def _kind_index(cfg, li):
 
 def _layer_cache(cache, cfg, li):
     """``(cache that holds layer li, the layer's index in it, put)``;
-    ``put(c)`` is the whole cache with that part replaced."""
-    if not isinstance(cache, (HybridKVCache, LinearStateCache)):
+    ``put(c)`` is the whole cache with that part replaced. A cross-
+    attention layer holds nothing: it is given the pages of the layer
+    whose K/V it reads (:func:`_yoco_sources`), and its ``put`` keeps
+    the cache as it was."""
+    kinds = kv_layer_kinds(cfg)
+    if kinds[li] == "cross":
+        part, idx, _put = _layer_cache(cache, cfg, _yoco_sources(cfg)[0])
+        return part, idx, lambda c: cache
+    if isinstance(cache, LinearStateCache):
+        part, idx, put = _layer_cache(cache.full, cfg, li)
+        if not isinstance(cache.full, HybridKVCache):
+            idx = _kind_index(cfg, li)
+        return part, idx, lambda c: LinearStateCache(
+            put(c), cache.state, cache.conv, cache.rows)
+    if not isinstance(cache, HybridKVCache):
         return cache, li, lambda c: c
     idx = _kind_index(cfg, li)
-    if isinstance(cache, LinearStateCache):
-        return cache.full, idx, lambda c: LinearStateCache(
-            c, cache.state, cache.conv, cache.rows)
-    if kv_layer_kinds(cfg)[li] == "window":
+    if kinds[li] == "window":
         return cache.window, idx, lambda c: HybridKVCache(cache.full, c)
     return cache.full, idx, lambda c: HybridKVCache(c, cache.window)
 
@@ -1613,7 +2066,13 @@ def init_kv_pages(cfg: TransformerConfig, num_pages, page_size):
     None)`` (:class:`LatentKVCache`). A model with linear layers takes
     ``num_pages`` as ``(pages, state rows)`` and gives ``((K pool of the
     full layers, state pool), (V pool, convolution tails))``
-    (:class:`LinearStateCache`; row 0 is the null row)."""
+    (:class:`LinearStateCache`; row 0 is the null row); with window layers
+    too its ``num_pages`` is ``((full pages, window pages), state rows)``
+    and the two attention pools are pairs. A differential-attention
+    model's pages are flat: (layers, num_pages, page_size * kv pairs, 2
+    hd) — a pair of 64-wide heads fills a 128-lane tile, and a page of
+    whole tiles can be copied by the decode kernel (``ops/pallas/
+    diff_attention.py``)."""
     if _is_mla(cfg):
         from ..ops.pallas.mla_attention import latent_pool_shape
         return jnp.zeros(latent_pool_shape(
@@ -1624,27 +2083,49 @@ def init_kv_pages(cfg: TransformerConfig, num_pages, page_size):
     def pool(n_layers, n_pages):
         shape = (n_layers, int(n_pages), int(page_size), _kv_heads(cfg),
                  hd)
+        if cfg.diff_attn:
+            shape = (n_layers, int(n_pages),
+                     int(page_size) * _kv_heads(cfg) // 2, 2 * hd)
         return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
     kinds = kv_layer_kinds(cfg)
-    if "linear" in kinds:
+
+    def full_and_window(spec):
+        (kf, vf), (kw, vw) = (pool(kinds.count(kind), n) for kind, n in
+                              zip(("full", "window"), spec))
+        return (kf, kw), (vf, vw)
+
+    if _has_state(cfg):
         if not isinstance(num_pages, (tuple, list)):
-            raise ValueError("a model with linear layers needs num_pages "
-                             "as (pages of the full layers, state rows)")
-        n_lin, rows = kinds.count("linear"), int(num_pages[1])
-        kf, vf = pool(kinds.count("full"), num_pages[0])
-        channels = (2 * cfg.linear_key_heads * cfg.linear_key_dim
-                    + cfg.linear_value_heads * cfg.linear_value_dim)
-        return ((kf, jnp.zeros((n_lin, rows, cfg.linear_value_heads,
-                                cfg.linear_key_dim, cfg.linear_value_dim),
-                               jnp.float32)),
-                (vf, jnp.zeros((n_lin, rows,
-                                (cfg.linear_conv_width - 1) * channels),
+            raise ValueError("a model with recurrent layers needs "
+                             "num_pages as (pages of the attention "
+                             "layers, state rows)")
+        rows = int(num_pages[1])
+        if "window" in kinds:
+            if not isinstance(num_pages[0], (tuple, list)):
+                raise ValueError("a model with window and recurrent layers "
+                                 "needs num_pages as ((full pages, window "
+                                 "pages), state rows)")
+            kf, vf = full_and_window(num_pages[0])
+        else:
+            kf, vf = pool(kinds.count("full"), num_pages[0])
+        if "mamba" in kinds:
+            n_st, channels = kinds.count("mamba"), \
+                cfg.mamba_expand * cfg.d_model
+            state = (cfg.mamba_d_state, channels)
+            taps = cfg.mamba_d_conv
+        else:
+            n_st = kinds.count("linear")
+            channels = (2 * cfg.linear_key_heads * cfg.linear_key_dim
+                        + cfg.linear_value_heads * cfg.linear_value_dim)
+            state = (cfg.linear_value_heads, cfg.linear_key_dim,
+                     cfg.linear_value_dim)
+            taps = cfg.linear_conv_width
+        return ((kf, jnp.zeros((n_st, rows) + state, jnp.float32)),
+                (vf, jnp.zeros((n_st, rows, (taps - 1) * channels),
                                cfg.dtype)))
     if isinstance(num_pages, (tuple, list)):
-        (kf, vf), (kw, vw) = (pool(kinds.count(kind), n) for kind, n in
-                              zip(("full", "window"), num_pages))
-        return (kf, kw), (vf, vw)
+        return full_and_window(num_pages)
     return pool(cfg.n_layers, num_pages)
 
 
@@ -1669,6 +2150,9 @@ def _cache_write_token(cache, li, k_t, v_t, pos_b, window=None):
         page = jnp.take_along_axis(
             cache.block_tables, entry[:, None], axis=1)[:, 0]
         off = pos_b % cache.page_size
+        if cache.k_pages.ndim == 4:     # a page's (token, head) rows flat
+            kvh = k_t.shape[1]
+            page, off = page[:, None], off[:, None] * kvh + jnp.arange(kvh)
         return PagedKVCache(
             cache.k_pages.at[li, page, off].set(
                 k_t.astype(cache.k_pages.dtype)),
@@ -1753,6 +2237,7 @@ def transformer_decode_step(params, cache, tokens_t, pos,
     if cfg.pos_type == "learned":
         x = x + params["pos"][pos_b]                  # (b, d) gather
     per_layer = []
+    memory = None               # the last Mamba layer's, for the GMUs
     for li_flat, layers, at, lp in _iter_layers(params, cfg):
         h = _norm(cfg, lp, "ln1", x)
         x_in = x
@@ -1765,6 +2250,21 @@ def transformer_decode_step(params, cache, tokens_t, pos,
             out, cache = _gdn_token(cfg, lp, h, cache,
                                     _kind_index(cfg, li_flat))
             x = x + out
+        elif kind == "mamba":
+            out, memory, cache = _mamba_token(cfg, lp, h, cache,
+                                              _kind_index(cfg, li_flat))
+            x = x + out
+        elif kind == "gmu":
+            x = x + _gmu(lp, h, memory)
+        elif cfg.diff_attn:
+            q, k_t, v_t = _diff_qkv(cfg, lp, h)
+            part, part_li, put = _layer_cache(cache, cfg, li_flat)
+            if k_t is not None:         # a cross layer writes nothing
+                part = _cache_write_token(part, part_li, _diff_pairs(k_t),
+                                          _diff_pairs(v_t), pos_b, window)
+                cache = put(part)
+            x = x + _diff_out(cfg, lp, li_flat, _diff_attend_token(
+                cfg, part, part_li, q, pos_b, window))
         else:
             q, k_t, v_t, gate = _attn_qkv(cfg, lp, h, pos_b, rotary)
             part, part_li, put = _layer_cache(cache, cfg, li_flat)
@@ -1804,13 +2304,13 @@ def _cache_write_prompt(cache, li, kg, vg, lengths=None, window=None):
         from ..ops.pallas.flash_attention import prefill_page_dest
         bt = prefill_page_dest(cache.block_tables, n_pb, ps, lengths,
                                window)
+        page = ((b, n_pb, ps * hk, hd) if cache.k_pages.ndim == 4
+                else (b, n_pb, ps, hk, hd))
         return PagedKVCache(
             cache.k_pages.at[li, bt].set(
-                kg.reshape(b, n_pb, ps, hk, hd)
-                .astype(cache.k_pages.dtype)),
+                kg.reshape(page).astype(cache.k_pages.dtype)),
             cache.v_pages.at[li, bt].set(
-                vg.reshape(b, n_pb, ps, hk, hd)
-                .astype(cache.v_pages.dtype)),
+                vg.reshape(page).astype(cache.v_pages.dtype)),
             cache.block_tables, cache.page_size)
     # (b, s, hk, d) -> dense layout (b, hk, s, d), written [:s]
     return {"k": cache["k"].at[li, :, :, :s].set(
@@ -1839,7 +2339,17 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
         x = x + params["pos"][:s]
     mask = jnp.tril(jnp.ones((s, s), bool))
     per_layer = []
+    memory = shared = None      # what the cross-decoder reads
+    last = (jnp.full((b,), s, jnp.int32) if lengths is None
+            else lengths) - 1
     for li_flat, layers, at, lp in _iter_layers(params, cfg):
+        if li_flat == cfg.cross_from:
+            # the cross-decoder caches nothing and a position sees only
+            # itself and the K/V layer's keys: the prompt's last REAL
+            # position alone runs it (YOCO's prefill)
+            x = jnp.take_along_axis(x, last[:, None, None], 1)
+            if memory is not None:
+                memory = jnp.take_along_axis(memory, last[:, None, None], 1)
         h = _norm(cfg, lp, "ln1", x)
         x_in = x
         kind, rotary, window = _layer_rule(cfg, li_flat)
@@ -1862,6 +2372,31 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
                     tail.reshape(b, -1).astype(cache.conv.dtype)),
                 cache.rows)
             x = x + out
+        elif kind == "mamba":
+            # as a linear layer: the state and tail after each REAL length
+            out, memory, state, tail = _mamba_prompt(cfg, lp, h, lengths)
+            lin = _kind_index(cfg, li_flat)
+            cache = LinearStateCache(
+                cache.full, cache.state.at[lin, cache.rows].set(state),
+                cache.conv.at[lin, cache.rows].set(
+                    tail.reshape(b, -1).astype(cache.conv.dtype)),
+                cache.rows)
+            x = x + out
+        elif kind == "gmu":
+            x = x + _gmu(lp, h, memory)
+        elif kind == "cross":
+            q, _k, _v = _diff_qkv(cfg, lp, h)
+            x = x + _diff_out(cfg, lp, li_flat, _diff_attend_seq(
+                cfg, q, *shared, lengths=last + 1))
+        elif cfg.diff_attn:
+            q, kg, vg = _diff_qkv(cfg, lp, h)
+            shared = kg, vg
+            part, part_li, put = _layer_cache(cache, cfg, li_flat)
+            cache = put(_cache_write_prompt(
+                part, part_li, _diff_pairs(kg), _diff_pairs(vg),
+                write_lengths, window))
+            x = x + _diff_out(cfg, lp, li_flat, _diff_attend_prompt(
+                cfg, q, kg, vg, window))
         else:
             # rotate BEFORE caching: decode stores rotated keys, so
             # prefill must too (q rotates here as well)
@@ -1900,7 +2435,9 @@ def _prefill_impl(params, tokens, cache, cfg, lengths, with_stats=False):
         f, st_l = _ffn(cfg, lp, _norm(cfg, lp, "ln2", x), x_in, layers, at)
         per_layer.append(st_l)
         x = x + f
-    if lengths is None:
+    if cfg.cross_from is not None:
+        xl = x[:, 0]
+    elif lengths is None:
         xl = x[:, -1]
     else:
         # each row's last REAL position, not the padded tail

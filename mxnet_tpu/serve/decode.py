@@ -337,15 +337,23 @@ class DecodeEngine(object):
         self._window = ("window" in kinds
                         and int(model_cfg.sliding_window))
         self._ring_pages = self._wpool = None
-        # a model with linear-attention layers keeps a second kind of
-        # state beside its full layers' pages: a fixed-size STATE ROW a
-        # live session, in pools of their own (transformer.
-        # LinearStateCache). A session in a slot holds one and a queued
-        # one none, so there is a row a slot and row 0, the null row of a
-        # step bucket's dummy slots: nothing to configure
+        # a model with recurrent (linear-attention or Mamba) layers keeps
+        # another kind of state beside its attention layers' pages: a
+        # fixed-size STATE ROW a live session, in pools of their own
+        # (transformer.LinearStateCache). A session in a slot holds one
+        # and a queued one none, so there is a row a slot and row 0, the
+        # null row of a step bucket's dummy slots: nothing to configure
         self._linear_layers = kinds.count("linear")
+        self._mamba_layers = kinds.count("mamba")
+        self._state_layers = self._linear_layers + self._mamba_layers
         self._state_rows = (PagePool(self._cfg.slots + 1, kind="state")
-                            if self._linear_layers else None)
+                            if self._state_layers else None)
+        # a cross-decoder's layers cache nothing: the cross-attention
+        # layers read the pages of the full layer before them, which with
+        # it makes the readers of that pool; a prefill runs the
+        # cross-decoder on the prompt's last position alone
+        self._shared_readers = (kinds.count("cross") + 1
+                                if "cross" in kinds else 0)
         if self._window:
             self._ring_pages = min(
                 pages_needed(self._window, self._cfg.page_size) + 1,
@@ -476,18 +484,26 @@ class DecodeEngine(object):
             "Sessions failed on deadline expiry (queued or decoding)")
         self._note_free()
 
+    def _pool_sizes(self):
+        """``num_pages`` as ``init_kv_pages`` takes it: the pages of the
+        global pool, paired with the window pool's where the model has
+        window layers, and that with the state rows where it has
+        recurrent layers."""
+        pages = self._cfg.num_pages
+        if self._window:
+            pages = (pages, self._cfg.window_pages)
+        if self._state_layers:
+            pages = (pages, self._state_rows.num_pages)
+        return pages
+
     def _fresh_pools(self):
         """Zeroed pools, committed to their device: a program's outputs
         are, so that a call on fresh pools keys the executable every
         later call does (the buffers are not copied)."""
         import jax
         from ..parallel.transformer import init_kv_pages
-        pages = self._cfg.num_pages
-        if self._window:
-            pages = (pages, self._cfg.window_pages)
-        elif self._linear_layers:
-            pages = (pages, self._state_rows.num_pages)
-        pools = init_kv_pages(self._model_cfg, pages, self._cfg.page_size)
+        pools = init_kv_pages(self._model_cfg, self._pool_sizes(),
+                              self._cfg.page_size)
         self._dev = next(iter(jax.tree_util.tree_leaves(pools)[0].devices()))
         return jax.device_put(pools, self._dev)
 
@@ -499,7 +515,7 @@ class DecodeEngine(object):
         if self._wpool is not None:
             self._m_pages_free.labels("window").set(
                 self._wpool.free_pages)
-        if self._linear_layers:
+        if self._state_layers:
             self._m_state_free.set(self._state_rows.free_pages)
 
     # -- lifecycle ---------------------------------------------------------
@@ -571,7 +587,7 @@ class DecodeEngine(object):
         n_pb = bucket // self._cfg.page_size
         return (self._params, self._k_pages, self._v_pages,
                 self._tables(_np.zeros(n_pb, _np.int32),
-                             self._second_table()),
+                             self._ring_table(), _np.zeros((), _np.int32)),
                 _np.zeros((1, bucket), _np.int32),
                 _np.array([bucket], _np.int32))
 
@@ -579,7 +595,8 @@ class DecodeEngine(object):
         return (self._params, self._k_pages, self._v_pages,
                 self._tables(
                     _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32),
-                    self._second_table(nslots)),
+                    self._ring_table(nslots),
+                    _np.zeros(nslots, _np.int32)),
                 self._on_device(_np.zeros(nslots, _np.int32)),
                 _np.zeros(nslots, _np.int32))
 
@@ -792,12 +809,12 @@ class DecodeEngine(object):
         # write covers the padded prompt) and prompt+max_new positions.
         # With window layers the prefill writes by the prompt's real
         # length: pages for the positions alone, and of the window
-        # pool at most a ring. A model with linear layers reserves pages
-        # for its full layers' positions here, and takes its state row
-        # when it leaves the queue for a slot (``_schedule``)
+        # pool at most a ring. A model with recurrent layers reserves
+        # pages for its attention layers' positions here, and takes its
+        # state row when it leaves the queue for a slot (``_schedule``)
         ps = self._cfg.page_size
         n_pages = pages_needed(plen + max_new, ps)
-        if not (self._window or self._linear_layers or self._latent):
+        if not (self._window or self._state_layers or self._latent):
             n_pages = max(n_pages, pages_needed(pick_bucket(
                 plen, self._cfg.prefill_buckets), ps))
         with self._cond:
@@ -956,20 +973,17 @@ class DecodeEngine(object):
             self._state_rows.free([sess.state_row])
             sess.state_row = None
 
-    def _tables(self, full, second):
+    def _tables(self, full, ring, rows=None):
         """Block tables as the programs take them: the one table, or
-        with window layers the (global, window-ring) pair, or with
-        linear layers the (full layers' table, state rows) pair."""
-        return ((full, second) if self._window or self._linear_layers
-                else full)
+        with window layers the (global, window-ring) pair; with
+        recurrent layers that paired with the state row(s)."""
+        tables = (full, ring) if self._window else full
+        return (tables, rows) if self._state_layers else tables
 
-    def _second_table(self, nslots=None):
-        """What rides beside the block table of one sequence (a prefill)
-        or of ``nslots`` rows (a step), zeroed: a window model's ring(s),
-        a linear model's state row(s) — row 0, the null row."""
+    def _ring_table(self, nslots=None):
+        """A window model's ring of one sequence (a prefill) or of
+        ``nslots`` rows (a step), zeroed: the null page."""
         lead = () if nslots is None else (nslots,)
-        if self._linear_layers:
-            return _np.zeros(lead, _np.int32)
         return _np.zeros(lead + (self._ring_pages or 0,), _np.int32)
 
     def _retire_locked(self, sess, error=None):
@@ -1202,7 +1216,7 @@ class DecodeEngine(object):
             while (self._waiting and len(self._live) + len(self._held)
                    < self._cfg.slots):
                 sess = self._waiting.popleft()
-                if self._linear_layers:
+                if self._state_layers:
                     # its state row, for its life in a slot: there is a
                     # row a slot, so a free slot has a free row
                     assert self._state_rows.free_pages, \
@@ -1284,12 +1298,10 @@ class DecodeEngine(object):
             self._prefilling = sess
             # snapshot under the lock: a concurrent close may null
             # page_ids the instant the session is failed
-            if self._window:
-                page_ids = (sess.block_table[:n_pb],
-                            sess.window_block_table)
-            elif self._linear_layers:
-                page_ids = (sess.block_table[:n_pb],
-                            _np.asarray(sess.state_row, _np.int32))
+            if self._window or self._state_layers:
+                page_ids = self._tables(
+                    sess.block_table[:n_pb], sess.window_block_table,
+                    _np.asarray(sess.state_row or 0, _np.int32))
             elif self._latent:       # the pages past the request's own
                 page_ids = sess.block_table[:n_pb].copy()    # are null
             else:
@@ -1306,6 +1318,16 @@ class DecodeEngine(object):
                 # linear layer (a bucket's padding is no work)
                 span.set_attr("linear_tokens",
                               sess.prompt_len * self._linear_layers)
+            if self._mamba_layers:
+                # ... and the selective scan, a Mamba layer
+                span.set_attr("mamba_tokens",
+                              sess.prompt_len * self._mamba_layers)
+            if self._shared_readers:
+                # the prompt's tokens that ran the self-decoder alone, and
+                # those that ran the cross-decoder too (the last one)
+                span.set_attr("self_decoder_only_tokens",
+                              sess.prompt_len - 1)
+                span.set_attr("cross_decoder_tokens", 1)
             t0 = _tm.monotonic()
             out, self._k_pages, self._v_pages = self._prefill_prog(bucket)(
                 self._params, self._k_pages, self._v_pages, page_ids,
@@ -1392,18 +1414,18 @@ class DecodeEngine(object):
         tokens = _np.zeros(nslots, _np.int32)
         pos = _np.zeros(nslots, _np.int32)
         bt = _np.zeros((nslots, self._cfg.pages_per_seq), _np.int32)
-        second = self._second_table(nslots)
+        ring = self._ring_table(nslots)
+        rows = _np.zeros(nslots, _np.int32)
         for i, sess in enumerate(live):
             tokens[i] = sess.last_token
             pos[i] = sess.pos
             bt[i] = sess.block_table
             if self._window:
-                second[i] = sess.window_block_table
-            elif self._linear_layers:
-                # a session released since the snapshot (a concurrent
-                # cancel or close) steps on the null row
-                second[i] = sess.state_row or 0
-        rec = self._dispatch(it, live, nslots, self._tables(bt, second),
+                ring[i] = sess.window_block_table
+            # a session released since the snapshot (a concurrent cancel
+            # or close) steps on the null row
+            rows[i] = sess.state_row or 0
+        rec = self._dispatch(it, live, nslots, self._tables(bt, ring, rows),
                              self._on_device(tokens), pos, None, why)
         rec.end = rec.t_dispatched + self._step_s.get(nslots, 0.0)
         self._settle(rec)
@@ -1438,6 +1460,25 @@ class DecodeEngine(object):
             # linear layer (dummy slots are no work); context_tokens is
             # then the full layers' K/V alone
             attrs["linear_rows"] = len(rows) * self._linear_layers
+        if self._mamba_layers:
+            # the same of the Mamba layers; and the rows that hold a
+            # state row (dummy slots step on the null row)
+            attrs["mamba_rows"] = len(rows) * self._mamba_layers
+            attrs["state_rows"] = len(rows)
+        if self._shared_readers:
+            # the layers whose attends read the one full layer's pages
+            # (context_tokens of them each), and the live pages a paged
+            # attend walks over the rows: of the full layer, and of a
+            # window layer's ring (its window's pages, as the kernel
+            # counts them)
+            last = (context - 1) // self._cfg.page_size
+            attrs["shared_kv_readers"] = self._shared_readers
+            attrs["full_pages_read"] = int((last + 1).sum())
+            if self._window:
+                first = _np.maximum(
+                    _np.maximum(context - self._window, 0)
+                    // self._cfg.page_size, last - self._ring_pages + 1)
+                attrs["window_pages_read"] = int((last - first + 1).sum())
         rec.t0 = _tm.monotonic()
         rec.feed, rec.out, self._k_pages, self._v_pages = \
             self._step_prog(nslots)(self._params, self._k_pages,

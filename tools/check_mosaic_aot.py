@@ -30,6 +30,8 @@ i8 = importlib.import_module("mxnet_tpu.ops.pallas.int8_matmul")
 mf = importlib.import_module("mxnet_tpu.ops.pallas.moe_ffn")
 ml = importlib.import_module("mxnet_tpu.ops.pallas.mla_attention")
 gd = importlib.import_module("mxnet_tpu.ops.pallas.gated_delta")
+mb = importlib.import_module("mxnet_tpu.ops.pallas.mamba")
+da = importlib.import_module("mxnet_tpu.ops.pallas.diff_attention")
 
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 
@@ -217,6 +219,38 @@ def cases():
                q, k, v, kp, vp, bt, interpret=False, lengths=ln, layer=2),
            [((1, 16384, 16, 256), BF16), kv, kv, wide, wide,
             ((1, 64), I32), ((1,), I32)])
+    # Phi-4-mini-flash's served shapes: 9 Mamba layers of 5120 channels and
+    # 16 states over 65 state rows; a 4096-token prompt's scan; 10 K/V
+    # pairs of 2 x 64 in flat pages of 128 tokens — the one full layer's
+    # pool and 8 window layers' rings of 5 entries; a prompt's both maps
+    yield ("mamba_recurrent_step layer8of9 b64n16ch5120",
+           lambda *a: mb.mamba_recurrent_step(*a, 8, interpret=False),
+           [((64, 5120), BF16), ((64, 5120), F32), ((64, 16), F32),
+            ((64, 16), F32), ((16, 5120), F32), ((5120,), BF16),
+            ((9, 65, 16, 5120), F32), ((64,), I32)])
+    yield ("mamba_chunk_scan s4096n16ch5120",
+           lambda *a: mb.mamba_chunk_scan(*a, interpret=False),
+           [((1, 4096, 5120), BF16), ((1, 4096, 5120), F32),
+            ((1, 4096, 16), F32), ((1, 4096, 16), F32), ((16, 5120), F32),
+            ((5120,), BF16)])
+    flat = ((1, 3072, 1280, 128), BF16)
+    yield ("diff_paged_decode full layer0of1 b64pairs10 page128",
+           lambda q, kp, vp, bt, ln: da.diff_paged_decode(
+               q, kp, vp, bt, ln, 128, 0.125, layer=0, interpret=False),
+           [((64, 10, 4, 128), F32), flat, flat, ((64, 80), I32),
+            ((64,), I32)])
+    rings = ((8, 400, 1280, 128), BF16)
+    yield ("diff_paged_decode window512 layer7of8 b64pairs10 ring5",
+           lambda q, kp, vp, bt, ln: da.diff_paged_decode(
+               q, kp, vp, bt, ln, 128, 0.125, window=512, layer=7,
+               interpret=False),
+           [((64, 10, 4, 128), F32), rings, rings, ((64, 5), I32),
+            ((64,), I32)])
+    kv = ((1, 4096, 10, 128), BF16)
+    yield ("diff_flash_prefill s4096 rows40 pairs10 window512",
+           lambda q, k, v: da.diff_flash_prefill(q, k, v, 0.125, 512,
+                                                 interpret=False),
+           [((1, 4096, 40, 128), BF16), kv, kv])
     yield ("int8_conv_im2col b32c64 56x56 3x3",
            lambda q, w, s: i8.int8_conv_im2col(
                q, w, s, (1, 1), (1, 1), (1, 1), interpret=False),

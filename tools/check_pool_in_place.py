@@ -11,10 +11,12 @@ decode-step and prefill programs for a DESCRIBED TPU v5e (the
 ``tools/check_mosaic_aot.py`` trick) with the TPU branches taken, at a
 small model whose pool dwarfs its activations, once with one pool, once
 with a pool a kind of layer, once with a latent-attention model's one
-latent pool (no V pool) and once with a linear-attention model's state
+latent pool (no V pool), once with a linear-attention model's state
 pools beside its full layers' pages (the recurrent kernel reads and writes
 a row's states in place; the convolution tails are gathered and scattered
-by XLA), and reads the compiled module:
+by XLA) and once with all three kinds — a decoder-hybrid-decoder's Mamba
+state rows, window rings and the one full layer's flat pages of K/V pairs
+— and reads the compiled module:
 
 * no instruction of the entry computation other than a Mosaic call produces
   an array of one layer's pool shape (``layer_copies``);
@@ -76,11 +78,22 @@ LINEAR = dict(vocab_size=512, d_model=256, n_heads=4, n_kv_heads=2,
               norm_zero_centered=True, linear_layout=(1, 1, 1, 0) * 2,
               linear_key_heads=4, linear_value_heads=8, linear_key_dim=128,
               linear_value_dim=128, linear_conv_width=4, dtype=jnp.bfloat16)
-# page counts no other array of the programs has a dimension of
-CONFIGS = (("one_pool", DENSE, 16411, None, PAGE, CONTEXT),
-           ("pool_a_kind", HYBRID, 16411, 16417, PAGE, CONTEXT),
-           ("latent_pool", LATENT, 211, None, 512, 2048),
-           ("state_rows", LINEAR, 16411, 1031, PAGE, CONTEXT))
+# Mamba layers at the served state (16 x channels a row), window and full
+# differential attention over pairs of 64-wide heads, a cross-decoder
+SAMBAY = dict(vocab_size=512, d_model=256, n_heads=4, n_kv_heads=2,
+              head_dim=64, n_layers=8, d_ff=512, max_len=CONTEXT,
+              pos_type="none", gate_act="silu", ffn_gated=True,
+              sliding_window=64, window_layout=(1,) * 4 + (0,) * 4,
+              mamba_layout=(1, 0) * 4, mamba_d_state=16, mamba_d_conv=4,
+              mamba_expand=2, mamba_dt_rank=16, cross_from=6,
+              diff_attn=True, attn_bias=True, dtype=jnp.bfloat16)
+# page counts no other array of the programs has a dimension of, as
+# init_kv_pages takes them
+CONFIGS = (("one_pool", DENSE, 16411, PAGE, CONTEXT),
+           ("pool_a_kind", HYBRID, (16411, 16417), PAGE, CONTEXT),
+           ("latent_pool", LATENT, 211, 512, 2048),
+           ("state_rows", LINEAR, (16411, 1031), PAGE, CONTEXT),
+           ("three_kinds", SAMBAY, ((16411, 16417), 1031), PAGE, CONTEXT))
 
 # value names, result types and opcodes of an HLO text's instructions
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
@@ -123,7 +136,10 @@ def layer_copies(text, layer_shapes):
 def check(name, program, compiled, pools):
     leaves = jax.tree_util.tree_leaves(pools)
     nbytes = lambda x: int(np.prod(x.shape)) * x.dtype.itemsize
-    layer_shapes = {tuple(d for d in p.shape[1:] if d != 1) for p in leaves}
+    # a pool of ONE layer is that layer whole: its in-place update has the
+    # layer's shape, and only the temporaries' bound can tell a copy of it
+    layer_shapes = {tuple(d for d in p.shape[1:] if d != 1) for p in leaves
+                    if p.shape[0] > 1}
     text = compiled.as_text()
     ma = compiled.memory_analysis()
     copies = layer_copies(text, layer_shapes)
@@ -157,8 +173,7 @@ def main():
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1, 1, 1),
                 ("dp", "sp", "tp", "pp", "ep"))
     ok = True
-    # ``second``: the second pool's entries — window pages or state rows
-    for name, model, pages, second, page, context in CONFIGS:
+    for name, model, pages, page, context in CONFIGS:
         cfg = TransformerConfig(**model)
         params = on_chip(jax.eval_shape(
             lambda: init_transformer_params(cfg, mesh, seed=0)[0]))
@@ -169,7 +184,8 @@ def main():
             window_pages=2 if "sliding_window" in model else None))
         dcfg = engine.config
         k_pool, v_pool = on_chip(jax.eval_shape(lambda: init_kv_pages(
-            cfg, (pages, second) if second else pages, page)))
+            cfg, pages, page)))
+        ring = engine._ring_pages or 0
         bucket, slots = dcfg.prefill_buckets[-1], dcfg.slot_buckets[-1]
         real_backend = jax.default_backend
         jax.default_backend = lambda: "tpu"   # on_tpu(): the Mosaic kernels
@@ -177,12 +193,11 @@ def main():
             step = engine._step_prog(slots).lower(
                 params, k_pool, v_pool,
                 engine._tables(i32(slots, dcfg.pages_per_seq),
-                               on_chip(engine._second_table(slots))),
+                               i32(slots, ring), i32(slots)),
                 i32(slots), i32(slots)).compile()
             prefill = engine._prefill_prog(bucket).lower(
                 params, k_pool, v_pool,
-                engine._tables(i32(bucket // page),
-                               on_chip(engine._second_table())),
+                engine._tables(i32(bucket // page), i32(ring), i32()),
                 i32(1, bucket), i32(1)).compile()
         finally:
             jax.default_backend = real_backend
